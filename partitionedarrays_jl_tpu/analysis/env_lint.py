@@ -105,10 +105,6 @@ NON_LOWERING: Dict[str, str] = {
         "(tests/test_native.py pins parity) — changes who computes the "
         "plan, never the plan"
     ),
-    "PA_TPU_COMPILE_CACHE": (
-        "XLA compile-cache location/enable — where compiled artifacts "
-        "persist, not what is traced"
-    ),
     "PA_TPU_PLAN_PROCS": (
         "multiprocess planning fan-out — checksum-pinned to the "
         "in-process path (tools/plan_multiproc.py)"

@@ -1,51 +1,83 @@
 """Native planning accelerator: lazy g++ build + ctypes bindings.
 
-The `.so` is compiled on first import from `planning.cpp` into
-`native/build/` (a few hundred ms, cached by source mtime) and every entry
-point degrades to pure NumPy when the toolchain or the build is missing —
-the library never *requires* the native layer, it just plans ~10x faster
-with it at 1e7+ DOFs. Disable explicitly with PA_TPU_NATIVE=0."""
+The `.so` is compiled on first use from `planning.cpp` into
+`native/build/` under a name keyed by a hash of the source and the
+compiler command, so the binary that loads is a function of the
+committed source and nothing else — file times do not survive a copy of
+the tree, and a binary left behind by another revision is never picked
+up. Every entry point degrades to pure NumPy when the toolchain or the
+build is missing — the library never *requires* the native layer, it
+just plans ~10x faster with it at 1e7+ DOFs — and says so once, with the
+compiler's own message. Disable explicitly with PA_TPU_NATIVE=0."""
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import warnings
 from typing import Optional
 
 import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "planning.cpp")
-_SO = os.path.join(_HERE, "build", "libpa_planning.so")
+_BUILD_DIR = os.path.join(_HERE, "build")
+# -ffp-contract=off: the CSR SpMV's left-to-right accumulation claim
+# (ops/sparse.py csr_spmv_impl) must hold bit-exactly on FMA-baseline
+# targets too — contraction would make default-mode host bits differ
+# between the native and NumPy fallback paths
+_CXX = ("g++", "-O3", "-std=c++17", "-ffp-contract=off", "-shared", "-fPIC")
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
+def _so_path() -> str:
+    """``build/libpa_planning-<hash>.so``: the hash covers the source
+    bytes and the compiler command."""
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_BUILD_DIR, f"libpa_planning-{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> None:
+    """Compile `planning.cpp` to ``so``; raises the compiler's failure."""
+    os.makedirs(_BUILD_DIR, exist_ok=True)
     # build to a unique temp name and os.replace into place: concurrent
     # first imports (multi-process launches) must never dlopen a
     # half-written file
-    tmp = f"{_SO}.{os.getpid()}.tmp"
-    # -ffp-contract=off: the CSR SpMV's left-to-right accumulation claim
-    # (ops/sparse.py csr_spmv_impl) must hold bit-exactly on FMA-baseline
-    # targets too — contraction would make default-mode host bits differ
-    # between the native and NumPy fallback paths
-    cmd = [
-        "g++", "-O3", "-std=c++17", "-ffp-contract=off",
-        "-shared", "-fPIC", _SRC, "-o", tmp,
-    ]
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
-        return True
-    except Exception:
-        try:
+        subprocess.run(
+            [*_CXX, _SRC, "-o", tmp],
+            check=True, capture_output=True, text=True, timeout=120,
+        )
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
             os.unlink(tmp)
-        except OSError:
-            pass
-        return False
+    # binaries of other source revisions are dead weight
+    for name in os.listdir(_BUILD_DIR):
+        other = os.path.join(_BUILD_DIR, name)
+        if name.startswith("libpa_planning") and name.endswith(".so") and (
+            other != so
+        ):
+            try:
+                os.unlink(other)
+            except OSError:
+                pass
+
+
+def _degraded(why: str) -> None:
+    warnings.warn(
+        "partitionedarrays_jl_tpu: native planning library unavailable — "
+        "planning falls back to NumPy (~10x slower at 1e7+ DOFs). "
+        f"{why}",
+        RuntimeWarning,
+        stacklevel=4,
+    )
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -56,165 +88,172 @@ def _load() -> Optional[ctypes.CDLL]:
     if os.environ.get("PA_TPU_NATIVE", "1") == "0":
         return None
     try:
-        fresh = os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-        if not fresh and not _build():
-            return None
-        lib = ctypes.CDLL(_SO)
-        i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
-        i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
-        u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
-        lib.pa_box_gids_to_lids.argtypes = [
-            i64p, ctypes.c_int64, i64p, i64p, i64p, ctypes.c_int32, i32p,
-        ]
-        lib.pa_box_gids_to_lids.restype = None
-        lib.pa_box_gids_to_lids_i32.argtypes = [
-            i32p, ctypes.c_int64, i64p, i64p, i64p, ctypes.c_int32, i32p,
-        ]
-        lib.pa_box_gids_to_lids_i32.restype = None
-        lib.pa_lookup_sorted.argtypes = [
-            i64p, ctypes.c_int64, i64p, i32p, ctypes.c_int64, i32p,
-        ]
-        lib.pa_lookup_sorted.restype = ctypes.c_int64
-        lib.pa_lookup_sorted_i32.argtypes = [
-            i32p, ctypes.c_int64, i64p, i32p, ctypes.c_int64, i32p,
-        ]
-        lib.pa_lookup_sorted_i32.restype = ctypes.c_int64
-        f64p = np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS")
-        f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")
-        for name, fp in (("pa_coo_to_csr_f64", f64p), ("pa_coo_to_csr_f32", f32p)):
-            fn = getattr(lib, name)
-            fn.argtypes = [
-                i32p, i32p, fp, ctypes.c_int64, ctypes.c_int64,
-                i32p, i32p, fp, i32p,
-            ]
-            fn.restype = ctypes.c_int64
-        for name, fp in (
-            ("pa_coo_to_csr_i64_f64", f64p), ("pa_coo_to_csr_i64_f32", f32p),
-        ):
-            fn = getattr(lib, name)
-            fn.argtypes = [
-                i64p, i64p, fp, ctypes.c_int64, ctypes.c_int64,
-                i32p, i32p, fp, i32p,
-            ]
-            fn.restype = ctypes.c_int64
-        lib.pa_unique_small_f64.argtypes = [
-            f64p, ctypes.c_int64, ctypes.c_int64, f64p,
-        ]
-        lib.pa_unique_small_f64.restype = ctypes.c_int64
-        lib.pa_row_classes_f64.argtypes = [
-            f64p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_int64, f64p, u8p,
-        ]
-        lib.pa_row_classes_f64.restype = ctypes.c_int64
-        lib.pa_ic0_f64.argtypes = [i32p, i32p, f64p, ctypes.c_int64, f64p]
-        lib.pa_ic0_f64.restype = ctypes.c_int64
-        for name, fp in (("pa_csr_split_f64", f64p), ("pa_csr_split_f32", f32p)):
-            fn = getattr(lib, name)
-            fn.argtypes = [
-                i32p, i32p, fp, ctypes.c_int64, ctypes.c_int32,
-                i32p, i32p, fp, i32p, i32p, fp,
-            ]
-            fn.restype = None
-        for name, fp in (("pa_csr_spmv_f64", f64p), ("pa_csr_spmv_f32", f32p)):
-            fn = getattr(lib, name)
-            fn.argtypes = [i32p, i32p, fp, ctypes.c_int64, fp, fp]
-            fn.restype = None
-        for name, fp in (("pa_dia_fill_f64", f64p), ("pa_dia_fill_f32", f32p)):
-            fn = getattr(lib, name)
-            fn.argtypes = [
-                i32p, i32p, fp, ctypes.c_int64, i64p, ctypes.c_int64,
-                ctypes.c_int64, f64p,
-            ]
-            fn.restype = ctypes.c_int64
-        for name, fp in (("pa_csr_diag_f64", f64p), ("pa_csr_diag_f32", f32p)):
-            fn = getattr(lib, name)
-            fn.argtypes = [i32p, i32p, fp, ctypes.c_int64, fp]
-            fn.restype = None
-        for name, fp in (("pa_galerkin3_f64", f64p), ("pa_galerkin3_f32", f32p)):
-            fn = getattr(lib, name)
-            fn.argtypes = [
-                i32p, i32p, fp, ctypes.c_int64, i64p, i64p, i64p, i64p,
-                i64p, i64p, i64p, ctypes.c_int32, f64p,
-            ]
-            fn.restype = ctypes.c_int64
-        for name, fp in (
-            ("pa_galerkin3_sub_f64", f64p), ("pa_galerkin3_sub_f32", f32p),
-        ):
-            fn = getattr(lib, name)
-            fn.argtypes = [
-                i32p, i32p, fp, ctypes.c_int64, i64p, i64p, i64p, i64p,
-                i64p, i64p, i64p, ctypes.c_int32, f64p, i64p, i64p,
-            ]
-            fn.restype = ctypes.c_int64
-        for name, fp in (
-            ("pa_galerkin_classify_f64", f64p),
-            ("pa_galerkin_classify_f32", f32p),
-        ):
-            fn = getattr(lib, name)
-            fn.argtypes = [
-                i32p, i32p, fp, ctypes.c_int64, i64p, i64p,
-                ctypes.c_int32, ctypes.c_int64, f64p, u8p,
-            ]
-            fn.restype = ctypes.c_int64
-        for name, fp in (
-            ("pa_galerkin_emit_f64", f64p), ("pa_galerkin_emit_f32", f32p),
-        ):
-            fn = getattr(lib, name)
-            fn.argtypes = [
-                f64p, i64p, i64p, i64p, i64p, i64p, i64p,
-                ctypes.c_int64, ctypes.c_int32, i32p, i32p, fp,
-            ]
-            fn.restype = ctypes.c_int64
-        for name, fp in (
-            ("pa_stencil_emit_f64", f64p), ("pa_stencil_emit_f32", f32p),
-        ):
-            fn = getattr(lib, name)
-            fn.argtypes = [
-                i64p, i64p, i64p, ctypes.c_int32, ctypes.c_double, f64p,
-                i64p, ctypes.c_int64, ctypes.c_int32, i32p, i32p, fp,
-                f64p, fp, ctypes.c_int32,
-            ]
-            fn.restype = ctypes.c_int64
-        for name, fp in (
-            ("pa_stencil_emit_range_f64", f64p),
-            ("pa_stencil_emit_range_f32", f32p),
-        ):
-            fn = getattr(lib, name)
-            fn.argtypes = [
-                i64p, i64p, i64p, ctypes.c_int32, ctypes.c_double, f64p,
-                i64p, ctypes.c_int64, ctypes.c_int32, i32p, i32p, fp,
-                f64p, fp, ctypes.c_int32, ctypes.c_int64, ctypes.c_int64,
-            ]
-            fn.restype = ctypes.c_int64
-        lib.pa_band_offsets.argtypes = [
-            i32p, i32p, ctypes.c_int64, ctypes.c_int64, i64p,
-            ctypes.c_int64,
-        ]
-        lib.pa_band_offsets.restype = ctypes.c_int64
-        for name, fp in (
-            ("pa_dia_classify_f64", f64p), ("pa_dia_classify_f32", f32p),
-        ):
-            fn = getattr(lib, name)
-            fn.argtypes = [
-                i32p, i32p, fp, ctypes.c_int64, i64p, ctypes.c_int64,
-                ctypes.c_int64, f64p, u8p, ctypes.c_int64,
-            ]
-            fn.restype = ctypes.c_int64
-        lib.pa_count_ge.argtypes = [i32p, ctypes.c_int64, ctypes.c_int32]
-        lib.pa_count_ge.restype = ctypes.c_int64
-        for name, fp in (
-            ("pa_csr_extract_hi_f64", f64p), ("pa_csr_extract_hi_f32", f32p),
-        ):
-            fn = getattr(lib, name)
-            fn.argtypes = [
-                i32p, i32p, fp, ctypes.c_int64, ctypes.c_int32,
-                i32p, i32p, fp,
-            ]
-            fn.restype = None
-        _lib = lib
-    except Exception:
-        _lib = None
+        so = _so_path()
+        if not os.path.exists(so):
+            _build(so)
+        _lib = _bind(ctypes.CDLL(so))
+    except subprocess.CalledProcessError as e:
+        _degraded(f"g++ exited {e.returncode}:\n{e.stderr.strip()}")
+    except (OSError, subprocess.TimeoutExpired, AttributeError) as e:
+        # no compiler / unreadable source / unloadable or incomplete binary
+        _degraded(f"{type(e).__name__}: {e}")
     return _lib
+
+
+def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
+    """Declare argtypes/restype for every exported kernel."""
+    i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
+    i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
+    u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
+    lib.pa_box_gids_to_lids.argtypes = [
+        i64p, ctypes.c_int64, i64p, i64p, i64p, ctypes.c_int32, i32p,
+    ]
+    lib.pa_box_gids_to_lids.restype = None
+    lib.pa_box_gids_to_lids_i32.argtypes = [
+        i32p, ctypes.c_int64, i64p, i64p, i64p, ctypes.c_int32, i32p,
+    ]
+    lib.pa_box_gids_to_lids_i32.restype = None
+    lib.pa_lookup_sorted.argtypes = [
+        i64p, ctypes.c_int64, i64p, i32p, ctypes.c_int64, i32p,
+    ]
+    lib.pa_lookup_sorted.restype = ctypes.c_int64
+    lib.pa_lookup_sorted_i32.argtypes = [
+        i32p, ctypes.c_int64, i64p, i32p, ctypes.c_int64, i32p,
+    ]
+    lib.pa_lookup_sorted_i32.restype = ctypes.c_int64
+    f64p = np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS")
+    f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")
+    for name, fp in (("pa_coo_to_csr_f64", f64p), ("pa_coo_to_csr_f32", f32p)):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            i32p, i32p, fp, ctypes.c_int64, ctypes.c_int64,
+            i32p, i32p, fp, i32p,
+        ]
+        fn.restype = ctypes.c_int64
+    for name, fp in (
+        ("pa_coo_to_csr_i64_f64", f64p), ("pa_coo_to_csr_i64_f32", f32p),
+    ):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            i64p, i64p, fp, ctypes.c_int64, ctypes.c_int64,
+            i32p, i32p, fp, i32p,
+        ]
+        fn.restype = ctypes.c_int64
+    lib.pa_unique_small_f64.argtypes = [
+        f64p, ctypes.c_int64, ctypes.c_int64, f64p,
+    ]
+    lib.pa_unique_small_f64.restype = ctypes.c_int64
+    lib.pa_row_classes_f64.argtypes = [
+        f64p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int64, f64p, u8p,
+    ]
+    lib.pa_row_classes_f64.restype = ctypes.c_int64
+    lib.pa_ic0_f64.argtypes = [i32p, i32p, f64p, ctypes.c_int64, f64p]
+    lib.pa_ic0_f64.restype = ctypes.c_int64
+    for name, fp in (("pa_csr_split_f64", f64p), ("pa_csr_split_f32", f32p)):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            i32p, i32p, fp, ctypes.c_int64, ctypes.c_int32,
+            i32p, i32p, fp, i32p, i32p, fp,
+        ]
+        fn.restype = None
+    for name, fp in (("pa_csr_spmv_f64", f64p), ("pa_csr_spmv_f32", f32p)):
+        fn = getattr(lib, name)
+        fn.argtypes = [i32p, i32p, fp, ctypes.c_int64, fp, fp]
+        fn.restype = None
+    for name, fp in (("pa_dia_fill_f64", f64p), ("pa_dia_fill_f32", f32p)):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            i32p, i32p, fp, ctypes.c_int64, i64p, ctypes.c_int64,
+            ctypes.c_int64, f64p,
+        ]
+        fn.restype = ctypes.c_int64
+    for name, fp in (("pa_csr_diag_f64", f64p), ("pa_csr_diag_f32", f32p)):
+        fn = getattr(lib, name)
+        fn.argtypes = [i32p, i32p, fp, ctypes.c_int64, fp]
+        fn.restype = None
+    for name, fp in (("pa_galerkin3_f64", f64p), ("pa_galerkin3_f32", f32p)):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            i32p, i32p, fp, ctypes.c_int64, i64p, i64p, i64p, i64p,
+            i64p, i64p, i64p, ctypes.c_int32, f64p,
+        ]
+        fn.restype = ctypes.c_int64
+    for name, fp in (
+        ("pa_galerkin3_sub_f64", f64p), ("pa_galerkin3_sub_f32", f32p),
+    ):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            i32p, i32p, fp, ctypes.c_int64, i64p, i64p, i64p, i64p,
+            i64p, i64p, i64p, ctypes.c_int32, f64p, i64p, i64p,
+        ]
+        fn.restype = ctypes.c_int64
+    for name, fp in (
+        ("pa_galerkin_classify_f64", f64p),
+        ("pa_galerkin_classify_f32", f32p),
+    ):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            i32p, i32p, fp, ctypes.c_int64, i64p, i64p,
+            ctypes.c_int32, ctypes.c_int64, f64p, u8p,
+        ]
+        fn.restype = ctypes.c_int64
+    for name, fp in (
+        ("pa_galerkin_emit_f64", f64p), ("pa_galerkin_emit_f32", f32p),
+    ):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            f64p, i64p, i64p, i64p, i64p, i64p, i64p,
+            ctypes.c_int64, ctypes.c_int32, i32p, i32p, fp,
+        ]
+        fn.restype = ctypes.c_int64
+    for name, fp in (
+        ("pa_stencil_emit_f64", f64p), ("pa_stencil_emit_f32", f32p),
+    ):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            i64p, i64p, i64p, ctypes.c_int32, ctypes.c_double, f64p,
+            i64p, ctypes.c_int64, ctypes.c_int32, i32p, i32p, fp,
+            f64p, fp, ctypes.c_int32,
+        ]
+        fn.restype = ctypes.c_int64
+    for name, fp in (
+        ("pa_stencil_emit_range_f64", f64p),
+        ("pa_stencil_emit_range_f32", f32p),
+    ):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            i64p, i64p, i64p, ctypes.c_int32, ctypes.c_double, f64p,
+            i64p, ctypes.c_int64, ctypes.c_int32, i32p, i32p, fp,
+            f64p, fp, ctypes.c_int32, ctypes.c_int64, ctypes.c_int64,
+        ]
+        fn.restype = ctypes.c_int64
+    lib.pa_band_offsets.argtypes = [
+        i32p, i32p, ctypes.c_int64, ctypes.c_int64, i64p,
+        ctypes.c_int64,
+    ]
+    lib.pa_band_offsets.restype = ctypes.c_int64
+    for name, fp in (
+        ("pa_dia_classify_f64", f64p), ("pa_dia_classify_f32", f32p),
+    ):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            i32p, i32p, fp, ctypes.c_int64, i64p, ctypes.c_int64,
+            ctypes.c_int64, f64p, u8p, ctypes.c_int64,
+        ]
+        fn.restype = ctypes.c_int64
+    lib.pa_count_ge.argtypes = [i32p, ctypes.c_int64, ctypes.c_int32]
+    lib.pa_count_ge.restype = ctypes.c_int64
+    for name, fp in (
+        ("pa_csr_extract_hi_f64", f64p), ("pa_csr_extract_hi_f32", f32p),
+    ):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            i32p, i32p, fp, ctypes.c_int64, ctypes.c_int32,
+            i32p, i32p, fp,
+        ]
+        fn.restype = None
+    return lib
 
 
 def available() -> bool:

@@ -32,6 +32,20 @@ from typing import Sequence, Tuple
 import numpy as np
 
 LANES = 128
+
+#: Scoped-VMEM limit handed to Mosaic with both kernels. The plans below
+#: budget the DMA buffers a kernel declares (<= 12-13 MiB); the kernel
+#: body's own temporaries — the int32 upcast of every packed code stream,
+#: the shifted windows, the class accumulators — come on top of that, and
+#: the compiler's default limit does not hold them. Found on device_kind
+#: "TPU v5 lite" (v5e; jax 0.9.0, libtpu 0.0.34; default limit 16 MiB):
+#: the 27-diagonal interpolation stencil of the GMG hierarchy on a
+#: (2,2,1) partition at 192^3 cells per chip (26 coded diagonals = 13
+#: nibble streams) needs 26.8 MiB and failed to compile ("exceeded scoped
+#: vmem limit by 10.80M"). The widest kernel the 13 MiB budget admits (16
+#: streams) needs ~40 MiB by the same arithmetic; 64 MiB holds it with
+#: room and is half of a v5e core's 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 64 * 2**20
 #: block rows per grid step (tuned: vals block = D * BR * 128 * 4B in VMEM,
 #: double-buffered by the pipeline; 512 rows -> 1.8 MB per diagonal-7 block)
 DEF_BLOCK_ROWS = 512
@@ -124,6 +138,9 @@ def dia_spmv_pallas(
             pltpu.VMEM((win_rows, LANES), vals.dtype),
             pltpu.SemaphoreType.DMA(()),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES
+        ),
         interpret=interpret,
     )(vals, x)
 
@@ -488,6 +505,7 @@ def dia_coded_padded_pallas(
         (BR, LANES), lambda j: (j, 0), memory_space=pltpu.VMEM
     )
     y_shape = jax.ShapeDtypeStruct((total_rows, LANES), codebook.dtype)
+    params = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
     scratch = [
         pltpu.VMEM((2, win_rows, LANES), codebook.dtype),
         pltpu.VMEM((2, max(Dc, 1), BR, LANES), codes.dtype),
@@ -517,6 +535,7 @@ def dia_coded_padded_pallas(
                 pltpu.SemaphoreType.DMA((2,)),  # p window sem
                 pltpu.SemaphoreType.DMA((2,)),  # codes sem
             ],
+            compiler_params=params,
             interpret=interpret,
         )(codebook, no, codes, x, pprev, beta)
     if axpy is None:
@@ -527,6 +546,7 @@ def dia_coded_padded_pallas(
             out_specs=y_spec,
             out_shape=y_shape,
             scratch_shapes=scratch,
+            compiler_params=params,
             interpret=interpret,
         )(codebook, no, codes, x)
     pprev, xacc, alpha = axpy
@@ -544,6 +564,7 @@ def dia_coded_padded_pallas(
         out_shape=[y_shape, jax.ShapeDtypeStruct(xacc.shape, xacc.dtype)],
         input_output_aliases={5: 1},
         scratch_shapes=scratch,
+        compiler_params=params,
         interpret=interpret,
     )(codebook, no, codes, x, pprev, xacc, alpha)
 

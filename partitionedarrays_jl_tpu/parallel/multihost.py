@@ -65,11 +65,9 @@ def multihost_init(
     — it must not silently degrade into N independent single-host runs."""
     import jax
 
-    try:
-        from jax._src.distributed import global_state
-    except ImportError:  # future jax relocations: fall through to init
-        global_state = None
-    if global_state is not None and getattr(global_state, "client", None) is not None:
+    from jax._src.distributed import global_state
+
+    if global_state.client is not None:
         return  # already joined the cluster
     # NOTE: do not probe jax.process_count() here — it would initialize the
     # local-only backend first, making the subsequent cluster join fail.

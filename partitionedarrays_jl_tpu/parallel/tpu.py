@@ -53,48 +53,6 @@ def _jax():
     return jax
 
 
-_shard_map_cached = None
-
-
-def _shard_map():
-    """`jax.shard_map` across jax versions (resolved once): newer jax
-    exports it at top level, older releases keep it in
-    `jax.experimental.shard_map`; the replication-check keyword was
-    renamed ``check_rep`` -> ``check_vma`` along the way — on a SEPARATE
-    schedule from the relocation, so the adapter keys the rename on the
-    resolved function's own signature, not on where it was imported
-    from. All call sites here pass keyword arguments only."""
-    global _shard_map_cached
-    if _shard_map_cached is not None:
-        return _shard_map_cached
-    import inspect
-
-    try:
-        from jax import shard_map as resolved
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as resolved
-    try:
-        params = inspect.signature(resolved).parameters
-        takes_vma = "check_vma" in params or any(
-            p.kind is inspect.Parameter.VAR_KEYWORD for p in params.values()
-        )
-    except (TypeError, ValueError):  # unsignaturable wrapper: assume new API
-        takes_vma = True
-    if takes_vma:
-        sm = resolved
-    else:
-
-        def sm(f, *, mesh, in_specs, out_specs, **kw):
-            if "check_vma" in kw:
-                kw["check_rep"] = kw.pop("check_vma")
-            return resolved(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-            )
-
-    _shard_map_cached = sm
-    return sm
-
-
 _backend_tokens = itertools.count()
 
 
@@ -158,26 +116,30 @@ class TPUBackend(AbstractBackend):
         and flatten it in C order: flat part p then sits on the device at
         p's grid coordinate of the physical torus, so the halo
         `ppermute`s between Cartesian neighbors ride single-hop ICI
-        links. Falls back to list order (with a warning on real TPUs) for
-        CPU meshes or any mesh_utils failure."""
+        links. CPU meshes use list order; so does a grid `mesh_utils`
+        declines for this slice's physical topology (its
+        NotImplementedError / ValueError), with a warning that names the
+        device order actually used — chip_smoke.py treats that warning
+        as a failure on a multi-chip host."""
         if (
             grid is not None
             and len(grid) > 1
             and math.prod(grid) == nparts == len(devs)
             and all(getattr(d, "platform", "") == "tpu" for d in devs)
         ):
-            try:
-                from jax.experimental import mesh_utils
+            from jax.experimental import mesh_utils
 
+            try:
                 nd = mesh_utils.create_device_mesh(grid, devices=devs)
                 return list(np.asarray(nd).reshape(-1))
-            except Exception as e:
+            except (NotImplementedError, ValueError) as e:
                 import warnings
 
                 warnings.warn(
                     f"TPUBackend: topology-aware device ordering for part "
-                    f"grid {grid} failed ({e!r}); using list order — halo "
-                    "neighbors may take multi-hop ICI routes.",
+                    f"grid {grid} failed ({e!r}); using list order "
+                    f"{[getattr(d, 'id', d) for d in devs[:nparts]]} — "
+                    "halo neighbors may take multi-hop ICI routes.",
                     stacklevel=3,
                 )
         return list(devs[:nparts])
@@ -228,8 +190,46 @@ def _stage(backend: TPUBackend, arr: np.ndarray, nparts: int):
     contributes just its local devices' rows; on one host it degenerates to
     a plain device_put."""
     jax = _jax()
+    _note_narrowing(jax, arr.dtype)
     sh = backend.sharding(nparts)
     return jax.make_array_from_callback(arr.shape, sh, lambda idx: arr[idx])
+
+
+_narrowing_noted = False
+
+
+def _note_narrowing(jax, dtype) -> None:
+    """Say ONCE per process that float64 host data is about to live on
+    the device as float32: without ``jax_enable_x64`` (TPUs have no
+    native float64) staging narrows, and a tolerance chosen for float64
+    (the public drivers' default dtype) is then unreachable."""
+    global _narrowing_noted
+    if (
+        _narrowing_noted
+        or np.dtype(dtype) != np.float64
+        or jax.config.jax_enable_x64
+    ):
+        return
+    _narrowing_noted = True
+    import warnings
+
+    warnings.warn(
+        "partitionedarrays_jl_tpu: staging float64 host data onto a "
+        "backend without jax_enable_x64 — it is stored and computed in "
+        "float32 on the device (float32 resolution floor applies to "
+        "every tolerance). Assemble in float32 (e.g. "
+        "assemble_poisson(..., dtype=np.float32)) to make this explicit, "
+        "or enable x64 where the platform supports it.",
+        RuntimeWarning,
+        stacklevel=4,
+    )
+
+
+def _device_dtype(dtype):
+    """The dtype a staged array of host ``dtype`` really has: float64
+    host data is float32 on a backend without x64, and THAT resolution
+    floor is the one a solver tolerance has to clear."""
+    return _jax().dtypes.canonicalize_dtype(dtype)
 
 
 class TPUData(SequentialData):
@@ -1076,10 +1076,10 @@ def _sdc_tolerances(dtype, P: int, no_max: int):
 class ELLFootprintError(RuntimeError):
     """The generic padded-ELL lowering was refused: its per-row gather
     program at this operator size is past the footprint ceiling that has
-    faulted real TPU workers (the 64^3 tet-elasticity probe — see
-    IRREGULAR_BENCH.json's 64^3 note). Raised INSTEAD of staging the
-    program, so no documented env-flag combination can reach the
-    device-fault path."""
+    faulted a real TPU worker (the 64^3-node tet-elasticity operator,
+    786432 rows: the ELL program faulted the device, SD and BSR on the
+    same operator ran). Raised INSTEAD of staging the program, so no
+    documented env-flag combination can reach the device-fault path."""
 
 
 #: Ceiling on the padded-ELL A_oo gather footprint (``no_max * L_oo``
@@ -2697,7 +2697,7 @@ def make_exchange_fn(rows: PRange, backend: TPUBackend, combine: str = "set") ->
     current (combine='set') or owners accumulated (combine='add', reverse
     plan) — the device form of exchange!/assemble!."""
     import jax
-    shard_map = _shard_map()
+    shard_map = jax.shard_map
 
     from .tpu_box import BoxExchangePlan
 
@@ -3312,7 +3312,7 @@ def make_spmv_fn(dA: DeviceMatrix) -> Callable:
     maps to the (P, Wr, K) block product — one operator stream per K
     columns (the body is rank-polymorphic; jit re-traces per rank)."""
     import jax
-    shard_map = _shard_map()
+    shard_map = jax.shard_map
 
     mesh = dA.backend.mesh(dA.row_layout.P)
     spec = dA.backend.parts_spec()
@@ -3434,7 +3434,7 @@ def make_cg_fn(
     form including ``sstep``."""
     import jax
     import jax.numpy as jnp
-    shard_map = _shard_map()
+    shard_map = jax.shard_map
 
     sstep_explicit = sstep is not None
     sstep = _resolve_sstep(sstep)
@@ -4400,7 +4400,7 @@ def make_block_cg_fn(
     freeze point)."""
     import jax
     import jax.numpy as jnp
-    shard_map = _shard_map()
+    shard_map = jax.shard_map
 
     K = int(rhs_batch)
     check(K >= 1, "make_block_cg_fn: rhs_batch must be >= 1")
@@ -4498,7 +4498,7 @@ def make_block_cg_fn(
 
             if sdccfg is not None:
                 # ---- SDC-defended block loop (see make_cg_fn's sdc
-                # branch for the trip taxonomy) — (K,) per-column
+                # branch for the trip classes) — (K,) per-column
                 # checksum/audit lanes, whole-block ring restore ----
                 ae = sdccfg["ae"]
                 R = sdccfg["R"]
@@ -5108,7 +5108,7 @@ def make_bicgstab_fn(
     inverse-diagonal operand (residuals stay true residuals)."""
     import jax
     import jax.numpy as jnp
-    shard_map = _shard_map()
+    shard_map = jax.shard_map
 
     mesh = dA.backend.mesh(dA.row_layout.P)
     spec = dA.backend.parts_spec()
@@ -5261,7 +5261,7 @@ def make_gmres_fn(
     left-preconditioned by an inverse-diagonal operand (owned slots)."""
     import jax
     import jax.numpy as jnp
-    shard_map = _shard_map()
+    shard_map = jax.shard_map
 
     m = int(restart)
     # m < 1 would compile an inner loop that never advances `it`, leaving
@@ -5448,7 +5448,7 @@ def make_minres_fn(dA: DeviceMatrix, tol: float, maxiter: int) -> Callable:
     sequential oracle the same way CG's do."""
     import jax
     import jax.numpy as jnp
-    shard_map = _shard_map()
+    shard_map = jax.shard_map
 
     mesh = dA.backend.mesh(dA.row_layout.P)
     spec = dA.backend.parts_spec()
@@ -5635,7 +5635,7 @@ def make_chebyshev_fn(
     decide termination. Spectrum bounds are compile-time constants."""
     import jax
     import jax.numpy as jnp
-    shard_map = _shard_map()
+    shard_map = jax.shard_map
 
     mesh = dA.backend.mesh(dA.row_layout.P)
     spec = dA.backend.parts_spec()
@@ -5745,7 +5745,8 @@ def tpu_chebyshev(
     from ..utils.helpers import warn_tol_below_floor
 
     backend = b.values.backend
-    floor_warned = warn_tol_below_floor(tol, b.dtype, name="chebyshev")
+    dev_dtype = _device_dtype(b.dtype)
+    floor_warned = warn_tol_below_floor(tol, dev_dtype, name="chebyshev")
     dA = device_matrix(A, backend)
     if maxiter is None:
         maxiter = 10 * int(A.rows.ngids)
@@ -5771,7 +5772,7 @@ def tpu_chebyshev(
 
     converged = bool(np.sqrt(rs) <= tol * max(1.0, np.sqrt(rs0)))
     return x, krylov_info(
-        it, residuals, converged, tol, b.dtype, floor_warned,
+        it, residuals, converged, tol, dev_dtype, floor_warned,
         final_rel=_final_true_rel(
             A, x, b, np.sqrt(rs) / max(1.0, np.sqrt(rs0)), np.sqrt(rs0),
             tol, force=floor_warned,
@@ -5842,7 +5843,8 @@ def _run_krylov(A, b, x0, tol, verbose, solve, minv=None, name="cg",
     from ..utils.helpers import krylov_info, warn_tol_below_floor
 
     backend = b.values.backend
-    floor_warned = warn_tol_below_floor(tol, b.dtype, name=name)
+    dev_dtype = _device_dtype(b.dtype)
+    floor_warned = warn_tol_below_floor(tol, dev_dtype, name=name)
     rec = telemetry.current_record()
     with telemetry.annotate(f"pa:{name}:stage"):
         dA = device_matrix(A, backend)
@@ -5924,7 +5926,7 @@ def _run_krylov(A, b, x0, tol, verbose, solve, minv=None, name="cg",
         )
     converged = bool(np.sqrt(rs) <= tol * max(1.0, np.sqrt(rs0)))
     info = krylov_info(
-        it, residuals, converged, tol, b.dtype, floor_warned,
+        it, residuals, converged, tol, dev_dtype, floor_warned,
         final_rel=_final_true_rel(
             A, x, b, np.sqrt(rs) / max(1.0, np.sqrt(rs0)), np.sqrt(rs0),
             tol, force=floor_warned,
@@ -6097,7 +6099,8 @@ def _tpu_block_cg_impl(
             dA, "cg", tol, maxiter, precond=minv is not None, fused=fused,
             rhs_batch=K,
         )
-        floor_warned = warn_tol_below_floor(tol, dt, name="block-cg")
+        dev_dtype = _device_dtype(dt)
+        floor_warned = warn_tol_below_floor(tol, dev_dtype, name="block-cg")
         db = _block_on_cols_layout(B, dA)
         if X0 is None:
             X0 = [PVector.full(0.0, A.cols, dtype=dt) for _ in range(K)]
@@ -6190,7 +6193,7 @@ def _tpu_block_cg_impl(
         )
         columns.append(
             krylov_info(
-                it_k, residuals, converged, tol, dt, floor_warned,
+                it_k, residuals, converged, tol, dev_dtype, floor_warned,
                 final_rel=_final_true_rel(
                     A, x, B[k],
                     np.sqrt(rs[k]) / max(1.0, np.sqrt(rs0[k])),
@@ -6626,18 +6629,13 @@ def case_program_texts(
         if not with_compiled:
             return low.as_text(), None, None
         compiled = low.compile()
-        mem = None
-        try:
-            ma = compiled.memory_analysis()
-            if ma is not None:
-                mem = {
-                    "argument_bytes": int(ma.argument_size_in_bytes),
-                    "output_bytes": int(ma.output_size_in_bytes),
-                    "temp_bytes": int(ma.temp_size_in_bytes),
-                    "alias_bytes": int(ma.alias_size_in_bytes),
-                }
-        except Exception:
-            mem = None  # older runtimes: memory_report falls back
+        ma = compiled.memory_analysis()
+        mem = None if ma is None else {
+            "argument_bytes": int(ma.argument_size_in_bytes),
+            "output_bytes": int(ma.output_size_in_bytes),
+            "temp_bytes": int(ma.temp_size_in_bytes),
+            "alias_bytes": int(ma.alias_size_in_bytes),
+        }
         return low.as_text(), compiled.as_text(), mem
 
 

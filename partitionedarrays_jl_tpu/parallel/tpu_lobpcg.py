@@ -44,8 +44,7 @@ def make_lobpcg_fn(
     multigrid-preconditioned modal analysis as ONE compiled program."""
     import jax
     import jax.numpy as jnp
-    from .tpu import _shard_map
-    shard_map = _shard_map()
+    shard_map = jax.shard_map
 
     m = int(nev)
     mesh = dA.backend.mesh(dA.row_layout.P)

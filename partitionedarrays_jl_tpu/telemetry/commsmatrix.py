@@ -418,9 +418,9 @@ def _round_chains(plan, backend, K: int):
     import jax.numpy as jnp
     import numpy as np
 
-    from ..parallel.tpu import _shard_map, _stage
+    from ..parallel.tpu import _stage
 
-    shard_map = _shard_map()
+    shard_map = jax.shard_map
     layout = plan.layout
     P, W = layout.P, layout.W
     o0, g0, trash = layout.o0, layout.g0, layout.trash
@@ -478,9 +478,9 @@ def _twolevel_round_chains(plan, backend, K: int):
     import jax.numpy as jnp
     import numpy as np
 
-    from ..parallel.tpu import _shard_map, _stage
+    from ..parallel.tpu import _stage
 
-    shard_map = _shard_map()
+    shard_map = jax.shard_map
     layout = plan.layout
     P, W = layout.P, layout.W
     S = plan.stage_width
@@ -546,11 +546,10 @@ def _full_exchange_chain(plan, dA, backend, K: int):
     from ..parallel.tpu import (
         _matrix_operands,
         _shard_exchange,
-        _shard_map,
         _shard_ops,
     )
 
-    shard_map = _shard_map()
+    shard_map = jax.shard_map
     layout = plan.layout
     P, W = layout.P, layout.W
     o0, g0 = layout.o0, layout.g0

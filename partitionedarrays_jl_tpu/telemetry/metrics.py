@@ -62,13 +62,12 @@ def reset(prefix: Optional[str] = None) -> None:
     registry().reset(prefix)
 
 
-_jax_listeners_attempted = False
 _jax_listeners_installed = False
 
-#: jax.monitoring event names -> our counters. `cache_hits` arrives via
-#: `record_event`; `cache_misses` via `record_event_duration_secs` (the
-#: miss carries its compile duration). Observed stable across the jax
-#: versions this repo has run on; treated as best-effort regardless.
+#: jax.monitoring event names -> our counters (jax 0.9.0: both arrive
+#: via `record_event`; a miss is recorded when the compiled executable
+#: is WRITTEN to the cache, so compiles under the compile-time floor
+#: count as neither).
 _JAX_EVENT_COUNTERS = {
     "/jax/compilation_cache/cache_hits": "persistent_cache.hit",
     "/jax/compilation_cache/cache_misses": "persistent_cache.miss",
@@ -77,32 +76,18 @@ _JAX_EVENT_COUNTERS = {
 
 def install_jax_cache_listeners() -> bool:
     """Bridge JAX's persistent-compilation-cache monitoring events into
-    ``persistent_cache.{hit,miss}``. Idempotent; returns whether the
-    listeners are (now) installed. Never raises — a jax that renamed
-    its monitoring hooks just leaves the counters at zero."""
-    global _jax_listeners_attempted, _jax_listeners_installed
-    if _jax_listeners_attempted:
-        return _jax_listeners_installed
-    # one attempt ever: a partial failure (first listener registered,
-    # second raises) must not leave a retry path that registers the
-    # first listener again and double-counts every hit
-    _jax_listeners_attempted = True
-    try:
-        import jax.monitoring as jm
+    ``persistent_cache.{hit,miss}``. Idempotent (a second registration
+    would double-count every hit); returns True."""
+    global _jax_listeners_installed
+    if _jax_listeners_installed:
+        return True
+    _jax_listeners_installed = True
+    import jax.monitoring as jm
 
-        def _on_event(event: str, **kw) -> None:
-            name = _JAX_EVENT_COUNTERS.get(event)
-            if name:
-                bump(name)
+    def _on_event(event: str, **kw) -> None:
+        name = _JAX_EVENT_COUNTERS.get(event)
+        if name:
+            bump(name)
 
-        def _on_duration(event: str, duration: float, **kw) -> None:
-            name = _JAX_EVENT_COUNTERS.get(event)
-            if name:
-                bump(name)
-
-        jm.register_event_listener(_on_event)
-        jm.register_event_duration_secs_listener(_on_duration)
-        _jax_listeners_installed = True
-    except Exception:
-        return False
+    jm.register_event_listener(_on_event)
     return True

@@ -255,10 +255,9 @@ def _marginal_s(run_chain: Callable[[int], float], k1: int, k2: int,
     cancels (the bench.py protocol, compacted). Min, not median: on a
     shared/loaded host, contention only ever INFLATES a run, so the
     min of each side is the least-contended estimate and the
-    difference is far more stable under load than median-of-reps (the
-    relay-RTT both-ways jitter that forced bench.py to medians does
-    not exist on this in-process path). One doubling retry absorbs
-    timer-noise inversions on very cheap chains."""
+    difference is far more stable under load than median-of-reps. One
+    doubling retry absorbs timer-noise inversions on very cheap
+    chains."""
     def timed(k: int) -> float:
         run_chain(k)
         run_chain(k)
@@ -302,12 +301,11 @@ def _phase_chains(dA, rhs_batch: Optional[int]) -> Dict[str, Callable]:
         _matrix_operands,
         _pdot_factory,
         _shard_exchange,
-        _shard_map,
         _shard_ops,
         _spmv_body,
     )
 
-    shard_map = _shard_map()
+    shard_map = jax.shard_map
     layout = dA.col_plan.layout
     P, W = layout.P, layout.W
     o0, g0 = layout.o0, layout.g0

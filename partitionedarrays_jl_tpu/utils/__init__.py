@@ -2,9 +2,6 @@ from .compile_cache import (
     compilation_cache_dir,
     enable_compilation_cache,
 )
-from .compile_cache import _maybe_enable_from_env as _cc_env
-
-_cc_env()
 from .helpers import (
     AbstractMethodError,
     abstractmethod,

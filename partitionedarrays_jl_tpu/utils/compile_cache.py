@@ -1,4 +1,4 @@
-"""Persistent XLA compilation cache (round-5 directive 1).
+"""Persistent XLA compilation cache.
 
 The compiled one-program solvers (`make_cg_fn`, `make_gmg_pcg_fn`,
 `make_fgmres_gmg_fn`, ...) are plain `jax.jit` programs, so JAX's
@@ -6,22 +6,25 @@ persistent compilation cache serializes their XLA executables to disk
 keyed by the HLO fingerprint — which already folds in everything our
 `_lowering_env_key` tracks (the lowering env modes change the traced
 HLO) plus shapes, dtypes, mesh and compiler flags. A second process
-that builds the same program pays tracing only; the 100+ s XLA compile
-of the 1e8-DOF GMG-PCG program is served from disk.
+that builds the same program pays tracing only.
 
 This mirrors the reference's headline that *setup* scales
 (/root/reference/README.md:49-63): with the cache on, warm
 time-to-first-solution drops the dominant compile line item.
 
-Usage::
+Where the cache lives is decided OUTSIDE the program:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set — that directory, verbatim, and no
+  other: an argument passed in code never moves it, so whoever launches
+  the process (a scheduler, a CI job, the chip tool) owns the placement.
+* unset — ``<checkout>/.jax_cache`` (git-ignored), a fixed path: a
+  directory that moves between runs never hits, so nothing here derives
+  a name from `tempfile`, a pid or the clock.
+
+Usage (every entry point that touches the chip calls this first)::
 
     import partitionedarrays_jl_tpu as pa
-    pa.enable_compilation_cache()            # default cache dir
-    pa.enable_compilation_cache("/fast/dir") # explicit dir
-
-or set ``PA_TPU_COMPILE_CACHE=1`` (default dir) / ``=<path>`` before
-importing the package — the package enables it at import time.
-``PA_TPU_COMPILE_CACHE=0`` (or unset) leaves the cache off.
+    pa.enable_compilation_cache()
 """
 from __future__ import annotations
 
@@ -29,8 +32,12 @@ import os
 
 __all__ = ["enable_compilation_cache", "compilation_cache_dir"]
 
-_DEFAULT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "partitionedarrays_jl_tpu", "xla"
+#: ``<checkout>/.jax_cache`` — the package directory's parent.
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
 )
 
 _enabled_dir: str | None = None
@@ -42,20 +49,33 @@ def compilation_cache_dir() -> str | None:
     return _enabled_dir
 
 
+def resolve_cache_dir(path: str | None = None) -> str:
+    """Where `enable_compilation_cache(path)` would put the cache:
+    ``JAX_COMPILATION_CACHE_DIR`` verbatim when set (``path`` is then
+    ignored), else ``path``, else `DEFAULT_DIR`."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    if path is None:
+        return DEFAULT_DIR
+    return os.path.abspath(os.path.expanduser(path))
+
+
 def enable_compilation_cache(path: str | None = None) -> str:
-    """Turn on JAX's persistent compilation cache at ``path`` (created
-    if missing; default ``~/.cache/partitionedarrays_jl_tpu/xla``) and
-    return the directory used.
+    """Turn on JAX's persistent compilation cache (directory created if
+    missing; see `resolve_cache_dir` for which one) and return the
+    directory used.
 
     Every XLA compile that takes >= 1 s is written to disk; later
     compiles of byte-identical HLO (same program, shapes, dtypes, mesh,
     lowering env modes) load the executable instead of recompiling —
-    including across processes. Safe to call more than once; the last
-    path wins. Calling this AFTER programs were already compiled only
-    affects subsequent compiles.
+    including across processes. Safe to call more than once. Calling
+    this AFTER programs were already compiled only affects subsequent
+    compiles.
     """
     global _enabled_dir
     import jax
+    from jax._src import compilation_cache as _cc
 
     # bridge jax's cache-hit/miss monitoring events into the telemetry
     # counters (persistent_cache.{hit,miss}) — the deterministic signal
@@ -64,9 +84,7 @@ def enable_compilation_cache(path: str | None = None) -> str:
 
     install_jax_cache_listeners()
 
-    if path is None:
-        path = _DEFAULT_DIR
-    path = os.path.abspath(os.path.expanduser(path))
+    path = resolve_cache_dir(path)
     # cache dirs usually live on a shared filesystem (that is the point:
     # one host compiles, every host loads) — N processes race to create
     # the same directory tree and NFS/overlay mounts surface transient
@@ -88,19 +106,6 @@ def enable_compilation_cache(path: str | None = None) -> str:
     # compile has initialized it (possibly with the cache OFF), a config
     # update alone never reaches it — drop the instance so the next
     # compile rebuilds it against the new directory
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except (ImportError, AttributeError):
-        pass  # newer jax picks the config change up directly
+    _cc.reset_cache()
     _enabled_dir = path
     return path
-
-
-def _maybe_enable_from_env() -> None:
-    """Package-import hook: honor ``PA_TPU_COMPILE_CACHE``."""
-    v = os.environ.get("PA_TPU_COMPILE_CACHE", "0")
-    if v.strip().lower() in ("", "0", "false", "off", "no", "none"):
-        return
-    enable_compilation_cache(None if v.strip().lower() in ("1", "true", "on", "yes") else v)

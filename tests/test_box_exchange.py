@@ -238,8 +238,7 @@ def test_box_and_generic_plans_agree_slotwise():
         spec = backend.parts_spec()
 
         def run(plan, si, sm, ri):
-            from partitionedarrays_jl_tpu.parallel.tpu import _shard_map
-            shard_map = _shard_map()
+            shard_map = jax.shard_map
 
             body = _shard_exchange(plan, "set")
 

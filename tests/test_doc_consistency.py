@@ -127,26 +127,6 @@ def test_scale_bench_artifact_agrees_with_guard_bands():
     assert rec["bands_ok_device"] is True
 
 
-def test_irregular_artifact_agrees_with_guard_bands():
-    bench_irr = _load_tool("bench_irregular")
-    rec = json.load(open(os.path.join(REPO, "IRREGULAR_BENCH.json")))
-    assert rec["methodology"] == bench_irr.METHODOLOGY
-    banded = 0
-    for row in rec["sizes"]:
-        n = row["n"]
-        if row.get("lowering") == "sd" and n in bench_irr.BANDS_SD:
-            lo, hi = bench_irr.BANDS_SD[n]
-            band = row.get("band")
-            assert band is not None, f"SD row n={n} missing its band"
-            assert (band["lo"], band["hi"]) == (lo, hi), (n, band)
-            assert band["measured"] == row[f"{row['lowering']}_gflops"]
-            assert row["in_band"] == (lo <= band["measured"] <= hi)
-            banded += 1
-    # every measured size is banded (the 48^3/64^3 rows used to ship
-    # silently unbanded — round-6 satellite)
-    assert banded == len(rec["sizes"]), (banded, len(rec["sizes"]))
-
-
 def test_multirhs_artifact_agrees_with_guard_bands():
     """The committed multi-RHS flagship artifact and the bench guard
     must agree: identical band bounds, recorded device metrics inside

@@ -1,8 +1,8 @@
 """The padded-ELL device-fault footprint guard (tpu.py:_ell_guard_check).
 
-The 64^3 tet-elasticity probe (IRREGULAR_BENCH.json) showed the generic
-padded-ELL lowering's gather kernels FAULT a real TPU worker outright at
-that scale, while SD and BSR on the same operator run fine. The guard
+At the 64^3-node tet-elasticity operator (786432 dofs, 27955824 nnz) the
+generic padded-ELL lowering's gather kernels FAULTED a real TPU worker
+outright, while SD and BSR on the same operator ran fine. The guard
 used to live only in tools/bench_irregular.py's leg selection; this file
 pins its library form: the lowering itself refuses (real TPU) or warns
 (host mesh) BEFORE staging an over-ceiling ELL program, whether ELL was
@@ -35,9 +35,11 @@ def _backend():
 
 def test_recorded_64cube_footprint_exceeds_default_ceiling():
     """The operator that faulted the worker must be refused by the
-    DEFAULT ceiling: at the recorded 64^3 shape (IRREGULAR_BENCH.json:
-    786432 dofs, 27955824 nnz) even the MEAN row width — a lower bound
-    on the padded ELL width — puts the footprint past the ceiling."""
+    DEFAULT ceiling: at the 64^3 shape (786432 dofs, 27955824 nnz —
+    the operator chip_smoke.py's irregular leg assembles, where the
+    refusal itself is checked on the chip) even the MEAN row width — a
+    lower bound on the padded ELL width — puts the footprint past the
+    ceiling."""
     dofs, nnz = 786432, 27955824
     mean_width_floor = -(-nnz // dofs)  # ceil; true padded L is >= this
     assert dofs * mean_width_floor > ELL_MAX_GATHER

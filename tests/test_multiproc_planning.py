@@ -3,6 +3,8 @@ the SAME per-part matrices as the in-process assembly fast path — the
 testable form of the "planning is embarrassingly parallel per part"
 claim (round-4 directive 3; reference analog: per-rank local assembly,
 test/test_fdm.jl:52-81)."""
+import os
+
 import numpy as np
 import pytest
 
@@ -104,3 +106,24 @@ def test_plan_procs_env_flag_matches_default(monkeypatch):
     monkeypatch.setenv("PA_TPU_PLAN_PROCS", "2")
     multi = pa.prun(driver, pa.sequential, (2, 1, 1))
     assert base == multi
+
+
+def test_planning_workers_never_import_jax():
+    """A spawned planning worker imports this package; the parent may
+    hold the chip, which one process owns at a time — so importing the
+    package (and the emission module the workers run) must not import
+    jax, let alone initialize a backend."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "import partitionedarrays_jl_tpu\n"
+        "from partitionedarrays_jl_tpu.native import parallel_emit\n"
+        "assert parallel_emit._worker\n"
+        "assert 'jax' not in sys.modules, 'package import pulled in jax'\n"
+    )
+    subprocess.run(
+        [sys.executable, "-c", code], check=True, timeout=120,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )

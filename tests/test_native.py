@@ -31,6 +31,56 @@ def test_native_builds_and_loads():
     assert native.available(), "g++ toolchain present: native layer must build"
 
 
+@pytest.mark.skipif(shutil.which("g++") is None, reason="no g++ toolchain")
+def test_binary_is_keyed_by_source_hash(tmp_path, monkeypatch):
+    """The loaded binary is a function of `planning.cpp`'s bytes: a
+    changed source forces a rebuild under a new name, and a binary left
+    under the old name — even a NEWER file, the case file times get
+    wrong after a tree copy — is never loaded."""
+    old_so = native._so_path()
+    src = tmp_path / "planning.cpp"
+    shutil.copy(native._SRC, src)
+    with open(src, "a") as f:
+        f.write("\n// a different revision of the source\n")
+    build = tmp_path / "build"
+    build.mkdir()
+    # stale binaries: the previous revision's hashed name and the
+    # pre-hash fixed name, both unloadable on purpose
+    stale = [build / os.path.basename(old_so), build / "libpa_planning.so"]
+    for s in stale:
+        s.write_bytes(b"not a shared object")
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_BUILD_DIR", str(build))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    new_so = native._so_path()
+    assert os.path.basename(new_so) != os.path.basename(old_so)
+    assert native.available()
+    assert native._lib._name == new_so and os.path.exists(new_so)
+    assert not any(s.exists() for s in stale), "stale binaries linger"
+
+
+def test_failed_build_degrades_loudly(tmp_path, monkeypatch):
+    """A compiler failure falls back to NumPy and says so, quoting the
+    compiler, instead of planning 10x slower in silence."""
+    src = tmp_path / "planning.cpp"
+    src.write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_BUILD_DIR", str(tmp_path / "build"))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    if shutil.which("g++") is None:
+        match = "native planning library unavailable"
+    else:
+        match = r"(?s)native planning library unavailable.*g\+\+ exited.*error"
+    with pytest.warns(RuntimeWarning, match=match):
+        assert not native.available()
+    out = np.full(3, -1, dtype=np.int32)
+    assert not native.box_gids_to_lids(
+        np.arange(3), (4,), (0,), (4,), out
+    )
+
+
 def test_box_gids_to_lids_matches_fallback():
     rng = np.random.default_rng(0)
     grid, lo, hi = (13, 9, 17), (3, 0, 5), (11, 4, 16)

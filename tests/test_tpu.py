@@ -325,6 +325,66 @@ def test_multihost_helpers_single_host():
     assert pa.prun(driver, pa.tpu, 4)
 
 
+def test_float64_staged_without_x64_says_so_once(monkeypatch):
+    """A chip has no float64: without x64, staging narrows float64 host
+    data to float32 — once per process that is said out loud, and the
+    solver holds the tolerance to the float32 floor it really runs at
+    (a float64 `tol=1e-10` solve reports stalled, not converged)."""
+    import importlib
+    import warnings
+
+    import jax
+
+    tpu_mod = importlib.import_module("partitionedarrays_jl_tpu.parallel.tpu")
+    monkeypatch.setattr(tpu_mod, "_narrowing_noted", False)
+    backend = tpu_mod.TPUBackend(devices=jax.devices()[:4])
+    with jax.enable_x64(False):
+        with pytest.warns(RuntimeWarning, match="float32 on the device"):
+            a = tpu_mod._stage(backend, np.ones((4, 3)), 4)
+        assert a.dtype == np.float32
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            err, info = pa.prun(
+                poisson_fdm_driver, backend, (2, 2), (8, 8), tol=1e-10
+            )
+        said = [str(w.message) for w in caught]
+        assert not any("staging float64" in m for m in said), "said twice"
+        assert any("below the float32 resolution floor" in m for m in said)
+        assert info["tol_below_dtype_floor"] and not info["converged"]
+        assert err < 1e-4
+    # with x64 on, float64 stays float64 and nothing is said
+    monkeypatch.setattr(tpu_mod, "_narrowing_noted", False)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        assert tpu_mod._stage(backend, np.ones((4, 3)), 4).dtype == np.float64
+
+
+def test_topology_order_fallback_is_announced_not_swallowed(monkeypatch):
+    """When `mesh_utils` declines a part grid for the slice's physical
+    topology, list order is used WITH a warning naming the order (the
+    chip smoke fails on it); any other error is a defect and surfaces."""
+    from types import SimpleNamespace
+
+    from jax.experimental import mesh_utils
+
+    devs = [SimpleNamespace(platform="tpu", id=i) for i in range(4)]
+    backend = pa.TPUBackend(devices=devs)
+
+    def declines(grid, devices):
+        raise NotImplementedError("no assignment for this topology")
+
+    monkeypatch.setattr(mesh_utils, "create_device_mesh", declines)
+    with pytest.warns(UserWarning, match=r"list order \[0, 1, 2, 3\]"):
+        assert backend._topology_order(4, devs, (2, 2, 1)) == devs
+
+    def broken(grid, devices):
+        raise RuntimeError("a defect, not a declined grid")
+
+    monkeypatch.setattr(mesh_utils, "create_device_mesh", broken)
+    with pytest.raises(RuntimeError, match="a defect"):
+        backend._topology_order(4, devs, (2, 2, 1))
+
+
 def test_padded_frame_solver_parity(monkeypatch):
     """Force the real-TPU padded kernel frame on the CPU mesh (Pallas
     interpret mode): the compiled CG and SpMV must agree with the host

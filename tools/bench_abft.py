@@ -138,6 +138,7 @@ def main():
     sizes = list(DEVICE_SIZES if platform == "tpu" else HOST_SIZES)
     if "--n" in argv:
         sizes = [int(argv[argv.index("--n") + 1])]
+    pa.enable_compilation_cache()
     backend = TPUBackend(devices=jax.devices()[:1])
 
     rows = []

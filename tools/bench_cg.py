@@ -2,7 +2,7 @@
 
 The whole Krylov loop is one `lax.while_loop` program ending in host
 scalar fetches, so a K-iteration solve IS a K-step dependency chain —
-exactly the shape the relay-safe methodology wants (docs/performance.md):
+exactly the shape the differenced-chain protocol of bench.py wants:
 difference two iteration counts far apart, median of several rounds.
 
 Prints one line: per-iteration microseconds and the derived effective
@@ -30,6 +30,7 @@ def main():
     )
 
     n = int(os.environ.get("PA_BENCH_N", "192"))
+    pa.enable_compilation_cache()
     backend = TPUBackend(devices=jax.devices()[:1])
 
     def driver(parts):

@@ -37,6 +37,7 @@ def main():
     # the equal-box level — the Galerkin levels must take stencil_fast
     # with the wrapped-segment mask, not the assembled-matrix path)
     periodic = os.environ.get("PA_GMG_PERIODIC", "0") == "1"
+    pa.enable_compilation_cache()
     backend = TPUBackend(devices=jax.devices()[:1])
 
     def driver(parts):

@@ -104,7 +104,10 @@ def assemble_varcoef_poisson(parts, ns, pa, dtype=np.float32):
     I = pa.map_parts(lambda t: t[0], trip)
     J = pa.map_parts(lambda t: t[1], trip)
     V = pa.map_parts(lambda t: t[2], trip)
-    return pa.PSparseMatrix.from_coo(I, J, V, rows, rows.copy(), ids="global")
+    # cols = rows + the stencil's ghost layer, so the same assembler
+    # serves a multi-part grid (one part: no off-part gids, cols == rows)
+    cols = pa.add_gids(rows, J)
+    return pa.PSparseMatrix.from_coo(I, J, V, rows, cols, ids="global")
 
 
 def _curve(pa, dA, ks, bench):
@@ -154,6 +157,7 @@ def main():
     if "--n" in argv:
         n = int(argv[argv.index("--n") + 1])
     ks = [k for k in KS if k <= max(KS)]
+    pa.enable_compilation_cache()
     backend = TPUBackend(devices=jax.devices()[:1])
 
     # headline: streaming-DIA variable-coefficient operator

@@ -1,10 +1,9 @@
-"""Reproducibility study for the driver-recorded metrics (VERDICT r3
-directive 5): run the halo and SpMV legs of bench.py K times each in ONE
-process and print the distribution, so the documented bands come from a
-measured spread instead of round-to-round anecdotes, and so the halo
-value/ratio swing (11.1 GB/s / 137x in docs vs 20.3 GB/s / 65.4x in
-BENCH_r03) can be attributed to the device numerator or the host-oracle
-denominator.
+"""Reproducibility study for bench.py's metrics: run the halo and SpMV
+legs K times each in ONE process and print the distribution, so a
+documented range comes from a measured spread instead of round-to-round
+anecdotes, and so a swing in the halo value/ratio (round 3 read
+11.1 GB/s / 137x in one session and 20.3 GB/s / 65.4x in another) can
+be attributed to the device numerator or the host-oracle denominator.
 
 The committed record (``docs/repro_r5.json`` by default) goes through
 the shared schema-versioned artifact writer (`telemetry.artifacts`),
@@ -37,6 +36,7 @@ def main():
 
     reps = int(os.environ.get("PA_REPRO_REPS", "5"))
     n = int(os.environ.get("PA_BENCH_N", "192"))
+    pa.enable_compilation_cache()
     backend = TPUBackend(devices=jax.devices()[:1])
     out = {"n": n, "reps": reps, "halo": [], "halo_host_oracle": [],
            "spmv": [], "methodology": bench.METHODOLOGY}

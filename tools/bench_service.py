@@ -244,6 +244,8 @@ def main():
     bm = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bm)
 
+    pa.enable_compilation_cache()
+
     backend = TPUBackend(devices=jax.devices()[:1])
     A = pa.prun(
         lambda parts: bm.assemble_varcoef_poisson(

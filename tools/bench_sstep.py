@@ -14,7 +14,7 @@ its three single-RHS shapes on one multi-part mesh —
                  (``PA_TPU_OVERLAP``): same collectives as standard,
                  interior SpMV scheduled against the in-flight halo.
 
-Protocol: the relay-safe differenced marginal of tools/bench_cg.py —
+Protocol: the differenced marginal of tools/bench_cg.py —
 each body compiled ONCE per maxiter leg (tol=0 pins the trip count),
 warmed, median-of-5 executions per leg, two legs differenced, median
 of 3 rounds. The whole solve is one `lax.while_loop` ending in host
@@ -143,6 +143,7 @@ def main():
     if "--n" in argv:
         n = int(argv[argv.index("--n") + 1])
         ns = (n, n)
+    pa.enable_compilation_cache()
     backend = TPUBackend(devices=jax.devices()[: int(np.prod(PARTS))])
 
     def fixture(parts):

@@ -145,6 +145,8 @@ def main():
     from partitionedarrays_jl_tpu.telemetry import artifacts
     from partitionedarrays_jl_tpu.telemetry import commsmatrix as cm
 
+    pa.enable_compilation_cache()
+
     backend = TPUBackend(devices=jax.devices()[: int(np.prod(PARTS))])
     node_of = [int(x) for x in NODE_MAP.split(",")]
 

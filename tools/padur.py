@@ -304,6 +304,9 @@ def _wait_for(predicate, timeout_s, what):
 def _spawn_server(journal_dir, ckpt_dir, url_file, slab_delay):
     if os.path.exists(url_file):
         os.unlink(url_file)
+    # a host-only drill: the server subprocess is pinned to the CPU
+    # platform on purpose — the parent may hold the chip, which belongs
+    # to one process, and kill -9 durability does not depend on the device
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PA_GATE_JOURNAL_FSYNC="1",
                # patx: spans persist next to the journal so the drill

@@ -376,6 +376,10 @@ def _wait_for(predicate, timeout_s, what):
 
 
 def _spawn_replica(fleet_dir, replica, lease_s):
+    # a host-only drill: the replicas are pinned to the CPU platform on
+    # purpose — a chip belongs to one process, so N serve subprocesses
+    # can never share one, and what the drill proves (routing, leases,
+    # journal failover) does not depend on the device
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PA_GATE_JOURNAL_FSYNC="1", PA_TX="1",
                PA_TX_DIR=os.path.join(fleet_dir, "tx"),

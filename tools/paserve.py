@@ -94,25 +94,27 @@ def main(argv=None):
         tuple(args.parts) if args.parts else (2,) * len(grid)
     )
     if args.backend == "tpu":
+        # one part per device, on the devices JAX gives this process: a
+        # chip where there is one, the virtual CPU mesh where the caller
+        # asked for it (tier-1's conftest; by hand:
+        # JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8).
+        # Nothing here picks a platform.
+        import jax
+
         need = 1
         for p in parts_grid:
             need *= p
-        # standalone runs need the virtual CPU mesh (same setup as
-        # tools/patrace.py --diff-static); in-process tier-1 use
-        # inherits the conftest mesh. XLA_FLAGS acts at first backend
-        # init, so this works even when jax is already imported.
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags
-                + f" --xla_force_host_platform_device_count={max(need, 8)}"
-            ).strip()
-        import jax
-
-        if not os.environ.get("JAX_PLATFORMS"):
-            os.environ["JAX_PLATFORMS"] = "cpu"
-            jax.config.update("jax_platforms", "cpu")
-        backend = pa.TPUBackend(devices=jax.devices()[:need])
+        devices = jax.devices()
+        if need > len(devices):
+            ap.error(
+                f"--backend tpu: part grid {parts_grid} needs {need} "
+                f"devices and JAX found {len(devices)} ({devices[0].platform})"
+                "; pass a smaller --parts, or ask for the virtual CPU mesh "
+                "with JAX_PLATFORMS=cpu "
+                "XLA_FLAGS=--xla_force_host_platform_device_count=8"
+            )
+        pa.enable_compilation_cache()
+        backend = pa.TPUBackend(devices=devices[:need])
     else:
         backend = pa.sequential
 

@@ -28,6 +28,7 @@ def main():
     )
 
     n = int(os.environ.get("PA_SCALE_N", "464"))
+    pa.enable_compilation_cache()
     backend = TPUBackend(devices=jax.devices()[:1])
 
     def driver(parts):
