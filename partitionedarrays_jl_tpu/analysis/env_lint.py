@@ -215,11 +215,6 @@ NON_LOWERING: Dict[str, str] = {
         "phase-profiling timing repetitions — host-side measurement "
         "parameter of the standalone profiling chains"
     ),
-    "PA_PROF_TRACE": (
-        "phase-profiling capture-method selector (jax-trace vs "
-        "split-timer) — chooses how a standalone profile is measured, "
-        "never what a solver program stages"
-    ),
     "PA_GATE_MEM_BUDGET": (
         "front-door tenancy budget (frontdoor/tenancy.py) — bounds how "
         "many operators stay RESIDENT (LRU paging of whole tenants); "

@@ -142,6 +142,7 @@ def dia_spmv_pallas(
             vmem_limit_bytes=VMEM_LIMIT_BYTES
         ),
         interpret=interpret,
+        name="pa_dia_stream_spmv",
     )(vals, x)
 
 
@@ -537,6 +538,7 @@ def dia_coded_padded_pallas(
             ],
             compiler_params=params,
             interpret=interpret,
+            name="pa_dia_coded_spmv_pfold",
         )(codebook, no, codes, x, pprev, beta)
     if axpy is None:
         return pl.pallas_call(
@@ -548,6 +550,7 @@ def dia_coded_padded_pallas(
             scratch_shapes=scratch,
             compiler_params=params,
             interpret=interpret,
+            name="pa_dia_coded_spmv",
         )(codebook, no, codes, x)
     pprev, xacc, alpha = axpy
     assert pprev.shape == x.shape == xacc.shape
@@ -566,6 +569,7 @@ def dia_coded_padded_pallas(
         scratch_shapes=scratch,
         compiler_params=params,
         interpret=interpret,
+        name="pa_dia_coded_spmv_axpy",
     )(codebook, no, codes, x, pprev, xacc, alpha)
 
 

@@ -53,6 +53,41 @@ def _jax():
     return jax
 
 
+#: Named scopes of the compiled programs' phases: the vocabulary of
+#: `telemetry.profile.PHASES`, prefixed ``pa.``. A scope is op metadata
+#: only (the lowered StableHLO is the same with and without it); it puts
+#: the phase into every device op's ``op_name``, so a profile's device
+#: time has an owner that survives a recompile. Scopes nest and the
+#: innermost names the phase: the exchange inside an SpMV is
+#: ``pa.halo_exchange``, a dot inside a loop body is ``pa.dot_allgather``.
+SCOPE_SPMV = "pa.spmv_local"
+SCOPE_HALO = "pa.halo_exchange"
+SCOPE_DOTS = "pa.dot_allgather"
+SCOPE_AXPY = "pa.axpy_sweep"
+
+
+def _scoped(scope: str, fn: Callable) -> Callable:
+    """``fn`` traced under ``jax.named_scope(scope)``."""
+
+    def scoped(*args, **kwargs):
+        with _jax().named_scope(scope):
+            return fn(*args, **kwargs)
+
+    return scoped
+
+
+def _krylov_loop(cond, step, init):
+    """`lax.while_loop` of a compiled Krylov program, traced under
+    `SCOPE_AXPY`: what a step does outside its SpMV, exchange and dots
+    (each under its own, inner, scope) is the vector updates and the
+    packing and unpacking of the carry. The `while` op is inside the
+    scope too, so what XLA derives from it (the select it fuses around
+    an in-place carry update takes the `while`'s metadata) stays owned."""
+    jax = _jax()
+    with jax.named_scope(SCOPE_AXPY):
+        return jax.lax.while_loop(cond, step, init)
+
+
 _backend_tokens = itertools.count()
 
 
@@ -189,10 +224,15 @@ def _stage(backend: TPUBackend, arr: np.ndarray, nparts: int):
     between slices) every controller holds the same host-side plan and
     contributes just its local devices' rows; on one host it degenerates to
     a plain device_put."""
+    from .. import telemetry
+
     jax = _jax()
     _note_narrowing(jax, arr.dtype)
     sh = backend.sharding(nparts)
-    return jax.make_array_from_callback(arr.shape, sh, lambda idx: arr[idx])
+    with telemetry.annotate("pa:stage:put"):
+        return jax.make_array_from_callback(
+            arr.shape, sh, lambda idx: arr[idx]
+        )
 
 
 _narrowing_noted = False
@@ -673,11 +713,11 @@ def _shard_exchange(plan, combine: str, abft: bool = False):
                 cv = cv.at[strash].set(0)
             return cv[:W]
 
-        return body_twolevel
+        return _scoped(SCOPE_HALO, body_twolevel)
 
     if isinstance(plan, BoxExchangePlan):
         check(not abft, "ABFT exchange checksums require the generic plan")
-        return shard_box_exchange(plan, combine)
+        return _scoped(SCOPE_HALO, shard_box_exchange(plan, combine))
 
     R = plan.R
     perms = plan.perms
@@ -700,7 +740,7 @@ def _shard_exchange(plan, combine: str, abft: bool = False):
         return xv
 
     if not abft:
-        return body
+        return _scoped(SCOPE_HALO, body)
 
     def body_abft(xv, si, sm, ri):
         # delta/scale follow the operand rank: () or per-column (K,)
@@ -725,7 +765,7 @@ def _shard_exchange(plan, combine: str, abft: bool = False):
             xv = xv.at[g0:].set(0)
         return xv, delta, scale
 
-    return body_abft
+    return _scoped(SCOPE_HALO, body_abft)
 
 
 class DeviceVector:
@@ -741,17 +781,20 @@ class DeviceVector:
 
     @classmethod
     def from_pvector(cls, v: PVector, backend: TPUBackend, layout=None) -> "DeviceVector":
+        from .. import telemetry
+
         layout = layout or device_layout(v.rows, _padded_for(backend))
         o0, g0 = layout.o0, layout.g0
-        stacked = np.zeros((layout.P, layout.W), dtype=v.dtype)
-        for p, (iset, vals) in enumerate(
-            zip(v.rows.partition.part_values(), v.values.part_values())
-        ):
-            vals = np.asarray(vals)
-            stacked[p, o0 : o0 + iset.num_oids] = _owned(iset, vals)
-            # hid_slots, not g0+hid: the box layout reorders the ghost
-            # region into direction segments
-            stacked[p, layout.hid_slots[p]] = _ghost(iset, vals)
+        with telemetry.annotate("pa:stage:pack"):
+            stacked = np.zeros((layout.P, layout.W), dtype=v.dtype)
+            for p, (iset, vals) in enumerate(
+                zip(v.rows.partition.part_values(), v.values.part_values())
+            ):
+                vals = np.asarray(vals)
+                stacked[p, o0 : o0 + iset.num_oids] = _owned(iset, vals)
+                # hid_slots, not g0+hid: the box layout reorders the ghost
+                # region into direction segments
+                stacked[p, layout.hid_slots[p]] = _ghost(iset, vals)
         data = _stage(backend, stacked, layout.P)
         return cls(data, v.rows, layout, backend)
 
@@ -766,20 +809,23 @@ def _host_frame_to_pvector(host: np.ndarray, rows: PRange, layout) -> PVector:
     """A fetched (P, W) host frame lifted back to a PVector (shared by
     DeviceVector.to_pvector and the multi-RHS block unstaging, which
     fetches one (P, W, K) slab and lifts each column)."""
+    from .. import telemetry
+
     o0 = layout.o0
     vals = []
-    for p, iset in enumerate(rows.partition.part_values()):
-        owned = host[p, o0 : o0 + iset.num_oids]
-        ghost = host[p, layout.hid_slots[p]]
-        if iset.owned_first:
-            v = np.concatenate([owned, ghost])
-        else:
-            v = np.empty(iset.num_lids, dtype=host.dtype)
-            v[np.asarray(iset.oid_to_lid)] = owned
-            v[np.asarray(iset.hid_to_lid)] = ghost
-        vals.append(v)
-    parts = rows.partition
-    return PVector(parts._like(vals), rows)
+    with telemetry.annotate("pa:fetch:lift"):
+        for p, iset in enumerate(rows.partition.part_values()):
+            owned = host[p, o0 : o0 + iset.num_oids]
+            ghost = host[p, layout.hid_slots[p]]
+            if iset.owned_first:
+                v = np.concatenate([owned, ghost])
+            else:
+                v = np.empty(iset.num_lids, dtype=host.dtype)
+                v[np.asarray(iset.oid_to_lid)] = owned
+                v[np.asarray(iset.hid_to_lid)] = ghost
+            vals.append(v)
+        parts = rows.partition
+        return PVector(parts._like(vals), rows)
 
 
 def _padded_for(backend: TPUBackend) -> bool:
@@ -2549,7 +2595,7 @@ def _pdot_factory(o0: int, no_max: int):
                 acc = acc + allp[i]
             return acc
 
-        return pdot
+        return _scoped(SCOPE_DOTS, pdot)
 
     def pdot(a, b):
         partial_ = jnp.sum(
@@ -2558,7 +2604,7 @@ def _pdot_factory(o0: int, no_max: int):
         allp = jax.lax.all_gather(partial_, "parts")
         return jnp.sum(allp, axis=0)
 
-    return pdot
+    return _scoped(SCOPE_DOTS, pdot)
 
 
 def _pdot_owned_factory(no_max: int):
@@ -2601,7 +2647,7 @@ def _pdot_owned_factory(no_max: int):
                 acc2 = acc2 + allp[i, ..., 1]
             return acc1, acc2
 
-        return dot1, dot2
+        return dot1, _scoped(SCOPE_DOTS, dot2)
 
     def dot2(a, b, c, d):
         p_ = jnp.stack(
@@ -2610,7 +2656,7 @@ def _pdot_owned_factory(no_max: int):
         s = jnp.sum(jax.lax.all_gather(p_, "parts"), axis=0)
         return s[..., 0], s[..., 1]
 
-    return dot1, dot2
+    return dot1, _scoped(SCOPE_DOTS, dot2)
 
 
 def _pdot_extra_factory(o0: int, no_max: int):
@@ -2648,7 +2694,7 @@ def _pdot_extra_factory(o0: int, no_max: int):
                 acc[..., i + 1] for i in range(len(extras))
             )
 
-        return pdotx
+        return _scoped(SCOPE_DOTS, pdotx)
 
     def pdotx(a, b, extras):
         p0 = jnp.sum(a[o0 : o0 + no_max] * b[o0 : o0 + no_max], axis=0)
@@ -2659,7 +2705,7 @@ def _pdot_extra_factory(o0: int, no_max: int):
         s = jnp.sum(allp, axis=0)
         return s[..., 0], tuple(s[..., i + 1] for i in range(len(extras)))
 
-    return pdotx
+    return _scoped(SCOPE_DOTS, pdotx)
 
 
 def _pgram_factory(o0: int, no_max: int):
@@ -2689,7 +2735,7 @@ def _pgram_factory(o0: int, no_max: int):
         allp = jax.lax.all_gather(partial_, "parts")
         return jnp.sum(allp, axis=0)
 
-    return pgram
+    return _scoped(SCOPE_DOTS, pgram)
 
 
 def make_exchange_fn(rows: PRange, backend: TPUBackend, combine: str = "set") -> Callable:
@@ -3249,7 +3295,8 @@ def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
             xacc, pprev, alpha = ax
             colL = dA.col_plan.layout
             cs = slice(colL.o0, colL.o0 + colL.no_max)
-            xacc2 = xacc.at[cs].add(_rp(alpha * pprev[cs]))
+            with jax.named_scope(SCOPE_AXPY):
+                xacc2 = xacc.at[cs].add(_rp(alpha * pprev[cs]))
         y, xv, exd, exs = _finish(full, partial_, xv, m)
         if axpy:
             return y, xacc2
@@ -3286,17 +3333,21 @@ def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
         else:
             # beta is a scalar (K=1) or a (K,) per-column vector — both
             # broadcast against the trailing axis of the owned slice
-            z = _bc(mvv[cs], rv) * rv[cs] if mvv is not None else rv[cs]
-            pnew = jnp.zeros_like(rv).at[cs].set(z + _rp(beta * pv[cs]))
-            if aud is not None:
-                # audit trips stream A·x through the same call site; a
-                # non-audit trip selects the folded direction bit-exactly
-                pnew = jnp.where(aud, audx, pnew)
+            with jax.named_scope(SCOPE_AXPY):
+                z = _bc(mvv[cs], rv) * rv[cs] if mvv is not None else rv[cs]
+                pnew = jnp.zeros_like(rv).at[cs].set(
+                    z + _rp(beta * pv[cs])
+                )
+                if aud is not None:
+                    # audit trips stream A·x through the same call site;
+                    # a non-audit trip selects the folded direction
+                    # bit-exactly
+                    pnew = jnp.where(aud, audx, pnew)
             full, partial_ = _aoo(pnew, m)
         y, xpost, exd, exs = _finish(full, partial_, pnew, m)
         return (y, pnew, xpost, exd, exs) if abft else (y, pnew)
 
-    return body_pfold if pfold else body
+    return _scoped(SCOPE_SPMV, body_pfold if pfold else body)
 
 
 def _shard_ops(jax, ms):
@@ -3929,7 +3980,7 @@ def make_cg_fn(
                         init_fs = init_fs + (
                             jnp.zeros((Ht, 2), dtype=bv.dtype),
                         )
-                    fin = jax.lax.while_loop(cond_fs, step_fs, init_fs)
+                    fin = _krylov_loop(cond_fs, step_fs, init_fs)
                     S, rs, it, hist, sdcst = (
                         fin[0], fin[2], fin[4], fin[5], fin[6]
                     )
@@ -4044,7 +4095,7 @@ def make_cg_fn(
                     init_ss = init_ss + (
                         jnp.zeros((Ht, 2), dtype=bv.dtype),
                     )
-                fin = jax.lax.while_loop(cond_ss, step_ss, init_ss)
+                fin = _krylov_loop(cond_ss, step_ss, init_ss)
                 x, rs, it, hist, sdcst = (
                     fin[0], fin[4], fin[5], fin[6], fin[7]
                 )
@@ -4117,7 +4168,7 @@ def make_cg_fn(
                 init_f = (S0, rz0, rs0, zero, jnp.int32(0), hist)
                 if Ht:
                     init_f = init_f + (jnp.zeros((Ht, 2), dtype=bv.dtype),)
-                fin = jax.lax.while_loop(cond_fused, step_fused, init_f)
+                fin = _krylov_loop(cond_fused, step_fused, init_f)
                 S, rs, it, hist = fin[0], fin[2], fin[4], fin[5]
                 out = (S[0][None], rs, rs0, it, hist)
                 return out + ((fin[6],) if Ht else ())
@@ -4261,7 +4312,7 @@ def make_cg_fn(
                     init_ss = init_ss + (
                         jnp.zeros((Ht, 2), dtype=bv.dtype),
                     )
-                fin = jax.lax.while_loop(cond, step_ss, init_ss)
+                fin = _krylov_loop(cond, step_ss, init_ss)
                 x, rs, it, hist = fin[0], fin[4], fin[5], fin[6]
                 out = (x[None], rs, rs0, it, hist)
                 return out + ((fin[7],) if Ht else ())
@@ -4270,7 +4321,7 @@ def make_cg_fn(
                 init_s = (xv, r, p, rz0, rs0, jnp.int32(0), hist)
                 if Ht:
                     init_s = init_s + (jnp.zeros((Ht, 2), dtype=bv.dtype),)
-                fin = jax.lax.while_loop(cond, step, init_s)
+                fin = _krylov_loop(cond, step, init_s)
                 x, rs, it, hist = fin[0], fin[4], fin[5], fin[6]
                 out = (x[None], rs, rs0, it, hist)
                 return out + ((fin[7],) if Ht else ())
@@ -4304,7 +4355,7 @@ def make_cg_fn(
                 return (x, r, p_new, p, alpha, rs_new, it + 1, hist)
 
             zero = jnp.zeros((), bv.dtype)
-            x, r, p, p_prev, alpha_prev, rs, it, hist = jax.lax.while_loop(
+            x, r, p, p_prev, alpha_prev, rs, it, hist = _krylov_loop(
                 cond_pipe, step_pipe,
                 (xv, r, p, jnp.zeros_like(p), zero, rs0, jnp.int32(0), hist),
             )
@@ -4732,7 +4783,7 @@ def make_block_cg_fn(
                         return (S3, rz3, rs3, beta3, itk3, it3, hist2, sdc2)
 
                     S, rz, rs, beta, itk, it, hist, sdcst = (
-                        jax.lax.while_loop(
+                        _krylov_loop(
                             cond_fs, step_fs,
                             (S0, rz0, rs0, beta0, it0, jnp.int32(0),
                              hist, sdc0),
@@ -4851,7 +4902,7 @@ def make_block_cg_fn(
                     )
                     return (x3, r3, p3, rz3, rs3, itk3, it3, hist2, sdc2)
 
-                x, r, p, rz, rs, itk, it, hist, sdcst = jax.lax.while_loop(
+                x, r, p, rz, rs, itk, it, hist, sdcst = _krylov_loop(
                     cond_ss, step_ss,
                     (xv, r, p, rz0, rs0, it0, jnp.int32(0), hist, sdc0),
                 )
@@ -4913,7 +4964,7 @@ def make_block_cg_fn(
                     init_f = init_f + (
                         jnp.zeros((Ht, 2, K), dtype=bv.dtype),
                     )
-                fin = jax.lax.while_loop(cond_f, step_f, init_f)
+                fin = _krylov_loop(cond_f, step_f, init_f)
                 S, rs, itk, hist = fin[0], fin[2], fin[4], fin[6]
                 out = (S[0][None], rs, rs0, itk, hist)
                 return out + ((fin[7],) if Ht else ())
@@ -4966,7 +5017,7 @@ def make_block_cg_fn(
             init_s = (xv, r, p, rz0, rs0, it0, jnp.int32(0), hist)
             if Ht:
                 init_s = init_s + (jnp.zeros((Ht, 2, K), dtype=bv.dtype),)
-            fin = jax.lax.while_loop(cond, step, init_s)
+            fin = _krylov_loop(cond, step, init_s)
             x, rs, itk, hist = fin[0], fin[4], fin[5], fin[7]
             out = (x[None], rs, rs0, itk, hist)
             return out + ((fin[8],) if Ht else ())
@@ -5210,7 +5261,7 @@ def make_bicgstab_fn(
                 jnp.bool_(True), hist,
             )
             x, r, p, v, rho, alpha, omega, rs, it, ok, hist = (
-                jax.lax.while_loop(cond, step, state)
+                _krylov_loop(cond, step, state)
             )
             return x[None], rs, rs0, it, hist
 
@@ -5381,7 +5432,7 @@ def make_gmres_fn(
                 cs = jnp.zeros(m, dtype=dt)
                 sn = jnp.zeros(m, dtype=dt)
                 g = jnp.zeros(m + 1, dtype=dt).at[0].set(beta)
-                V, R, cs, sn, g, j, it, hist, res, ok = jax.lax.while_loop(
+                V, R, cs, sn, g, j, it, hist, res, ok = _krylov_loop(
                     inner_cond, inner_step,
                     (V, R, cs, sn, g, jnp.int32(0), it, hist,
                      jnp.asarray(beta, dt), jnp.bool_(True)),
@@ -5401,7 +5452,7 @@ def make_gmres_fn(
                 hist = hist.at[jnp.minimum(it, H - 1)].set(res)
                 return (x, r, it, res, hist, ok)
 
-            x, r_c, it, res, hist, ok = jax.lax.while_loop(
+            x, r_c, it, res, hist, ok = _krylov_loop(
                 outer_cond, outer_step,
                 (xv, r0, jnp.int32(0), jnp.sqrt(rs0), hist, jnp.bool_(True)),
             )
@@ -5546,7 +5597,7 @@ def make_minres_fn(dA: DeviceMatrix, tol: float, maxiter: int) -> Callable:
                 beta0, 0 * one, beta0, jnp.int32(0), jnp.bool_(True), hist,
             )
             (x, v, v_old, w, w_old, c_old, s_old, c, s, eta, beta_k, res,
-             it, ok, hist) = jax.lax.while_loop(cond, step, state)
+             it, ok, hist) = _krylov_loop(cond, step, state)
             return x[None], res * res, rs0, it, hist
 
         return shard_map(
@@ -5701,7 +5752,7 @@ def make_chebyshev_fn(
                 )
                 return (x, r, d, rho, rs, it, hist)
 
-            x, r, d, rho, rs, it, hist = jax.lax.while_loop(
+            x, r, d, rho, rs, it, hist = _krylov_loop(
                 cond,
                 step,
                 (xv, r, d, 1.0 / sigma1, rs0, jnp.int32(0), hist),
@@ -5831,6 +5882,32 @@ def _decode_sdc_outputs(name: str, sdcvec, it=None) -> dict:
     return sdc_info
 
 
+def _count_staged(*frames) -> None:
+    """The ``solve.*`` staging counters of one device solve: the call,
+    and the bytes of the device frames its vectors went into (``None``
+    entries skipped)."""
+    from .. import telemetry
+
+    telemetry.bump("solve.calls")
+    telemetry.bump(
+        "solve.staged_bytes",
+        sum(int(f.nbytes) for f in frames if f is not None),
+    )
+
+
+def _outputs_to_host(out) -> list:
+    """A compiled solve's outputs as host arrays (the answer frame,
+    first, through `fetch_global`), under the ``pa:fetch:d2h`` span,
+    their bytes counted in ``solve.fetched_bytes``."""
+    from .. import telemetry
+    from .multihost import fetch_global
+
+    with telemetry.annotate("pa:fetch:d2h"):
+        host = [fetch_global(out[0])] + [np.asarray(o) for o in out[1:]]
+    telemetry.bump("solve.fetched_bytes", sum(h.nbytes for h in host))
+    return host
+
+
 def _run_krylov(A, b, x0, tol, verbose, solve, minv=None, name="cg",
                 info_extra=None):
     """Shared device-Krylov driver: stage vectors in the matrix's col
@@ -5838,7 +5915,12 @@ def _run_krylov(A, b, x0, tol, verbose, solve, minv=None, name="cg",
     host PVector. The info dict matches the host solvers' contract:
     `residuals` has iterations+1 entries (capped at the compiled history
     length); ``info_extra`` keys (e.g. the CG body variant) merge into
-    it."""
+    it.
+
+    Every boundary is a `telemetry.annotate` span (stage with its
+    operator / pack / put leaves, solve = the dispatch, wait, fetch with
+    its d2h / lift leaves, finish), so the host time of a call has an
+    owner in a profile and in ``info.record.timings``."""
     from .. import telemetry
     from ..utils.helpers import krylov_info, warn_tol_below_floor
 
@@ -5847,7 +5929,8 @@ def _run_krylov(A, b, x0, tol, verbose, solve, minv=None, name="cg",
     floor_warned = warn_tol_below_floor(tol, dev_dtype, name=name)
     rec = telemetry.current_record()
     with telemetry.annotate(f"pa:{name}:stage"):
-        dA = device_matrix(A, backend)
+        with telemetry.annotate("pa:stage:operator"):
+            dA = device_matrix(A, backend)
         x0 = x0 if x0 is not None else PVector.full(
             0.0, A.cols, dtype=b.dtype
         )
@@ -5858,91 +5941,98 @@ def _run_krylov(A, b, x0, tol, verbose, solve, minv=None, name="cg",
             if minv is not None
             else None
         )
+        _count_staged(
+            db.data, dx0.data, None if dmv is None else dmv.data
+        )
     with telemetry.annotate(f"pa:{name}:solve"):
         if dmv is not None:
             out = solve(db.data, dx0.data, dmv.data)
         else:
             out = solve(db.data, dx0.data)
-    out = list(out)
-    x_data, rs, rs0, it, hist = out[:5]
-    k = 5
-    sdcvec = None
-    if getattr(solve, "has_sdc", False):
-        sdcvec = out[k]
-        k += 1
-    trace_n = int(getattr(solve, "trace_iters", 0))
-    ab = out[k] if trace_n else None
-    x = DeviceVector(x_data, A.cols, dA.col_layout, backend).to_pvector()
-    rs, rs0, it = float(rs), float(rs0), int(it)
-    residuals = np.asarray(hist)[: min(it + 1, len(np.asarray(hist)))]
-    if rec is not None and rec.enabled:
-        # attach BEFORE the typed-raise paths below: an aborted record
-        # still carries its trace and comms accounting for post-mortems
-        if ab is not None:
-            abh = np.asarray(ab)
-            n = min(it, trace_n)
-            if it > trace_n:
-                # true ring: the buffer holds the LAST trace_n committed
-                # iterations, rotated — unroll so entry j is absolute
-                # iteration trace_start + j
-                abh = np.roll(abh, -(it % trace_n), axis=0)
-                rec.trace_start = it - trace_n
-            rec.alpha = [float(v) for v in abh[:n, 0]]
-            rec.beta = [float(v) for v in abh[:n, 1]]
-        ck = getattr(solve, "comms_kwargs", None)
-        if ck is not None:
-            profile = telemetry.cg_comms_profile(dA, b.dtype, **ck)
-            # the SDC-defended loop pays its per-iteration collectives
-            # on EVERY while trip (commit, audit, restore alike) — the
-            # wire accounting counts trips, not committed iterations
-            comm_it = (
-                int(np.asarray(sdcvec)[4]) if sdcvec is not None else it
+    with telemetry.annotate(f"pa:{name}:wait"):
+        # the host waits here while the device works, so that the fetch
+        # below times the copy and not the solve
+        out = _jax().block_until_ready(list(out))
+    with telemetry.annotate(f"pa:{name}:fetch"):
+        out = _outputs_to_host(out)
+        x = _host_frame_to_pvector(out[0], A.cols, dA.col_layout)
+    with telemetry.annotate(f"pa:{name}:finish"):
+        rs, rs0, it, hist = out[1:5]
+        k = 5
+        sdcvec = None
+        if getattr(solve, "has_sdc", False):
+            sdcvec = out[k]
+            k += 1
+        trace_n = int(getattr(solve, "trace_iters", 0))
+        ab = out[k] if trace_n else None
+        rs, rs0, it = float(rs), float(rs0), int(it)
+        residuals = hist[: min(it + 1, len(hist))]
+        if rec is not None and rec.enabled:
+            # attach BEFORE the typed-raise paths below: an aborted record
+            # still carries its trace and comms accounting for post-mortems
+            if ab is not None:
+                abh = ab
+                n = min(it, trace_n)
+                if it > trace_n:
+                    # true ring: the buffer holds the LAST trace_n committed
+                    # iterations, rotated — unroll so entry j is absolute
+                    # iteration trace_start + j
+                    abh = np.roll(abh, -(it % trace_n), axis=0)
+                    rec.trace_start = it - trace_n
+                rec.alpha = [float(v) for v in abh[:n, 0]]
+                rec.beta = [float(v) for v in abh[:n, 1]]
+            ck = getattr(solve, "comms_kwargs", None)
+            if ck is not None:
+                profile = telemetry.cg_comms_profile(dA, b.dtype, **ck)
+                # the SDC-defended loop pays its per-iteration collectives
+                # on EVERY while trip (commit, audit, restore alike) — the
+                # wire accounting counts trips, not committed iterations
+                comm_it = int(sdcvec[4]) if sdcvec is not None else it
+                rec.comms = telemetry.observed_comms(profile, comm_it)
+        if verbose:
+            for i, r in enumerate(residuals[1:], start=1):
+                print(f"{name} it={i} residual={r:.3e}")
+        from .health import NonFiniteError, health_enabled
+
+        if sdcvec is not None:
+            info_extra = {
+                **(info_extra or {}),
+                "sdc": _decode_sdc_outputs(name, sdcvec, it=it),
+            }
+
+        if health_enabled() and not (np.isfinite(rs) and np.isfinite(rs0)):
+            # the compiled loop exited on its in-graph finite guard (one
+            # iteration after the poison entered); surface it typed, with
+            # the history tail as the diagnostic
+            raise NonFiniteError(
+                f"{name}: non-finite residual after {it} device iterations "
+                f"(rs={rs!r}) — solver state was NaN/Inf-poisoned",
+                diagnostics={
+                    "context": name,
+                    "iteration": it,
+                    "rs": rs,
+                    "residual_tail": [float(v) for v in residuals[-4:]],
+                },
             )
-            rec.comms = telemetry.observed_comms(profile, comm_it)
-    if verbose:
-        for i, r in enumerate(residuals[1:], start=1):
-            print(f"{name} it={i} residual={r:.3e}")
-    from .health import NonFiniteError, health_enabled
-
-    if sdcvec is not None:
-        info_extra = {
+        converged = bool(np.sqrt(rs) <= tol * max(1.0, np.sqrt(rs0)))
+        info = krylov_info(
+            it, residuals, converged, tol, dev_dtype, floor_warned,
+            final_rel=_final_true_rel(
+                A, x, b, np.sqrt(rs) / max(1.0, np.sqrt(rs0)), np.sqrt(rs0),
+                tol, force=floor_warned,
+            ),
             **(info_extra or {}),
-            "sdc": _decode_sdc_outputs(name, sdcvec, it=it),
-        }
-
-    if health_enabled() and not (np.isfinite(rs) and np.isfinite(rs0)):
-        # the compiled loop exited on its in-graph finite guard (one
-        # iteration after the poison entered); surface it typed, with
-        # the history tail as the diagnostic
-        raise NonFiniteError(
-            f"{name}: non-finite residual after {it} device iterations "
-            f"(rs={rs!r}) — solver state was NaN/Inf-poisoned",
-            diagnostics={
-                "context": name,
-                "iteration": it,
-                "rs": rs,
-                "residual_tail": [float(v) for v in residuals[-4:]],
-            },
         )
-    converged = bool(np.sqrt(rs) <= tol * max(1.0, np.sqrt(rs0)))
-    info = krylov_info(
-        it, residuals, converged, tol, dev_dtype, floor_warned,
-        final_rel=_final_true_rel(
-            A, x, b, np.sqrt(rs) / max(1.0, np.sqrt(rs0)), np.sqrt(rs0),
-            tol, force=floor_warned,
-        ),
-        **(info_extra or {}),
-    )
-    # paspec: spectral estimate (α/β ring when carried, residual-history
-    # rate always) + anomaly detection — host-side, on the still-active
-    # record so convergence_anomaly events land in it. CG family ONLY:
-    # the store's Lanczos/κ-rate semantics are CG's, and a bicgstab
-    # rate EWMAing into the same key would skew CG forecasts
-    if name in ("cg", "pcg"):
-        telemetry.observe_solve(
-            A, rec, info=info, dtype=b.dtype, minv=minv
-        )
-    return x, info
+        # paspec: spectral estimate (α/β ring when carried, residual-history
+        # rate always) + anomaly detection — host-side, on the still-active
+        # record so convergence_anomaly events land in it. CG family ONLY:
+        # the store's Lanczos/κ-rate semantics are CG's, and a bicgstab
+        # rate EWMAing into the same key would skew CG forecasts
+        if name in ("cg", "pcg"):
+            telemetry.observe_solve(
+                A, rec, info=info, dtype=b.dtype, minv=minv
+            )
+        return x, info
 
 
 def _final_true_rel(A, x, b, rel_est, rs0_norm, tol, force=False):
@@ -6007,20 +6097,23 @@ def _block_on_cols_layout(Bs, dA: DeviceMatrix, with_ghosts: bool = False):
     """Stage K column PVectors as ONE (P, W, K) device slab in the
     matrix's col layout (owned values; ``with_ghosts`` also places the
     ghost slots — used for start vectors that already carry a halo)."""
+    from .. import telemetry
+
     layout = dA.col_layout
     K = len(Bs)
     dt = np.result_type(*[b.dtype for b in Bs])
-    stacked = np.zeros((layout.P, layout.W, K), dtype=dt)
-    for k, b in enumerate(Bs):
-        for p, (iset, vals) in enumerate(
-            zip(b.rows.partition.part_values(), b.values.part_values())
-        ):
-            vals = np.asarray(vals)
-            stacked[p, layout.o0 : layout.o0 + iset.num_oids, k] = _owned(
-                iset, vals
-            )
-            if with_ghosts:
-                stacked[p, layout.hid_slots[p], k] = _ghost(iset, vals)
+    with telemetry.annotate("pa:stage:pack"):
+        stacked = np.zeros((layout.P, layout.W, K), dtype=dt)
+        for k, b in enumerate(Bs):
+            for p, (iset, vals) in enumerate(
+                zip(b.rows.partition.part_values(), b.values.part_values())
+            ):
+                vals = np.asarray(vals)
+                stacked[
+                    p, layout.o0 : layout.o0 + iset.num_oids, k
+                ] = _owned(iset, vals)
+                if with_ghosts:
+                    stacked[p, layout.hid_slots[p], k] = _ghost(iset, vals)
     return _stage(dA.backend, stacked, layout.P)
 
 
@@ -6091,10 +6184,11 @@ def _tpu_block_cg_impl(
 ):
     from .. import telemetry
     from ..utils.helpers import krylov_info, warn_tol_below_floor
-    from .multihost import fetch_global
 
+    # the spans of `_run_krylov`, boundary for boundary
     with telemetry.annotate(f"pa:{name}:stage"):
-        dA = device_matrix(A, backend)
+        with telemetry.annotate("pa:stage:operator"):
+            dA = device_matrix(A, backend)
         solve = _krylov_fn_for(
             dA, "cg", tol, maxiter, precond=minv is not None, fused=fused,
             rhs_batch=K,
@@ -6115,165 +6209,171 @@ def _tpu_block_cg_impl(
             if minv is not None
             else None
         )
+        _count_staged(db, dx0, None if dmv is None else dmv.data)
     with telemetry.annotate(f"pa:{name}:solve"):
         if dmv is not None:
             out = solve(db, dx0, dmv.data)
         else:
             out = solve(db, dx0)
-    out = list(out)
-    x_data, rs, rs0, itk, hist = out[:5]
-    k_out = 5
-    sdcvec = None
-    if getattr(solve, "has_sdc", False):
-        sdcvec = out[k_out]
-        k_out += 1
-    trace_n = int(getattr(solve, "trace_iters", 0))
-    ab = out[k_out] if trace_n else None
-    if rec is not None and rec.enabled:
-        trips = (
-            int(np.asarray(sdcvec)[4])
+    with telemetry.annotate(f"pa:{name}:wait"):
+        out = _jax().block_until_ready(list(out))
+    with telemetry.annotate(f"pa:{name}:fetch"):
+        out = _outputs_to_host(out)
+        xs = [
+            _host_frame_to_pvector(out[0][..., k], A.cols, dA.col_layout)
+            for k in range(K)
+        ]
+    with telemetry.annotate(f"pa:{name}:finish"):
+        rs, rs0, itk, hist = out[1:5]
+        k_out = 5
+        sdcvec = None
+        if getattr(solve, "has_sdc", False):
+            sdcvec = out[k_out]
+            k_out += 1
+        trace_n = int(getattr(solve, "trace_iters", 0))
+        ab = out[k_out] if trace_n else None
+        if rec is not None and rec.enabled:
+            trips = (
+                int(np.asarray(sdcvec)[4])
+                if sdcvec is not None
+                else int(np.asarray(itk).max())
+            )
+            if ab is not None:
+                abh = np.asarray(ab)  # (Ht, 2, K)
+                # ring slots are indexed by the GLOBAL trip counter, which
+                # equals the slowest column's committed count
+                itks = np.asarray(itk).astype(int).ravel()
+                itmax = int(itks.max())
+                n = min(itmax, trace_n)
+                if itmax > trace_n:
+                    abh = np.roll(abh, -(itmax % trace_n), axis=0)
+                    rec.trace_start = itmax - trace_n
+                # per-column traces: alpha[k]/beta[k] is column k's list;
+                # entries on trips AFTER column k converged are the frozen
+                # α=0/stale-β selects, not recurrence values — masked None
+                rec.alpha = [
+                    [
+                        float(abh[j, 0, k])
+                        if rec.trace_start + j < itks[k] else None
+                        for j in range(n)
+                    ]
+                    for k in range(K)
+                ]
+                rec.beta = [
+                    [
+                        float(abh[j, 1, k])
+                        if rec.trace_start + j < itks[k] else None
+                        for j in range(n)
+                    ]
+                    for k in range(K)
+                ]
+            ck = getattr(solve, "comms_kwargs", None)
+            if ck is not None:
+                profile = telemetry.cg_comms_profile(dA, dt, **ck)
+                rec.comms = telemetry.observed_comms(profile, trips)
+        sdc_info = (
+            _decode_sdc_outputs("block-cg", sdcvec)
             if sdcvec is not None
-            else int(np.asarray(itk).max())
+            else None
         )
-        if ab is not None:
-            abh = np.asarray(ab)  # (Ht, 2, K)
-            # ring slots are indexed by the GLOBAL trip counter, which
-            # equals the slowest column's committed count
-            itks = np.asarray(itk).astype(int).ravel()
-            itmax = int(itks.max())
-            n = min(itmax, trace_n)
-            if itmax > trace_n:
-                abh = np.roll(abh, -(itmax % trace_n), axis=0)
-                rec.trace_start = itmax - trace_n
-            # per-column traces: alpha[k]/beta[k] is column k's list;
-            # entries on trips AFTER column k converged are the frozen
-            # α=0/stale-β selects, not recurrence values — masked None
-            rec.alpha = [
-                [
-                    float(abh[j, 0, k])
-                    if rec.trace_start + j < itks[k] else None
-                    for j in range(n)
-                ]
-                for k in range(K)
-            ]
-            rec.beta = [
-                [
-                    float(abh[j, 1, k])
-                    if rec.trace_start + j < itks[k] else None
-                    for j in range(n)
-                ]
-                for k in range(K)
-            ]
-        ck = getattr(solve, "comms_kwargs", None)
-        if ck is not None:
-            profile = telemetry.cg_comms_profile(dA, dt, **ck)
-            rec.comms = telemetry.observed_comms(profile, trips)
-    sdc_info = (
-        _decode_sdc_outputs("block-cg", sdcvec)
-        if sdcvec is not None
-        else None
-    )
-    host = fetch_global(x_data)  # (P, W, K)
-    rs = np.asarray(rs, dtype=np.float64)
-    rs0 = np.asarray(rs0, dtype=np.float64)
-    itk = np.asarray(itk, dtype=np.int64)
-    hist = np.asarray(hist)
-    xs, columns = [], []
-    name = "block-pcg" if minv is not None else "block-cg"
-    for k in range(K):
-        x = _host_frame_to_pvector(host[..., k], A.cols, dA.col_layout)
-        xs.append(x)
-        it_k = int(itk[k])
-        residuals = hist[: min(it_k + 1, hist.shape[0]), k]
-        if verbose:
-            for i, rv in enumerate(residuals[1:], start=1):
-                print(f"{name} col={k} it={i} residual={rv:.3e}")
-        converged = bool(
-            np.sqrt(rs[k]) <= tol * max(1.0, np.sqrt(rs0[k]))
-        )
-        columns.append(
-            krylov_info(
-                it_k, residuals, converged, tol, dev_dtype, floor_warned,
-                final_rel=_final_true_rel(
-                    A, x, B[k],
-                    np.sqrt(rs[k]) / max(1.0, np.sqrt(rs0[k])),
-                    np.sqrt(rs0[k]), tol, force=floor_warned,
-                ),
+        rs = np.asarray(rs, dtype=np.float64)
+        rs0 = np.asarray(rs0, dtype=np.float64)
+        itk = np.asarray(itk, dtype=np.int64)
+        hist = np.asarray(hist)
+        columns = []
+        for k in range(K):
+            x = xs[k]
+            it_k = int(itk[k])
+            residuals = hist[: min(it_k + 1, hist.shape[0]), k]
+            if verbose:
+                for i, rv in enumerate(residuals[1:], start=1):
+                    print(f"{name} col={k} it={i} residual={rv:.3e}")
+            converged = bool(
+                np.sqrt(rs[k]) <= tol * max(1.0, np.sqrt(rs0[k]))
             )
-        )
-    from .health import NonFiniteError, health_enabled
+            columns.append(
+                krylov_info(
+                    it_k, residuals, converged, tol, dev_dtype, floor_warned,
+                    final_rel=_final_true_rel(
+                        A, x, B[k],
+                        np.sqrt(rs[k]) / max(1.0, np.sqrt(rs0[k])),
+                        np.sqrt(rs0[k]), tol, force=floor_warned,
+                    ),
+                )
+            )
+        from .health import NonFiniteError, health_enabled
 
-    # per-column verdict export: the service's chunk-boundary contract
-    # (status is per column, so ONE poisoned request never forces its
-    # co-batched neighbors onto an error path). PA_HEALTH_CHECKS=0
-    # disables the verdict along with the guards — matching the host
-    # oracle, where no SolverHealthError fires (and so no verdict is
-    # recorded) with health off — so the two per-column exports never
-    # disagree.
-    bad = (
-        [k for k in range(K) if not np.isfinite(rs[k])]
-        if health_enabled()
-        else []
-    )
-    column_health = [
-        {
-            "status": "nonfinite" if k in bad else "ok",
-            "converged": bool(columns[k]["converged"]),
-            "iterations": int(itk[k]),
+        # per-column verdict export: the service's chunk-boundary contract
+        # (status is per column, so ONE poisoned request never forces its
+        # co-batched neighbors onto an error path). PA_HEALTH_CHECKS=0
+        # disables the verdict along with the guards — matching the host
+        # oracle, where no SolverHealthError fires (and so no verdict is
+        # recorded) with health off — so the two per-column exports never
+        # disagree.
+        bad = (
+            [k for k in range(K) if not np.isfinite(rs[k])]
+            if health_enabled()
+            else []
+        )
+        column_health = [
+            {
+                "status": "nonfinite" if k in bad else "ok",
+                "converged": bool(columns[k]["converged"]),
+                "iterations": int(itk[k]),
+            }
+            for k in range(K)
+        ]
+        if bad:
+            if column_errors == "report":
+                for k in bad:
+                    columns[k]["status"] = "nonfinite"
+                    columns[k]["converged"] = False
+                telemetry.emit_event(
+                    "column_verdict", label=name, columns=bad,
+                    iterations=[int(itk[k]) for k in bad],
+                )
+            else:
+                raise NonFiniteError(
+                    f"{name}: non-finite residual in column(s) {bad} — those "
+                    "columns' solver state was NaN/Inf-poisoned (each froze one "
+                    "iteration after the poison entered; the other columns "
+                    "completed normally)",
+                    diagnostics={
+                        "context": name,
+                        "columns": bad,
+                        "iterations": [int(itk[k]) for k in bad],
+                        "rs": [float(rs[k]) for k in bad],
+                    },
+                )
+        # the aggregate's "worst" column: an UNCONVERGED column wins over a
+        # merely-slow converged one (a broken-down column frozen at 3
+        # iterations must not let argmax(iterations) stamp the aggregate
+        # status 'converged' while converged is False)
+        bad_cols = [k for k in range(K) if not columns[k]["converged"]]
+        worst = (
+            max(bad_cols, key=lambda k: int(itk[k]))
+            if bad_cols
+            else int(np.argmax(itk))
+        )
+        info = {
+            "iterations": int(itk.max()),
+            "iterations_per_column": [int(v) for v in itk],
+            "residuals": columns[worst]["residuals"],
+            "converged": not bad_cols,
+            "status": columns[worst]["status"],
+            "columns": columns,
+            "column_health": column_health,
+            "rhs_batch": K,
+            "cg_body": "fused" if fused else "standard",
         }
-        for k in range(K)
-    ]
-    if bad:
-        if column_errors == "report":
-            for k in bad:
-                columns[k]["status"] = "nonfinite"
-                columns[k]["converged"] = False
-            telemetry.emit_event(
-                "column_verdict", label=name, columns=bad,
-                iterations=[int(itk[k]) for k in bad],
-            )
-        else:
-            raise NonFiniteError(
-                f"{name}: non-finite residual in column(s) {bad} — those "
-                "columns' solver state was NaN/Inf-poisoned (each froze one "
-                "iteration after the poison entered; the other columns "
-                "completed normally)",
-                diagnostics={
-                    "context": name,
-                    "columns": bad,
-                    "iterations": [int(itk[k]) for k in bad],
-                    "rs": [float(rs[k]) for k in bad],
-                },
-            )
-    # the aggregate's "worst" column: an UNCONVERGED column wins over a
-    # merely-slow converged one (a broken-down column frozen at 3
-    # iterations must not let argmax(iterations) stamp the aggregate
-    # status 'converged' while converged is False)
-    bad_cols = [k for k in range(K) if not columns[k]["converged"]]
-    worst = (
-        max(bad_cols, key=lambda k: int(itk[k]))
-        if bad_cols
-        else int(np.argmax(itk))
-    )
-    info = {
-        "iterations": int(itk.max()),
-        "iterations_per_column": [int(v) for v in itk],
-        "residuals": columns[worst]["residuals"],
-        "converged": not bad_cols,
-        "status": columns[worst]["status"],
-        "columns": columns,
-        "column_health": column_health,
-        "rhs_batch": K,
-        "cg_body": "fused" if fused else "standard",
-    }
-    if sdc_info is not None:
-        info["sdc"] = sdc_info
-    if floor_warned:
-        info["tol_below_dtype_floor"] = True
-    # paspec: per-column spectral estimates from the block ring (masked
-    # post-convergence trips truncate), host-side, before rec.finish
-    telemetry.observe_solve(A, rec, info=info, dtype=dt, minv=minv)
-    return xs, info
+        if sdc_info is not None:
+            info["sdc"] = sdc_info
+        if floor_warned:
+            info["tol_below_dtype_floor"] = True
+        # paspec: per-column spectral estimates from the block ring (masked
+        # post-convergence trips truncate), host-side, before rec.finish
+        telemetry.observe_solve(A, rec, info=info, dtype=dt, minv=minv)
+        return xs, info
 
 
 def tpu_bicgstab(
@@ -6398,15 +6498,17 @@ def _krylov_fn_for(
 def _b_on_cols_layout(b: PVector, dA: DeviceMatrix) -> DeviceVector:
     """b lives on A.rows (no ghosts); the compiled CG keeps every vector in
     the cols layout (same owned gids). Restack b's owned values there."""
+    from .. import telemetry
+
     layout = dA.col_layout
-    stacked = np.zeros((layout.P, layout.W), dtype=b.dtype)
-    for p, (iset, vals) in enumerate(
-        zip(b.rows.partition.part_values(), b.values.part_values())
-    ):
-        stacked[p, layout.o0 : layout.o0 + iset.num_oids] = _owned(
-            iset, np.asarray(vals)
-        )
-    jax = _jax()
+    with telemetry.annotate("pa:stage:pack"):
+        stacked = np.zeros((layout.P, layout.W), dtype=b.dtype)
+        for p, (iset, vals) in enumerate(
+            zip(b.rows.partition.part_values(), b.values.part_values())
+        ):
+            stacked[p, layout.o0 : layout.o0 + iset.num_oids] = _owned(
+                iset, np.asarray(vals)
+            )
     data = _stage(dA.backend, stacked, layout.P)
     return DeviceVector(data, dA.cols, layout, dA.backend)
 

@@ -28,6 +28,7 @@ from .tpu import (
     DeviceVector,
     _shard_ops,
     TPUBackend,
+    _krylov_loop,
     _matrix_operands,
     _pdot_factory,
     _spmv_body,
@@ -562,6 +563,13 @@ def _vcycle_shard_body(h, dh):
 
     def vcycle(b_vec, mats, cinv):
         def solve_level(level, b_l, x0_l=None):
+            # one scope per level, nested for the coarser ones: an op's
+            # level is its innermost `pa.gmg.l<k>`; inside, the phases
+            # `pa.gmg.smooth|restrict|coarse|prolong`
+            with jax.named_scope(f"pa.gmg.l{level}"):
+                return level_body(level, b_l, x0_l)
+
+        def level_body(level, b_l, x0_l):
             lv = dh["levels"][level]
             m = mats["lv"][level]
             # every operand frame has its OWN geometry: on real TPU the
@@ -588,119 +596,122 @@ def _vcycle_shard_body(h, dh):
             # same values the host loop computes, minus the wasted
             # SpMV); a warm start (the second W-cycle pass) runs full
             # sweeps.
-            if x0_l is None:
-                if pre == 0:
-                    x = jnp.zeros_like(b_l)
+            with jax.named_scope("pa.gmg.smooth"):
+                if x0_l is None:
+                    if pre == 0:
+                        x = jnp.zeros_like(b_l)
+                    else:
+                        x = jnp.zeros_like(b_l).at[sl].set(
+                            omega * dinv[sl] * b_l[sl]
+                        )
+                    sweeps_left = max(pre - 1, 0)
                 else:
-                    x = jnp.zeros_like(b_l).at[sl].set(
-                        omega * dinv[sl] * b_l[sl]
-                    )
-                sweeps_left = max(pre - 1, 0)
-            else:
-                x = x0_l
-                sweeps_left = pre
-            for _ in range(sweeps_left):
+                    x = x0_l
+                    sweeps_left = pre
+                for _ in range(sweeps_left):
+                    q = spmv_A(x)
+                    x = x.at[sl].add(omega * dinv[sl] * (b_l[sl] - q[sl]))
+            with jax.named_scope("pa.gmg.restrict"):
                 q = spmv_A(x)
-                x = x.at[sl].add(omega * dinv[sl] * (b_l[sl] - q[sl]))
-            q = spmv_A(x)
-            if "stencil" in lv:
-                # MATRIX-FREE factored restriction R = Eᵀ·S: refresh the
-                # residual's ghosts through the level's box exchange,
-                # apply S as 3^d shifted slices of the extended box,
-                # extract the even points — no operators staged at all.
-                # Multi-variant plans (unequal boxes) switch on the
-                # shard's variant index (m["A"]["si"], the exchange's own
-                # selector); every branch pads to the coarse frame width
-                descs, shells = lv["stencil"], lv["shell"]
-                shmask = m.get("shmask")
-                rv = jnp.zeros_like(b_l).at[sl].set(b_l[sl] - q[sl])
-                rv = bodies[level]["exch_A"](
-                    rv, m["A"]["si"], m["A"]["sm"], m["A"]["ri"]
-                )
-                if level + 1 == L:
-                    nc_pad = mats["gmap"].shape[-1]
-                else:
-                    nc_pad = dh["levels"][level + 1][
-                        "dA"
-                    ].col_plan.layout.no_max
+                if "stencil" in lv:
+                    # MATRIX-FREE factored restriction R = Eᵀ·S: refresh the
+                    # residual's ghosts through the level's box exchange,
+                    # apply S as 3^d shifted slices of the extended box,
+                    # extract the even points — no operators staged at all.
+                    # Multi-variant plans (unequal boxes) switch on the
+                    # shard's variant index (m["A"]["si"], the exchange's own
+                    # selector); every branch pads to the coarse frame width
+                    descs, shells = lv["stencil"], lv["shell"]
+                    shmask = m.get("shmask")
+                    rv = jnp.zeros_like(b_l).at[sl].set(b_l[sl] - q[sl])
+                    rv = bodies[level]["exch_A"](
+                        rv, m["A"]["si"], m["A"]["sm"], m["A"]["ri"]
+                    )
+                    if level + 1 == L:
+                        nc_pad = mats["gmap"].shape[-1]
+                    else:
+                        nc_pad = dh["levels"][level + 1][
+                            "dA"
+                        ].col_plan.layout.no_max
 
-                def _restrict(v, x_, nc_pad=nc_pad):
-                    fbx, cbx, stx = descs[v]
-                    w = _stencil_apply(
-                        jnp, LA, shells[v], x_, fbx, shmask
-                    )
-                    rc = _box_extract(jnp, w, fbx, cbx, stx)
-                    pad = nc_pad - rc.shape[0]
-                    return jnp.pad(rc, (0, pad)) if pad else rc
+                    def _restrict(v, x_, nc_pad=nc_pad):
+                        fbx, cbx, stx = descs[v]
+                        w = _stencil_apply(
+                            jnp, LA, shells[v], x_, fbx, shmask
+                        )
+                        rc = _box_extract(jnp, w, fbx, cbx, stx)
+                        pad = nc_pad - rc.shape[0]
+                        return jnp.pad(rc, (0, pad)) if pad else rc
 
-                if len(descs) == 1:
-                    rc_own = _restrict(0, rv)
-                else:
-                    rc_own = jax.lax.switch(
-                        m["dsel"][0].astype(jnp.int32),
-                        [
-                            (lambda x_, v=v: _restrict(v, x_))
-                            for v in range(len(descs))
-                        ],
-                        rv,
-                    )
-            elif structured:
-                # factored restriction R = Eᵀ·S: stencil-apply the fine
-                # residual (coded-DIA speed), refresh ghosts so embedded
-                # points owned elsewhere are readable, extract the
-                # even-point slots — no per-row gathers
-                LS = lv["dS"].col_plan.layout
-                LSr = lv["dS"].row_layout
-                rS = jnp.zeros(LS.W, dtype=b_l.dtype).at[
-                    LS.o0 : LS.o0 + no
-                ].set(b_l[sl] - q[sl])
-                w, _ = bodies[level]["S"](rS, m["S"])
-                fast = lv.get("emb_fast")
-                if fast is not None:
-                    # equal-box shards: the even-point extraction runs as
-                    # transpose/major-stride rounds — each axis is rotated
-                    # to the MAJOR position before its stride-2 slice, so
-                    # no lane-axis stride ever happens (measured 155 µs vs
-                    # 6.4 ms for the gather and 11.2 ms for a direct
-                    # strided slice at 192³ — Mosaic relayouts dwarf the
-                    # transpose copies). No ghost refresh needed: staging
-                    # verified every embedded point is an own even point.
-                    fb, cb, st = fast
-                    rc_own = _box_extract(
-                        jnp, w[LSr.o0 : LSr.o0 + no], fb, cb, st
-                    )
-                else:
-                    v = jnp.zeros(LS.W, dtype=b_l.dtype).at[
+                    if len(descs) == 1:
+                        rc_own = _restrict(0, rv)
+                    else:
+                        rc_own = jax.lax.switch(
+                            m["dsel"][0].astype(jnp.int32),
+                            [
+                                (lambda x_, v=v: _restrict(v, x_))
+                                for v in range(len(descs))
+                            ],
+                            rv,
+                        )
+                elif structured:
+                    # factored restriction R = Eᵀ·S: stencil-apply the fine
+                    # residual (coded-DIA speed), refresh ghosts so embedded
+                    # points owned elsewhere are readable, extract the
+                    # even-point slots — no per-row gathers
+                    LS = lv["dS"].col_plan.layout
+                    LSr = lv["dS"].row_layout
+                    rS = jnp.zeros(LS.W, dtype=b_l.dtype).at[
                         LS.o0 : LS.o0 + no
-                    ].set(w[LSr.o0 : LSr.o0 + no])
-                    v = bodies[level]["exch_set"](
-                        v, m["S"]["si"], m["S"]["sm"], m["S"]["ri"]
-                    )
-                    rc_own = v[m["emb"]]  # pads read the (zero) trash slot
-            else:
-                # assembled restriction matrix (fallback path)
-                LR = lv["dR"].col_plan.layout
-                LRr = lv["dR"].row_layout
-                r = jnp.zeros(LR.W, dtype=b_l.dtype).at[
-                    LR.o0 : LR.o0 + no
-                ].set(b_l[sl] - q[sl])
-                rc, _ = bodies[level]["R"](r, m["R"])
-                rc_own = rc[LRr.o0 : LRr.o0 + LRr.no_max]
+                    ].set(b_l[sl] - q[sl])
+                    w, _ = bodies[level]["S"](rS, m["S"])
+                    fast = lv.get("emb_fast")
+                    if fast is not None:
+                        # equal-box shards: the even-point extraction runs as
+                        # transpose/major-stride rounds — each axis is rotated
+                        # to the MAJOR position before its stride-2 slice, so
+                        # no lane-axis stride ever happens (measured 155 µs vs
+                        # 6.4 ms for the gather and 11.2 ms for a direct
+                        # strided slice at 192³ — Mosaic relayouts dwarf the
+                        # transpose copies). No ghost refresh needed: staging
+                        # verified every embedded point is an own even point.
+                        fb, cb, st = fast
+                        rc_own = _box_extract(
+                            jnp, w[LSr.o0 : LSr.o0 + no], fb, cb, st
+                        )
+                    else:
+                        v = jnp.zeros(LS.W, dtype=b_l.dtype).at[
+                            LS.o0 : LS.o0 + no
+                        ].set(w[LSr.o0 : LSr.o0 + no])
+                        v = bodies[level]["exch_set"](
+                            v, m["S"]["si"], m["S"]["sm"], m["S"]["ri"]
+                        )
+                        rc_own = v[m["emb"]]  # pads read the (zero) trash slot
+                else:
+                    # assembled restriction matrix (fallback path)
+                    LR = lv["dR"].col_plan.layout
+                    LRr = lv["dR"].row_layout
+                    r = jnp.zeros(LR.W, dtype=b_l.dtype).at[
+                        LR.o0 : LR.o0 + no
+                    ].set(b_l[sl] - q[sl])
+                    rc, _ = bodies[level]["R"](r, m["R"])
+                    rc_own = rc[LRr.o0 : LRr.o0 + LRr.no_max]
             if level + 1 == L:
-                # dense coarse solve, replicated: gather every shard's
-                # owned coarse residual AND gid map (the gmap operand is
-                # sharded — each shard holds only its own row), place by
-                # gid, one mat-vec with the host-precomputed inverse,
-                # read back my slots. Identical on every shard.
-                rc_all = jax.lax.all_gather(rc_own, "parts")  # (P, no_c)
-                gm_all = jax.lax.all_gather(mats["gmap"], "parts")
-                glob = jnp.zeros(nc + 1, dtype=b_l.dtype).at[
-                    gm_all.reshape(-1)
-                ].set(rc_all.reshape(-1))
-                ec_glob = jnp.concatenate(
-                    [cinv @ glob[:nc], jnp.zeros(1, dtype=b_l.dtype)]
-                )
-                ec_own = ec_glob[mats["gmap"]]
+                with jax.named_scope("pa.gmg.coarse"):
+                    # dense coarse solve, replicated: gather every shard's
+                    # owned coarse residual AND gid map (the gmap operand is
+                    # sharded — each shard holds only its own row), place by
+                    # gid, one mat-vec with the host-precomputed inverse,
+                    # read back my slots. Identical on every shard.
+                    rc_all = jax.lax.all_gather(rc_own, "parts")  # (P, no_c)
+                    gm_all = jax.lax.all_gather(mats["gmap"], "parts")
+                    glob = jnp.zeros(nc + 1, dtype=b_l.dtype).at[
+                        gm_all.reshape(-1)
+                    ].set(rc_all.reshape(-1))
+                    ec_glob = jnp.concatenate(
+                        [cinv @ glob[:nc], jnp.zeros(1, dtype=b_l.dtype)]
+                    )
+                    ec_own = ec_glob[mats["gmap"]]
             else:
                 nxt = dh["levels"][level + 1]["dA"].col_plan.layout
                 bc = jnp.zeros(nxt.W, dtype=b_l.dtype).at[
@@ -711,92 +722,94 @@ def _vcycle_shard_body(h, dh):
                     # second coarse pass, warm-started (W-cycle γ = 2)
                     ec = solve_level(level + 1, bc, ec)
                 ec_own = ec[nxt.o0 : nxt.o0 + nxt.no_max]
-            if "stencil" in lv:
-                # matrix-free prolongation P = S·E: interleave the
-                # coarse correction onto the even fine points, refresh
-                # ghosts (neighbor parts' interleaved values), stencil
-                descs, shells = lv["stencil"], lv["shell"]
-                shmask = m.get("shmask")
+            with jax.named_scope("pa.gmg.prolong"):
+                if "stencil" in lv:
+                    # matrix-free prolongation P = S·E: interleave the
+                    # coarse correction onto the even fine points, refresh
+                    # ghosts (neighbor parts' interleaved values), stencil
+                    descs, shells = lv["stencil"], lv["shell"]
+                    shmask = m.get("shmask")
 
-                def _interleave(v, e_):
-                    fbx, cbx, stx = descs[v]
-                    t_ = _box_interleave(
-                        jnp, e_[: int(np.prod(cbx))], fbx, cbx, stx
-                    )
-                    pad = no - t_.shape[0]
-                    return jnp.pad(t_, (0, pad)) if pad else t_
+                    def _interleave(v, e_):
+                        fbx, cbx, stx = descs[v]
+                        t_ = _box_interleave(
+                            jnp, e_[: int(np.prod(cbx))], fbx, cbx, stx
+                        )
+                        pad = no - t_.shape[0]
+                        return jnp.pad(t_, (0, pad)) if pad else t_
 
-                def _apply_S(v, z_):
-                    ef_ = _stencil_apply(
-                        jnp, LA, shells[v], z_, descs[v][0], shmask
-                    )
-                    pad = no - ef_.shape[0]
-                    return jnp.pad(ef_, (0, pad)) if pad else ef_
+                    def _apply_S(v, z_):
+                        ef_ = _stencil_apply(
+                            jnp, LA, shells[v], z_, descs[v][0], shmask
+                        )
+                        pad = no - ef_.shape[0]
+                        return jnp.pad(ef_, (0, pad)) if pad else ef_
 
-                if len(descs) == 1:
-                    t = _interleave(0, ec_own)
-                else:
-                    t = jax.lax.switch(
-                        m["dsel"][0].astype(jnp.int32),
-                        [
-                            (lambda e_, v=v: _interleave(v, e_))
-                            for v in range(len(descs))
-                        ],
-                        ec_own,
+                    if len(descs) == 1:
+                        t = _interleave(0, ec_own)
+                    else:
+                        t = jax.lax.switch(
+                            m["dsel"][0].astype(jnp.int32),
+                            [
+                                (lambda e_, v=v: _interleave(v, e_))
+                                for v in range(len(descs))
+                            ],
+                            ec_own,
+                        )
+                    z = jnp.zeros_like(b_l).at[sl].set(t)
+                    z = bodies[level]["exch_A"](
+                        z, m["A"]["si"], m["A"]["sm"], m["A"]["ri"]
                     )
-                z = jnp.zeros_like(b_l).at[sl].set(t)
-                z = bodies[level]["exch_A"](
-                    z, m["A"]["si"], m["A"]["sm"], m["A"]["ri"]
-                )
-                if len(descs) == 1:
-                    ef_own = _apply_S(0, z)
+                    if len(descs) == 1:
+                        ef_own = _apply_S(0, z)
+                    else:
+                        ef_own = jax.lax.switch(
+                            m["dsel"][0].astype(jnp.int32),
+                            [
+                                (lambda z_, v=v: _apply_S(v, z_))
+                                for v in range(len(descs))
+                            ],
+                            z,
+                        )
+                    x = x.at[sl].add(ef_own)
+                elif structured:
+                    # factored prolongation P = S·E: scatter the coarse
+                    # correction onto the even fine points (N/8 elements),
+                    # assemble embedded-into-ghost values to their owners,
+                    # then one stencil SpMV
+                    LS = lv["dS"].col_plan.layout
+                    LSr = lv["dS"].row_layout
+                    fast = lv.get("emb_fast")
+                    if fast is not None:
+                        # scatter-free interleave, mirror of _box_extract:
+                        # each axis rotates to MAJOR position for its zero
+                        # interleave (stack+reshape), parity shift, crop
+                        fb, cb, st = fast
+                        t = _box_interleave(jnp, ec_own, fb, cb, st)
+                        z = jnp.zeros(LS.W, dtype=b_l.dtype).at[
+                            LS.o0 : LS.o0 + no
+                        ].set(t)
+                    else:
+                        z = jnp.zeros(LS.W, dtype=b_l.dtype).at[m["emb"]].set(
+                            ec_own
+                        ).at[LS.trash].set(0.0)
+                        z = bodies[level]["exch_add"](
+                            z, m["rsi"], m["rsm"], m["rri"]
+                        )
+                    ef, _ = bodies[level]["S"](z, m["S"])
+                    x = x.at[sl].add(ef[LSr.o0 : LSr.o0 + no])
                 else:
-                    ef_own = jax.lax.switch(
-                        m["dsel"][0].astype(jnp.int32),
-                        [
-                            (lambda z_, v=v: _apply_S(v, z_))
-                            for v in range(len(descs))
-                        ],
-                        z,
-                    )
-                x = x.at[sl].add(ef_own)
-            elif structured:
-                # factored prolongation P = S·E: scatter the coarse
-                # correction onto the even fine points (N/8 elements),
-                # assemble embedded-into-ghost values to their owners,
-                # then one stencil SpMV
-                LS = lv["dS"].col_plan.layout
-                LSr = lv["dS"].row_layout
-                fast = lv.get("emb_fast")
-                if fast is not None:
-                    # scatter-free interleave, mirror of _box_extract:
-                    # each axis rotates to MAJOR position for its zero
-                    # interleave (stack+reshape), parity shift, crop
-                    fb, cb, st = fast
-                    t = _box_interleave(jnp, ec_own, fb, cb, st)
-                    z = jnp.zeros(LS.W, dtype=b_l.dtype).at[
-                        LS.o0 : LS.o0 + no
-                    ].set(t)
-                else:
-                    z = jnp.zeros(LS.W, dtype=b_l.dtype).at[m["emb"]].set(
-                        ec_own
-                    ).at[LS.trash].set(0.0)
-                    z = bodies[level]["exch_add"](
-                        z, m["rsi"], m["rsm"], m["rri"]
-                    )
-                ef, _ = bodies[level]["S"](z, m["S"])
-                x = x.at[sl].add(ef[LSr.o0 : LSr.o0 + no])
-            else:
-                LP = lv["dP"].col_plan.layout
-                LPr = lv["dP"].row_layout
-                ecp = jnp.zeros(LP.W, dtype=b_l.dtype).at[
-                    LP.o0 : LP.o0 + LP.no_max
-                ].set(ec_own)
-                ef, _ = bodies[level]["P"](ecp, m["P"])
-                x = x.at[sl].add(ef[LPr.o0 : LPr.o0 + no])
-            for _ in range(post):
-                q = spmv_A(x)
-                x = x.at[sl].add(omega * dinv[sl] * (b_l[sl] - q[sl]))
+                    LP = lv["dP"].col_plan.layout
+                    LPr = lv["dP"].row_layout
+                    ecp = jnp.zeros(LP.W, dtype=b_l.dtype).at[
+                        LP.o0 : LP.o0 + LP.no_max
+                    ].set(ec_own)
+                    ef, _ = bodies[level]["P"](ecp, m["P"])
+                    x = x.at[sl].add(ef[LPr.o0 : LPr.o0 + no])
+            with jax.named_scope("pa.gmg.smooth"):
+                for _ in range(post):
+                    q = spmv_A(x)
+                    x = x.at[sl].add(omega * dinv[sl] * (b_l[sl] - q[sl]))
             return x
 
         return solve_level(0, b_vec)
@@ -863,7 +876,7 @@ def make_gmg_solve_fn(h, backend: TPUBackend, tol: float, maxiter: int):
                 hist = hist.at[jnp.minimum(it, H - 1)].set(jnp.sqrt(rs))
                 return (x, r, rs, it, hist)
 
-            x, r, rs, it, hist = jax.lax.while_loop(
+            x, r, rs, it, hist = _krylov_loop(
                 cond, step, (xv, r0, rs0, jnp.int32(0), hist)
             )
             return x[None], rs, rs0, it, hist
@@ -964,7 +977,7 @@ def make_gmg_pcg_fn(h, backend: TPUBackend, tol: float, maxiter: int):
                 )
                 return (x, r, p, rz, rs_new, it + 1, hist)
 
-            x, r, p, rz, rs, it, hist = jax.lax.while_loop(
+            x, r, p, rz, rs, it, hist = _krylov_loop(
                 cond, step,
                 (xv, r, p, jnp.asarray(1.0, bv.dtype), rs0,
                  jnp.int32(0), hist),
@@ -982,6 +995,9 @@ def make_gmg_pcg_fn(h, backend: TPUBackend, tol: float, maxiter: int):
     def run(b, x0):
         return fn(b, x0, dh["cinv"], ops)
 
+    # the jitted program, for `jit_fn.lower(b, x0, dh["cinv"], ops)` (the
+    # convention of `make_cg_fn`)
+    run.jit_fn = fn
     return run
 
 
@@ -1136,7 +1152,7 @@ def make_fgmres_gmg_fn(
                 _x, _beta, it, _h, conv = st
                 return (~conv) & (it < maxiter)
 
-            x, beta, it, hist, _conv = jax.lax.while_loop(
+            x, beta, it, hist, _conv = _krylov_loop(
                 cond,
                 cycle,
                 (xv, beta0, jnp.int32(0), hist, beta0 <= tol * rs0),
@@ -1184,22 +1200,35 @@ def tpu_fgmres_gmg(
 
 
 def _run_gmg(h, b, x0, tol, maxiter, verbose, make_fn, name):
+    from .. import telemetry
     from .tpu import _run_krylov
 
     backend = b.values.backend
     cache = getattr(h, "_fn_cache", None)
     if cache is None:
         cache = h._fn_cache = {}
-    key = (name, backend._token, float(tol), int(maxiter)) + _gmg_env_key(
-        backend
-    )
-    if key not in cache:
-        cache[key] = make_fn()
-    # the compiled fns share the Krylov (b, x0) -> 5-tuple contract, so
-    # the staging/lifting/info logic is _run_krylov's verbatim
-    return _run_krylov(
-        h.levels[0].A, b, x0, tol, verbose, cache[key], name=name
-    )
+    env_key = _gmg_env_key(backend)
+    key = (name, backend._token, float(tol), int(maxiter)) + env_key
+    with telemetry.solve_scope(
+        name, backend="tpu", tol=float(tol), maxiter=int(maxiter),
+        dtype=str(np.dtype(b.dtype)), env_key=env_key,
+    ) as rec:
+        if key not in cache:
+            cache[key] = make_fn()
+        # the compiled fns share the Krylov (b, x0) -> 5-tuple contract,
+        # so the staging/lifting/info logic is _run_krylov's verbatim
+        x, info = _run_krylov(
+            h.levels[0].A, b, x0, tol, verbose, cache[key], name=name
+        )
+        # The record (timings, events) retires into the history ring:
+        # `telemetry.last_record(name)`. The info stays the plain dict it
+        # was, so a caller that keeps every info keeps no record alive:
+        # with an `InfoDict` here, `poisson7_192.gmg_pcg`'s closed loop
+        # (it keeps them all) read `solve_p95_s` 29 % higher on the chip
+        # host, every 43rd to 45th solve repaying its staging buffers'
+        # page faults (PERF.md, PR 26).
+        rec.finish(info)
+        return x, info
 
 
 def tpu_gmg_solve(

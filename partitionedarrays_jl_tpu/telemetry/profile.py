@@ -10,20 +10,16 @@ ROADMAP item 2's s-step decision (is small-N latency-bound or
 FLOP-bound?) and item 3's node-aware planning both need that split as a
 MEASURED object, not a guess.
 
-Two capture methods, one schema:
-
-* **jax-trace** (``PA_PROF_TRACE=1`` / ``auto``) — run the fixed-trip
-  solve under ``jax.profiler`` and bucket the captured device-op spans
-  by name into the phases. Platforms whose runtime writes a parseable
-  Perfetto JSON get op-level truth; platforms that only emit
-  ``.xplane.pb`` (no parser dependency here) fall back to:
-* **split-timer** (always available, deterministic) — time each phase
-  as its OWN compiled k-step chain (the `bench.py` marginal-chain
-  protocol: warm, median-of-reps, difference two trip counts so
-  dispatch cancels) built from the same `DeviceMatrix` the solver
-  lowers from: the halo exchange body, the full SpMV (halo included —
-  the local share is the difference), one deterministic dot
-  all_gather, and the three-update axpy sweep.
+One capture method, the deterministic **split-timer**: time each phase
+as its OWN compiled k-step chain (the `bench.py` marginal-chain
+protocol: warm, median-of-reps, difference two trip counts so dispatch
+cancels) built from the same `DeviceMatrix` the solver lowers from: the
+halo exchange body, the full SpMV (halo included — the local share is
+the difference), one deterministic dot all_gather, and the three-update
+axpy sweep. Op-level truth from a captured profile is read elsewhere:
+the compiled programs carry the SAME four phases as `jax.named_scope`s
+(``pa.spmv_local`` ... — `parallel/tpu.py` ``SCOPE_*``), and
+`benchmark/layer_metrics/_scoped.py` reduces a chip trace by them.
 
 The exported `PhaseProfile` is schema-versioned, keyed by the palint
 lowering-case name and the operator fingerprint, and carries BOTH
@@ -47,13 +43,9 @@ Env knobs (host-side, NON_LOWERING-exempt with reasons):
 * ``PA_PROF`` (default ``1``) — master switch for profile capture.
 * ``PA_PROF_REPS`` (default ``5``) — timed repetitions per chain
   measurement (median taken).
-* ``PA_PROF_TRACE`` (default ``auto``) — ``1`` force the jax.profiler
-  path, ``0`` never try it, ``auto`` try once and fall back.
 """
 from __future__ import annotations
 
-import glob
-import gzip
 import json
 import math
 import os
@@ -71,7 +63,6 @@ __all__ = [
     "PHASE_SUM_BAND_WIDE",
     "prof_enabled",
     "prof_reps",
-    "prof_trace_mode",
     "lowering_descriptor",
     "phase_case_name",
     "phase_case_of",
@@ -166,12 +157,6 @@ def prof_reps() -> int:
     except ValueError:
         return 5
     return max(3, v)
-
-
-def prof_trace_mode() -> str:
-    """PA_PROF_TRACE in {"0", "1", "auto"}; anything else -> "auto"."""
-    v = os.environ.get("PA_PROF_TRACE", "auto")
-    return v if v in ("0", "1", "auto") else "auto"
 
 
 def lowering_descriptor(dA) -> Dict[str, str]:
@@ -489,71 +474,6 @@ def _body_chain(dA, b, x0, fused, precond, rhs_batch,
 
 
 # ---------------------------------------------------------------------------
-# the jax-trace path (op-level truth where the runtime exposes it)
-# ---------------------------------------------------------------------------
-
-
-def _trace_phase_fractions(fn, b, x0) -> Optional[dict]:
-    """Capture one fixed-trip solve under ``jax.profiler`` and bucket
-    device-op span durations by name into the phases. Returns
-    ``{phase: fraction}`` or None when the runtime wrote no parseable
-    Perfetto JSON (e.g. only ``.xplane.pb`` — the CPU wheel here), in
-    which case the caller falls back to the split-timer."""
-    import tempfile
-
-    import numpy as np
-
-    try:
-        import jax
-    except Exception:  # pragma: no cover - jax always present here
-        return None
-    with tempfile.TemporaryDirectory(prefix="paprof-") as d:
-        try:
-            jax.profiler.start_trace(d)
-            out = fn(b, x0, None)
-            np.asarray(out[1])
-            jax.profiler.stop_trace()
-        except Exception:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
-            return None
-        events = []
-        for pat in ("**/*.trace.json.gz", "**/*.trace.json"):
-            for path in glob.glob(os.path.join(d, pat), recursive=True):
-                try:
-                    opener = gzip.open if path.endswith(".gz") else open
-                    with opener(path, "rt", encoding="utf-8") as f:
-                        events.extend(
-                            json.load(f).get("traceEvents") or []
-                        )
-                except Exception:
-                    continue
-        if not events:
-            return None
-        buckets = {p: 0.0 for p in PHASES}
-        for ev in events:
-            if ev.get("ph") != "X" or not ev.get("dur"):
-                continue
-            name = str(ev.get("name", "")).lower()
-            if "collective-permute" in name or "ppermute" in name:
-                buckets["halo_exchange"] += ev["dur"]
-            elif "all-gather" in name or "all-reduce" in name:
-                buckets["dot_allgather"] += ev["dur"]
-            elif any(t in name for t in ("convert", "add", "subtract",
-                                         "multiply", "axpy")):
-                buckets["axpy_sweep"] += ev["dur"]
-            elif any(t in name for t in ("fusion", "dot", "gather",
-                                         "scatter", "reduce")):
-                buckets["spmv_local"] += ev["dur"]
-        total = sum(buckets.values())
-        if total <= 0.0:
-            return None
-        return {p: v / total for p, v in buckets.items()}
-
-
-# ---------------------------------------------------------------------------
 # capture
 # ---------------------------------------------------------------------------
 
@@ -599,7 +519,6 @@ def capture_phase_profile(
         _block_on_cols_layout,
         _resolve_fused,
         device_matrix,
-        make_cg_fn,
     )
     from .throughput import operator_fingerprint
 
@@ -648,71 +567,54 @@ def capture_phase_profile(
     overlap_on = bool(comms_kwargs.get("overlap"))
 
     method = "split-timer"
-    fractions = None
-    # the trace path buckets every collective-permute span into one
-    # halo bucket — it cannot attribute per fabric tier, so two-level
-    # profiles always take the split-timer's tier-restricted chains
-    if prof_trace_mode() != "0" and not twolevel_on:
-        fn = make_cg_fn(
-            dA, tol=0.0, maxiter=k2, fused=fused, precond=precond,
-            rhs_batch=rhs_batch, sstep=(sstep or None), overlap=overlap,
+    # wall-clock timings on a shared host can still catch a load
+    # spike between the total and the phase chains; re-measure the
+    # WHOLE attempt (phases AND total, same protocol) up to 3
+    # times, accept the first in-band ratio, and otherwise keep
+    # the attempt closest to band-center — a consistently-broken
+    # attribution still lands (and stays) out of band
+    chains = _phase_chains(dA, rhs_batch)
+    best = None
+    # the s-step trip runs `unit` basis levels, each a 2-lane pair
+    # slab (SpMV + halo), then ONE Gram gather — scale the chain
+    # marginals to the trip the same way the comms inventory scales
+    sc = unit * (2 if sstep >= 2 else 1)
+    for attempts in range(1, 4):
+        t_exch = _marginal_s(chains["exchange"], k1, k2, reps)
+        t_spmv = _marginal_s(chains["spmv"], k1, k2, reps)
+        t_dot1 = _marginal_s(chains["dot"], k1, k2, reps)
+        t_axpy = _marginal_s(chains["axpy"], k1, k2, reps)
+        if twolevel_on:
+            # per-fabric halo attribution: each tier measured as
+            # its own restricted chain (the aggregation's staging
+            # hops and local copies are fast-fabric work)
+            halo = {
+                "halo_ici": sc * _marginal_s(
+                    chains["halo_ici"], k1, k2, reps
+                ),
+                "halo_dcn_agg": sc * _marginal_s(
+                    chains["halo_dcn"], k1, k2, reps
+                ),
+            }
+        else:
+            halo = {"halo_exchange": sc * t_exch}
+        cand = dict(halo)
+        cand.update({
+            "spmv_local": sc * max(t_spmv - t_exch, 0.0),
+            "dot_allgather": n_gathers * t_dot1,
+            "axpy_sweep": t_axpy,
+        })
+        r = sum(cand.values()) / measured if measured > 0 else (
+            float("inf")
         )
-        fractions = _trace_phase_fractions(fn, b, x0)
-        if fractions is not None:
-            method = "jax-trace"
-
-    attempts = 1
-    if fractions is not None:
-        phase_s = {p: fractions[p] * measured for p in PHASES}
-    else:
-        # wall-clock timings on a shared host can still catch a load
-        # spike between the total and the phase chains; re-measure the
-        # WHOLE attempt (phases AND total, same protocol) up to 3
-        # times, accept the first in-band ratio, and otherwise keep
-        # the attempt closest to band-center — a consistently-broken
-        # attribution still lands (and stays) out of band
-        chains = _phase_chains(dA, rhs_batch)
-        best = None
-        # the s-step trip runs `unit` basis levels, each a 2-lane pair
-        # slab (SpMV + halo), then ONE Gram gather — scale the chain
-        # marginals to the trip the same way the comms inventory scales
-        sc = unit * (2 if sstep >= 2 else 1)
-        for attempts in range(1, 4):
-            t_exch = _marginal_s(chains["exchange"], k1, k2, reps)
-            t_spmv = _marginal_s(chains["spmv"], k1, k2, reps)
-            t_dot1 = _marginal_s(chains["dot"], k1, k2, reps)
-            t_axpy = _marginal_s(chains["axpy"], k1, k2, reps)
-            if twolevel_on:
-                # per-fabric halo attribution: each tier measured as
-                # its own restricted chain (the aggregation's staging
-                # hops and local copies are fast-fabric work)
-                halo = {
-                    "halo_ici": sc * _marginal_s(
-                        chains["halo_ici"], k1, k2, reps
-                    ),
-                    "halo_dcn_agg": sc * _marginal_s(
-                        chains["halo_dcn"], k1, k2, reps
-                    ),
-                }
-            else:
-                halo = {"halo_exchange": sc * t_exch}
-            cand = dict(halo)
-            cand.update({
-                "spmv_local": sc * max(t_spmv - t_exch, 0.0),
-                "dot_allgather": n_gathers * t_dot1,
-                "axpy_sweep": t_axpy,
-            })
-            r = sum(cand.values()) / measured if measured > 0 else (
-                float("inf")
-            )
-            dist = abs(math.log(r)) if r > 0 else float("inf")
-            if best is None or dist < best[0]:
-                best = (dist, cand, measured)
-            if band[0] <= r <= band[1]:
-                break
-            if attempts < 3:  # the final attempt keeps `best` as-is
-                measured = _marginal_s(body_chain, k1, k2, reps) * unit
-        _, phase_s, measured = best
+        dist = abs(math.log(r)) if r > 0 else float("inf")
+        if best is None or dist < best[0]:
+            best = (dist, cand, measured)
+        if band[0] <= r <= band[1]:
+            break
+        if attempts < 3:  # the final attempt keeps `best` as-is
+            measured = _marginal_s(body_chain, k1, k2, reps) * unit
+    _, phase_s, measured = best
 
     boundary_frac = None
     if overlap_on:

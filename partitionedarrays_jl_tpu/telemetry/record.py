@@ -161,6 +161,10 @@ class SolveRecord:
                  enabled: Optional[bool] = None):
         self.schema_version = RECORD_SCHEMA_VERSION
         self.solver = solver
+        #: Position among the records this process began (`begin_record`
+        #: numbers them from 1): the identifier the `pa:solve` root span
+        #: carries, so a profile's spans join the record they belong to.
+        self.seq = 0
         self.enabled = telemetry_enabled() if enabled is None else enabled
         self.started_at = time.time()
         self._t0 = time.perf_counter()
@@ -279,6 +283,7 @@ class SolveRecord:
         return {
             "schema_version": self.schema_version,
             "solver": self.solver,
+            "seq": self.seq,
             "started_at": self.started_at,
             "wall_s": self.wall_s,
             "config": _jsonable(self.config),
@@ -317,15 +322,19 @@ _lock = registry().lock
 _stack: List[SolveRecord] = []
 _history: List[SolveRecord] = []
 _seq = 0
+_begun = 0
 
 
 def begin_record(solver: str, **config) -> SolveRecord:
     """Open a record and push it onto the active stack. Always returns
     a record object (inert when ``PA_METRICS=0``) so call sites never
     branch."""
+    global _begun
     rec = SolveRecord(solver, config=config)
-    if rec.enabled:
-        with _lock:
+    with _lock:
+        _begun += 1
+        rec.seq = _begun
+        if rec.enabled:
             _stack.append(rec)
     return rec
 
@@ -393,20 +402,26 @@ def solve_scope(solver: str, **config):
     """``with solve_scope("cg", tol=...) as rec:`` — opens a record; a
     raising body finalizes it as an aborted record (events retained), a
     clean body is expected to call ``rec.finish(info)`` itself (the
-    scope closes it empty otherwise)."""
+    scope closes it empty otherwise). The whole scope is one ``pa:solve``
+    profiler span carrying ``solver`` and the record's ``seq`` as
+    keyword stats: the root under which the solve path's `annotate`
+    spans nest on the calling thread."""
+    from .trace import profiler_span
+
     rec = begin_record(solver, **config)
-    try:
-        yield rec
-    except BaseException as e:
-        emit_event(
-            "solve_aborted", label=type(e).__name__,
-            solver=solver, message=str(e)[:500],
-        )
-        rec.finish_error(e)
-        raise
-    else:
-        if not rec.finished:
-            rec.finish(None)
+    with profiler_span("pa:solve", solver=solver, seq=rec.seq):
+        try:
+            yield rec
+        except BaseException as e:
+            emit_event(
+                "solve_aborted", label=type(e).__name__,
+                solver=solver, message=str(e)[:500],
+            )
+            rec.finish_error(e)
+            raise
+        else:
+            if not rec.finished:
+                rec.finish(None)
 
 
 # ---------------------------------------------------------------------------

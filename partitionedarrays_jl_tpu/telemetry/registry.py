@@ -137,6 +137,18 @@ CATALOG: Dict[str, MetricSpec] = {
         _spec("events.*", "counter", "1",
               "telemetry/record.py:emit_event",
               "one counter per telemetry event kind emitted"),
+        # -- device solve boundaries (one bump site per solve) ---------
+        _spec("solve.calls", "counter", "1",
+              "parallel/tpu.py:_count_staged",
+              "device solves whose vectors were staged (single and "
+              "block alike)"),
+        _spec("solve.staged_bytes", "counter", "bytes",
+              "parallel/tpu.py:_count_staged",
+              "bytes of the device frames the solves' vectors were "
+              "staged into"),
+        _spec("solve.fetched_bytes", "counter", "bytes",
+              "parallel/tpu.py:_outputs_to_host",
+              "bytes of the solves' outputs copied back to the host"),
         # -- service lifecycle counters -------------------------------
         _spec("service.admitted", "counter", "1",
               "service/service.py:submit",

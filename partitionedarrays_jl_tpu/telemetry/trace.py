@@ -13,21 +13,30 @@ All timestamps are absolute wall-clock microseconds (records carry
 ``started_at``; PTimer spans record their own epoch starts), so records
 and timer sections from the same process land on one coherent timeline.
 
-`annotate` is the in-process bridge to ``jax.profiler``: a context
-manager that wraps ``jax.profiler.TraceAnnotation`` when profiling is
-available (spans then ALSO appear in captured XLA profiles) and
-degrades to a no-op otherwise — staging/compile/solve phases are
-annotated with it in the solver drivers.
+`annotate` is the one span helper of the device solve path: it opens a
+``jax.profiler.TraceAnnotation`` (so the span lands in a captured
+profile on the same clock as the device ops) AND adds the span's
+``perf_counter`` duration to the current record's ``timings`` under the
+span's last component, so an operator without a profiler reads the same
+split from ``info.record.timings``. The spans a device solve opens
+(``pa:solve`` root from `solve_scope`; ``pa:<solver>:stage|solve|wait|
+fetch|finish`` and the ``pa:stage:*`` / ``pa:fetch:*`` leaves from
+``parallel/tpu.py`` `_run_krylov` / `_tpu_block_cg_impl`) are listed in
+docs/observability.md, "Spans and scopes of a solve".
 """
 from __future__ import annotations
 
 import json
-from contextlib import contextmanager
+import time
+from contextlib import contextmanager, nullcontext
 from typing import Iterable, List, Optional
+
+from .record import current_record
 
 __all__ = [
     "TRACE_SCHEMA_VERSION",
     "annotate",
+    "profiler_span",
     "chrome_trace",
     "record_trace_events",
     "write_chrome_trace",
@@ -36,27 +45,38 @@ __all__ = [
 TRACE_SCHEMA_VERSION = 1
 
 
-@contextmanager
-def annotate(name: str):
-    """``with annotate("pa:solve"): ...`` — a `jax.profiler`
-    TraceAnnotation when jax is importable (so the span shows up inside
-    captured device profiles), a no-op otherwise. Never raises."""
-    ctx = None
+def profiler_span(name: str, **stats):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` (``stats`` become
+    the event's keyword stats in the captured profile; the event's name
+    stays bare), or a null context where jax is not importable."""
     try:
         from jax.profiler import TraceAnnotation
 
-        ctx = TraceAnnotation(name)
-        ctx.__enter__()
+        return TraceAnnotation(name, **stats)
     except Exception:
-        ctx = None
-    try:
-        yield
-    finally:
-        if ctx is not None:
-            try:
-                ctx.__exit__(None, None, None)
-            except Exception:
-                pass
+        return nullcontext()
+
+
+@contextmanager
+def annotate(name: str):
+    """``with annotate("pa:cg:stage"): ...`` — a profiler span (see
+    `profiler_span`) whose wall time is also added to the current
+    record's ``timings[<last component of name>]`` when that record is
+    enabled: ``pa:cg:stage`` -> ``timings["stage"]``, ``pa:stage:pack``
+    -> ``timings["pack"]`` (spans that repeat inside one solve add up).
+    Inactive, a span costs well under a microsecond."""
+    rec = current_record()
+    timed = rec is not None and rec.enabled
+    t0 = time.perf_counter() if timed else 0.0
+    with profiler_span(name):
+        try:
+            yield
+        finally:
+            if timed:
+                leaf = name.rsplit(":", 1)[-1]
+                rec.timings[leaf] = (
+                    rec.timings.get(leaf, 0.0) + time.perf_counter() - t0
+                )
 
 
 def record_trace_events(rec, tid: int = 0) -> List[dict]:
@@ -80,6 +100,7 @@ def record_trace_events(rec, tid: int = 0) -> List[dict]:
                 "status": d.get("status"),
                 "config": d.get("config"),
                 "comms": d.get("comms"),
+                "timings": d.get("timings"),
             },
         }
     ]

@@ -14,9 +14,8 @@ The ISSUE-10 tentpole acceptance lives here:
 * `tools/paprof.py --check` is the tier-1 in-process smoke.
 
 Kept lean (tier-1 sits at ~748s of the 870s budget): ONE (6, 6)
-4-part fixture shared module-wide, the split-timer path pinned via
-``PA_PROF_TRACE=0`` (deterministic, no trace capture cost), and tiny
-trip counts.
+4-part fixture shared module-wide, the deterministic split-timer (the
+one capture method), and tiny trip counts.
 """
 import importlib.util
 import json
@@ -78,7 +77,6 @@ def test_phase_profile_sums_in_band_and_reconciles(fixture_Ab,
     the per-phase collective split reconciles per kind against
     cg_comms_profile's per-iteration inventory — both recomputed
     independently by `reconcile_phases`."""
-    monkeypatch.setenv("PA_PROF_TRACE", "0")
     A, backend = fixture_Ab
     profile = prof.capture_phase_profile(A, backend, reps=3)
     # a loaded host (the full tier-1 suite around this test) can push
@@ -171,10 +169,8 @@ def test_pa_prof_off_noop_and_solver_hlo_identical(fixture_Ab,
         return fn.jit_fn.lower(zb, zb, zb[..., 0], ops).as_text()
 
     monkeypatch.setenv("PA_PROF", "1")
-    monkeypatch.setenv("PA_PROF_TRACE", "1")
     on = text()
     monkeypatch.setenv("PA_PROF", "0")
-    monkeypatch.setenv("PA_PROF_TRACE", "0")
     off = text()
     assert on == off
     assert prof.capture_phase_profile(A, backend) is None
@@ -314,7 +310,7 @@ def test_paprof_check_smoke(capsys, monkeypatch):
     trimmed: the suite sits near its wall-clock budget)."""
     monkeypatch.setenv("PA_PROF_REPS", "3")
     paprof = _load_tool("paprof")
-    rc = paprof.main(["--check", "--trace", "0"])
+    rc = paprof.main(["--check"])
     out = capsys.readouterr().out
     assert rc == 0, out
     assert "paprof --check: OK" in out

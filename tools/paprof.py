@@ -44,8 +44,7 @@ item 3). Legs:
 
 Options: ``--case standard|fused|block_k1_fused|block_k4_fused|
 sstep2|overlap|twolevel`` (body form; default the shipped default), ``--k K``
-(block width), ``--n N`` (grid edge, default 6), ``--trace 0|1|auto``
-(override PA_PROF_TRACE).
+(block width), ``--n N`` (grid edge, default 6).
 
 Usage:
     python tools/paprof.py --check
@@ -373,23 +372,7 @@ def main(argv=None):
                     help="block width (rhs_batch; 0 = single RHS)")
     ap.add_argument("--n", type=int, default=6,
                     help="fixture grid edge (default 6)")
-    ap.add_argument("--trace", choices=("0", "1", "auto"),
-                    help="override PA_PROF_TRACE for this run")
     args = ap.parse_args(argv)
-
-    if args.trace is not None:
-        # scoped override, restored on exit: tier-1 runs main()
-        # in-process and must not leak the mode into later tests or
-        # into artifacts' pa_env stamps
-        prev = os.environ.get("PA_PROF_TRACE")
-        os.environ["PA_PROF_TRACE"] = args.trace
-        try:
-            return _dispatch(ap, args)
-        finally:
-            if prev is None:
-                os.environ.pop("PA_PROF_TRACE", None)
-            else:
-                os.environ["PA_PROF_TRACE"] = prev
     return _dispatch(ap, args)
 
 
