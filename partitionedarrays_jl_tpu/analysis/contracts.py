@@ -38,7 +38,7 @@ from .program_report import (
 #: compiles; lowered-only cases have no ``copy`` ops to budget.
 COPY_BUDGETS: Dict[str, int] = {
     "standard": 40,
-    "fused": 40,
+    "fused": 24,
     "sstep2": 40,
     "overlap": 40,
 }

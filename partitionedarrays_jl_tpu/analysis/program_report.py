@@ -95,8 +95,7 @@ class WhileLoop:
     #: the raw dimension spelling of the dialect ("7x3" / "7,3"), ""
     #: for scalars.
     carries: List[Tuple[str, str]] = field(default_factory=list)
-    #: Total carry payload in bytes (the while-loop working set the
-    #: PR 2 packed-carry fusion exists to shrink).
+    #: Total carry payload in bytes (the while loop's working set).
     carry_bytes: int = 0
     #: Raw text of the loop's regions (cond+body) — used by the
     #: no-host-transfer-inside-loop contract.

@@ -864,7 +864,7 @@ def _plan_verify_enabled() -> bool:
 
 
 def _fused_cg_enabled() -> bool:
-    """The fused streaming CG body (packed (k, W) carry, one-sweep
+    """The fused streaming CG body (one-sweep
     x/r updates + shared-gather dot partials, direction fold riding the
     SpMV pass — see `make_cg_fn`), default ON. Strict-bits keeps the
     standard body as the bit-exact oracle; strict tests opt back in
@@ -3315,6 +3315,14 @@ def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
         overlaps the A_oo compute — a surface-sized effect that the
         fused body's saved volume sweeps dominate.
 
+        Contract of the returned pair, on the Pallas fold and the jnp
+        fold alike: ``p`` and ``A p`` are whole frames, exactly zero off
+        the owned band (ghost and trash slots always; a part's pad rows
+        whenever ``rv`` and ``pv`` are zero there, as the loop's are).
+        `make_cg_fn`'s fused body rests on it: ``p`` is carried to the
+        next trip as it is, and x and r are updated on the owned slice
+        alone.
+
         ``aud``/``audx`` (the SDC audit switch, built only under
         ``audit``): on an audit trip the folded direction is REPLACED by
         ``audx`` (the current iterate), so the body's one SpMV call site
@@ -3426,10 +3434,9 @@ def make_cg_fn(
       of the NEXT SpMV pass (`_spmv_body(pfold=True)` — in-kernel on the
       coded padded path, a fused jnp expression on the BSR/SD/ELL/XLA
       lowerings);
-    * the vector state lives in ONE packed (3, W) carry — x, r, p share
-      a buffer, which also sidesteps the per-carry while-loop copies
-      behind the 292³–300³ XLA anomaly (SCALE_CURVE.json): inside that
-      window the packed-carry body is logged as the structural escape.
+    * x, r and the previous direction are three (W,) while-loop carries,
+      x and r updated where they lie; the fold's ``p`` is the next
+      trip's carry as the SpMV pass wrote it.
 
     Every scalar follows the textbook recurrence on the same dots in the
     same order, so the iteration trajectory is IDENTICAL to the standard
@@ -3625,18 +3632,6 @@ def make_cg_fn(
         raise ValueError(
             "make_cg_fn: the pipelined (lag-1) form is unpreconditioned-"
             "only — drop precond or pipelined"
-        )
-    if fused and 24.5e6 <= no_max <= 27.5e6:
-        # the 292³–300³ regional XLA anomaly (SCALE_CURVE.json: the
-        # standard body's per-carry buffer copies spike 2-3x here): the
-        # packed-carry fused body is the structural escape — say so, so
-        # a user A/B-ing the window knows which body ran
-        print(
-            "[partitionedarrays_jl_tpu] make_cg_fn: owned size "
-            f"{no_max} is inside the 292³–300³ XLA anomaly window — "
-            "using the packed-carry fused body as the structural escape "
-            "(PA_TPU_FUSED_CG=0 reverts to the standard body)",
-            flush=True,
         )
     pdot = _pdot_factory(o0, no_max)
     odot1, odot2 = _pdot_owned_factory(no_max)
@@ -4104,17 +4099,14 @@ def make_cg_fn(
 
             if fused:
                 slf = slice(o0, o0 + no_max)
-                # packed (k, W) carry: x, r, p_prev share ONE buffer, so
-                # the update sweep reads/writes one stacked region and
-                # the while loop carries one vector buffer instead of
-                # three (the structural escape from XLA's per-carry
-                # copies). p_prev starts at 0 with beta 0, so the first
-                # fold yields p_0 = z_0 exactly like the standard body.
-                S0 = jnp.stack([xv, r, jnp.zeros_like(xv)])
+                # x, r and the previous direction are three (W,) carries,
+                # updated where they lie. p_prev starts at 0 with beta 0,
+                # so the first fold yields p_0 = z_0 exactly like the
+                # standard body.
                 zero = jnp.zeros((), bv.dtype)
 
                 def cond_fused(state):
-                    _S, rz, rs, _beta, it = state[:5]
+                    rz, rs, _beta, it = state[3:7]
                     go = jnp.logical_and(
                         jnp.sqrt(rs) > tol * jnp.maximum(1.0, jnp.sqrt(rs0)),
                         it < maxiter,
@@ -4126,13 +4118,10 @@ def make_cg_fn(
                     return go
 
                 def step_fused(state):
-                    if Ht:
-                        S, rz, rs, beta, it, hist, ab = state
-                    else:
-                        S, rz, rs, beta, it, hist = state
-                        ab = None
-                    x, r_, p_prev = S[0], S[1], S[2]
-                    # (b) direction fold rides the SpMV pass itself
+                    x, r_, p_prev, rz, rs, beta, it, hist = state[:8]
+                    # (b) direction fold rides the SpMV pass itself; its
+                    # p is the next trip's p_prev as it is (whole frame,
+                    # zero off the owned band: body_pfold's contract)
                     q, p = body_pfold(
                         r_, p_prev, beta, mats, mvv if precond else None
                     )
@@ -4141,8 +4130,9 @@ def make_cg_fn(
                     # (a) ONE sweep: both vector updates and the dot
                     # partial(s); the preconditioned pair of reductions
                     # shares one all_gather (odot2)
-                    xo = x[slf] + _rp(alpha * p[slf])
-                    ro = r_[slf] + _rp(-alpha * q[slf])
+                    x = x.at[slf].add(_rp(alpha * p[slf]))
+                    r_ = r_.at[slf].add(_rp(-alpha * q[slf]))
+                    ro = r_[slf]
                     if precond:
                         zo = mvv[slf] * ro
                         rz_new, rs_new = odot2(ro, zo, ro, ro)
@@ -4150,28 +4140,26 @@ def make_cg_fn(
                         rs_new = odot1(ro, ro)
                         rz_new = rs_new
                     beta_new = rz_new / rz
-                    S2 = (
-                        S.at[0, slf].set(xo)
-                        .at[1, slf].set(ro)
-                        .at[2, slf].set(p[slf])
-                    )
                     hist2 = hist.at[jnp.minimum(it + 1, H - 1)].set(
                         jnp.sqrt(rs_new)
                     )
-                    out = (S2, rz_new, rs_new, beta_new, it + 1, hist2)
+                    out = (x, r_, p, rz_new, rs_new, beta_new, it + 1, hist2)
                     if Ht:
-                        out = out + (ab.at[it % Ht].set(
+                        out = out + (state[8].at[it % Ht].set(
                             jnp.stack([alpha, beta_new])
                         ),)
                     return out
 
-                init_f = (S0, rz0, rs0, zero, jnp.int32(0), hist)
+                init_f = (
+                    xv, r, jnp.zeros_like(xv), rz0, rs0, zero, jnp.int32(0),
+                    hist,
+                )
                 if Ht:
                     init_f = init_f + (jnp.zeros((Ht, 2), dtype=bv.dtype),)
                 fin = _krylov_loop(cond_fused, step_fused, init_f)
-                S, rs, it, hist = fin[0], fin[2], fin[4], fin[5]
-                out = (S[0][None], rs, rs0, it, hist)
-                return out + ((fin[6],) if Ht else ())
+                x, rs, it, hist = fin[0], fin[4], fin[6], fin[7]
+                out = (x[None], rs, rs0, it, hist)
+                return out + ((fin[8],) if Ht else ())
 
             def cond(state):
                 _x, _r, _p, rz, rs, it = state[:6]
@@ -6058,7 +6046,7 @@ def tpu_cg(
     entries). ``pipelined`` selects the lag-1 form with the solution
     update fused into the SpMV kernel; ``fused`` (default: resolved from
     ``PA_TPU_FUSED_CG``, ON outside strict-bits) selects the fused
-    streaming body with the packed (3, W) carry (see `make_cg_fn`). The
+    streaming body (see `make_cg_fn`). The
     info dict records which body ran under ``cg_body``."""
     from .. import telemetry
 

@@ -100,7 +100,7 @@ def main():
         f"fused_cg_per_iteration_us={dtf * 1e6:.1f} "
         f"spmv_equiv_gflops={flops / dtf / 1e9:.1f} "
         f"speedup_vs_standard={dt / dtf:.3f}x "
-        "(packed-carry fused body, PA_TPU_FUSED_CG default)"
+        "(fused body, PA_TPU_FUSED_CG default)"
     )
     dtp = measure(pipelined=True)
     rec["bodies"]["pipelined"] = {

@@ -375,7 +375,7 @@ def curve():
         # CG marginal on the same operator (the band's protocol): the
         # shipped default (fused body) is the headline, and the standard
         # body rides along as the A/B — inside the 292-300 XLA anomaly
-        # window this pair IS the packed-carry-escape measurement
+        # window this pair is the A/B of the two bodies
         # (docs/performance.md §Per-DOF scaling)
         k1, k2 = (60, 1000) if dofs < 2e7 else (40, 440)
         # both bodies PINNED explicitly (not env-resolved): the artifact's
