@@ -389,30 +389,17 @@ def leg_stream_dia(pa, parts, ns) -> dict:
     return rec
 
 
-def _as_float32(pa, A, *vectors):
-    A.values = pa.map_parts(
-        lambda M: pa.CSRMatrix(
-            M.indptr, M.indices, M.data.astype(np.float32), M.shape
-        ),
-        A.values,
-    )
-    A.invalidate_blocks()
-    for v in vectors:
-        v.values = pa.map_parts(
-            lambda a: np.asarray(a, dtype=np.float32), v.values
-        )
-
-
 def leg_irregular(pa, parts, nodes: int) -> dict:
     from partitionedarrays_jl_tpu.parallel.tpu import (
         ELL_MAX_GATHER, DeviceMatrix, ELLFootprintError, _env_overrides,
     )
 
     def assemble():
-        A, b, _xe, x0 = pa.assemble_elasticity_tet(parts, (nodes,) * 3)
-        # the assembler is float64-only and the chip has no float64:
-        # narrow here, in the open, not in staging
-        _as_float32(pa, A, b, x0)
+        # the chip has no float64: assemble in float32, in the open (the
+        # sums run in float64 and the result is rounded once)
+        A, b, _xe, x0 = pa.assemble_elasticity_tet(
+            parts, (nodes,) * 3, dtype=np.float32
+        )
         return A, b, x0
 
     (A, b, x0), setup = timed(assemble)

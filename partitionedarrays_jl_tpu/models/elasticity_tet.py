@@ -20,10 +20,15 @@ into the data structures. This driver reproduces that shape TPU-first:
   manufactured solution imposed (reference pattern:
   test/test_fem_sa.jl and test/test_fdm.jl boundary handling).
 * **Assembly**: each part assembles the elements whose first node it
-  owns, so rows AND cols touch remote parts; `assemble_coo` migrates the
-  off-owner triplets (reference: src/Interfaces.jl:2406-2492) and the
-  resulting variable-length Table exchanges ride the same Exchanger
-  machinery the TPU backend lowers to edge-colored `ppermute` rounds.
+  owns, so rows AND cols touch remote parts. A tet contributes 16 node
+  pairs, each with a 3x3 block; a part sums the blocks of equal node
+  pairs FIRST (one sort of node-pair keys, no index-set lookup), and only
+  the reduced pairs are expanded to scalar triplets: 9 per stored block
+  instead of 144 per tet, a sixth of the raw triplets. `assemble_coo`
+  then migrates the off-owner triplets (reference:
+  src/Interfaces.jl:2406-2492) and the resulting variable-length Table
+  exchanges ride the same Exchanger machinery the TPU backend lowers to
+  edge-colored `ppermute` rounds.
 * **Solve**: Jacobi-preconditioned CG, error gate vs the manufactured
   solution (reference tolerance: test/test_fem_sa.jl:137).
 """
@@ -35,7 +40,8 @@ import numpy as np
 
 from ..parallel.backends import AbstractPData, map_parts
 from ..parallel.prange import variable_partition
-from ..parallel.psparse import assemble_matrix_from_coo
+from ..ops.sparse import CSRMatrix
+from ..parallel.psparse import PSparseMatrix, assemble_matrix_from_coo
 from ..parallel.pvector import PVector
 from ..parallel.index_sets import GID_DTYPE
 from ..utils.helpers import check
@@ -109,6 +115,21 @@ def morton_permutation(coords: np.ndarray, bits: int = 10) -> np.ndarray:
     return perm
 
 
+def p1_gradients(coords: np.ndarray, tets: np.ndarray):
+    """``(g, vol)``: the constant gradients of the four barycentric
+    functions of each tet, (E, 4, 3), and its volume, (E,). All that a P1
+    element matrix is made of."""
+    M = coords[tets[:, 1:]] - coords[tets[:, :1]]  # (E, 3, 3) edge rows
+    vol = np.abs(np.linalg.det(M)) / 6.0
+    # grad(lambda_a) for a = 1..3 are the rows of inv(M^T): lambda_a(x) =
+    # G[a-1]·(x - X0) with G·M^T = I
+    G = np.linalg.inv(np.swapaxes(M, 1, 2))
+    g = np.empty((len(tets), 4, 3))
+    g[:, 1:] = G
+    g[:, 0] = -G.sum(axis=1)
+    return g, vol
+
+
 def p1_elasticity_ke(
     coords: np.ndarray, tets: np.ndarray, lam: float = 1.0, mu: float = 1.0
 ) -> np.ndarray:
@@ -117,15 +138,7 @@ def p1_elasticity_ke(
     Standard B^T C B * vol with engineering strain (Voigt order
     xx, yy, zz, xy, yz, xz); dof order = node-major (n0x n0y n0z n1x ...)."""
     E = len(tets)
-    X = coords[tets]  # (E, 4, 3)
-    M = X[:, 1:] - X[:, :1]  # (E, 3, 3) edge rows
-    vol = np.abs(np.linalg.det(M)) / 6.0
-    # grad(lambda_a) for a = 1..3 are the rows of inv(M^T): lambda_a(x) =
-    # G[a-1]·(x - X0) with G·M^T = I
-    G = np.linalg.inv(np.swapaxes(M, 1, 2))
-    g = np.empty((E, 4, 3))
-    g[:, 1:] = G
-    g[:, 0] = -G.sum(axis=1)
+    g, vol = p1_gradients(coords, tets)
     B = np.zeros((E, 6, 12))
     for a in range(4):
         gx, gy, gz = g[:, a, 0], g[:, a, 1], g[:, a, 2]
@@ -139,6 +152,52 @@ def p1_elasticity_ke(
     C = np.diag([2 * mu + lam] * 3 + [mu] * 3).astype(float)
     C[:3, :3] += lam - np.diag([lam] * 3)
     return np.einsum("eki,kl,elj,e->eij", B, C, B, vol, optimize=True)
+
+
+#: Node pairs whose blocks are formed and summed at a time: the chunk's
+#: temporaries (a few (chunk, 3, 3) float64 arrays) stay inside memory the
+#: allocator hands back, where a whole-mesh (E, 4, 4, 3, 3) array is 1.4 GB
+#: of fresh pages at 64^3 nodes.
+_PAIR_CHUNK = 1 << 14
+
+
+def _node_pair_blocks(et, g, vol, n_nodes, lam=1.0, mu=1.0):
+    """Sum the 3x3 stiffness blocks of equal (row node, col node) pairs
+    over the elements ``et`` (E, 4): returns ``(row_node, col_node,
+    blocks)``, the pairs unique and sorted by (row, col), ``blocks`` of
+    shape (U, 3, 3).
+
+    Each tet gives 16 node pairs; pair (a, b) of element e carries the
+    closed form of `p1_elasticity_ke`'s block for isotropic Hooke,
+    ``vol (lam g_a g_b^T + mu g_b g_a^T + mu (g_a . g_b) I)``. ONE stable
+    sort of the 16 E integer keys puts equal pairs side by side (no
+    index-set lookup is involved); the blocks are then formed in sorted
+    order, `_PAIR_CHUNK` unique pairs at a time, and summed with
+    `np.add.reduceat`, duplicates in element order."""
+    key = (et[:, :, None] * np.int64(n_nodes) + et[:, None, :]).reshape(-1)
+    order = np.argsort(key, kind="stable")
+    key = key[order]
+    head = np.empty(len(key), dtype=bool)
+    head[:1] = True
+    np.not_equal(key[1:], key[:-1], out=head[1:])
+    starts = np.flatnonzero(head)
+    key = key[starts]
+    U = len(starts)
+    ends = np.append(starts, len(order))
+    blocks = np.empty((U, 3, 3))
+    diag = np.arange(3)
+    for u0 in range(0, U, _PAIR_CHUNK):
+        u1 = min(u0 + _PAIR_CHUNK, U)
+        s0, s1 = ends[u0], ends[u1]
+        slot = order[s0:s1]
+        e, a, b = slot >> 4, (slot >> 2) & 3, slot & 3
+        ga = g[e, a] * vol[e, None]
+        gb = g[e, b]
+        blk = lam * (ga[:, :, None] * gb[:, None, :])
+        blk += mu * (gb[:, :, None] * ga[:, None, :])
+        blk[:, diag, diag] += mu * np.einsum("si,si->s", ga, gb)[:, None]
+        blocks[u0:u1] = np.add.reduceat(blk, starts[u0:u1] - s0, axis=0)
+    return key // n_nodes, key % n_nodes, blocks
 
 
 def _exact_disp(coords: np.ndarray, scale: np.ndarray) -> np.ndarray:
@@ -159,86 +218,111 @@ def assemble_elasticity_tet(
     nodes_per_dim: Sequence[int] = (5, 5, 5),
     jitter: float = 0.2,
     seed: int = 0,
+    dtype=np.float64,
 ):
     """Assemble the distributed elasticity system; returns (A, b, x̂, x0).
 
     The mesh is built replicated on host (it is plan-time metadata, like
     every partitioner input); each part keeps only the elements and dofs
     it owns. Rows carry no ghosts after migration; cols carry the column
-    ghost layer discovered from the kept triplets."""
+    ghost layer discovered from the kept triplets.
+
+    Geometry, element matrices and every sum are float64; ``dtype`` rounds
+    the assembled ``A``, ``b``, ``x̂`` and ``x0`` once, at the end, so
+    ``dtype=np.float32`` is the float64 result cast entry by entry. The
+    phases run under host spans ``pa:assemble:<phase>`` (mesh, elements,
+    reduce, expand, migrate, vectors; docs/observability.md)."""
+    from ..telemetry import annotate
+
     ns = tuple(int(n) for n in nodes_per_dim)
-    coords0, tets0, boundary0 = tet_mesh(ns, jitter=jitter, seed=seed)
-    perm = morton_permutation(coords0)
-    N = len(coords0)
-    coords = np.empty_like(coords0)
-    coords[perm] = coords0
-    boundary = np.zeros(N, dtype=bool)
-    boundary[perm] = boundary0
-    tets = perm[tets0]
-    ndofs = 3 * N
+    with annotate("pa:assemble:mesh"):
+        coords0, tets0, boundary0 = tet_mesh(ns, jitter=jitter, seed=seed)
+        perm = morton_permutation(coords0)
+        N = len(coords0)
+        coords = np.empty_like(coords0)
+        coords[perm] = coords0
+        boundary = np.zeros(N, dtype=bool)
+        boundary[perm] = boundary0
+        tets = perm[tets0]
+        ndofs = 3 * N
 
-    # node block partition (Morton-ordered) -> dof variable_partition so a
-    # node's 3 dofs never split across parts
-    P = parts.num_parts
-    node_first = np.array([(N * p) // P for p in range(P + 1)], dtype=np.int64)
-    noids = map_parts(lambda p: 3 * int(node_first[p + 1] - node_first[p]), parts)
-    rows0 = variable_partition(
-        parts, noids, ngids=ndofs, part_to_firstgid=3 * node_first[:-1]
-    )
-    node_owner = np.searchsorted(node_first, np.arange(N), side="right") - 1
-    xhat = _exact_disp(coords, np.array(ns, dtype=float))
+        # node block partition (Morton-ordered) -> dof variable_partition
+        # so a node's 3 dofs never split across parts
+        P = parts.num_parts
+        node_first = np.array(
+            [(N * p) // P for p in range(P + 1)], dtype=np.int64
+        )
+        noids = map_parts(
+            lambda p: 3 * int(node_first[p + 1] - node_first[p]), parts
+        )
+        rows0 = variable_partition(
+            parts, noids, ngids=ndofs, part_to_firstgid=3 * node_first[:-1]
+        )
+        tet_owner = (
+            np.searchsorted(node_first, tets[:, 0], side="right") - 1
+        )
+        xhat = _exact_disp(coords, np.array(ns, dtype=float))
 
-    ke_all = None  # assembled lazily once, shared by every part's closure
+    def _local_coo(p, iset):
+        et = tets[tet_owner == p]
+        with annotate("pa:assemble:elements"):
+            g, vol = p1_gradients(coords, et)
+        with annotate("pa:assemble:reduce"):
+            rn, cn, blocks = _node_pair_blocks(et, g, vol, N)
+            # boundary test functions drop out (identity rows added by
+            # owners); boundary trial columns stay: their values are
+            # imposed through x0/x̂, as the reference keeps them too
+            keep = ~boundary[rn]
+            rn, cn, blocks = rn[keep], cn[keep], blocks[keep]
+        with annotate("pa:assemble:expand"):
+            d = np.arange(3, dtype=GID_DTYPE)
+            shape = (len(rn), 3, 3)
+            I = np.broadcast_to((3 * rn)[:, None, None] + d[:, None], shape)
+            J = np.broadcast_to((3 * cn)[:, None, None] + d[None, :], shape)
+            gids = np.asarray(iset.oid_to_gid, dtype=GID_DTYPE)
+            gb = gids[boundary[gids // 3]]  # identity rows, added by owners
+            return (
+                np.concatenate([I.reshape(-1), gb]),
+                np.concatenate([J.reshape(-1), gb]),
+                np.concatenate([blocks.reshape(-1), np.ones(len(gb))]),
+            )
 
-    def _local_coo(p):
-        nonlocal ke_all
-        mine = node_owner[tets[:, 0]] == p
-        et = tets[mine]
-        if ke_all is None:
-            ke_all = p1_elasticity_ke(coords, tets)
-        ke = ke_all[mine]
-        # 12 global dof ids per element
-        gd = (3 * et[:, :, None] + np.arange(3)).reshape(-1, 12)
-        I = np.repeat(gd, 12, axis=1).reshape(-1)
-        J = np.tile(gd, (1, 12)).reshape(-1)
-        V = ke.reshape(-1)
-        # boundary test functions drop out (identity rows added by owners);
-        # boundary trial columns move to the rhs via the imposed values, a
-        # fold done after compression by keeping the column and setting
-        # x0/x̂ there — the reference keeps these columns too.
-        keep = ~boundary[I // 3]
-        return I[keep], J[keep], V[keep]
-
-    coo = map_parts(_local_coo, parts)
-    I = map_parts(lambda c: c[0].astype(GID_DTYPE), coo)
-    J = map_parts(lambda c: c[1].astype(GID_DTYPE), coo)
+    coo = map_parts(_local_coo, parts, rows0.partition)
+    I = map_parts(lambda c: c[0], coo)
+    J = map_parts(lambda c: c[1], coo)
     V = map_parts(lambda c: c[2], coo)
-
-    def _boundary_coo(iset):
-        g = np.asarray(iset.oid_to_gid)
-        gb = g[boundary[g // 3]]
-        return gb, gb, np.ones(len(gb))
-
-    bcoo = map_parts(_boundary_coo, rows0.partition)
-    I = map_parts(lambda a, b: np.concatenate([a, b[0]]), I, bcoo)
-    J = map_parts(lambda a, b: np.concatenate([a, b[1]]), J, bcoo)
-    V = map_parts(lambda a, b: np.concatenate([a, b[2]]), V, bcoo)
-
-    A = assemble_matrix_from_coo(I, J, V, rows0)
+    del coo
+    with annotate("pa:assemble:migrate"):
+        A = assemble_matrix_from_coo(I, J, V, rows0)
+    del I, J, V
     cols = A.cols
 
-    def _vals(iset):
-        g = np.asarray(iset.lid_to_gid)
-        return xhat[g // 3, g % 3]
+    with annotate("pa:assemble:vectors"):
+        def _vals(iset):
+            g = np.asarray(iset.lid_to_gid)
+            return xhat[g // 3, g % 3]
 
-    x_exact = PVector(map_parts(_vals, cols.partition), cols)
-    b = A @ x_exact
+        x_exact = PVector(map_parts(_vals, cols.partition), cols)
+        b = A @ x_exact
 
-    def _x0(iset):
-        g = np.asarray(iset.lid_to_gid)
-        return np.where(boundary[g // 3], xhat[g // 3, g % 3], 0.0)
+        def _x0(iset):
+            g = np.asarray(iset.lid_to_gid)
+            return np.where(boundary[g // 3], xhat[g // 3, g % 3], 0.0)
 
-    x0 = PVector(map_parts(_x0, cols.partition), cols)
+        x0 = PVector(map_parts(_x0, cols.partition), cols)
+        dt = np.dtype(dtype)
+        if dt != np.float64:
+            A = PSparseMatrix(
+                map_parts(
+                    lambda M: CSRMatrix(
+                        M.indptr, M.indices, M.data.astype(dt), M.shape
+                    ),
+                    A.values,
+                ),
+                A.rows, cols,
+            )
+            for v in (b, x_exact, x0):
+                v.values = map_parts(lambda a: np.asarray(a, dtype=dt), v.values)
     return A, b, x_exact, x0
 
 

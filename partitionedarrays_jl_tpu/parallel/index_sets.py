@@ -347,9 +347,14 @@ class IndexRange(AbstractIndexSet):
 
     def gids_to_lids(self, gids, missing_to: int = -1) -> np.ndarray:
         gids = np.atleast_1d(_as_gids(gids))
+        rel = gids - self.firstgid
+        owned = (rel >= 0) & (rel < self.noids)
+        if owned.all():
+            # a batch of owned ids only (an assembled COO batch on its
+            # owner, every batch on one part): no fill, no masked copies
+            return rel.astype(INDEX_DTYPE)
         out = np.full(gids.shape, missing_to, dtype=INDEX_DTYPE)
-        owned = (gids >= self.firstgid) & (gids < self.firstgid + self.noids)
-        out[owned] = (gids[owned] - self.firstgid).astype(INDEX_DTYPE)
+        out[owned] = rel[owned].astype(INDEX_DTYPE)
         if len(self._hid_to_gid):
             sorted_gids, perm = self._index()
             rest = ~owned

@@ -381,6 +381,14 @@ def assemble_coo(
         v = np.asarray(v)
         lids = ri.gids_to_lids(i)
         check((lids >= 0).all(), "assemble_coo: triplet row is not a local row")
+        if ri.num_hids == 0:
+            # no ghost row, so no part to ship to and every triplet stays:
+            # nothing to sort out by owner, nothing to zero (one part, or
+            # a part whose elements touch its own rows only)
+            return (
+                Table.empty(GID_DTYPE), Table.empty(GID_DTYPE),
+                Table.empty(v.dtype), i, j, v,
+            )
         owner = ri.lid_to_part[lids]
         keep = owner == ri.part
         rows_i, rows_j, rows_v = [], [], []
@@ -412,6 +420,8 @@ def assemble_coo(
     def _append(s, rit, rjt, rvt):
         i, j, v = s[3], s[4], s[5]
         n = int(rit.ptrs[-1])
+        if n == 0:  # nothing arrived: the batch as it is, not a copy of it
+            return i, j, v
         return (
             np.concatenate([i, rit.data[:n]]),
             np.concatenate([j, rjt.data[:n]]),

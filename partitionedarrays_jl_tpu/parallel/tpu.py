@@ -64,6 +64,14 @@ SCOPE_SPMV = "pa.spmv_local"
 SCOPE_HALO = "pa.halo_exchange"
 SCOPE_DOTS = "pa.dot_allgather"
 SCOPE_AXPY = "pa.axpy_sweep"
+#: Inside `SCOPE_SPMV`, on the two node-block lowerings: the gathers of
+#: the operand (with the concatenation that lays them out for the
+#: product) and the batched products (with the concatenate / slice that
+#: lays out the result). The names carry no ``pa.`` of their own, so a
+#: reader that takes an op's innermost ``pa.`` component still reads
+#: `SCOPE_SPMV` (benchmark/layer_metrics/_scoped.py `phase_of`).
+SCOPE_SD_GATHER, SCOPE_SD_EINSUM = "sd.gather", "sd.einsum"
+SCOPE_BSR_GATHER, SCOPE_BSR_EINSUM = "bsr.gather", "bsr.einsum"
 
 
 def _scoped(scope: str, fn: Callable) -> Callable:
@@ -1497,6 +1505,7 @@ class DeviceMatrix:
                 self.sd_vals = tuple(
                     _stage(backend, c["vals"], P) for c in sd["chunks"]
                 )
+                _count_sd_lowering(sd, sum(m.nnz for m in oo))
             else:
                 bsr = self._detect_bsr(oo, P, noids, no_max, dt)
                 if bsr is not None:
@@ -3170,22 +3179,25 @@ def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
             eq = "grc,gck->grk" if tail else "grc,gc->gr"
             for idx_c, val_c in zip(m["sd_i"], m["sd_v"]):
                 len_c, emax_c = idx_c.shape
-                xs = yp[g0_ * G : (g0_ + len_c) * G].reshape(
-                    (len_c, G * bs) + tail
-                )
-                xe = yn[idx_c].reshape((len_c, emax_c * bs) + tail)
-                xg = jnp.concatenate([xs, xe], axis=1)
-                outs.append(
-                    jnp.einsum(
-                        eq, val_c, xg,
-                        preferred_element_type=xv.dtype,
-                        precision=jax.lax.Precision.HIGHEST,
+                with jax.named_scope(SCOPE_SD_GATHER):
+                    xs = yp[g0_ * G : (g0_ + len_c) * G].reshape(
+                        (len_c, G * bs) + tail
                     )
-                )
+                    xe = yn[idx_c].reshape((len_c, emax_c * bs) + tail)
+                    xg = jnp.concatenate([xs, xe], axis=1)
+                with jax.named_scope(SCOPE_SD_EINSUM):
+                    outs.append(
+                        jnp.einsum(
+                            eq, val_c, xg,
+                            preferred_element_type=xv.dtype,
+                            precision=jax.lax.Precision.HIGHEST,
+                        )
+                    )
                 g0_ += len_c
-            return None, jnp.concatenate(outs, axis=0).reshape(
-                (-1,) + tail
-            )[:no_max]
+            with jax.named_scope(SCOPE_SD_EINSUM):
+                return None, jnp.concatenate(outs, axis=0).reshape(
+                    (-1,) + tail
+                )[:no_max]
         if dA.bsr_bs is not None:
             # node-block gather: one index per bs×bs block (~bs²× fewer
             # element-at-a-time gathers than ELL), block products as one
@@ -3194,16 +3206,18 @@ def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
             cl = dA.col_plan.layout
             tail = xv.shape[1:]
             yn = xv[cl.o0 : cl.o0 + cl.no_max].reshape((-1, bs) + tail)
-            xg = yn[m["bsr_c"]]  # (nn, Lb, bs[, K])
+            with jax.named_scope(SCOPE_BSR_GATHER):
+                xg = yn[m["bsr_c"]]  # (nn, Lb, bs[, K])
             # HIGHEST precision: at DEFAULT the TPU MXU would run this f32
             # dot as lossy bf16 passes, silently breaking the "matches the
             # sequential oracle to FMA rounding" accuracy contract
-            return None, jnp.einsum(
-                "nlij,nljk->nik" if tail else "nlij,nlj->ni",
-                m["bsr_v"], xg,
-                preferred_element_type=xv.dtype,
-                precision=jax.lax.Precision.HIGHEST,
-            ).reshape((-1,) + tail)
+            with jax.named_scope(SCOPE_BSR_EINSUM):
+                return None, jnp.einsum(
+                    "nlij,nljk->nik" if tail else "nlij,nlj->ni",
+                    m["bsr_v"], xg,
+                    preferred_element_type=xv.dtype,
+                    precision=jax.lax.Precision.HIGHEST,
+                ).reshape((-1,) + tail)
         return None, _ell_rowsum(m["oo_v"], m["oo_c"], xv)
 
     def _finish(full, partial_, xv, m):
@@ -5868,6 +5882,27 @@ def _decode_sdc_outputs(name: str, sdcvec, it=None) -> dict:
             diagnostics=diag,
         )
     return sdc_info
+
+
+def _count_sd_lowering(sd: dict, nnz: int) -> None:
+    """The ``lowering.sd.*`` counters of one operator staged in the
+    supernode-dense form: the non-zeros it densified, the dense entries
+    and bytes it made of them, its groups, and the padded external node
+    slots its products gather (``sd`` as `DeviceMatrix._detect_sd`
+    returned it). ``nnz / dense_entries`` is the fill."""
+    from .. import telemetry
+
+    vals = [c["vals"] for c in sd["chunks"]]
+    telemetry.bump("lowering.sd.nnz", int(nnz))
+    telemetry.bump("lowering.sd.dense_entries", sum(int(v.size) for v in vals))
+    telemetry.bump("lowering.sd.bytes", sum(int(v.nbytes) for v in vals))
+    telemetry.bump(
+        "lowering.sd.groups", sum(int(v.shape[0] * v.shape[1]) for v in vals)
+    )
+    telemetry.bump(
+        "lowering.sd.gather_slots",
+        sum(int(c["idx"].size) for c in sd["chunks"]),
+    )
 
 
 def _count_staged(*frames) -> None:

@@ -149,6 +149,25 @@ CATALOG: Dict[str, MetricSpec] = {
         _spec("solve.fetched_bytes", "counter", "bytes",
               "parallel/tpu.py:_outputs_to_host",
               "bytes of the solves' outputs copied back to the host"),
+        # -- the supernode-dense lowering, where an operator is staged --
+        _spec("lowering.sd.nnz", "counter", "1",
+              "parallel/tpu.py:_count_sd_lowering",
+              "stored non-zeros of the operators staged in the "
+              "supernode-dense form"),
+        _spec("lowering.sd.dense_entries", "counter", "1",
+              "parallel/tpu.py:_count_sd_lowering",
+              "entries of the dense group blocks made of them (nnz over "
+              "this is the fill)"),
+        _spec("lowering.sd.bytes", "counter", "bytes",
+              "parallel/tpu.py:_count_sd_lowering",
+              "bytes of those blocks: what one product streams"),
+        _spec("lowering.sd.groups", "counter", "1",
+              "parallel/tpu.py:_count_sd_lowering",
+              "supernode groups (one dense block each, pad groups "
+              "included)"),
+        _spec("lowering.sd.gather_slots", "counter", "1",
+              "parallel/tpu.py:_count_sd_lowering",
+              "padded external node slots the products gather"),
         # -- service lifecycle counters -------------------------------
         _spec("service.admitted", "counter", "1",
               "service/service.py:submit",
