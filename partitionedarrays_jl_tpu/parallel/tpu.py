@@ -33,7 +33,7 @@ import itertools
 import math
 import os
 from functools import partial
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -72,6 +72,9 @@ SCOPE_AXPY = "pa.axpy_sweep"
 #: `SCOPE_SPMV` (benchmark/layer_metrics/_scoped.py `phase_of`).
 SCOPE_SD_GATHER, SCOPE_SD_EINSUM = "sd.gather", "sd.einsum"
 SCOPE_BSR_GATHER, SCOPE_BSR_EINSUM = "bsr.gather", "bsr.einsum"
+#: Inside `SCOPE_SPMV` as well: the boundary (A_oh) rows in their face-slab
+#: form (`DeviceMatrix._detect_oh_slabs`), which `oh_rows_us` reads.
+SCOPE_OH = "oh"
 
 
 def _scoped(scope: str, fn: Callable) -> Callable:
@@ -1353,6 +1356,21 @@ def device_exchange_plan(rows: PRange, padded: bool = False,
     return cache[key]
 
 
+class OhSlab(NamedTuple):
+    """One class of the face-slab form of A_oh
+    (`DeviceMatrix._detect_oh_slabs`), all static: the ghost segment's
+    offset into the ghost region and its slab shape, the class's sub-box
+    of that slab and of the owned box (one shape, two corners), and where
+    its coefficients start in the staged ``(P, dense)`` array."""
+
+    seg: int
+    slab: Tuple[int, ...]
+    ghost_lo: Tuple[int, ...]
+    row_lo: Tuple[int, ...]
+    shape: Tuple[int, ...]
+    v0: int
+
+
 class DeviceMatrix:
     """A PSparseMatrix lowered to stacked padded-ELL blocks in HBM:
     A_oo and A_oh as (P, no_max, L) val/col arrays, cols indexing the
@@ -1368,6 +1386,7 @@ class DeviceMatrix:
         "bsr_cols", "bsr_vals", "bsr_bs",
         "sd_idx", "sd_vals", "sd_g", "sd_bs",
         "ohb_rows", "ohb_cols", "ohb_vals", "ohb_bs",
+        "ohs_vals", "ohs_geo",
         "abft_w",
         "rows", "cols", "row_layout", "col_layout", "col_plan", "backend",
         "padded", "flops_per_spmv", "_cg_cache", "_ops_cache",
@@ -1556,6 +1575,7 @@ class DeviceMatrix:
             else sum(m.nnz for m in full) - self.oh_nnz
         )
         self.ohb_rows = self.ohb_cols = self.ohb_vals = self.ohb_bs = None
+        self.ohs_vals = self.ohs_geo = None
         self.oh_vals = self.oh_cols = self.oh_rows = None
         self._cg_cache = {}
         self._ops_cache = None
@@ -1580,7 +1600,19 @@ class DeviceMatrix:
             self.ohb_vals = tuple(
                 _stage(backend, c["vals"], P) for c in ohb["chunks"]
             )
-        else:
+        # a box layout keeps the ghosts of a direction in the sender's
+        # scan order: where the boundary block is made of face slabs its
+        # rows need no index at all
+        ohs = (
+            self._detect_oh_slabs(A, oh, P, col_layout, dt)
+            if self.oh_nnz and ohb is None
+            else None
+        )
+        if ohs is not None:
+            self.ohs_geo = ohs["geo"]
+            self.ohs_vals = _stage(backend, ohs["vals"], P)
+            _count_oh_lowering(self.oh_nnz, slabs=ohs["geo"])
+        elif ohb is None and self.oh_nnz:
             nb_max = max(
                 (int(np.count_nonzero(m.row_lengths())) for m in oh),
                 default=0,
@@ -1611,6 +1643,7 @@ class DeviceMatrix:
             self.oh_vals = _stage(backend, oh_vals.astype(dt), P)
             self.oh_cols = _stage(backend, oh_cols, P)
             self.oh_rows = _stage(backend, oh_rows, P)
+            _count_oh_lowering(self.oh_nnz, ell_entries=oh_vals.size)
 
         # ABFT checksum row: w = 1ᵀA per part over the local COLUMN
         # frame, precomputed once per lowering — the compiled CG then
@@ -2100,6 +2133,119 @@ class DeviceMatrix:
                 ch["cols"][p, bpos[e] - b0, slot[e]] = S.indices[e]
                 ch["vals"][p, bpos[e] - b0, slot[e]] = S.data[e]
         return {"bs": bs, "chunks": chunks}
+
+    #: Accept the face-slab form of A_oh while the dense entries of all
+    #: its classes stay within this many times the stored entries of the
+    #: fullest part (a Cartesian part touches half the directions the
+    #: union of the parts does, so a stencil reads about 2) ...
+    OH_SLAB_MAX_FILL = 4
+
+    #: ... and while it has at most this many classes: each is a handful
+    #: of slice ops compiled into every program (a 27-point operator on a
+    #: 3-D part grid has 98).
+    OH_SLAB_MAX_CLASSES = 128
+
+    @classmethod
+    def _detect_oh_slabs(cls, A, oh, P, col_layout, dt):
+        """Face-slab staging of the A_oh boundary block on a box layout.
+
+        The box layout keeps a direction's ghosts as one segment in the
+        sender's C-order slab scan, and the owned block is the C-order
+        box scan, so a stored entry has coordinates on both sides: its
+        row in the owned box and its ghost in the direction's slab. The
+        entries of one direction whose two coordinates differ by the
+        same vector form a CLASS: rows and ghosts are then the same
+        sub-box shifted, in the same order, and the class applies as
+        ``y[row sub-box] += coef * x[ghost sub-box]`` on static slices,
+        with no gather, scatter or index operand. The sub-box of a class
+        is the bounding box of its ghosts over all parts (one compiled
+        program serves every shard); positions a part has no entry at
+        (Dirichlet-trimmed rows, a direction with no neighbour) carry
+        coefficient zero. A depth-1 star stencil gives one class a
+        face, a 27-point operator nine shifted ones a face and three an
+        edge.
+
+        Returns ``{"geo": tuple of OhSlab, "vals": (P, dense)}`` or None,
+        and the caller keeps the ELL form: under strict-bits (whose
+        left-to-right fold the ELL form is), with no box layout or more
+        than one box-shape variant, with rows that are not the owned
+        columns' box scan (a transfer), past `OH_SLAB_MAX_CLASSES`, or
+        when the classes' dense entries pass `OH_SLAB_MAX_FILL` times
+        the stored entries of the fullest part."""
+        info = col_layout.box_info
+        if strict_bits() or info is None or len(info.box_shapes) != 1:
+            return None
+        box = info.box_shape
+        no = math.prod(box)
+        for r, c in zip(
+            A.rows.partition.part_values(), A.cols.partition.part_values()
+        ):
+            if (
+                getattr(r, "box_shape", None) != box
+                or r.box_lo != c.box_lo
+                or r.num_oids != no
+            ):
+                return None
+        offs = np.array([d.off for d in info.dirs], dtype=np.int64)
+        # (direction, row - ghost) -> [lo, hi, [(part, ghosts, values)]]
+        found = {}
+        for p in range(P):
+            m = oh[p]
+            if not m.nnz:
+                continue
+            rel = np.asarray(info.ghost_rel_slots[p], dtype=np.int64)[
+                m.indices
+            ]
+            which = np.searchsorted(offs, rel, side="right") - 1
+            rows = np.stack(np.unravel_index(m.row_of_nz(), box))
+            for k in np.unique(which):
+                d = info.dirs[k]
+                e = np.nonzero(which == k)[0]
+                ghosts = np.stack(np.unravel_index(rel[e] - d.off, d.shape))
+                deltas, cls_of = np.unique(
+                    rows[:, e] - ghosts, axis=1, return_inverse=True
+                )
+                cls_of = cls_of.reshape(-1)
+                for c in range(deltas.shape[1]):
+                    sel = cls_of == c
+                    g = ghosts[:, sel]
+                    lo, hi = g.min(axis=1), g.max(axis=1) + 1
+                    ent = found.setdefault(
+                        (int(k), tuple(int(x) for x in deltas[:, c])),
+                        [lo, hi, []],
+                    )
+                    ent[0] = np.minimum(ent[0], lo)
+                    ent[1] = np.maximum(ent[1], hi)
+                    ent[2].append((p, g, m.data[e[sel]]))
+        dense = sum(
+            int(np.prod(hi - lo)) for lo, hi, _ in found.values()
+        )
+        if (
+            len(found) > cls.OH_SLAB_MAX_CLASSES
+            or dense > cls.OH_SLAB_MAX_FILL * max(m.nnz for m in oh)
+        ):
+            return None
+        vals = np.zeros((P, dense), dtype=dt)
+        geo = []
+        v0 = 0
+        for k, delta in sorted(found):
+            lo, hi, entries = found[k, delta]
+            d = info.dirs[k]
+            shape = tuple(int(x) for x in hi - lo)
+            for p, g, v in entries:
+                pos = np.ravel_multi_index(tuple(g - lo[:, None]), shape)
+                # one entry a position in a compressed CSR; summed if not
+                np.add.at(vals[p], v0 + pos, v)
+            geo.append(
+                OhSlab(
+                    seg=d.off, slab=tuple(d.shape),
+                    ghost_lo=tuple(int(x) for x in lo),
+                    row_lo=tuple(int(x) + dx for x, dx in zip(lo, delta)),
+                    shape=shape, v0=v0,
+                )
+            )
+            v0 += math.prod(shape)
+        return {"geo": tuple(geo), "vals": vals}
 
     @classmethod
     def _detect_bsr(cls, oo, P, noids, no_max, dt):
@@ -2863,6 +3009,8 @@ def _matrix_operands(dA: DeviceMatrix) -> dict:
         ops["abft_w"] = dA.abft_w
     if dA.ohb_bs is not None:
         ops.update(ohb_r=dA.ohb_rows, ohb_c=dA.ohb_cols, ohb_v=dA.ohb_vals)
+    elif dA.ohs_geo is not None:
+        ops["ohs_v"] = dA.ohs_vals
     elif dA.oh_vals is not None:
         ops.update(oh_v=dA.oh_vals, oh_c=dA.oh_cols, oh_r=dA.oh_rows)
     if dA.dia_mode == "coded":
@@ -3220,6 +3368,101 @@ def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
                 ).reshape((-1,) + tail)
         return None, _ell_rowsum(m["oo_v"], m["oo_c"], xv)
 
+    def _oh_slabs(y, xv, coef):
+        """The boundary rows in their face-slab form (see
+        `DeviceMatrix._detect_oh_slabs`): per class, the ghost sub-box
+        of the direction's segment times its coefficients, added into
+        the row sub-box of the owned block. Static slices only.
+
+        How a row sub-box is addressed (PERF.md, PR 29, has what each
+        form read on the chip). Flattened from its axis ``a`` on, the
+        owned block is ``box[:a] + (prod(box[a:]),)``, and a sub-box
+        padded to whole steps of axis ``a`` is one run of that last
+        axis. A class takes the smallest ``a`` whose run stays within
+        `OH_SLAB_MAX_FILL` times the class: 0 for a face normal to the
+        slowest axis, which is then a slice of the flat frame itself,
+        updated in place; 1 for the next axis, and so on. The classes
+        of one ``a`` > 0 share one view of the owned block, and where
+        the flattened axis is whole 128-lane rows the view splits it
+        into ``(rows, LANES)``: on the padded frame that is the flat
+        order itself (a reshape to the box's own shape is a relayout of
+        the whole block there, in and out), and a run widens to the
+        lane rows it touches."""
+        from ..ops.pallas_dia import LANES
+
+        cl = dA.col_layout
+        box = cl.box_info.box_shape
+        dim = len(box)
+        no = math.prod(box)
+        tail = xv.shape[1:]
+        keep = [(0, 0)] * len(tail)
+
+        def _split(s):
+            return next(
+                a for a in range(dim)
+                if s.shape[a] * math.prod(box[a + 1 :])
+                <= DeviceMatrix.OH_SLAB_MAX_FILL * math.prod(s.shape[a:])
+            )
+
+        def _slice_add(view, starts, upd):
+            starts = tuple(starts) + (0,) * (view.ndim - len(starts))
+            limits = tuple(a + n for a, n in zip(starts, upd.shape))
+            return jax.lax.dynamic_update_slice(
+                view, jax.lax.slice(view, starts, limits) + upd, starts
+            )
+
+        by_split = {}
+        for s in dA.ohs_geo:
+            by_split.setdefault(_split(s), []).append(s)
+        for a, classes in sorted(by_split.items()):
+            step = math.prod(box[a + 1 :])  # elements a step of axis a spans
+            run = box[a] * step
+            lanes = a > 0 and run % LANES == 0
+            if a == 0:
+                view, base = y, o0
+            else:
+                last = (run // LANES, LANES) if lanes else (run,)
+                view = y[o0 : o0 + no].reshape(box[:a] + last + tail)
+                base = 0
+            for s in classes:
+                seg = xv[
+                    cl.g0 + s.seg : cl.g0 + s.seg + math.prod(s.slab)
+                ].reshape(s.slab + tail)[
+                    tuple(slice(g, g + n) for g, n in zip(s.ghost_lo, s.shape))
+                ]
+                c = coef[s.v0 : s.v0 + math.prod(s.shape)]
+                upd = c.reshape(s.shape + (1,) * len(tail)) * seg
+                # whole steps of axis a: the axes behind it out to the box
+                upd = jnp.pad(
+                    upd,
+                    [(0, 0)] * (a + 1)
+                    + [
+                        (s.row_lo[j], box[j] - s.row_lo[j] - s.shape[j])
+                        for j in range(a + 1, dim)
+                    ]
+                    + keep,
+                ).reshape(s.shape[:a] + (s.shape[a] * step,) + tail)
+                lo = base + s.row_lo[a] * step
+                hi = lo + s.shape[a] * step
+                if lanes:
+                    r0, r1 = lo // LANES, -(-hi // LANES)
+                    upd = jnp.pad(
+                        upd,
+                        [(0, 0)] * a
+                        + [(lo - r0 * LANES, r1 * LANES - hi)]
+                        + keep,
+                    ).reshape(s.shape[:a] + (r1 - r0, LANES) + tail)
+                    view = _slice_add(view, s.row_lo[:a] + (r0,), upd)
+                else:
+                    view = _slice_add(view, s.row_lo[:a] + (lo,), upd)
+            y = (
+                view if a == 0
+                else jax.lax.dynamic_update_slice_in_dim(
+                    y, view.reshape((no,) + tail), o0, 0
+                )
+            )
+        return y
+
     def _finish(full, partial_, xv, m):
         """Shared SpMV tail: halo-exchange the operand, embed the A_oo
         product in the row frame, add the boundary (A_oh) contribution.
@@ -3287,6 +3530,9 @@ def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
                     y = y.at[rows_c].add(
                         yb.reshape(rows_c.shape + tail)
                     )
+            elif dA.ohs_geo is not None:
+                with jax.named_scope(SCOPE_OH):
+                    y = _oh_slabs(y, xv, m["ohs_v"])
             else:
                 y = y.at[m["oh_r"]].add(
                     _ell_rowsum(m["oh_v"], m["oh_c"], xv)
@@ -5111,6 +5357,7 @@ def make_diff_solve_fn(
         a.dtype
         for a in (
             dA.oh_vals,
+            dA.ohs_vals,
             dA.ohb_vals[0] if dA.ohb_vals else None,  # per-bucket tuple
             dA.sd_vals[0] if dA.sd_vals else None,  # per-bucket tuple
             dA.bsr_vals, dA.dia_cb, dA.dia_vals, dA.oo_vals,
@@ -5903,6 +6150,25 @@ def _count_sd_lowering(sd: dict, nnz: int) -> None:
         "lowering.sd.gather_slots",
         sum(int(c["idx"].size) for c in sd["chunks"]),
     )
+
+
+def _count_oh_lowering(nnz: int, slabs=None, ell_entries=None) -> None:
+    """The ``lowering.oh.*`` counters of one operator's boundary block:
+    its stored entries, and what they were staged as: the classes and
+    dense entries of the face-slab form (``slabs`` as
+    `DeviceMatrix._detect_oh_slabs` returned them), or the padded
+    entries of the ELL form."""
+    from .. import telemetry
+
+    telemetry.bump("lowering.oh.nnz", int(nnz))
+    if slabs is not None:
+        telemetry.bump("lowering.oh.slab_classes", len(slabs))
+        telemetry.bump(
+            "lowering.oh.slab_entries",
+            sum(math.prod(s.shape) for s in slabs),
+        )
+    else:
+        telemetry.bump("lowering.oh.ell_entries", int(ell_entries))
 
 
 def _count_staged(*frames) -> None:

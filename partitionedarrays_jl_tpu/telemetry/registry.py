@@ -168,6 +168,23 @@ CATALOG: Dict[str, MetricSpec] = {
         _spec("lowering.sd.gather_slots", "counter", "1",
               "parallel/tpu.py:_count_sd_lowering",
               "padded external node slots the products gather"),
+        # -- the boundary (A_oh) block, where an operator is staged ---
+        _spec("lowering.oh.nnz", "counter", "1",
+              "parallel/tpu.py:_count_oh_lowering",
+              "stored entries of the boundary blocks staged in the "
+              "face-slab or the ELL form (ghost-coupled entries, all "
+              "parts)"),
+        _spec("lowering.oh.slab_classes", "counter", "1",
+              "parallel/tpu.py:_count_oh_lowering",
+              "classes of the face-slab form: one static slice pair "
+              "each in every compiled program"),
+        _spec("lowering.oh.slab_entries", "counter", "1",
+              "parallel/tpu.py:_count_oh_lowering",
+              "dense coefficient entries of those classes, a part"),
+        _spec("lowering.oh.ell_entries", "counter", "1",
+              "parallel/tpu.py:_count_oh_lowering",
+              "padded entries of the boundary blocks kept in the ELL "
+              "form (all parts): one gathered element each"),
         # -- service lifecycle counters -------------------------------
         _spec("service.admitted", "counter", "1",
               "service/service.py:submit",
