@@ -39,8 +39,6 @@ from .program_report import (
 COPY_BUDGETS: Dict[str, int] = {
     "standard": 40,
     "fused": 24,
-    "sstep2": 40,
-    "overlap": 40,
 }
 
 
@@ -352,159 +350,6 @@ def _check_memory_budget(reports, cases):
     return out
 
 
-def _check_sstep_gather_collapse(reports, cases):
-    """ISSUE 17's headline invariant: the s-step (CA-CG) body's solve
-    loop — ONE outer trip covering s textbook iterations — carries
-    exactly ONE dot `all_gather` (the (2s+1)×(2s+1) Gram block
-    reduction), where the standard body pays 2 scalar gathers PER
-    iteration (2s per s). If a second gather creeps into the while
-    region, the communication-avoiding claim is structurally dead no
-    matter what the bench says."""
-    from ..telemetry.comms import expected_from_report
-
-    out = []
-    for name, case in cases.items():
-        if case.get("tags", {}).get("body") != "sstep":
-            continue
-        rep = reports.get(name)
-        if rep is None or rep.dialect != "stablehlo":
-            continue
-        got = expected_from_report(rep)["per_iteration"]["all_gather"][
-            "ops"
-        ]
-        if got != 1:
-            out.append(Violation(
-                "sstep-gather-collapse", [name],
-                "the s-step solve loop must carry exactly ONE dot "
-                "all_gather per outer trip (the Gram block reduction "
-                "that replaces 2s scalar gathers)",
-                expected=1, found=got,
-            ))
-    return out
-
-
-def _check_overlap_parity(reports, cases):
-    """The overlap body reorders the SpMV schedule only (interior
-    compute against the in-flight halo) — per-kind collective ops AND
-    payload bytes must match the standard body it reorders exactly.
-    An inventory change means the 'overlap' stopped being a schedule
-    and became a different algorithm."""
-    out = []
-    for name, case in cases.items():
-        tags = case.get("tags", {})
-        base = tags.get("overlap_off")
-        if not tags.get("overlap") or not base:
-            continue
-        if name not in reports or base not in reports:
-            continue
-        ron, roff = reports[name], reports[base]
-        con, coff = _counts(ron), _counts(roff)
-        bon = {k: ron.collective_bytes.get(k, 0) for k in COLLECTIVE_KINDS}
-        boff = {
-            k: roff.collective_bytes.get(k, 0) for k in COLLECTIVE_KINDS
-        }
-        if con != coff or bon != boff:
-            out.append(Violation(
-                "overlap-collective-parity", [name, base],
-                "overlap body changes the collective inventory — it "
-                "must reorder the standard body's schedule, not its "
-                "communication",
-                expected={"ops": coff, "bytes": boff},
-                found={"ops": con, "bytes": bon},
-            ))
-    return out
-
-
-def _check_twolevel_fabric_budget(reports, cases):
-    """ISSUE 18's headline invariant, per fabric tier. The node-aware
-    two-level plan exists to spend FEWER slow-fabric messages: its
-    aggregated node tier must run exactly one wire round's edge per
-    ordered (node, node) pair — strictly fewer slow-fabric messages
-    than the flat plan's when the cost model chose aggregation — and
-    ship no more slow-fabric wire slots than the flat plan budgeted.
-    The lowered program's solve loop must carry exactly the schedule's
-    wire-round count of `collective_permute` ops (the staged gather/
-    scatter hops are copies, not extra collectives), and every
-    non-permute collective kind must match the flat baseline exactly
-    (aggregation reroutes the halo; it must not touch the dots).
-    Consumes the ``fabric`` attachment `plan_verifier.audit_case` adds
-    to two-level plan audits; skips silently without audits."""
-    from ..telemetry.comms import expected_from_report
-
-    out = []
-    for name, case in cases.items():
-        tags = case.get("tags", {})
-        if not tags.get("twolevel"):
-            continue
-        audit = case.get("plan_audit")
-        fabric = (audit or {}).get("fabric")
-        if fabric is not None:
-            slow_flat = fabric["flat_slow_edges"]
-            pairs = fabric["node_pairs"]
-            if fabric["node_tier_edges"] != pairs:
-                out.append(Violation(
-                    "twolevel-fabric-budget", [name],
-                    "node-tier wire edges != ordered (node, node) "
-                    "pairs — the slow fabric must carry exactly one "
-                    "aggregated message per pair",
-                    expected=pairs, found=fabric["node_tier_edges"],
-                ))
-            used = bool((fabric.get("decision") or {}).get("use"))
-            if pairs > slow_flat or (used and pairs >= slow_flat > 0):
-                out.append(Violation(
-                    "twolevel-fabric-budget", [name],
-                    "aggregation does not reduce the slow-fabric "
-                    "message count below the flat plan's",
-                    expected=f"< {slow_flat} node pairs"
-                    if used else f"<= {slow_flat} node pairs",
-                    found=pairs,
-                ))
-            if fabric["node_tier_wire_slots"] > fabric[
-                "flat_slow_wire_slots"
-            ]:
-                out.append(Violation(
-                    "twolevel-fabric-budget", [name],
-                    "node-tier wire slots exceed the flat plan's "
-                    "slow-fabric slot budget — aggregation may pack, "
-                    "never widen",
-                    expected=f"<= {fabric['flat_slow_wire_slots']}",
-                    found=fabric["node_tier_wire_slots"],
-                ))
-            rep = reports.get(name)
-            if rep is not None and rep.dialect == "stablehlo":
-                got = expected_from_report(rep)["per_iteration"][
-                    "collective_permute"
-                ]["ops"]
-                if got != fabric["wire_rounds"]:
-                    out.append(Violation(
-                        "twolevel-fabric-budget", [name],
-                        "solve-loop collective_permute ops != the "
-                        "two-level schedule's wire-round count — a "
-                        "staging hop leaked onto the wire (or a wire "
-                        "round vanished)",
-                        expected=fabric["wire_rounds"], found=got,
-                    ))
-        base = tags.get("twolevel_off")
-        if base and name in reports and base in reports:
-            ron, roff = reports[name], reports[base]
-            for kind in COLLECTIVE_KINDS:
-                if kind == "collective_permute":
-                    continue
-                con = ron.collectives.get(kind, 0)
-                coff = roff.collectives.get(kind, 0)
-                bon = ron.collective_bytes.get(kind, 0)
-                boff = roff.collective_bytes.get(kind, 0)
-                if con != coff or bon != boff:
-                    out.append(Violation(
-                        "twolevel-fabric-budget", [name, base],
-                        f"two-level body changes the {kind} inventory "
-                        "— aggregation reroutes the halo permutes only",
-                        expected={"ops": coff, "bytes": boff},
-                        found={"ops": con, "bytes": bon},
-                    ))
-    return out
-
-
 def _check_copy_budget(reports, cases):
     """The PR 2 buffer-copy canary: the compiled body's ``copy`` count
     is the structural signature of XLA's while-carry copies — the
@@ -593,22 +438,6 @@ CONTRACTS: List[Contract] = [
              "no infeed/outfeed/non-SPMD custom-call inside any while "
              "region",
              _check_no_host_transfer_in_loop),
-    Contract("sstep-gather-collapse",
-             "the s-step solve loop carries exactly ONE dot all_gather "
-             "per outer trip — the CA-CG block reduction (ISSUE 17)",
-             _check_sstep_gather_collapse),
-    Contract("overlap-collective-parity",
-             "overlap body matches the standard body's per-kind "
-             "collective ops and bytes — a schedule, not an algorithm "
-             "(ISSUE 17)",
-             _check_overlap_parity),
-    Contract("twolevel-fabric-budget",
-             "the node-aware plan's slow-fabric tier carries one "
-             "aggregated message per (node, node) pair within the flat "
-             "plan's slot budget, the loop's permute ops equal the "
-             "schedule's wire rounds, and non-permute collectives match "
-             "the flat baseline (ISSUE 18)",
-             _check_twolevel_fabric_budget),
     Contract("copy-budget",
              "compiled copy-op count within the pinned per-body budget "
              "(the PR 2 buffer-copy-anomaly canary)",

@@ -70,9 +70,6 @@ MEMORY_BUDGETS: Dict[str, int] = {
     "block_k4_fused_abft": 80_000,
     "strict_standard": 59_000,
     "fused_f32": 10_000,
-    "sstep2": 22_000,
-    "overlap": 16_000,
-    "twolevel": 30_000,
 }
 
 

@@ -70,13 +70,10 @@ __all__ = [
     "plan_verify_enabled",
     "plans_equal",
     "referenced_ghosts",
-    "load_twolevel_fixture",
-    "twolevel_fixture",
     "verify_box_plan",
     "verify_device_plan",
     "verify_exchanger",
     "verify_plan",
-    "verify_twolevel_plan",
 ]
 
 #: The check classes, in report order. Each has a committed negative
@@ -502,186 +499,6 @@ def verify_device_plan(
 
 
 # ---------------------------------------------------------------------------
-# two-level staged plan (ISSUE 18 node-aware tier)
-# ---------------------------------------------------------------------------
-
-
-def _base_delivery(plan) -> dict:
-    """The flat plan's logical delivery: ``(dst_part, ghost_slot) ->
-    (src_part, owned_slot)`` read lane-by-lane off the base arrays —
-    the oracle the staged schedule must reproduce exactly."""
-    exp = {}
-    trash = plan.layout.trash
-    for r, perm in enumerate(plan.perms):
-        for src, dst in perm:
-            snd = np.asarray(plan.snd_idx[src, r])
-            msk = np.asarray(plan.snd_mask[src, r])
-            rcv = np.asarray(plan.rcv_idx[dst, r])
-            for lane in np.nonzero(msk)[0].tolist():
-                d = int(rcv[lane])
-                if d != trash:
-                    exp[(int(dst), d)] = (int(src), int(snd[lane]))
-    return exp
-
-
-def verify_twolevel_plan(
-    plan,
-    referenced: Optional[Sequence[np.ndarray]] = None,
-    name: str = "device-twolevel",
-) -> List[PlanDefect]:
-    """Verify a `TwoLevelDeviceExchangePlan` (or its box sibling):
-
-    1. All FIVE flat checks run UNCHANGED on the plan's logical-
-       delivery view (the base-class flat arrays — two-level changes
-       the schedule, never what is delivered), then
-    2. the staged schedule itself is checked: every wire round a
-       self-send-free partial permutation with symmetric per-edge
-       counts, and a full SYMBOLIC simulation of ``tl_rounds`` over
-       the combined frame (ghost slab + stage + stage trash) whose
-       final ghost content must equal the flat delivery slot-for-slot.
-       Simulation defects map onto the same five classes: a staged
-       write collision or a misrouted payload is ``ghost-race``, a
-       slot the stages never fill is ``coverage``, a slot the flat
-       plan leaves untouched but a stage writes is ``dead-slot``,
-       schedule-shape violations are ``rounds``/``symmetry``.
-    """
-    out = verify_device_plan(plan, referenced, name=name)
-    layout = plan.layout
-    P, W = layout.P, layout.W
-    S = plan.stage_width
-    trash = layout.trash
-    strash = W + S
-    Wc = W + S + 1
-
-    # wire-round shape: per-round partial permutation. Tiers REUSE
-    # (src, dst) pairs across rounds by design (a direct edge and a
-    # scatter edge may share endpoints), so the flat schedule's
-    # cross-round edge-uniqueness check does not apply — semantic
-    # double delivery is caught by the simulation instead.
-    for r, rd in enumerate(plan.tl_rounds):
-        senders, receivers = set(), set()
-        for src, dst in rd.perm:
-            if not (0 <= src < P and 0 <= dst < P):
-                out.append(PlanDefect(
-                    "rounds", name, None,
-                    f"staged round {r} ({rd.tier}) edge ({src}, {dst}) "
-                    f"names an out-of-range part (P={P})",
-                    details={"round": r},
-                ))
-                continue
-            if src == dst:
-                out.append(PlanDefect(
-                    "rounds", name, src,
-                    f"self-send in staged round {r} ({rd.tier}): edge "
-                    f"({src}, {dst}) — local copies must be perm-free "
-                    "rounds, not ppermute self-edges",
-                    details={"round": r},
-                ))
-            if src in senders:
-                out.append(PlanDefect(
-                    "rounds", name, src,
-                    f"staged round {r} ({rd.tier}) is not a partial "
-                    f"permutation: part {src} sends twice",
-                    details={"round": r},
-                ))
-            if dst in receivers:
-                out.append(PlanDefect(
-                    "rounds", name, dst,
-                    f"staged round {r} ({rd.tier}) is not a partial "
-                    f"permutation: part {dst} receives twice",
-                    details={"round": r},
-                ))
-            senders.add(src)
-            receivers.add(dst)
-            k_snd = int(np.count_nonzero(rd.snd_mask[src]))
-            tgt = np.asarray(rd.rcv_idx[dst])
-            k_rcv = int(np.count_nonzero((tgt != strash) & (tgt != trash)))
-            if k_snd != k_rcv:
-                out.append(PlanDefect(
-                    "symmetry", name, dst,
-                    f"asymmetric counts on staged round-{r} ({rd.tier}) "
-                    f"edge {src}→{dst}: {k_snd} packed vs {k_rcv} landed",
-                    details={"round": r, "edge": [src, dst],
-                             "snd": k_snd, "rcv": k_rcv},
-                ))
-
-    # symbolic simulation: slot (p, s) of the live frame carries the
-    # unique id p*Wc + s; -1 = empty. Copies preserve ids, so the
-    # final ghost content IS the provenance of what each slot holds.
-    cv = np.full((P, Wc), -1, dtype=np.int64)
-    for p in range(P):
-        cv[p, :W] = np.arange(W, dtype=np.int64) + p * Wc
-    for r, rd in enumerate(plan.tl_rounds):
-        L_r = int(rd.snd_idx.shape[-1])
-        buf = np.full((P, L_r), -1, dtype=np.int64)
-        for p in range(P):
-            lanes = np.asarray(rd.snd_mask[p], dtype=bool)
-            buf[p, lanes] = cv[p, np.asarray(rd.snd_idx[p])[lanes]]
-        if rd.perm:
-            routed = np.full_like(buf, -1)
-            for src, dst in rd.perm:
-                if 0 <= src < P and 0 <= dst < P:
-                    routed[dst] = buf[src]
-        else:
-            routed = buf
-        for p in range(P):
-            tgt = np.asarray(rd.rcv_idx[p])
-            live = (tgt != strash) & (tgt != trash)
-            uniq, counts = np.unique(tgt[live], return_counts=True)
-            dup = uniq[counts > 1]
-            if dup.size:
-                out.append(PlanDefect(
-                    "ghost-race", name, p,
-                    f"staged round {r} ({rd.tier}): colliding writes "
-                    f"into slot(s) {sorted(dup.tolist())[:8]} on part "
-                    f"{p} — the scatter resolves the race arbitrarily",
-                    details={"round": r, "slots": dup.tolist()[:16]},
-                ))
-            cv[p, tgt] = routed[p]
-            cv[p, trash] = -1
-            cv[p, strash] = -1
-
-    exp = _base_delivery(plan)
-    g0 = layout.g0
-    for p in range(P):
-        for g in range(g0, trash):
-            want = exp.get((p, g))
-            got = int(cv[p, g])
-            stale = p * Wc + g  # the slot's own seeded (never-written) id
-            if want is None:
-                if got != stale:
-                    out.append(PlanDefect(
-                        "dead-slot", name, p,
-                        f"staged schedule writes ghost slot {g} the "
-                        "flat delivery leaves untouched",
-                        details={"slot": g},
-                    ))
-                continue
-            src, s_slot = want
-            want_id = src * Wc + s_slot
-            if got == want_id:
-                continue
-            if got in (-1, stale):
-                out.append(PlanDefect(
-                    "coverage", name, p,
-                    f"staged schedule never delivers ghost slot {g} "
-                    f"(flat plan delivers part {src} slot {s_slot} "
-                    "there) — stale reads every exchange",
-                    details={"slot": g, "expected": [src, s_slot]},
-                ))
-            else:
-                out.append(PlanDefect(
-                    "ghost-race", name, p,
-                    f"staged schedule delivers the WRONG payload into "
-                    f"ghost slot {g}: part {got // Wc} slot {got % Wc} "
-                    f"instead of part {src} slot {s_slot}",
-                    details={"slot": g, "expected": [src, s_slot],
-                             "got": [got // Wc, got % Wc]},
-                ))
-    return out
-
-
-# ---------------------------------------------------------------------------
 # box slice plan
 # ---------------------------------------------------------------------------
 
@@ -813,13 +630,6 @@ def verify_plan(
         return verify_exchanger(
             plan, parts, referenced, name=name or "exchanger"
         )
-    # the two-level staged plans (and their fixture shims) carry
-    # tl_rounds — dispatch structurally so loaded fixtures need no
-    # class identity
-    if hasattr(plan, "tl_rounds"):
-        return verify_twolevel_plan(
-            plan, referenced, name=name or "device-twolevel"
-        )
     from ..parallel.tpu_box import BoxExchangePlan
 
     if isinstance(plan, BoxExchangePlan):
@@ -899,17 +709,6 @@ def plan_fingerprint(plan) -> tuple:
                   for d in info.dirs),
             tuple(_b(r) for r in info.ghost_rel_slots),
             _b(info.seg_mask),
-        )
-    if hasattr(plan, "tl_rounds"):
-        return (
-            "twolevel", tuple(plan.node_of), int(plan.stage_width),
-            tuple(
-                (rd.tier, rd.perm, _b(rd.snd_idx), _b(rd.snd_mask),
-                 _b(rd.rcv_idx))
-                for rd in plan.tl_rounds
-            ),
-            plan.R, plan.L, plan.perms,
-            _b(plan.snd_idx), _b(plan.snd_mask), _b(plan.rcv_idx),
         )
     return (
         "generic", plan.R, plan.L, plan.perms,
@@ -1045,114 +844,6 @@ def load_exchanger_fixture(path_or_dict):
     return ex, parts, referenced, d.get("defect")
 
 
-class _FixtureLayout:
-    """Layout summary rebuilt from a two-level fixture — just the
-    fields the verifier reads."""
-
-    box_info = None
-
-    def __init__(self, d):
-        self.P = int(d["P"])
-        self.W = int(d["W"])
-        self.o0 = int(d["o0"])
-        self.g0 = int(d["g0"])
-        self.trash = int(d["trash"])
-        self.noids = np.asarray(d["noids"])
-        self.nhids = np.asarray(d["nhids"])
-        self.hid_slots = [np.asarray(h) for h in d["hid_slots"]]
-
-
-class _FixtureTwoLevelRound:
-    def __init__(self, d):
-        self.tier = d["tier"]
-        self.perm = tuple(tuple(e) for e in d["perm"])
-        self.snd_idx = np.asarray(d["snd_idx"])
-        self.snd_mask = np.asarray(d["snd_mask"], dtype=bool)
-        self.rcv_idx = np.asarray(d["rcv_idx"])
-
-
-class _FixtureTwoLevelPlan:
-    """Deserialized two-level plan — structurally dispatches through
-    `verify_plan` via its ``tl_rounds`` attribute."""
-
-    def __init__(self, d):
-        self.layout = _FixtureLayout(d["layout"])
-        self.perms = tuple(
-            tuple(tuple(e) for e in perm) for perm in d["perms"]
-        )
-        self.snd_idx = np.asarray(d["snd_idx"])
-        self.snd_mask = np.asarray(d["snd_mask"], dtype=bool)
-        self.rcv_idx = np.asarray(d["rcv_idx"])
-        self.R = len(self.perms)
-        self.L = int(self.snd_idx.shape[-1]) if self.R else 0
-        self.node_of = tuple(int(n) for n in d["node_of"])
-        self.stage_width = int(d["stage_width"])
-        self.tl_rounds = tuple(
-            _FixtureTwoLevelRound(r) for r in d["tl_rounds"]
-        )
-
-
-def twolevel_fixture(plan, referenced=None,
-                     defect: Optional[str] = None,
-                     note: str = "") -> dict:
-    """Serialize a two-level device plan (mutations and all — the
-    committed negative corpus stores the BROKEN plan, not a recipe)
-    as a JSON-able dict."""
-    layout = plan.layout
-    return {
-        "format": "paplan-twolevel-fixture",
-        "version": 1,
-        "defect": defect,
-        "note": note,
-        "layout": {
-            "P": int(layout.P), "W": int(layout.W),
-            "o0": int(layout.o0), "g0": int(layout.g0),
-            "trash": int(layout.trash),
-            "noids": np.asarray(layout.noids).tolist(),
-            "nhids": np.asarray(layout.nhids).tolist(),
-            "hid_slots": [np.asarray(h).tolist()
-                          for h in layout.hid_slots],
-        },
-        "perms": [list(map(list, perm)) for perm in plan.perms],
-        "snd_idx": np.asarray(plan.snd_idx).tolist(),
-        "snd_mask": np.asarray(plan.snd_mask).astype(int).tolist(),
-        "rcv_idx": np.asarray(plan.rcv_idx).tolist(),
-        "node_of": [int(n) for n in plan.node_of],
-        "stage_width": int(plan.stage_width),
-        "tl_rounds": [
-            {
-                "tier": rd.tier,
-                "perm": list(map(list, rd.perm)),
-                "snd_idx": np.asarray(rd.snd_idx).tolist(),
-                "snd_mask": np.asarray(rd.snd_mask).astype(int).tolist(),
-                "rcv_idx": np.asarray(rd.rcv_idx).tolist(),
-            }
-            for rd in plan.tl_rounds
-        ],
-        "referenced": (
-            None if referenced is None
-            else [np.asarray(m).astype(int).tolist() for m in referenced]
-        ),
-    }
-
-
-def load_twolevel_fixture(path_or_dict):
-    """Load a committed two-level fixture back into ``(plan,
-    referenced, defect)`` ready for `verify_twolevel_plan`."""
-    if isinstance(path_or_dict, dict):
-        d = path_or_dict
-    else:
-        with open(path_or_dict, encoding="utf-8") as f:
-            d = json.load(f)
-    if d.get("format") != "paplan-twolevel-fixture":
-        raise ValueError(f"not a paplan twolevel fixture: {path_or_dict}")
-    referenced = (
-        None if d.get("referenced") is None
-        else [np.asarray(m, dtype=bool) for m in d["referenced"]]
-    )
-    return _FixtureTwoLevelPlan(d), referenced, d.get("defect")
-
-
 # ---------------------------------------------------------------------------
 # the lowering-matrix hook (analysis.matrix / palint)
 # ---------------------------------------------------------------------------
@@ -1186,45 +877,15 @@ def audit_case(backend, case: dict) -> dict:
             ),
         }
         plan = dA.col_plan
-        if hasattr(plan, "tl_rounds"):
-            kind = "device-twolevel"
-        elif isinstance(plan, BoxExchangePlan):
-            kind = "device-box"
-        else:
-            kind = "device-generic"
+        kind = (
+            "device-box" if isinstance(plan, BoxExchangePlan)
+            else "device-generic"
+        )
         plans[kind] = verify_plan(plan, referenced=ref, name=kind)
-        fabric = None
-        if kind == "device-twolevel":
-            node_of = plan.node_of
-            L = int(plan.snd_idx.shape[-1])
-            flat_edges = [(s, d) for perm in plan.perms for s, d in perm]
-            slow = [(s, d) for s, d in flat_edges
-                    if node_of[s] != node_of[d]]
-            node_rounds = [rd for rd in plan.tl_rounds
-                           if rd.tier == "node"]
-            fabric = {
-                "node_of": [int(n) for n in node_of],
-                "flat_slow_edges": len(slow),
-                "node_pairs": len({(node_of[s], node_of[d])
-                                   for s, d in slow}),
-                "node_tier_edges": sum(len(rd.perm)
-                                       for rd in node_rounds),
-                "flat_slow_wire_slots": len(slow) * L,
-                "node_tier_wire_slots": sum(
-                    int(rd.snd_idx.shape[-1]) * len(rd.perm)
-                    for rd in node_rounds
-                ),
-                "wire_rounds": int(plan.wire_rounds),
-                "tiers": [rd.tier for rd in plan.tl_rounds if rd.perm],
-                "decision": dict(plan.decision),
-            }
-    audit = {
+    return {
         "kind": kind,
         "plans": {
             k: [d.to_dict() for d in v] for k, v in plans.items()
         },
         "n_defects": sum(len(v) for v in plans.values()),
     }
-    if fabric is not None:
-        audit["fabric"] = fabric
-    return audit

@@ -294,7 +294,6 @@ def cg(
     tol: float = 1e-8,
     maxiter: Optional[int] = None,
     verbose: bool = False,
-    pipelined: bool = False,
     fused: Optional[bool] = None,
     checkpoint=None,
     _resume_state: Optional[dict] = None,
@@ -328,22 +327,13 @@ def cg(
     backend it matches the sequential oracle to FMA rounding with identical
     iteration counts (exchanges are bit-identical — the BASELINE.md gate).
 
-    ``pipelined=True`` selects the lag-1 form on the TPU backend: the
-    solution update x += α·p applies one iteration late, fused into the
-    next SpMV kernel's streaming pass (tpu.py:make_cg_fn — the x pass is
-    the loop's one VMEM-spilling HBM sweep). Every scalar follows the
-    textbook recurrence, so the iteration trajectory is identical; on
-    the host backend the flag is a no-op (eager NumPy has no fusion to
-    exploit — the standard loop IS the lag-1 loop's value sequence).
-
     ``fused`` selects the TPU backend's fused streaming body (default:
     resolved from ``PA_TPU_FUSED_CG`` — ON outside strict-bits): one
-    update+dot sweep, direction fold riding the SpMV pass, packed
-    (3, W) carry — same trajectory, fewer large-N HBM sweeps per
-    iteration (tpu.py:make_cg_fn). This host loop IS the fused body's
-    value sequence already (eager NumPy), so the flag is likewise a
-    host no-op; the device info dict records the body under
-    ``cg_body``.
+    update+dot sweep, direction fold riding the SpMV pass — same
+    trajectory, fewer large-N HBM sweeps per iteration
+    (tpu.py:make_cg_fn). This host loop IS the fused body's value
+    sequence already (eager NumPy), so the flag is a host no-op; the
+    device info dict records the body under ``cg_body``.
 
     Resilience hooks: ``checkpoint`` takes a
     `parallel.checkpoint.SolverCheckpointer`; every ``checkpoint.every``
@@ -361,11 +351,6 @@ def cg(
         B = _check_block_args(
             "cg", b, x0, B, checkpoint, _resume_state, column_errors
         )
-        if pipelined:
-            raise ValueError(
-                "cg: the pipelined (lag-1) form is single-RHS only — "
-                "drop pipelined or B"
-            )
         if isinstance(B[0].values.backend, TPUBackend):
             return tpu_block_cg(
                 A, B, X0=X0, tol=tol, maxiter=maxiter, verbose=verbose,
@@ -389,7 +374,7 @@ def cg(
         # Device path: the whole loop is one compiled shard_map program.
         return tpu_cg(
             A, b, x0=x0, tol=tol, maxiter=maxiter, verbose=verbose,
-            pipelined=pipelined, fused=fused,
+            fused=fused,
         )
     from .. import telemetry
 

@@ -216,7 +216,7 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
                    n_blocks: int, block_rows: int, halo_rows: int,
                    n_coded: int,
                    cls_pattern: Tuple[Tuple[bool, ...], ...] = None,
-                   has_axpy: bool = False, has_pfold: bool = False):
+                   has_pfold: bool = False):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -233,15 +233,6 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
         # xw_ref is the r window source here.
         (pw_ref, beta_ref, y_ref, po_ref,
          xs_ref, ps_ref, comb_ref, cs_ref, xsem, psem, csem) = refs
-    elif has_axpy:
-        # lagged-axpy fusion (pipelined CG): while the VPU-bound SpMV
-        # streams, the DMA engines also move one block each of the
-        # PREVIOUS search direction and the solution accumulator, and the
-        # kernel applies x += alpha*p_prev on the owned band — the lone
-        # HBM pass that otherwise costs ~1/3 of a CG iteration rides the
-        # kernel's spare DMA bandwidth instead.
-        (pp_ref, xin_ref, alpha_ref, y_ref, xout_ref,
-         xs_ref, cs_ref, xsem, csem) = refs
     else:
         y_ref, xs_ref, cs_ref, xsem, csem = refs
 
@@ -403,31 +394,6 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
         def _pfold_zero():
             po_ref[:] = jnp.zeros_like(po_ref)
 
-    if has_axpy:
-        # frame block j holds owned elements (j-1)*BR*LANES..; pads,
-        # ghost and trash slots copy through unchanged (x keeps its
-        # zero-ghost invariant — the host loop never touches them either)
-        @pl.when((j >= 1) & (j <= n_blocks))
-        def _axpy():
-            e2 = (
-                (j - 1) * block_rows * LANES
-                + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_rows, LANES), 0
-                ) * LANES
-                + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_rows, LANES), 1
-                )
-            )
-            xout_ref[:] = jnp.where(
-                e2 < no_ref[0],
-                xin_ref[:] + alpha_ref[0] * pp_ref[:],
-                xin_ref[:],
-            )
-
-        @pl.when((j < 1) | (j > n_blocks))
-        def _axpy_copy():
-            xout_ref[:] = xin_ref[:]
-
 
 def dia_coded_padded_pallas(
     codebook: "jax.Array",  # noqa: F821
@@ -441,7 +407,6 @@ def dia_coded_padded_pallas(
     total_rows: int,
     interpret: bool = False,
     cls_pattern: Tuple[Tuple[bool, ...], ...] = None,
-    axpy: Tuple["jax.Array", "jax.Array", "jax.Array"] = None,  # noqa: F821
     pfold: Tuple["jax.Array", "jax.Array"] = None,  # noqa: F821
 ):
     """Full-vector coded SpMV on the padded layout: x is a whole
@@ -453,20 +418,11 @@ def dia_coded_padded_pallas(
     on stream 0): K per-class nonzero masks over the diagonals enabling
     the per-class-accumulator decode — see `_padded_kernel`.
 
-    ``axpy=(pprev, xacc, alpha)`` additionally applies the lagged
-    solution update of pipelined CG in the same pass: returns
-    ``(y, xacc')`` with ``xacc' = xacc + alpha*pprev`` on the owned band
-    (other slots copy through; xacc aliased in/out, alpha a (1,)-shaped
-    SMEM scalar). The update rides the kernel's spare DMA bandwidth
-    instead of its own HBM pass (tpu.py:make_cg_fn); callers must first
-    check `axpy_vmem_ok(plan)` — the plan's VMEM gate does not include
-    the three extra double-buffered pipeline blocks.
-
-    ``pfold=(pprev, beta)`` (fused CG, mutually exclusive with axpy)
-    instead treats ``x`` as the RESIDUAL vector and computes the SpMV of
-    the combined direction ``p = x + beta*pprev`` without ever reading a
-    materialized p: both windows are DMA'd, combined once in VMEM, and
-    the band sum runs on the combined copy. Returns ``(y, p)`` with
+    ``pfold=(pprev, beta)`` (fused CG) instead treats ``x`` as the
+    RESIDUAL vector and computes the SpMV of the combined direction
+    ``p = x + beta*pprev`` without ever reading a materialized p: both
+    windows are DMA'd, combined once in VMEM, and the band sum runs on
+    the combined copy. Returns ``(y, p)`` with
     ``y = A_oo p`` and ``p`` masked to the owned band (every other slot
     exactly zero) — the standard loop's standalone direction-update
     sweep is absorbed by the SpMV pass (tpu.py:make_cg_fn fused body).
@@ -475,9 +431,6 @@ def dia_coded_padded_pallas(
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    assert not (axpy is not None and pfold is not None), (
-        "axpy and pfold fusions are mutually exclusive"
-    )
     D = codebook.shape[0]
     Dc = codes.shape[0]
     assert D == len(offsets) == len(kk) == len(code_row)
@@ -493,8 +446,7 @@ def dia_coded_padded_pallas(
         _padded_kernel, qr=qr, kk=tuple(int(k) for k in kk),
         code_row=tuple(int(c) for c in code_row), n_blocks=nB,
         block_rows=BR, halo_rows=H, n_coded=Dc,
-        cls_pattern=cls_pattern, has_axpy=axpy is not None,
-        has_pfold=pfold is not None,
+        cls_pattern=cls_pattern, has_pfold=pfold is not None,
     )
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),  # codebook
@@ -540,45 +492,17 @@ def dia_coded_padded_pallas(
             interpret=interpret,
             name="pa_dia_coded_spmv_pfold",
         )(codebook, no, codes, x, pprev, beta)
-    if axpy is None:
-        return pl.pallas_call(
-            kernel,
-            grid=(total_rows // BR,),
-            in_specs=in_specs,
-            out_specs=y_spec,
-            out_shape=y_shape,
-            scratch_shapes=scratch,
-            compiler_params=params,
-            interpret=interpret,
-            name="pa_dia_coded_spmv",
-        )(codebook, no, codes, x)
-    pprev, xacc, alpha = axpy
-    assert pprev.shape == x.shape == xacc.shape
-    blk = pl.BlockSpec((BR, LANES), lambda j: (j, 0), memory_space=pltpu.VMEM)
     return pl.pallas_call(
         kernel,
         grid=(total_rows // BR,),
-        in_specs=in_specs + [
-            blk,  # pprev
-            blk,  # xacc in
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # alpha
-        ],
-        out_specs=[y_spec, blk],
-        out_shape=[y_shape, jax.ShapeDtypeStruct(xacc.shape, xacc.dtype)],
-        input_output_aliases={5: 1},
+        in_specs=in_specs,
+        out_specs=y_spec,
+        out_shape=y_shape,
         scratch_shapes=scratch,
         compiler_params=params,
         interpret=interpret,
-        name="pa_dia_coded_spmv_axpy",
-    )(codebook, no, codes, x, pprev, xacc, alpha)
-
-
-def axpy_vmem_ok(plan: dict, itemsize: int = 4) -> bool:
-    """Whether the fused-axpy variant's three extra double-buffered
-    (BR, 128) pipeline blocks still fit the VMEM budget the plan was
-    gated on."""
-    extra = 6 * plan["block_rows"] * LANES * itemsize
-    return plan.get("vmem", 0) + extra <= 13 * 2**20
+        name="pa_dia_coded_spmv",
+    )(codebook, no, codes, x)
 
 
 def pfold_vmem_ok(plan: dict, itemsize: int = 4) -> bool:

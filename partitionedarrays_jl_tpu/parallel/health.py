@@ -89,7 +89,6 @@ __all__ = [
     "PartLossError",
     "SilentCorruptionError",
     "PlanSoundnessError",
-    "LoweringConflictError",
     "health_enabled",
     "exchange_validation_enabled",
     "stagnation_raises",
@@ -141,15 +140,6 @@ class SolverHealthError(RuntimeError):
 
 class NonFiniteError(SolverHealthError):
     """NaN/Inf detected in solver state or an exchanged payload."""
-
-
-class LoweringConflictError(SolverHealthError):
-    """Two requested solver-body forms cannot compose into one lowered
-    program (e.g. ``fused`` × ``sstep``, ``sstep`` under strict-bits).
-    Raised at BUILD time by `make_cg_fn` — before anything is traced —
-    naming both sides of the conflict, instead of silently picking one
-    form and changing the program the caller asked for.
-    ``diagnostics["conflict"]`` carries the ``(a, b)`` pair."""
 
 
 class SolverBreakdownError(SolverHealthError):

@@ -410,8 +410,7 @@ def _color_edges(edges):
 def _exchange_edges(exchanger: Exchanger, layout) -> list:
     """The directed slot-level neighbor edges of an Exchanger over a
     device layout: ``(src, dst, snd_slots, rcv_slots)`` per edge — the
-    shared input of the flat plan's coloring and the two-level plan's
-    tiered schedule (both deliver exactly these slots)."""
+    input of the plan's coloring."""
     P = layout.P
     edges = []
     parts_snd = exchanger.parts_snd.part_values()
@@ -461,210 +460,6 @@ class DeviceExchangePlan:
         self.perms = tuple(self.perms)
 
 
-class WidenedDeviceExchangePlan(DeviceExchangePlan):
-    """The depth-s widened generic plan (s-step CG, ISSUE 17): the SAME
-    round structure and index matrices as the depth-1 plan — s-step
-    ships its aggregated ghost region as ``ghost_depth`` re-runs of
-    these rounds per outer trip, each carrying a 2-lane basis-pair slab
-    — tagged with the depth so comms accounting and the plan audit can
-    name the aggregation. `verify_plan` dispatches through the base
-    class: all five soundness checks run on the same structure."""
-
-    __slots__ = ("ghost_depth",)
-
-    def __init__(self, exchanger, layout, depth: int):
-        super().__init__(exchanger, layout)
-        self.ghost_depth = int(depth)
-
-
-class TwoLevelRound:
-    """One round of a two-level staged schedule: a tier tag, the
-    (possibly empty) ppermute pairs, and ragged per-round (P, L_r)
-    pack/mask/unpack index rows into the COMBINED frame
-    ``[xv (W) | stage (S) | stage trash]``. An empty ``perm`` marks an
-    intra-part copy round (local gather into / scatter out of the
-    node representative's stage region) — no wire traffic at all."""
-
-    __slots__ = ("tier", "perm", "snd_idx", "snd_mask", "rcv_idx")
-
-    def __init__(self, tier, perm, snd_idx, snd_mask, rcv_idx):
-        self.tier = tier
-        self.perm = tuple(perm)
-        self.snd_idx = snd_idx
-        self.snd_mask = snd_mask
-        self.rcv_idx = rcv_idx
-
-
-#: Tier vocabulary of a two-level schedule, in execution order for the
-#: aggregated (slow-fabric) path; "direct" rounds are the untouched
-#: fast-fabric ppermutes and run first.
-TWOLEVEL_TIERS = ("direct", "local_out", "gather", "node", "scatter",
-                  "local_in")
-
-
-class TwoLevelDeviceExchangePlan(DeviceExchangePlan):
-    """Node-aware two-level exchange plan (ISSUE 18, the TAPSpMV split
-    of arXiv:1612.08060 mapped onto mesh axes): messages crossing the
-    slow fabric are aggregated through ONE per-node representative part
-    — intra-node gather of the outbound slow-fabric slots into the
-    representative's stage region, one representative-to-representative
-    transfer per ordered (node, node) pair, intra-node scatter on
-    arrival — while same-node (fast-fabric) neighbors keep their direct
-    ppermute rounds.
-
-    The base-class state (``snd_idx``/``rcv_idx``/``perms``/``R``/``L``
-    built by ``super().__init__``) is the flat LOGICAL-DELIVERY view:
-    exactly the slots the schedule must deliver, so all five PR 8 plan
-    verifier checks run on it unchanged and
-    `canonical_exchange_fingerprint` (exchanger-derived) is invariant
-    across flat <-> two-level construction. The EXECUTED schedule lives
-    in ``tl_rounds``: ragged per-round index rows into the combined
-    frame ``[xv | stage (stage_width) | stage trash]``, each round
-    either a ppermute (non-empty ``perm``) or an intra-part copy. Every
-    hop is a pure copy — delivered ghost values are bitwise identical
-    to the flat plan's (the strict-bits trajectory pin in
-    tests/test_twolevel.py).
-
-    Aggregated message layout: per ordered (node a, node b) pair the
-    member messages are ordered by (sender, receiver) part id, packed
-    contiguously into rep(a)'s stage out-block and mirrored at the same
-    offsets in rep(b)'s stage in-block — both representatives derive
-    the layout from the same host-side plan, so no metadata crosses the
-    wire."""
-
-    __slots__ = ("node_of", "node_reps", "stage_width", "tl_rounds",
-                 "decision")
-
-    def __init__(self, exchanger, layout, node_of, decision=None):
-        super().__init__(exchanger, layout)
-        P, W = layout.P, layout.W
-        node_of = tuple(int(n) for n in node_of)
-        check(len(node_of) == P, "two-level plan: node map length != P")
-        self.node_of = node_of
-        reps = {}
-        for p, n in enumerate(node_of):
-            reps.setdefault(n, p)
-        self.node_reps = reps
-        edges = _exchange_edges(exchanger, layout)
-        fast = [e for e in edges if node_of[e[0]] == node_of[e[1]]]
-        slow = [e for e in edges if node_of[e[0]] != node_of[e[1]]]
-        # group slow messages per ordered (node, node) pair, member
-        # order fixed by (sender, receiver) part ids (docstring)
-        pairs = {}
-        for e in sorted(slow, key=lambda e: (node_of[e[0]], node_of[e[1]],
-                                             e[0], e[1])):
-            pairs.setdefault((node_of[e[0]], node_of[e[1]]), []).append(e)
-        # stage allocation: contiguous out/in block per pair on each
-        # representative; non-representative parts stage nothing
-        cursor = [0] * P
-        out_at, in_at = {}, {}
-        for ab, msgs in pairs.items():
-            a, b = ab
-            n_ab = sum(len(s) for _, _, s, _ in msgs)
-            out_at[ab] = cursor[reps[a]]
-            cursor[reps[a]] += n_ab
-            in_at[ab] = cursor[reps[b]]
-            cursor[reps[b]] += n_ab
-        self.stage_width = S = max(cursor)
-        strash = W + S
-        local_out, local_in = [], []   # (part, snd_slots, rcv_slots)
-        gather_by, scatter_by = {}, {}  # merged per (src, dst) edge
-        node_edges = []
-        for ab, msgs in pairs.items():
-            a, b = ab
-            ra, rb = reps[a], reps[b]
-            o, i = out_at[ab], in_at[ab]
-            node_snd, node_rcv = [], []
-            for p, q, snd, rcv in msgs:
-                k = len(snd)
-                out_slots = W + o + np.arange(k, dtype=INDEX_DTYPE)
-                in_slots = W + i + np.arange(k, dtype=INDEX_DTYPE)
-                snd = np.asarray(snd, dtype=INDEX_DTYPE)
-                rcv = np.asarray(rcv, dtype=INDEX_DTYPE)
-                if p == ra:
-                    local_out.append((p, snd, out_slots))
-                else:
-                    g = gather_by.setdefault((p, ra), ([], []))
-                    g[0].append(snd)
-                    g[1].append(out_slots)
-                if q == rb:
-                    local_in.append((q, in_slots, rcv))
-                else:
-                    s = scatter_by.setdefault((rb, q), ([], []))
-                    s[0].append(in_slots)
-                    s[1].append(rcv)
-                node_snd.append(out_slots)
-                node_rcv.append(in_slots)
-                o += k
-                i += k
-            node_edges.append((ra, rb, np.concatenate(node_snd),
-                               np.concatenate(node_rcv)))
-
-        def _round(tier, entries, permuted):
-            L_r = max(len(e[2]) for e in entries)
-            si = np.zeros((P, L_r), dtype=INDEX_DTYPE)
-            smk = np.zeros((P, L_r), dtype=bool)
-            ri = np.full((P, L_r), strash, dtype=INDEX_DTYPE)
-            perm = []
-            for src, dst, snd, rcv in entries:
-                k = len(snd)
-                si[src, :k] = snd
-                smk[src, :k] = True
-                ri[dst, :k] = rcv
-                if permuted:
-                    perm.append((src, dst))
-            return TwoLevelRound(tier, tuple(perm), si, smk, ri)
-
-        def _local_round(tier, copies):
-            per = {}
-            for p, snd, rcv in copies:
-                s, r = per.setdefault(p, ([], []))
-                s.append(snd)
-                r.append(rcv)
-            entries = [
-                (p, p, np.concatenate(s), np.concatenate(r))
-                for p, (s, r) in sorted(per.items())
-            ]
-            return _round(tier, entries, permuted=False)
-
-        tl = []
-        for edges_r in _color_edges(fast):
-            tl.append(_round("direct", edges_r, permuted=True))
-        if pairs:
-            if local_out:
-                tl.append(_local_round("local_out", local_out))
-            gathers = [
-                (p, ra, np.concatenate(s), np.concatenate(r))
-                for (p, ra), (s, r) in sorted(gather_by.items())
-            ]
-            for edges_r in _color_edges(gathers):
-                tl.append(_round("gather", edges_r, permuted=True))
-            for edges_r in _color_edges(node_edges):
-                tl.append(_round("node", edges_r, permuted=True))
-            scatters = [
-                (rb, q, np.concatenate(s), np.concatenate(r))
-                for (rb, q), (s, r) in sorted(scatter_by.items())
-            ]
-            for edges_r in _color_edges(scatters):
-                tl.append(_round("scatter", edges_r, permuted=True))
-            if local_in:
-                tl.append(_local_round("local_in", local_in))
-        self.tl_rounds = tuple(tl)
-        self.decision = dict(decision or {})
-
-    @property
-    def wire_rounds(self) -> int:
-        """Rounds that actually hit the wire (non-empty perm) — the
-        executed ppermute count comms accounting must mirror."""
-        return sum(1 for rd in self.tl_rounds if rd.perm)
-
-    def fabric_of_round(self, rd) -> str:
-        """The fabric tier a schedule round's wire traffic rides:
-        ``node`` rounds cross the slow fabric, every other permuted
-        tier stays on the fast one (intra-node)."""
-        return "dcn" if rd.tier == "node" else "ici"
-
-
 def _shard_exchange(plan, combine: str, abft: bool = False):
     """Per-shard halo exchange body (used inside shard_map): R static
     `ppermute` rounds. `combine='set'` for owner->ghost halo updates,
@@ -693,38 +488,6 @@ def _shard_exchange(plan, combine: str, abft: bool = False):
     import jax.numpy as jnp
 
     from .tpu_box import BoxExchangePlan, shard_box_exchange
-
-    if isinstance(plan, TwoLevelDeviceExchangePlan):
-        # the staged two-level schedule (ISSUE 18). ABFT and the 'add'
-        # assembly reverse keep the flat plan (_twolevel_env resolves
-        # off under ABFT; make_exchange_fn builds the flat reverse), so
-        # this body only ever runs the owner->ghost 'set' direction.
-        check(not abft, "ABFT exchange checksums require the flat plan")
-        check(combine == "set",
-              "two-level exchange serves the owner->ghost direction only")
-        W = plan.layout.W
-        S = plan.stage_width
-        tl = plan.tl_rounds
-        strash = W + S
-
-        def body_twolevel(xv, si, sm, ri):
-            # combined frame [xv | stage | stage trash]; every hop is a
-            # pure copy, so the delivered ghosts are bitwise the flat
-            # plan's values
-            pad = jnp.zeros((S + 1,) + xv.shape[1:], dtype=xv.dtype)
-            cv = jnp.concatenate([xv, pad], axis=0)
-            for r, rd in enumerate(tl):
-                mask = sm[r].reshape(sm[r].shape + (1,) * (cv.ndim - 1))
-                buf = jnp.where(mask, cv[si[r]], 0)
-                if rd.perm:
-                    buf = jax.lax.ppermute(buf, "parts", perm=rd.perm)
-                cv = cv.at[ri[r]].set(buf)
-                # keep both trash slots clean (padding invariants)
-                cv = cv.at[plan.layout.trash].set(0)
-                cv = cv.at[strash].set(0)
-            return cv[:W]
-
-        return _scoped(SCOPE_HALO, body_twolevel)
 
     if isinstance(plan, BoxExchangePlan):
         check(not abft, "ABFT exchange checksums require the generic plan")
@@ -884,162 +647,15 @@ def _fused_cg_enabled() -> bool:
     return os.environ.get("PA_TPU_FUSED_CG", "1") != "0" and not strict_bits()
 
 
-def _resolve_fused(fused, pipelined: bool) -> bool:
+def _resolve_fused(fused) -> bool:
     """The ONE resolution of the CG body choice: an explicit ``fused``
-    wins; ``None`` takes the env default (off under pipelined — the two
-    forms are mutually exclusive). Every layer (`tpu_cg`, the program
-    cache key, `make_cg_fn`) resolves through here so the compiled
-    program, the cache key, and the reported ``cg_body`` can never
-    disagree."""
+    wins; ``None`` takes the env default. Every layer (`tpu_cg`, the
+    program cache key, `make_cg_fn`) resolves through here so the
+    compiled program, the cache key, and the reported ``cg_body`` can
+    never disagree."""
     if fused is None:
-        return _fused_cg_enabled() and not pipelined
+        return _fused_cg_enabled()
     return bool(fused)
-
-
-def _sstep_env() -> int:
-    """The ONE resolution of the communication-avoiding s-step CG depth
-    (``PA_TPU_SSTEP``, default 0 = off; 1 is the degenerate form — the
-    textbook standard body). An s >= 2 selects the CA-CG body
-    (`make_cg_fn(sstep=s)`): s Krylov basis vectors per outer while
-    trip, ONE block all_gather carrying the whole Gram payload in place
-    of the 2s per-iteration scalar gathers. Strict-bits keeps the
-    textbook body as the oracle — the env resolves to 0 there (an
-    EXPLICIT ``sstep=`` >= 2 under strict-bits refuses typed instead,
-    see `_check_body_conflicts`). Lowering-affecting: folded into
-    `_lowering_env_key`, so every staged-matrix/program cache rekeys on
-    a flip."""
-    try:
-        v = int(os.environ.get("PA_TPU_SSTEP", "0") or "0")
-    except ValueError:
-        raise ValueError(
-            "PA_TPU_SSTEP must be an integer s-step depth (iterations "
-            "per outer step)"
-        )
-    if strict_bits():
-        return 0
-    return max(0, v)
-
-
-def _overlap_env() -> bool:
-    """The ONE resolution of the explicit interior/boundary overlap
-    SpMV form (``PA_TPU_OVERLAP=1``, default off). The overlap body
-    splits `_spmv_body`'s tail into interior rows (no ghost reads,
-    fenced with `optimization_barrier` so the compiler schedules them
-    against the in-flight ppermute rounds) and boundary rows finished
-    on halo arrival. The split changes the SCHEDULE, not the
-    arithmetic — values are bitwise identical to the standard tail, so
-    the mode stays available under strict-bits (and the bitwise pin in
-    tests/test_sstep.py proves it). Lowering-affecting: folded into
-    `_lowering_env_key`."""
-    return os.environ.get("PA_TPU_OVERLAP", "0") == "1"
-
-
-def _resolve_sstep(sstep) -> int:
-    """The ONE resolution of the s-step depth: an explicit ``sstep``
-    wins; ``None`` takes the env default (`_sstep_env`). Normalized so
-    0 and 1 both mean "the textbook standard body" (1 is the degenerate
-    s-step — identical program)."""
-    s = _sstep_env() if sstep is None else int(sstep)
-    return max(0, s)
-
-
-def _resolve_overlap(overlap) -> bool:
-    """The ONE resolution of the overlap-body choice: explicit wins,
-    ``None`` takes the env default (`_overlap_env`)."""
-    if overlap is None:
-        return _overlap_env()
-    return bool(overlap)
-
-
-def _twolevel_env() -> str:
-    """The ONE resolution of the node-aware two-level exchange mode
-    (``PA_TPU_TWOLEVEL`` in {0, 1, auto}, default 0 = flat; ISSUE 18).
-    ``1`` aggregates every slow-fabric message through the per-node
-    representatives whenever the node map shows >= 2 nodes with
-    cross-node edges; ``auto`` lets the measured cost model
-    (`telemetry.commsmatrix.twolevel_decision` over the committed
-    COMMS_MATRIX.json fabric fits) decide per neighbor graph whether
-    aggregation pays. Strict-bits keeps the flat plan as the bitwise
-    oracle and ABFT pins the flat plan (its per-round checksum lanes
-    are built on it) — the env resolves to ``0`` under either, the
-    PR 17 refusal/fallback convention. Lowering-affecting: folded into
-    `_lowering_env_key`, so every staged-matrix/program cache rekeys
-    on a flip."""
-    v = (os.environ.get("PA_TPU_TWOLEVEL", "0") or "0").strip().lower()
-    if v not in ("0", "1", "auto"):
-        raise ValueError("PA_TPU_TWOLEVEL must be 0, 1 or auto")
-    if strict_bits() or _abft_enabled():
-        return "0"
-    return v
-
-
-def _node_map_env() -> str:
-    """Raw ``PA_TPU_NODE_MAP`` spec (comma-separated part -> node ids,
-    e.g. ``0,0,1,1``) — the explicit fabric-topology override. Empty =
-    derive the map from the backend's device process indices
-    (`_resolve_node_map`). Keyed via `_lowering_env_key` (the raw
-    string) so a remapped topology restages."""
-    return (os.environ.get("PA_TPU_NODE_MAP", "") or "").strip()
-
-
-def _comms_matrix_env() -> str:
-    """``PA_TPU_COMMS_MATRIX``: path of the measured comms-matrix
-    record the ``auto`` cost model fits its per-fabric latency/
-    bandwidth model from (empty = the committed COMMS_MATRIX.json next
-    to the package when present, else the documented
-    DEFAULT_FABRIC_MODEL constants). Keyed via `_lowering_env_key`: a
-    different measurement feed can flip the auto decision, which
-    changes the staged plan."""
-    return (os.environ.get("PA_TPU_COMMS_MATRIX", "") or "").strip()
-
-
-def _resolve_node_map(P: int, backend=None):
-    """The ONE resolution of the part -> node map: the explicit
-    ``PA_TPU_NODE_MAP`` spec wins (length-P validated); otherwise the
-    backend's device ``process_index`` per mesh slot (the real
-    multi-host fabric boundary); ``None`` when neither names >= 1 node
-    (callers keep the flat plan)."""
-    spec = _node_map_env()
-    if spec:
-        try:
-            nodes = tuple(int(t) for t in spec.split(","))
-        except ValueError:
-            raise ValueError(
-                "PA_TPU_NODE_MAP must be a comma-separated part->node "
-                "map, e.g. 0,0,1,1"
-            )
-        if len(nodes) != P:
-            raise ValueError(
-                f"PA_TPU_NODE_MAP names {len(nodes)} parts but the mesh "
-                f"has {P}"
-            )
-        return nodes
-    if backend is not None:
-        devs = backend.devices()[:P]
-        if len(devs) == P:
-            return tuple(int(d.process_index) for d in devs)
-    return None
-
-
-def _sstep_resolve_env(pipelined, precond, rhs_batch, fused, have_sdc):
-    """Mirror `make_cg_fn`'s ENV-driven body resolution for callers
-    that must know the concrete body before building (the program cache
-    key in `_krylov_fn_for`, the telemetry body label in `tpu_cg`):
-    returns ``(eff_sstep, fused)``. The env-requested s-step body wins
-    over the env-default fused body (an EXPLICIT ``fused=True`` still
-    reaches `make_cg_fn`'s typed conflict), and every composition the
-    s-step body refuses — pipelined, precond, block, SDC — resolves to
-    depth 0 here exactly as `make_cg_fn`'s fallback does."""
-    s_env = _sstep_env()
-    if (
-        s_env >= 2 and not pipelined and not precond
-        and rhs_batch is None
-    ):
-        if fused is None:
-            fused = False
-        if not fused and not have_sdc:
-            return s_env, _resolve_fused(fused, pipelined)
-    return 0, _resolve_fused(fused, pipelined)
 
 
 def _trace_config() -> int:
@@ -1237,113 +853,22 @@ def device_layout(rows: PRange, padded: bool = False) -> DeviceLayout:
     return cache[key]
 
 
-def _twolevel_plan_request(rows: PRange, layout, depth: int, backend):
-    """Resolve whether THIS plan build goes two-level: returns
-    ``(node_of, decision)`` — ``node_of`` None keeps the flat plan.
+def device_exchange_plan(rows: PRange, padded: bool = False):
+    """Build (and cache on ``rows``) the device halo-exchange plan: the
+    slice-based `BoxExchangePlan` where the layout found a box structure
+    (`device_layout`), the index-vector `DeviceExchangePlan` otherwise."""
+    from .tpu_box import BoxExchangePlan
 
-    The PR 17 refusal/fallback conventions: strict-bits/ABFT already
-    resolved the env to "0" (`_twolevel_env`); an s-step widened plan
-    (depth >= 2) falls back to the flat widened plan with a stderr note
-    (two-level x matrix-powers aggregation is the named follow-up); a
-    single-node map or a neighbor graph with no cross-node edges keeps
-    the flat plan silently (there is nothing to aggregate). Mode
-    ``auto`` additionally asks the measured cost model
-    (`telemetry.commsmatrix.twolevel_decision`) whether aggregation
-    pays on this graph."""
-    import sys
-
-    mode = _twolevel_env()
-    if mode == "0":
-        return None, None
-    if depth >= 2:
-        sys.stderr.write(
-            "partitionedarrays_jl_tpu: PA_TPU_TWOLEVEL requested but the "
-            f"depth-{depth} s-step widened plan stays flat (two-level "
-            "aggregation of the matrix-powers exchange is the named "
-            "follow-up)\n"
-        )
-        return None, None
-    node_of = _resolve_node_map(layout.P, backend)
-    if node_of is None or len(set(node_of)) < 2:
-        return None, None
-    edges = _exchange_edges(rows.exchanger, layout)
-    profile = [(p, q, len(s)) for p, q, s, _ in edges]
-    if not any(node_of[p] != node_of[q] for p, q, _ in profile):
-        return None, None
-    from ..telemetry.commsmatrix import twolevel_decision
-
-    decision = twolevel_decision(
-        profile, node_of, matrix_path=_comms_matrix_env() or None
-    )
-    decision["mode"] = mode
-    if mode == "auto" and not decision["use"]:
-        return None, decision
-    decision["use"] = True
-    return node_of, decision
-
-
-def device_exchange_plan(rows: PRange, padded: bool = False,
-                         depth: int = 1, backend=None):
-    """Build (and cache on ``rows``) the device halo-exchange plan.
-
-    ``depth`` >= 2 returns the WIDENED plan variant for the s-step CG
-    body (ISSUE 17): the same round structure and slot indices as the
-    depth-1 plan, tagged with ``ghost_depth = depth`` — the s-step
-    outer trip re-runs this plan once per basis level, so the
-    aggregated ghost traffic it ships per trip is ``depth`` ×  the
-    per-level slab (each level a 2-lane ``(W, 2)`` pair payload).
-    Depth 1 is the exact pre-s-step object: the SAME cached instance,
-    byte-identical plan fingerprint (the tests/test_sstep.py regression
-    pin). Graph-distance-``s`` ghost widening (the matrix-powers-kernel
-    exchange that would collapse the per-level rounds into one) is the
-    named follow-up — the widened-plan type is where it lands.
-
-    The PR 8 plan verifier passes widened plans unchanged: they are
-    subclasses of the depth-1 plan types, so `verify_plan` dispatches
-    to the same five checks over the same index structure.
-
-    ``backend`` (optional) feeds the two-level node map default
-    (device ``process_index`` per mesh slot) when
-    ``PA_TPU_TWOLEVEL`` != 0 and no explicit ``PA_TPU_NODE_MAP`` is
-    set — see `_twolevel_plan_request` for the full selection rule."""
-    from .tpu_box import (
-        BoxExchangePlan,
-        TwoLevelBoxExchangePlan,
-        WidenedBoxExchangePlan,
-    )
-
-    depth = max(1, int(depth))
     cache = getattr(rows, "_device_plan", None)
     if cache is None:
         cache = rows._device_plan = {}
     layout = device_layout(rows, padded)
-    node_of, decision = _twolevel_plan_request(rows, layout, depth, backend)
-    key = (padded, layout.box_info is not None, depth, node_of)
+    key = (padded, layout.box_info is not None)
     if key not in cache:
-        if node_of is not None:
-            plan = (
-                TwoLevelBoxExchangePlan(
-                    rows.exchanger, layout, node_of, decision=decision
-                )
-                if layout.box_info is not None
-                else TwoLevelDeviceExchangePlan(
-                    rows.exchanger, layout, node_of, decision=decision
-                )
-            )
-        elif layout.box_info is not None:
-            plan = (
-                BoxExchangePlan(layout, layout.box_info)
-                if depth == 1
-                else WidenedBoxExchangePlan(
-                    layout, layout.box_info, depth=depth
-                )
-            )
-        elif depth == 1:
-            plan = DeviceExchangePlan(rows.exchanger, layout)
+        if layout.box_info is not None:
+            plan = BoxExchangePlan(layout, layout.box_info)
         else:
-            plan = WidenedDeviceExchangePlan(
-                rows.exchanger, layout, depth=depth
-            )
+            plan = DeviceExchangePlan(rows.exchanger, layout)
         if _plan_verify_enabled():
             # opt-in construction-time soundness gate (PA_PLAN_VERIFY=1):
             # a malformed plan raises the typed PlanSoundnessError HERE,
@@ -1379,7 +904,6 @@ class DeviceMatrix:
 
     __slots__ = (
         "oo_vals", "oo_cols", "oh_vals", "oh_cols", "oh_rows", "oh_nnz",
-        "oo_nnz",
         "dia_offsets", "dia_vals", "pallas_plan",
         "dia_mode", "dia_cb", "dia_no", "dia_codes", "dia_kk", "dia_code_row",
         "dia_cls_pattern",
@@ -1494,14 +1018,7 @@ class DeviceMatrix:
         check(row_layout.no_max == no_max, "rows layout mismatch")
         self.rows, self.cols = A.rows, A.cols
         self.row_layout, self.col_layout = row_layout, col_layout
-        # s-step mode stages the depth-s widened column plan (same
-        # rounds/indices, ghost_depth tag) — `_lowering_env_key` carries
-        # _sstep_env(), so a flip restages rather than serving this plan
-        _s = _sstep_env()
-        self.col_plan = device_exchange_plan(
-            A.cols, self.padded, depth=_s if _s >= 2 else 1,
-            backend=backend,
-        )
+        self.col_plan = device_exchange_plan(A.cols, self.padded)
         self.backend = backend
         L_oh = max((int(m.row_lengths().max()) if m.nnz else 0 for m in oh), default=0)
         L_oh = max(L_oh, 1)
@@ -1566,14 +1083,6 @@ class DeviceMatrix:
         # O(surface) and O(volume) serial work; an empty block (single
         # part, or interior-only coupling) skips the gather entirely.
         self.oh_nnz = sum(m.nnz for m in oh)
-        # interior/boundary nnz split — the structural attribution input
-        # of the overlap body's `boundary_spmv` phase (telemetry.profile).
-        # On the no-split DIA fast path `oo` is never materialized: the
-        # owned share is the full local nnz minus the extracted A_oh side.
-        self.oo_nnz = (
-            sum(m.nnz for m in oo) if oo is not None
-            else sum(m.nnz for m in full) - self.oh_nnz
-        )
         self.ohb_rows = self.ohb_cols = self.ohb_vals = self.ohb_bs = None
         self.ohs_vals = self.ohs_geo = None
         self.oh_vals = self.oh_cols = self.oh_rows = None
@@ -2606,20 +2115,6 @@ def _lowering_env_key() -> tuple:
         # RESOLVED guard pair re-runs admission on a real flip
         # (tests/test_static_analysis.py pins the re-guard).
         _ell_guard_env(),
-        # the s-step / overlap body modes (ISSUE 17): like the fused
-        # flag, the body choice itself is re-resolved per program, but
-        # s-step ALSO changes the staged matrix (the depth-s widened
-        # column exchange plan attaches at staging), so both key here
-        _sstep_env(),
-        _overlap_env(),
-        # the node-aware two-level exchange tier (ISSUE 18): the mode,
-        # the raw topology override, and the cost-model feed path all
-        # change which column exchange plan stages, so all three key —
-        # a remapped node topology or a different measured matrix
-        # restages instead of serving the stale schedule
-        _twolevel_env(),
-        _node_map_env(),
-        _comms_matrix_env(),
     )
 
 
@@ -2863,36 +2358,6 @@ def _pdot_extra_factory(o0: int, no_max: int):
     return _scoped(SCOPE_DOTS, pdotx)
 
 
-def _pgram_factory(o0: int, no_max: int):
-    """The s-step CG block reduction: ``pgram(V) -> G`` where ``V`` is
-    the owned-region Krylov basis slab ``(no_max, m)`` (m = 2s+1
-    columns) and ``G = Vᵀ V`` the replicated ``(m, m)`` Gram matrix —
-    every inner product the s inner iterations need, shipped on ONE
-    all_gather of the per-part ``(m, m)`` partial in place of the 2s
-    scalar gathers the standard body pays (`_pdot_owned_factory`'s
-    stacked-partial move, widened from a pair of lanes to the whole
-    moment payload). The cross-part fold is the same deterministic
-    part-order sum as `_pdot_factory`. s-step never runs under
-    strict-bits (the textbook body stays the oracle — `_sstep_env`), so
-    there is no fixed-tree variant here. HIGHEST precision on the
-    local partial: the Gram entries feed every α/β in the trip, and the
-    MXU's bf16 passes would poison the whole recurrence."""
-    import jax
-    import jax.numpy as jnp
-
-    def pgram(V):
-        Vo = V[o0 : o0 + no_max] if o0 else V[:no_max]
-        partial_ = jnp.einsum(
-            "wi,wj->ij", Vo, Vo,
-            preferred_element_type=V.dtype,
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        allp = jax.lax.all_gather(partial_, "parts")
-        return jnp.sum(allp, axis=0)
-
-    return _scoped(SCOPE_DOTS, pgram)
-
-
 def make_exchange_fn(rows: PRange, backend: TPUBackend, combine: str = "set") -> Callable:
     """Compiled halo update: (P, W) sharded array -> same with ghosts
     current (combine='set') or owners accumulated (combine='add', reverse
@@ -2902,14 +2367,9 @@ def make_exchange_fn(rows: PRange, backend: TPUBackend, combine: str = "set") ->
 
     from .tpu_box import BoxExchangePlan
 
-    plan = device_exchange_plan(rows, _padded_for(backend), backend=backend)
+    plan = device_exchange_plan(rows, _padded_for(backend))
     if combine == "add":
-        if isinstance(plan, TwoLevelDeviceExchangePlan):
-            # assembly reverse stays on the flat plan (aggregation only
-            # serves the owner->ghost forward direction; the reverse
-            # 'add' accumulation order is the flat plan's contract)
-            plan = DeviceExchangePlan(rows.exchanger.reverse(), plan.layout)
-        elif isinstance(plan, BoxExchangePlan):
+        if isinstance(plan, BoxExchangePlan):
             plan = plan.reverse()
         else:
             # reverse plan: swap pack/unpack roles
@@ -2921,26 +2381,17 @@ def make_exchange_fn(rows: PRange, backend: TPUBackend, combine: str = "set") ->
     @jax.jit
     def fn(x, si, sm, ri):
         def shard_fn(xs, sis, sms, ris):
-            # tree-mapped: the two-level plan ships ragged per-round
-            # tuples where the flat/box plans ship single arrays
-            pick = lambda t: jax.tree.map(lambda v: v[0], t)
-            return body(xs[0], pick(sis), pick(sms), pick(ris))[None]
+            return body(xs[0], sis[0], sms[0], ris[0])[None]
 
-        tspec = lambda t: jax.tree.map(lambda _: spec, t)
         return shard_map(
             shard_fn,
             mesh=mesh,
-            in_specs=(spec, tspec(si), tspec(sm), tspec(ri)),
+            in_specs=(spec, spec, spec, spec),
             out_specs=spec,
             check_vma=False,
         )(x, si, sm, ri)
 
-    if isinstance(plan, TwoLevelDeviceExchangePlan):
-        P = plan.layout.P
-        si = tuple(_stage(backend, rd.snd_idx, P) for rd in plan.tl_rounds)
-        sm = tuple(_stage(backend, rd.snd_mask, P) for rd in plan.tl_rounds)
-        ri = tuple(_stage(backend, rd.rcv_idx, P) for rd in plan.tl_rounds)
-    elif isinstance(plan, BoxExchangePlan):
+    if isinstance(plan, BoxExchangePlan):
         # everything is compiled in; tiny dummies keep the fn signature —
         # except the reverse path's sm slot, which carries the real
         # segment mask (orphan slab slots must not accumulate into owners)
@@ -2989,14 +2440,7 @@ def _matrix_operands(dA: DeviceMatrix) -> dict:
         return dA._ops_cache
     plan = dA.col_plan
     P = plan.layout.P
-    if isinstance(plan, TwoLevelDeviceExchangePlan):
-        # staged schedule: one ragged (P, L_r) leaf per round — tuples
-        # flow through the operand pytree exactly like the sd_i/sd_v
-        # width-bucket chunks, and the body indexes si[r] per round
-        si = tuple(_stage(dA.backend, rd.snd_idx, P) for rd in plan.tl_rounds)
-        sm = tuple(_stage(dA.backend, rd.snd_mask, P) for rd in plan.tl_rounds)
-        ri = tuple(_stage(dA.backend, rd.rcv_idx, P) for rd in plan.tl_rounds)
-    elif isinstance(plan, BoxExchangePlan):
+    if isinstance(plan, BoxExchangePlan):
         si, sm, ri = _box_dummy_operands(
             dA.backend, P, variants=plan.info.variants
         )
@@ -3027,44 +2471,19 @@ def _matrix_operands(dA: DeviceMatrix) -> dict:
     return ops
 
 
-def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
-               abft: bool = False, audit: bool = False,
-               overlap: Optional[bool] = None):
+def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
+               abft: bool = False, audit: bool = False):
     """Per-shard overlapped SpMV: pack+permute the halo, compute the A_oo
     partial on pre-exchange owned values (independent of the collective —
     XLA overlaps them), then unpack and add the A_oh ghost contribution
     on the compact boundary-row set.
 
-    ``overlap`` (default: `_overlap_env()` — ``PA_TPU_OVERLAP=1``)
-    makes the interior/boundary split EXPLICIT in the lowered program
-    (AsyncSparse, arXiv:2604.17834): the interior (A_oo) result — which
-    reads no ghost slots — is fenced behind an `optimization_barrier`
-    issued before the exchange's ppermute rounds complete, and the
-    boundary (A_oh) finish is fenced to run only after the
-    barrier-joined (interior, halo) pair — so the compiler's schedule
-    computes interior rows while the halo is in flight and finishes
-    boundary rows on arrival, instead of relying on XLA's implicit
-    latency hiding. The barriers change the SCHEDULE, never the
-    arithmetic: every value is bitwise identical to the default tail
-    (pinned under strict-bits by tests/test_sstep.py), and the
-    per-kind collective inventory is identical to the standard body
-    (the palint ``overlap-collective-parity`` contract).
-
-    With ``axpy=True`` the returned body has the signature
-    ``body(xv, m, xacc, pprev, alpha) -> (y, xacc')`` and ALSO applies
-    the lagged solution update ``xacc' = xacc + alpha*pprev`` (owned
-    region). On the padded coded path the update rides the Pallas
-    kernel's spare DMA bandwidth (see pipelined CG in `make_cg_fn` —
-    measured: the standalone x pass costs ~1/3 of a CG iteration because
-    x spills the loop's VMEM-resident working set); elsewhere it is the
-    plain in-loop update (same values, no overlap).
-
     With ``pfold=True`` (fused CG, `make_cg_fn(fused=True)`) the body is
     ``body(rv, pv, beta, m, mvv=None) -> (y, p)``: the next search
     direction ``p = z + beta*pv`` materializes inside the SpMV's own
-    streaming pass instead of its own HBM sweep — the generalization of
-    the `_dia_coded_full_axpy` pattern to the direction update, with a
-    jnp fold covering the BSR/SD/ELL/XLA-DIA lowerings.
+    streaming pass instead of its own HBM sweep: in the Pallas kernel on
+    the padded coded path, as a jnp fold on the BSR/SD/ELL/XLA-DIA
+    lowerings.
 
     Every body is RANK-POLYMORPHIC over the operand: ``(W,)`` applies the
     operator to one vector, ``(W, K)`` to a K-column multi-RHS block —
@@ -3075,7 +2494,7 @@ def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
     einsum, and the halo exchange ships ``(…, K)`` slabs per wire round
     (JITSPMM, arxiv 2312.05639 — amortize the operand stream across
     columns and feed the MXU). The Pallas kernels (coded padded frame,
-    streaming DIA, in-kernel pfold/axpy) keep a K=1-only guard and the
+    streaming DIA, in-kernel pfold) keep a K=1-only guard and the
     block path falls back to the equivalent XLA forms of the same
     arithmetic.
 
@@ -3098,7 +2517,6 @@ def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
     layout = dA.row_layout
     no_max = layout.no_max
     o0, g0 = layout.o0, layout.g0
-    overlap = _resolve_overlap(overlap)
 
     strict = strict_bits()  # captured at trace/build time
 
@@ -3222,48 +2640,24 @@ def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
             acc = term if acc is None else acc + term
         return jnp.where(_bc(jnp.arange(no_max) < no[0], xv), acc, 0)
 
-    if axpy and pplan is not None and dA.dia_cb is not None:
-        from ..ops.pallas_dia import axpy_vmem_ok
-
-        # the plan's VMEM gate did not include the axpy variant's three
-        # extra double-buffered pipeline blocks — re-check headroom and
-        # fall back to the plain lagged update when it is gone
-        _axpy_in_kernel = axpy_vmem_ok(
-            pplan, itemsize=np.dtype(dA.dia_cb.dtype).itemsize
-        )
-    else:
-        _axpy_in_kernel = False
-
     if (
         pfold and pplan is not None and dA.dia_cb is not None
         and not abft and not audit
     ):
         from ..ops.pallas_dia import pfold_vmem_ok
 
-        # same reasoning for the direction-fold variant's extra window /
-        # combined-copy / p-output VMEM. The SDC modes (abft/audit) keep
-        # this kernel OFF: the audit's operand switch and the checksum's
-        # exchanged-operand capture both live in the XLA fold — the
-        # ABFT-off guard with XLA fallback, mirroring the K>1 precedent
+        # the plan's VMEM gate did not include the direction-fold
+        # variant's extra window / combined-copy / p-output blocks:
+        # re-check headroom and fall back to the jnp fold when it is
+        # gone. The SDC modes (abft/audit) keep this kernel OFF: the
+        # audit's operand switch and the checksum's exchanged-operand
+        # capture both live in the XLA fold — the ABFT-off guard with
+        # XLA fallback, mirroring the K>1 precedent
         _pfold_in_kernel = pfold_vmem_ok(
             pplan, itemsize=np.dtype(dA.dia_cb.dtype).itemsize
         )
     else:
         _pfold_in_kernel = False
-
-    def _dia_coded_full_axpy(cb, no, codes, xv, xacc, pprev, alpha):
-        from ..ops.pallas_dia import LANES, dia_coded_padded_pallas
-
-        y, xacc2 = dia_coded_padded_pallas(
-            cb, no.astype(jnp.int32), codes, xv.reshape(-1, LANES),
-            offsets, kk, code_row, pplan, xv.shape[0] // LANES,
-            interpret=interpret, cls_pattern=dA.dia_cls_pattern,
-            axpy=(
-                pprev.reshape(-1, LANES), xacc.reshape(-1, LANES),
-                jnp.reshape(alpha, (1,)).astype(xv.dtype),
-            ),
-        )
-        return y.reshape(-1), xacc2.reshape(-1)
 
     def _dia_coded_full_pfold(cb, no, codes, rv, pv, beta):
         from ..ops.pallas_dia import LANES, dia_coded_padded_pallas
@@ -3467,20 +2861,7 @@ def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
         """Shared SpMV tail: halo-exchange the operand, embed the A_oo
         product in the row frame, add the boundary (A_oh) contribution.
         Returns (y, exchanged operand, exchange checksum delta, scale) —
-        the checksum pair is None unless ``abft``.
-
-        With ``overlap`` the interior product is fenced ahead of the
-        exchange and barrier-joined with the arrived halo before the
-        boundary finish — an explicit interior-rows / ppermute-in-flight
-        / boundary-rows-on-arrival schedule with identical values."""
-        if overlap:
-            # fence the ghost-free interior result so it is a scheduling
-            # unit independent of the in-flight ppermute rounds (values
-            # pass through the barrier bit-unchanged)
-            if full is not None:
-                full = jax.lax.optimization_barrier(full)
-            else:
-                partial_ = jax.lax.optimization_barrier(partial_)
+        the checksum pair is None unless ``abft``."""
         if abft:
             xv, exd, exs = exch(xv, m["si"], m["sm"], m["ri"])
         else:
@@ -3496,11 +2877,6 @@ def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
             y = jnp.zeros((layout.W,) + tail, dtype=xv.dtype).at[
                 o0 : o0 + no_max
             ].set(partial_)
-        if overlap and dA.oh_nnz:
-            # barrier-join: the boundary finish reads BOTH the interior
-            # embedding and the arrived halo — fencing the pair makes
-            # "finish boundary rows on arrival" explicit in the HLO
-            y, xv = jax.lax.optimization_barrier((y, xv))
         if dA.oh_nnz:
             # ghost contribution only on the boundary rows (padded rows
             # target the trash slot with exact-zero values)
@@ -3540,26 +2916,9 @@ def _spmv_body(dA: DeviceMatrix, axpy: bool = False, pfold: bool = False,
             y = y.at[g0:].set(0)
         return y, xv, exd, exs
 
-    def body(xv, m, *ax):
-        xacc2 = None
-        if mode == "coded" and pplan is not None and axpy and _axpy_in_kernel:
-            full, xacc2 = _dia_coded_full_axpy(
-                m["cb"], m["no"], m["codes"], xv, *ax
-            )
-            partial_ = None
-        else:
-            full, partial_ = _aoo(xv, m)
-        if axpy and xacc2 is None:
-            # fallback paths: the plain (unfused) lagged update — same
-            # values and order as the standard recurrence's axpy
-            xacc, pprev, alpha = ax
-            colL = dA.col_plan.layout
-            cs = slice(colL.o0, colL.o0 + colL.no_max)
-            with jax.named_scope(SCOPE_AXPY):
-                xacc2 = xacc.at[cs].add(_rp(alpha * pprev[cs]))
+    def body(xv, m):
+        full, partial_ = _aoo(xv, m)
         y, xv, exd, exs = _finish(full, partial_, xv, m)
-        if axpy:
-            return y, xacc2
         return (y, xv, exd, exs) if abft else (y, xv)
 
     def body_pfold(rv, pv, beta, m, mvv=None, aud=None, audx=None):
@@ -3668,22 +3027,18 @@ def make_spmv_fn(dA: DeviceMatrix) -> Callable:
 
 def make_cg_fn(
     dA: DeviceMatrix, tol: float, maxiter: int, precond: bool = False,
-    pipelined: bool = False, fused: Optional[bool] = None,
-    rhs_batch: Optional[int] = None, sstep: Optional[int] = None,
-    overlap: Optional[bool] = None,
+    fused: Optional[bool] = None, rhs_batch: Optional[int] = None,
 ) -> Callable:
     """The whole CG solve as ONE compiled shard_map program:
     `lax.while_loop` whose body does the overlapped SpMV, deterministic
     all-gather dots, and owned-region axpys. With ``precond`` the loop is
     preconditioned CG against a diagonal preconditioner supplied as an
     extra (P, W) operand (owned slots = inverse diagonal). Returns
-    (x_stacked, iterations, final_residual).
+    (x_stacked, iterations, final_residual). PERF.md section 5 has where
+    an iteration's time goes on the chip.
 
     ``fused`` (default: `_fused_cg_enabled()` — ON except strict-bits,
-    ``PA_TPU_FUSED_CG=0`` reverts) selects the fused streaming body for
-    large-N bandwidth-bound iterations (docs/performance.md §Per-DOF
-    scaling: at ≥320³ the standard body's five separate axpy/dot sweeps
-    run AT the ~677 GB/s HBM roofline, ~4.8 GB/iteration at 464³):
+    ``PA_TPU_FUSED_CG=0`` reverts) selects the fused streaming body:
 
     * the solution/residual updates ``x += α·p``, ``r -= α·q`` and the
       ``r·r`` (and ``r·z``) dot partials run in ONE sweep over the owned
@@ -3705,198 +3060,46 @@ def make_cg_fn(
     (unfused) body remains the strict-bits oracle and the default when
     ``PA_TPU_FUSED_CG=0``.
 
-    ``pipelined=True`` (unpreconditioned only) is the lag-1 form: the
-    solution update x += α·p is applied one iteration LATE, fused into
-    the next iteration's SpMV kernel where it rides spare DMA bandwidth
-    (`_spmv_body(axpy=True)`), with one flush after the loop. Motivation
-    (measured, 192³ f32 one chip): r/p/q stay VMEM-resident across the
-    loop so their updates are nearly free, but adding x to the working
-    set spills — the lone x pass costs ~80 µs of the 242 µs iteration.
-    Every scalar (α, β, residuals) follows the textbook recurrence on
-    the same dots in the same order, so the iteration trajectory is
-    IDENTICAL to the standard form — only where x materializes changes
-    (validated in tests/test_tpu.py).
-
     ``rhs_batch=K`` selects the BLOCK (multi-RHS) program instead: the
     operands become (P, W, K) slabs, the operator streams once per K
     columns (`_spmv_body`'s rank-polymorphic lowerings), and every
     column runs the textbook single-vector recurrence with per-column
-    scalars — see `make_block_cg_fn`, to which this delegates.
-
-    ``sstep=s`` (default: ``PA_TPU_SSTEP`` via `_sstep_env`; s <= 1 is
-    the textbook body) selects the communication-avoiding s-step/CA-CG
-    body: each outer while trip builds the s-deep Krylov basis
-    ``[p, Ap, …, Aˢp, r, Ar, …, Aˢ⁻¹r]`` by s levels of a PAIR SpMV
-    over the stacked ``(W, 2)`` operand (one halo exchange per level,
-    shipping the 2-lane slab through the depth-s widened plan — the
-    aggregated s-step ghost region), computes the whole (2s+1)-column
-    Gram payload with ONE block all_gather (`_pgram_factory`), runs the
-    s inner iterations as scalar recurrences in basis COORDINATES, and
-    materializes x/r/p once at trip end. Collective count per s
-    iterations: s exchanges + 1 dot all_gather, vs the standard body's
-    s exchanges + 2s gathers — the latency-floor attack (ROADMAP item
-    1; the palint ``sstep-gather-collapse`` contract pins the 1).
-    Monomial-basis conditioning degrades like κ̂ˢ, so choose s from the
-    measured spectrum (`telemetry.suggest_s`); the inner recurrences
-    re-associate the dots, so the trajectory is NOT bitwise the
-    textbook one for s >= 2 (s = 1 builds the identical standard
-    program). Single-RHS, unpreconditioned, unfused, SDC-off only —
-    explicit conflicting forms refuse with the typed
-    `LoweringConflictError`; env-driven conflicts fall back to the
-    textbook body with a stderr note (the pipelined-SDC precedent).
-
-    ``overlap`` (default: ``PA_TPU_OVERLAP`` via `_overlap_env`)
-    threads the explicit interior/boundary overlap SpMV tail
-    (`_spmv_body(overlap=True)`) through whichever body is selected —
-    it changes the schedule, never the values, and composes with every
-    form including ``sstep``."""
+    scalars — see `make_block_cg_fn`, to which this delegates."""
     import jax
     import jax.numpy as jnp
     shard_map = jax.shard_map
 
-    sstep_explicit = sstep is not None
-    sstep = _resolve_sstep(sstep)
-    overlap = _resolve_overlap(overlap)
-
-    def _conflict(other: str):
-        # unconditional typed refusal (not check()): silently picking a
-        # body would change the program the caller asked for
-        from .health import LoweringConflictError
-
-        raise LoweringConflictError(
-            "make_cg_fn: the s-step (communication-avoiding) body does "
-            f"not compose with {other} — drop sstep or {other}",
-            diagnostics={"conflict": ("sstep", other)},
-        )
-
-    def _sstep_env_fallback(other: str) -> int:
-        # env-driven s-step meeting an incompatible form: the explicit
-        # request wins, s-step reverts to the textbook body — say so
-        # (the pipelined-SDC precedent: a user counting on the env var
-        # must know which body ran)
-        import sys
-
-        print(
-            "[partitionedarrays_jl_tpu] make_cg_fn: PA_TPU_SSTEP is set "
-            f"but this program uses {other} — the s-step body does not "
-            "compose with it; building the textbook body instead",
-            file=sys.stderr,
-            flush=True,
-        )
-        return 0
-
-    if sstep >= 2 and strict_bits():
-        # only reachable with an EXPLICIT sstep (the env resolves to 0
-        # under strict-bits): the textbook body is the strict oracle
-        _conflict("strict_bits (the textbook body is the bitwise oracle)")
-    if sstep >= 2 and fused:
-        # an explicit fused=True; the env default yields to s-step below
-        _conflict("fused")
-
     if rhs_batch is not None:
-        if pipelined:
-            # unconditional (not check()): the lag-1 x placement has no
-            # block generalization this round — refuse, don't reinterpret
-            raise ValueError(
-                "make_cg_fn: the pipelined (lag-1) form is single-RHS "
-                "only — drop pipelined or rhs_batch"
-            )
-        if sstep >= 2:
-            if sstep_explicit:
-                _conflict("rhs_batch")
-            _sstep_env_fallback("rhs_batch (block CG)")
         return make_block_cg_fn(
-            dA, tol, maxiter, rhs_batch, precond=precond, fused=fused,
-            overlap=overlap,
+            dA, tol, maxiter, rhs_batch, precond=precond, fused=fused
         )
-
-    if sstep >= 2:
-        if pipelined:
-            if sstep_explicit:
-                _conflict("pipelined")
-            sstep = _sstep_env_fallback("the pipelined (lag-1) form")
-        elif precond:
-            if sstep_explicit:
-                _conflict("precond")
-            sstep = _sstep_env_fallback("preconditioning")
-        else:
-            # the s-step body IS an unfused body: the PA_TPU_FUSED_CG
-            # default yields (an explicit fused=True refused above)
-            fused = False
-    if sstep < 2:
-        fused = _resolve_fused(fused, pipelined)
-    if fused and pipelined:
-        # unconditional (not check()): the two bodies place the x update
-        # differently — silently picking one would change the program
-        raise ValueError(
-            "make_cg_fn: fused and pipelined are mutually exclusive forms"
-        )
+    fused = _resolve_fused(fused)
     mesh = dA.backend.mesh(dA.row_layout.P)
     spec = dA.backend.parts_spec()
     none_spec = jax.sharding.PartitionSpec()
     # the SDC defense (in-graph ABFT checksums + true-residual audit +
     # device-resident rollback ring) — None resolves to the exact
-    # pre-SDC program. The pipelined (lag-1) form is exempt this round:
-    # its in-kernel x placement has no audit/rollback generalization
-    # (docs/resilience.md).
+    # pre-SDC program.
     sdccfg = _sdc_config(maxiter)
-    if sstep >= 2 and sdccfg is not None:
-        # the s-step coordinate recurrences have no checksum/audit
-        # generalization this round; the defense wins over an env-driven
-        # s-step request (safety first), an explicit one refuses typed
-        if sstep_explicit:
-            _conflict("the SDC defense (PA_TPU_ABFT/PA_HEALTH_AUDIT_*)")
-        sstep = _sstep_env_fallback("the SDC defense (ABFT/audit)")
-    if pipelined and sdccfg is not None:
-        # say it out loud: the lowering still pays ABFT's side costs
-        # (generic exchange plan, staged checksum row) but this body
-        # runs UNDEFENDED — a user counting on the env var must know
-        import sys
-
-        print(
-            "[partitionedarrays_jl_tpu] make_cg_fn: the pipelined "
-            "(lag-1) body has no SDC defense this round — "
-            "PA_TPU_ABFT/PA_HEALTH_AUDIT_EVERY are ignored for this "
-            "program (use the standard or fused body for a defended "
-            "solve)",
-            file=sys.stderr,
-            flush=True,
-        )
-        sdccfg = None
     abft_on = bool(sdccfg and sdccfg["abft"])
     # device α/β trace ring (PA_TRACE_ITERS, telemetry): a (Ht, 2)
     # replicated carry written on committed iterations only — no new
     # collectives (alpha/beta are scalars the dot gathers already
     # replicated). Depth 0 (the default) leaves the traced program
-    # byte-identical to the pre-telemetry one; the pipelined body is
-    # trace-exempt (the same precedent as its SDC exemption).
-    Ht = 0 if pipelined else int(min(_trace_config(), maxiter))
-    body_spmv = _spmv_body(dA, abft=abft_on, overlap=overlap)
-    body_axpy = (
-        _spmv_body(dA, axpy=True, overlap=overlap) if pipelined else None
-    )
+    # byte-identical to the pre-telemetry one.
+    Ht = int(min(_trace_config(), maxiter))
+    body_spmv = _spmv_body(dA, abft=abft_on)
     body_pfold = (
-        _spmv_body(
-            dA, pfold=True, abft=abft_on, audit=sdccfg is not None,
-            overlap=overlap,
-        )
+        _spmv_body(dA, pfold=True, abft=abft_on, audit=sdccfg is not None)
         if fused
         else None
     )
     no_max = dA.row_layout.no_max
     o0 = dA.row_layout.o0
     g0 = dA.row_layout.g0
-    if pipelined and precond:
-        # unconditional (not check()): with PA_TPU_CHECKS=0 a stripped
-        # guard would silently drop the preconditioner and change results
-        raise ValueError(
-            "make_cg_fn: the pipelined (lag-1) form is unpreconditioned-"
-            "only — drop precond or pipelined"
-        )
     pdot = _pdot_factory(o0, no_max)
     odot1, odot2 = _pdot_owned_factory(no_max)
     dox = _pdot_extra_factory(0, no_max) if sdccfg is not None else None
-    pgram = _pgram_factory(0, no_max) if sstep >= 2 else None
     ops = _matrix_operands(dA)
     specs = jax.tree.map(lambda _: spec, ops)
     strict = strict_bits()
@@ -3909,19 +3112,6 @@ def make_cg_fn(
     # per-iteration residual history, fixed-shape for the while_loop carry
     # (capped: a convergence curve beyond this many entries is truncated)
     H = int(min(maxiter + 1, 4096))
-
-    # s-step basis-shift matrix (static): with monomial columns ordered
-    # [p, Ap, .., A^s p, r, Ar, .., A^{s-1} r], multiplying coordinates
-    # by B is "apply A" — a degree bump inside each block. The last
-    # column of each block has no in-span image; the recurrences never
-    # need it (p_j has degree ≤ s-1 when w = A p_j is formed).
-    B_shift = None
-    if sstep >= 2:
-        B_shift = np.zeros((2 * sstep + 1, 2 * sstep + 1))
-        for _i in range(sstep):
-            B_shift[_i + 1, _i] = 1.0
-        for _i in range(sstep - 1):
-            B_shift[sstep + 2 + _i, sstep + 1 + _i] = 1.0
 
     @jax.jit
     def fn(b, x0, mv, m):
@@ -4468,148 +3658,13 @@ def make_cg_fn(
                     ),)
                 return out
 
-            if sstep >= 2:
-                # ---- communication-avoiding s-step (CA-CG) loop ----
-                # One outer while trip = s textbook iterations. The trip
-                # builds the monomial Krylov basis by s levels of a PAIR
-                # SpMV on the stacked (W, 2) [p | r] operand (one halo
-                # exchange per level, both lanes on one wire round),
-                # ships the ENTIRE inner-product workload as one Gram
-                # all_gather, then runs the s α/β recurrences on basis
-                # COORDINATES (m = 2s+1 scalars each) — zero collectives
-                # — and materializes x/r/p with three owned-region GEMVs
-                # at trip end. Residual norms come from the coordinate
-                # quadratic form r_cᵀ G r_c (clamped at 0: near
-                # convergence the re-associated form can round a hair
-                # negative); convergence is checked once per trip, so a
-                # solve can run up to s-1 iterations past tolerance —
-                # `iterations` stays honest (trips × s).
-                slf2 = slice(o0, o0 + no_max)
-                m_dim = 2 * sstep + 1
-                hp = jax.lax.Precision.HIGHEST
-
-                def gemv(V, c):
-                    return jnp.einsum(
-                        "wm,m->w", V, c,
-                        preferred_element_type=V.dtype, precision=hp,
-                    )
-
-                def step_ss(state):
-                    if Ht:
-                        x, r_, p_, _rz, rs_, it, hist_, ab = state
-                    else:
-                        x, r_, p_, _rz, rs_, it, hist_ = state
-                        ab = None
-                    # s basis levels: cur carries [Aʲp | Aʲr] in the
-                    # cols layout; the body returns the rows-range
-                    # product, so each level re-embeds the owned rows
-                    # (ghost slots zero — the next level's exchange
-                    # refills them from the owners, exactly like the
-                    # textbook body's per-iteration p update)
-                    cur = jnp.stack([p_, r_], axis=-1)
-                    pcols = [p_[slf2]]
-                    rcols = [r_[slf2]]
-                    for lev in range(sstep):
-                        y_lv, _ = body_spmv(cur, mats)
-                        yo = y_lv[slf2]
-                        pcols.append(yo[:, 0])
-                        if lev < sstep - 1:
-                            rcols.append(yo[:, 1])
-                            cur = (
-                                jnp.zeros(
-                                    (p_.shape[0], 2), dtype=p_.dtype
-                                ).at[slf2].set(yo)
-                            )
-                    V = jnp.stack(pcols + rcols, axis=-1)
-                    G = pgram(V)  # the ONE dot all_gather of the trip
-                    Bs = jnp.asarray(B_shift, dtype=bv.dtype)
-                    p_c = jnp.zeros((m_dim,), bv.dtype).at[0].set(1.0)
-                    r_c = (
-                        jnp.zeros((m_dim,), bv.dtype)
-                        .at[sstep + 1].set(1.0)
-                    )
-                    x_c = jnp.zeros((m_dim,), bv.dtype)
-                    rs_j = rs_
-                    hist2, ab2 = hist_, ab
-                    for j in range(sstep):
-                        w = Bs @ p_c  # coords of A p_j (in-span by deg)
-                        alpha = rs_j / (p_c @ (G @ w))
-                        x_c = x_c + alpha * p_c
-                        r_c = r_c - alpha * w
-                        rs_new = jnp.maximum(r_c @ (G @ r_c), 0.0)
-                        beta = rs_new / rs_j
-                        p_c = r_c + beta * p_c
-                        hist2 = hist2.at[
-                            jnp.minimum(it + j + 1, H - 1)
-                        ].set(jnp.sqrt(rs_new))
-                        if Ht:
-                            ab2 = ab2.at[(it + j) % Ht].set(
-                                jnp.stack([alpha, beta])
-                            )
-                        rs_j = rs_new
-                    x2 = x.at[slf2].add(gemv(V, x_c))
-                    r2 = r_.at[slf2].set(gemv(V, r_c))
-                    p2 = p_.at[slf2].set(gemv(V, p_c))
-                    out = (x2, r2, p2, rs_j, rs_j, it + sstep, hist2)
-                    if Ht:
-                        out = out + (ab2,)
-                    return out
-
-                init_ss = (xv, r, p, rz0, rs0, jnp.int32(0), hist)
-                if Ht:
-                    init_ss = init_ss + (
-                        jnp.zeros((Ht, 2), dtype=bv.dtype),
-                    )
-                fin = _krylov_loop(cond, step_ss, init_ss)
-                x, rs, it, hist = fin[0], fin[4], fin[5], fin[6]
-                out = (x[None], rs, rs0, it, hist)
-                return out + ((fin[7],) if Ht else ())
-
-            if not pipelined:
-                init_s = (xv, r, p, rz0, rs0, jnp.int32(0), hist)
-                if Ht:
-                    init_s = init_s + (jnp.zeros((Ht, 2), dtype=bv.dtype),)
-                fin = _krylov_loop(cond, step, init_s)
-                x, rs, it, hist = fin[0], fin[4], fin[5], fin[6]
-                out = (x[None], rs, rs0, it, hist)
-                return out + ((fin[7],) if Ht else ())
-
-            sl = slice(o0, o0 + no_max)
-
-            def cond_pipe(state):
-                _x, _r, _p, _pp, _ap, rs, it, _h = state
-                return (
-                    (jnp.sqrt(rs) > tol * jnp.maximum(1.0, jnp.sqrt(rs0)))
-                    & (it < maxiter)
-                    & jnp.isfinite(rs)  # same in-graph guard as `cond`
-                )
-
-            def step_pipe(state):
-                x, r, p, p_prev, alpha_prev, rs, it, hist = state
-                # the SpMV also flushes LAST iteration's x update inside
-                # the kernel's streaming pass
-                q, x = body_axpy(
-                    p, mats, x, p_prev, alpha_prev
-                )
-                pq = pdot(p, q)
-                alpha = rs / pq
-                r = r.at[sl].add(_rp(-alpha * q[sl]))
-                rs_new = pdot(r, r)
-                beta = rs_new / rs
-                p_new = p.at[sl].set(r[sl] + _rp(beta * p[sl]))
-                hist = hist.at[jnp.minimum(it + 1, H - 1)].set(
-                    jnp.sqrt(rs_new)
-                )
-                return (x, r, p_new, p, alpha, rs_new, it + 1, hist)
-
-            zero = jnp.zeros((), bv.dtype)
-            x, r, p, p_prev, alpha_prev, rs, it, hist = _krylov_loop(
-                cond_pipe, step_pipe,
-                (xv, r, p, jnp.zeros_like(p), zero, rs0, jnp.int32(0), hist),
-            )
-            # flush the final lagged update (no-op when zero iterations)
-            x = x.at[sl].add(_rp(alpha_prev * p_prev[sl]))
-            return x[None], rs, rs0, it, hist
+            init_s = (xv, r, p, rz0, rs0, jnp.int32(0), hist)
+            if Ht:
+                init_s = init_s + (jnp.zeros((Ht, 2), dtype=bv.dtype),)
+            fin = _krylov_loop(cond, step, init_s)
+            x, rs, it, hist = fin[0], fin[4], fin[5], fin[6]
+            out = (x[None], rs, rs0, it, hist)
+            return out + ((fin[7],) if Ht else ())
 
         nouts = 4 + (1 if sdccfg is not None else 0) + (1 if Ht else 0)
         return shard_map(
@@ -4650,10 +3705,8 @@ def make_cg_fn(
     # the plan-level collective inventory of this body (telemetry.comms)
     # — the measured half of the static-vs-measured accounting
     run.comms_kwargs = dict(
-        precond=bool(precond), pipelined=bool(pipelined),
-        fused=bool(fused), rhs_batch=None,
+        precond=bool(precond), fused=bool(fused), rhs_batch=None,
         sdc=sdccfg is not None, abft=abft_on,
-        sstep=int(sstep) if sstep >= 2 else 0, overlap=bool(overlap),
     )
     return run
 
@@ -4661,7 +3714,6 @@ def make_cg_fn(
 def make_block_cg_fn(
     dA: DeviceMatrix, tol: float, maxiter: int, rhs_batch: int,
     precond: bool = False, fused: Optional[bool] = None,
-    overlap: Optional[bool] = None,
 ) -> Callable:
     """Block (multi-RHS) CG: ONE compiled shard_map program solving
     ``A X = B`` for K = ``rhs_batch`` right-hand sides against the SAME
@@ -4703,7 +3755,7 @@ def make_block_cg_fn(
 
     K = int(rhs_batch)
     check(K >= 1, "make_block_cg_fn: rhs_batch must be >= 1")
-    fused = _resolve_fused(fused, False)
+    fused = _resolve_fused(fused)
     mesh = dA.backend.mesh(dA.row_layout.P)
     spec = dA.backend.parts_spec()
     none_spec = jax.sharding.PartitionSpec()
@@ -4716,16 +3768,11 @@ def make_block_cg_fn(
     # block α/β trace ring: an (Ht, 2, K) replicated carry, committed
     # iterations only. The SDC-defended block loop is trace-exempt this
     # round (its per-column freeze/rollback bookkeeping has no committed
-    # α/β slot per trip) — same precedent as the pipelined body's SDC
-    # exemption, noted in docs/observability.md.
+    # α/β slot per trip), noted in docs/observability.md.
     Ht = 0 if sdccfg is not None else int(min(_trace_config(), maxiter))
-    overlap = _resolve_overlap(overlap)
-    body_spmv = _spmv_body(dA, abft=abft_on, overlap=overlap)
+    body_spmv = _spmv_body(dA, abft=abft_on)
     body_pfold = (
-        _spmv_body(
-            dA, pfold=True, abft=abft_on, audit=sdccfg is not None,
-            overlap=overlap,
-        )
+        _spmv_body(dA, pfold=True, abft=abft_on, audit=sdccfg is not None)
         if fused
         else None
     )
@@ -5310,9 +4357,8 @@ def make_block_cg_fn(
     run.has_sdc = sdccfg is not None
     run.trace_iters = Ht
     run.comms_kwargs = dict(
-        precond=bool(precond), pipelined=False, fused=bool(fused),
-        rhs_batch=K, sdc=sdccfg is not None, abft=abft_on,
-        sstep=0, overlap=bool(overlap),
+        precond=bool(precond), fused=bool(fused), rhs_batch=K,
+        sdc=sdccfg is not None, abft=abft_on,
     )
     return run
 
@@ -6338,32 +5384,22 @@ def tpu_cg(
     maxiter: Optional[int] = None,
     verbose: bool = False,
     minv: Optional[PVector] = None,
-    pipelined: bool = False,
     fused: Optional[bool] = None,
 ) -> Tuple[PVector, dict]:
     """Device (preconditioned) CG: the whole loop is one compiled
     shard_map program. `minv` is an optional diagonal preconditioner (a
     PVector over A.cols holding the inverse diagonal in its owned
-    entries). ``pipelined`` selects the lag-1 form with the solution
-    update fused into the SpMV kernel; ``fused`` (default: resolved from
-    ``PA_TPU_FUSED_CG``, ON outside strict-bits) selects the fused
-    streaming body (see `make_cg_fn`). The
-    info dict records which body ran under ``cg_body``."""
+    entries). ``fused`` (default: resolved from ``PA_TPU_FUSED_CG``, ON
+    outside strict-bits) selects the fused streaming body (see
+    `make_cg_fn`). The info dict records which body ran under
+    ``cg_body``."""
     from .. import telemetry
 
     backend = b.values.backend
     check(isinstance(backend, TPUBackend), "tpu_cg needs a TPU-backend PVector")
     maxiter = maxiter if maxiter is not None else 4 * A.rows.ngids
-    _sdc0 = None if pipelined else _sdc_config(int(maxiter))
-    eff_sstep, fused = _sstep_resolve_env(
-        pipelined, minv is not None, None, fused, _sdc0 is not None
-    )
-    body = (
-        "pipelined" if pipelined
-        else f"sstep{eff_sstep}" if eff_sstep
-        else "fused" if fused
-        else "standard"
-    )
+    fused = _resolve_fused(fused)
+    body = "fused" if fused else "standard"
     name = "pcg" if minv is not None else "cg"
     with telemetry.solve_scope(
         name, backend="tpu", tol=float(tol), maxiter=int(maxiter),
@@ -6372,8 +5408,7 @@ def tpu_cg(
     ) as rec:
         dA = device_matrix(A, backend)
         solve = _krylov_fn_for(
-            dA, "cg", tol, maxiter, precond=minv is not None,
-            pipelined=pipelined, fused=fused,
+            dA, "cg", tol, maxiter, precond=minv is not None, fused=fused,
         )
         x, info = _run_krylov(
             A, b, x0, tol, verbose, solve, minv=minv, name=name,
@@ -6452,7 +5487,7 @@ def tpu_block_cg(
         "tpu_block_cg needs TPU-backend PVectors",
     )
     maxiter = maxiter if maxiter is not None else 4 * A.rows.ngids
-    fused = _resolve_fused(fused, False)
+    fused = _resolve_fused(fused)
     dt = np.result_type(*[b.dtype for b in B])
     name = "block-pcg" if minv is not None else "block-cg"
     with telemetry.solve_scope(
@@ -6699,40 +5734,28 @@ def tpu_bicgstab(
 
 def _krylov_fn_for(
     dA: DeviceMatrix, method: str, tol: float, maxiter: int,
-    precond: bool = False, pipelined: bool = False,
-    fused: Optional[bool] = None, rhs_batch: Optional[int] = None,
+    precond: bool = False, fused: Optional[bool] = None,
+    rhs_batch: Optional[int] = None,
 ):
     # the SDC config (audit period, budgets, tolerance overrides, the
     # device fault clause) is resolved at build time — key it so an env
     # flip rebuilds the program instead of serving a stale defense
-    # (pipelined programs are SDC-exempt and must not retrace on flips)
-    sdccfg = None if pipelined else _sdc_config(int(maxiter))
-    # env-driven s-step / overlap: the cache key must hold the CONCRETE
-    # body choice, so mirror make_cg_fn's resolution order — the s-step
-    # body wins over an env-default fused, and every composition it
-    # refuses (pipelined/precond/block/SDC) falls back to the standard
-    # depth (make_cg_fn prints the fallback note when it builds)
-    eff_sstep = 0
+    sdccfg = _sdc_config(int(maxiter))
     if method == "cg":
         # the cache key must be the CONCRETE body choice (the env mode is
         # also part of _lowering_env_key, which rekeys the DeviceMatrix
         # itself on a flip)
-        eff_sstep, fused = _sstep_resolve_env(
-            pipelined, precond, rhs_batch, fused, sdccfg is not None
-        )
-    eff_overlap = _overlap_env()
+        fused = _resolve_fused(fused)
     # the trace-ring depth changes the traced program (an extra carry),
     # so it joins the key through the same helper make_cg_fn resolves
     # it with (_trace_config — a registered env-key site). Key the
-    # EFFECTIVE depth, mirroring the builders' clamps: the pipelined
-    # body, the SDC-defended block body, and bicgstab have no ring, and
-    # depth saturates at maxiter — a PA_TRACE_ITERS flip must not
-    # rebuild a program the flip cannot reach.
+    # EFFECTIVE depth, mirroring the builders' clamps: the SDC-defended
+    # block body and bicgstab have no ring, and depth saturates at
+    # maxiter — a PA_TRACE_ITERS flip must not rebuild a program the
+    # flip cannot reach.
     from .. import telemetry
 
-    if method != "cg" or pipelined or (
-        rhs_batch is not None and sdccfg is not None
-    ):
+    if method != "cg" or (rhs_batch is not None and sdccfg is not None):
         trace_ht = 0
         requested = _trace_config()
         if requested > 0:
@@ -6740,11 +5763,7 @@ def _krylov_fn_for(
             # the α/β ring must say so — a typed event names the body,
             # so a missing spectrum is explained, never mysterious
             # (tools/paspec.py and tools/patrace.py surface it)
-            body = (
-                "pipelined" if pipelined
-                else "sdc-block" if method == "cg"
-                else method
-            )
+            body = "sdc-block" if method == "cg" else method
             telemetry.emit_event(
                 "trace_unavailable", label=body, requested=requested,
                 method=method,
@@ -6755,9 +5774,8 @@ def _krylov_fn_for(
     else:
         trace_ht = int(min(_trace_config(), int(maxiter)))
     key = (
-        method, float(tol), int(maxiter), bool(precond), bool(pipelined),
-        bool(fused), rhs_batch, sdccfg["key"] if sdccfg else None,
-        trace_ht, eff_sstep, eff_overlap,
+        method, float(tol), int(maxiter), bool(precond), bool(fused),
+        rhs_batch, sdccfg["key"] if sdccfg else None, trace_ht,
     )
 
     if key not in dA._cg_cache:
@@ -6768,8 +5786,8 @@ def _krylov_fn_for(
         )
         if method == "cg":
             dA._cg_cache[key] = make_cg_fn(
-                dA, tol, maxiter, precond=precond, pipelined=pipelined,
-                fused=fused, rhs_batch=rhs_batch,
+                dA, tol, maxiter, precond=precond, fused=fused,
+                rhs_batch=rhs_batch,
             )
         else:
             dA._cg_cache[key] = make_bicgstab_fn(
@@ -6855,11 +5873,6 @@ _MATRIX_BASE_ENV = {
     "PA_TPU_GMG_BOX": None,
     "PA_TPU_GMG_STENCIL": None,
     "PA_TRACE_ITERS": None,
-    "PA_TPU_SSTEP": None,
-    "PA_TPU_OVERLAP": None,
-    "PA_TPU_TWOLEVEL": None,
-    "PA_TPU_NODE_MAP": None,
-    "PA_TPU_COMMS_MATRIX": None,
 }
 
 
@@ -6899,27 +5912,6 @@ def lowering_matrix(fast: bool = False):
                    "abft_off": "standard_nobox"}),
         dict(name="standard_f32", env={}, kwargs={"fused": False},
              dtype="f32", tags={"body": "standard", "staged": "f32"}),
-        # the ISSUE 17 perf bodies: s-step (CA-CG, one Gram gather per
-        # s iterations — the sstep-gather-collapse contract) and the
-        # interior/boundary overlap schedule (collective parity with
-        # the standard body it reorders — overlap-collective-parity)
-        dict(name="sstep2", env={"PA_TPU_SSTEP": "2"}, kwargs={},
-             dtype="f64", tags={"body": "sstep", "s": 2}),
-        dict(name="overlap", env={"PA_TPU_OVERLAP": "1"},
-             kwargs={"fused": False}, dtype="f64",
-             tags={"body": "standard", "overlap": True,
-                   "overlap_off": "standard"}),
-        # the ISSUE 18 node-aware tier: two-level exchange over an
-        # explicit 2-node map of the 8-part probe, A/B'd against the
-        # flat generic plan it rewrites (twolevel-fabric-budget +
-        # collective-parity contracts key off these tags)
-        dict(name="twolevel",
-             env={"PA_TPU_TWOLEVEL": "1",
-                  "PA_TPU_NODE_MAP": "0,0,0,0,1,1,1,1",
-                  "PA_TPU_BOX": "0"},
-             kwargs={"fused": False}, dtype="f64",
-             tags={"body": "standard", "plan": "twolevel",
-                   "twolevel": True, "twolevel_off": "standard_nobox"}),
     ]
     if fast:
         return cases

@@ -355,59 +355,6 @@ class BoxExchangePlan:
         return BoxExchangePlan(self.layout, self.info, not self.reverse_mode)
 
 
-class WidenedBoxExchangePlan(BoxExchangePlan):
-    """The depth-s widened box plan (s-step CG, tpu.py ISSUE 17): the
-    SAME direction slices and unpack segments as the depth-1 plan —
-    the s-step outer trip re-runs them once per basis level with a
-    2-lane ``(W, 2)`` pair slab, so the aggregated ghost region shipped
-    per trip is ``ghost_depth`` × the per-level payload — tagged with
-    the depth for comms accounting and the plan audit. `verify_plan`
-    dispatches through the base class (isinstance), so all five
-    soundness checks run unchanged on the widened variant."""
-
-    __slots__ = ("ghost_depth",)
-
-    def __init__(self, layout, info: BoxInfo, depth: int,
-                 reverse_mode: bool = False):
-        super().__init__(layout, info, reverse_mode)
-        self.ghost_depth = int(depth)
-
-    def reverse(self) -> "WidenedBoxExchangePlan":
-        return WidenedBoxExchangePlan(
-            self.layout, self.info, self.ghost_depth,
-            not self.reverse_mode,
-        )
-
-
-from .tpu import TwoLevelDeviceExchangePlan  # noqa: E402 — cycle-safe:
-# tpu.py defers ALL of its tpu_box imports into function bodies, so this
-# module-level import never re-enters a half-initialized module.
-
-
-class TwoLevelBoxExchangePlan(TwoLevelDeviceExchangePlan):
-    """The box-family two-level sibling (tpu.py ISSUE 18): built from
-    the exchanger over the BOX layout (whose ghost region is reordered
-    into direction segments), NOT a `BoxExchangePlan` subclass — the
-    slice bodies cannot redirect slow-fabric slots through a stage, so
-    the two-level schedule keeps the index-vector form over the box
-    layout's slot maps (``DeviceLayout.lid_slots`` carries the segment
-    reorder, so the staged schedule delivers into the box frame's real
-    ghost segments). Same-node directions still ride direct ppermute
-    rounds; only cross-node messages take the gather/node/scatter
-    detour. `verify_plan` dispatches through the two-level base: the
-    five flat checks run on the logical-delivery view, the staged-
-    schedule simulation on ``tl_rounds``."""
-
-    __slots__ = ()
-
-    def __init__(self, exchanger, layout, node_of, decision=None):
-        from ..utils.helpers import check as _check
-
-        _check(layout.box_info is not None,
-               "TwoLevelBoxExchangePlan requires a box layout")
-        super().__init__(exchanger, layout, node_of, decision=decision)
-
-
 def shard_box_exchange(plan: BoxExchangePlan, combine: str):
     """Per-shard exchange body with the SAME signature as tpu.py's
     `_shard_exchange` bodies: body(xv, si, sm, ri) — the three index

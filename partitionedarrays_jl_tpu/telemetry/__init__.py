@@ -106,7 +106,6 @@ from . import spectrum  # noqa: F401
 from .spectrum import (  # noqa: F401
     ANOMALY_KINDS,
     SPECTRUM_SCHEMA_VERSION,
-    SSTEP_MAX,
     SpectrumStore,
     check_deadline_feasible,
     detect_anomalies,
@@ -122,8 +121,6 @@ from .spectrum import (  # noqa: F401
     spec_admit_enabled,
     spec_enabled,
     spectrum_fingerprint,
-    sstep_stability_limit,
-    suggest_s,
 )
 from .spectrum import store as spectrum_store  # noqa: F401
 from . import tracing  # noqa: F401
@@ -151,7 +148,6 @@ __all__ = [
     "ANOMALY_KINDS",
     "ARTIFACT_SCHEMA_VERSION",
     "SPECTRUM_SCHEMA_VERSION",
-    "SSTEP_MAX",
     "SpectrumStore",
     "check_deadline_feasible",
     "detect_anomalies",
@@ -169,8 +165,6 @@ __all__ = [
     "spectrum",
     "spectrum_fingerprint",
     "spectrum_store",
-    "sstep_stability_limit",
-    "suggest_s",
     "CATALOG",
     "COMMS_MATRIX_SCHEMA_VERSION",
     "COMM_KINDS",

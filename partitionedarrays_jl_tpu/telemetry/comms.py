@@ -60,20 +60,11 @@ def _exchange_inventory(dA, abft: bool, K: int, itemsize: int):
     column plan: the generic index plan runs R `ppermute` rounds of the
     padded max-edge slab (ABFT: one checksum slot wider); the box plan
     runs one `ppermute` per geometric direction, each shipping that
-    direction's segment slab; the two-level plan runs one `ppermute`
-    per WIRE round of its staged schedule (direct + gather + node +
-    scatter — local copy rounds ship nothing), each shipping that
-    round's ragged lane slab."""
-    from ..parallel.tpu import TwoLevelDeviceExchangePlan
+    direction's segment slab."""
     from ..parallel.tpu_box import BoxExchangePlan
 
     plan = dA.col_plan
-    if isinstance(plan, TwoLevelDeviceExchangePlan):
-        sizes = [rd.snd_idx.shape[-1] for rd in plan.tl_rounds
-                 if rd.perm]
-        if not sizes:
-            return 0, 0
-    elif isinstance(plan, BoxExchangePlan):
+    if isinstance(plan, BoxExchangePlan):
         sizes = [d.size for d in plan.info.dirs]
     else:
         if plan.R == 0:
@@ -87,13 +78,10 @@ def cg_comms_profile(
     dA,
     dtype,
     precond: bool = False,
-    pipelined: bool = False,
     fused: bool = False,
     rhs_batch: Optional[int] = None,
     sdc: bool = False,
     abft: bool = False,
-    sstep: int = 0,
-    overlap: bool = False,
 ) -> dict:
     """The plan-level collective inventory of one compiled CG body:
     ``{"setup": {kind: {ops, bytes}}, "per_iteration": {...}}``.
@@ -109,17 +97,6 @@ def cg_comms_profile(
     * the SDC-defended bodies route the p·q dot through the extra-lane
       gather (`_pdot_extra_factory`): ABFT adds two checksum lanes to
       that one payload, never an op.
-
-    ``sstep >= 2`` switches to the s-step (CA-CG) body's per-OUTER-TRIP
-    inventory — one trip covers ``sstep`` textbook iterations, so the
-    returned dict carries ``"unit": sstep`` and `observed_comms`
-    evaluates the profile at ``iterations // unit`` trips: per trip,
-    ``sstep`` pair-SpMV halo updates (a 2-lane ``(W, 2)`` slab each —
-    basis levels) and exactly ONE ``(2s+1, 2s+1)`` Gram `all_gather`
-    (the palint ``sstep-gather-collapse`` contract). ``overlap``
-    reorders the SpMV schedule only (interior compute vs in-flight
-    halo) — per-kind parity with the standard body, no inventory
-    change (the palint ``overlap-collective-parity`` contract).
     """
     import numpy as np
 
@@ -147,22 +124,9 @@ def cg_comms_profile(
     if precond:
         ag(setup, 1)
 
-    if int(sstep) >= 2:
-        # ---- one OUTER TRIP of the s-step body (= sstep iterations) --
-        s = int(sstep)
-        m = 2 * s + 1
-        # s basis levels, each one halo update of the (W, 2) pair slab
-        _add(per_it, "collective_permute", s * ex_ops, s * ex_bytes * 2)
-        # the ONE block all_gather: the (m, m) local Gram partial
-        _add(per_it, "all_gather", 1, P * m * m * itemsize)
-        return {"setup": setup, "per_iteration": per_it, "unit": s}
-
     # ---- one iteration ----
     exchange(per_it)  # the body's one SpMV call site
-    if pipelined:
-        ag(per_it, 1)  # p·q
-        ag(per_it, 1)  # r·r
-    elif sdc:
+    if sdc:
         ag(per_it, 1 + (2 if abft else 0))  # p·q via the extra-lane dot
         if fused or block:
             ag(per_it, 2 if precond else 1)  # fused one-sweep dot pair
@@ -183,33 +147,24 @@ def cg_comms_profile(
 
 def observed_comms(profile: dict, iterations: int) -> dict:
     """The runtime accounting of one finished solve: the profile
-    evaluated at the solve's actual iteration count. An s-step profile
-    (``"unit" > 1``) is evaluated at the TRIP count — the s-step body
-    always commits whole trips, so ``iterations`` is an exact multiple
-    of the unit."""
+    evaluated at the solve's actual iteration count."""
     it = int(iterations)
-    unit = int(profile.get("unit", 1))
-    units = it // unit if unit > 1 else it
     obs = _zero()
     for k in COMM_KINDS:
         obs[k]["ops"] = (
             profile["setup"][k]["ops"]
-            + profile["per_iteration"][k]["ops"] * units
+            + profile["per_iteration"][k]["ops"] * it
         )
         obs[k]["bytes"] = (
             profile["setup"][k]["bytes"]
-            + profile["per_iteration"][k]["bytes"] * units
+            + profile["per_iteration"][k]["bytes"] * it
         )
-    out = {
+    return {
         "iterations": it,
         "setup": profile["setup"],
         "per_iteration": profile["per_iteration"],
         "observed": obs,
     }
-    if unit > 1:
-        out["unit"] = unit
-        out["comm_units"] = units
-    return out
 
 
 def expected_from_report(report) -> dict:
@@ -242,9 +197,7 @@ def reconcile(report, comms: dict) -> list:
     lowered program's static expectation, at the solve's iteration
     count. Returns human-readable mismatch strings (empty = agree)."""
     exp = expected_from_report(report)
-    # s-step solves: the while region is ONE outer trip, so the static
-    # per-iteration split multiplies by trips, not textbook iterations
-    it = int(comms.get("comm_units", comms["iterations"]))
+    it = int(comms["iterations"])
     out = []
     for k in COMM_KINDS:
         for field in ("ops", "bytes"):

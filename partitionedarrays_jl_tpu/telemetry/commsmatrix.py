@@ -2,11 +2,8 @@
 
 Every exchange plan in the repo is COSTED as if all neighbors were
 equidistant: `telemetry.comms` counts rounds and per-device bytes, and
-the palint contracts pin those counts — but nothing records what each
-edge actually COSTS on the fabric it crosses. ROADMAP item 3's
-node-aware tier (the TAPSpMV split, arXiv:1612.08060: route slow-fabric
-messages through one local representative) is a *cost-model-driven*
-plan transformation; this module builds exactly that cost model:
+the palint contracts pin those counts — but nothing else records what
+each edge actually COSTS on the fabric it crosses. This module does:
 
 * **Static side** — `static_matrix` walks the plan's round schedule
   (generic `DeviceExchangePlan`: the edge-colored `ppermute` rounds;
@@ -25,8 +22,7 @@ plan transformation; this module builds exactly that cost model:
   share.
 * **Fabric classification** — every edge is labeled by the link it
   crosses (``self`` / ``ici`` [same process] / ``dcn`` [cross-process]
-  by default; pass ``classify`` to override with topology knowledge) —
-  the grouping key a node-aware planner aggregates over.
+  by default; pass ``classify`` to override with topology knowledge).
 
 The export (`COMMS_MATRIX.json` via the shared artifacts writer) is
 schema-versioned and carries the static reconciliation verdict inline.
@@ -37,52 +33,26 @@ from typing import Callable, List, Optional, Sequence
 
 __all__ = [
     "COMMS_MATRIX_SCHEMA_VERSION",
-    "DEFAULT_FABRIC_MODEL",
     "classify_edge",
     "fabric_summary",
-    "fit_fabric_model",
-    "twolevel_decision",
     "static_matrix",
     "reconcile_matrix",
     "measure_comms_matrix",
     "render_comms_matrix",
 ]
 
-#: v2 (ISSUE 18): edge rows carry the two-level schedule ``tier``, the
-#: record carries a recomputable per-fabric ``fabric_summary`` block and
-#: (for two-level plans) ``node_of`` + the cost-model ``decision``.
+#: v2: the record carries a recomputable per-fabric ``fabric_summary``
+#: block.
 COMMS_MATRIX_SCHEMA_VERSION = 2
 
-#: Per-fabric linear cost priors, ``s = alpha_s + bytes *
-#: beta_s_per_byte`` — the fallback `twolevel_decision` uses when no
-#: committed matrix is supplied (or a fabric has too few measured edge
-#: sizes to fit). Magnitudes are the public TPU-pod figures the docs
-#: cite: ICI latency ~1 us at tens of GB/s per link, DCN latency tens
-#: of us at single-digit GB/s per host. Only the RATIO between fabrics
-#: matters for the aggregate-or-not decision.
-DEFAULT_FABRIC_MODEL = {
-    "ici": {"alpha_s": 1.0e-6, "beta_s_per_byte": 1.0 / 45.0e9},
-    "dcn": {"alpha_s": 25.0e-6, "beta_s_per_byte": 1.0 / 2.5e9},
-}
-
-
 def classify_edge(src: int, dst: int, backend=None,
-                  P: Optional[int] = None,
-                  node_of: Optional[Sequence[int]] = None) -> str:
+                  P: Optional[int] = None) -> str:
     """Default fabric label of one exchange edge: ``self`` loops stay
     on-device, parts whose devices share a process are ``ici``
     neighbors, cross-process edges are ``dcn``. The hook point for
-    topology-aware classifiers (mesh-axis distance, rack locality).
-
-    ``node_of`` (a per-part node id map, the same spec
-    ``PA_TPU_NODE_MAP`` feeds the two-level planner) takes priority
-    over the backend's process indices — so the SAME override reaches
-    plan construction and the committed matrix (the ISSUE-18
-    `bench_ici` threading fix)."""
+    topology-aware classifiers (mesh-axis distance, rack locality)."""
     if src == dst:
         return "self"
-    if node_of is not None:
-        return "ici" if node_of[src] == node_of[dst] else "dcn"
     if backend is None or P is None:
         return "unknown"
     try:
@@ -116,149 +86,19 @@ def fabric_summary(edges: Sequence[dict]) -> dict:
     return out
 
 
-def fit_fabric_model(matrix: dict) -> dict:
-    """Per-fabric ``alpha_s``/``beta_s_per_byte`` least-squares fit of
-    ``measured_s ~ alpha + beta * payload_bytes`` over a matrix's edge
-    rows. Fabrics with fewer than two DISTINCT measured payload sizes
-    (a single-size fit cannot separate latency from bandwidth) fall
-    back to `DEFAULT_FABRIC_MODEL`; each entry records which via
-    ``"source"``."""
-    import numpy as np
-
-    by_fabric: dict = {}
-    for e in matrix.get("edges", ()):
-        t = e.get("measured_s")
-        if t is None:
-            continue
-        by_fabric.setdefault(e["fabric"], []).append(
-            (float(e["payload_bytes"]), float(t))
-        )
-    model = {}
-    for fabric, prior in DEFAULT_FABRIC_MODEL.items():
-        pts = by_fabric.get(fabric, [])
-        sizes = {b for b, _ in pts}
-        if len(sizes) >= 2:
-            b = np.array([p[0] for p in pts])
-            t = np.array([p[1] for p in pts])
-            A = np.stack([np.ones_like(b), b], axis=1)
-            (alpha, beta), *_ = np.linalg.lstsq(A, t, rcond=None)
-            model[fabric] = {
-                "alpha_s": max(float(alpha), 0.0),
-                "beta_s_per_byte": max(float(beta), 0.0),
-                "source": "fit",
-                "points": len(pts),
-            }
-        else:
-            model[fabric] = dict(prior, source="default",
-                                 points=len(pts))
-    return model
-
-
-def twolevel_decision(
-    profile: Sequence,
-    node_of: Sequence[int],
-    matrix_path: Optional[str] = None,
-    itemsize: int = 8,
-) -> dict:
-    """The measured-not-guessed aggregation rule (ISSUE 18): given a
-    neighbor profile ``[(src_part, dst_part, payload_slots), ...]`` and
-    a per-part node map, cost the flat schedule's slow-fabric edges
-    against the two-level detour under the per-fabric linear model —
-    fit from the committed ``COMMS_MATRIX.json`` at ``matrix_path``
-    when given, `DEFAULT_FABRIC_MODEL` otherwise.
-
-    * flat: every cross-node edge is its own slow-fabric message —
-      ``n_slow * alpha_dcn + bytes * beta_dcn``.
-    * two-level: one slow message per (node, node) pair plus the
-      intra-node gather/scatter hops and a second trip of the staged
-      bytes over the fast fabric — ``n_pairs * alpha_dcn + bytes *
-      beta_dcn + (gathers + scatters) * alpha_ici + 2 * bytes *
-      beta_ici``.
-
-    ``use`` is True iff aggregation strictly reduces the slow-fabric
-    edge count AND the modeled time. The dict is stamped into the
-    plan's ``decision`` attribute and the v2 matrix record."""
-    import json
-    import os
-
-    node_of = [int(n) for n in node_of]
-    model = {k: dict(v, source="default")
-             for k, v in DEFAULT_FABRIC_MODEL.items()}
-    model_source = "default"
-    if matrix_path and os.path.exists(matrix_path):
-        try:
-            with open(matrix_path) as fh:
-                model = fit_fabric_model(json.load(fh))
-            model_source = matrix_path
-        except Exception:
-            pass
-
-    reps: dict = {}
-    for p, n in enumerate(node_of):
-        reps.setdefault(n, p)
-    slow = [(int(p), int(q), int(k)) for p, q, k in profile
-            if node_of[int(p)] != node_of[int(q)]]
-    n_slow = len(slow)
-    slow_bytes = sum(k for _, _, k in slow) * int(itemsize)
-    pairs = {(node_of[p], node_of[q]) for p, q, _ in slow}
-    gathers = {(p, reps[node_of[p]]) for p, _, _ in slow
-               if p != reps[node_of[p]]}
-    scatters = {(reps[node_of[q]], q) for _, q, _ in slow
-                if q != reps[node_of[q]]}
-    a_d = model["dcn"]["alpha_s"]
-    b_d = model["dcn"]["beta_s_per_byte"]
-    a_i = model["ici"]["alpha_s"]
-    b_i = model["ici"]["beta_s_per_byte"]
-    flat_s = n_slow * a_d + slow_bytes * b_d
-    two_s = (
-        len(pairs) * a_d + slow_bytes * b_d
-        + (len(gathers) + len(scatters)) * a_i
-        + 2 * slow_bytes * b_i
-    )
-    return {
-        "use": bool(n_slow > 0 and len(pairs) < n_slow
-                    and two_s < flat_s),
-        "model_source": model_source,
-        "model": model,
-        "slow_edges_flat": n_slow,
-        "node_pair_edges": len(pairs),
-        "gather_edges": len(gathers),
-        "scatter_edges": len(scatters),
-        "slow_payload_bytes": slow_bytes,
-        "flat_modeled_s": round(flat_s, 12),
-        "twolevel_modeled_s": round(two_s, 12),
-    }
-
-
 def _plan_rounds(plan):
-    """Normalize any plan family into
-    ``[(wire_slots, [(src, dst, payload_slots), ...], tier), ...]`` —
-    ``tier`` is ``"direct"`` for the flat families, the two-level
-    schedule tier (gather/node/scatter/direct) for wire rounds of a
-    `TwoLevelDeviceExchangePlan` (its local copy rounds ship nothing
-    and are not rows: the matrix accounts the WIRE)."""
+    """Normalize either plan family into
+    ``[(wire_slots, [(src, dst, payload_slots), ...]), ...]``."""
     import numpy as np
 
-    from ..parallel.tpu import TwoLevelDeviceExchangePlan
     from ..parallel.tpu_box import BoxExchangePlan
 
-    if isinstance(plan, TwoLevelDeviceExchangePlan):
-        out = []
-        for rd in plan.tl_rounds:
-            if not rd.perm:
-                continue
-            edges = []
-            for src, dst in rd.perm:
-                payload = int(np.count_nonzero(rd.snd_mask[src]))
-                edges.append((int(src), int(dst), payload))
-            out.append((int(rd.snd_idx.shape[-1]), edges, rd.tier))
-        return out
     if isinstance(plan, BoxExchangePlan):
         out = []
         for d in plan.info.dirs:
             out.append(
                 (int(d.size), [(int(p), int(q), int(d.size))
-                               for p, q in d.perm], "direct")
+                               for p, q in d.perm])
             )
         return out
     out = []
@@ -268,7 +108,7 @@ def _plan_rounds(plan):
         for src, dst in perm:
             payload = int(np.count_nonzero(plan.snd_mask[src, r]))
             edges.append((int(src), int(dst), payload))
-        out.append((L, edges, "direct"))
+        out.append((L, edges))
     return out
 
 
@@ -281,36 +121,26 @@ def static_matrix(
 ) -> dict:
     """The plan-derived half of the matrix: per-round, per-edge byte
     accounting (no timing). ``classify(src, dst)`` overrides the
-    default fabric labeling. Two-level plans label via their OWN node
-    map (the planner's fabric view and the matrix's must agree) and
-    stamp the node map + cost-model decision into the record."""
+    default fabric labeling."""
     import numpy as np
 
-    from ..parallel.tpu import TwoLevelDeviceExchangePlan
     from ..parallel.tpu_box import BoxExchangePlan
 
     itemsize = int(np.dtype(dtype).itemsize)
     K = max(1, int(K))
     P = plan.layout.P
     rounds = _plan_rounds(plan)
-    twolevel = isinstance(plan, TwoLevelDeviceExchangePlan)
-    node_of = plan.node_of if twolevel else None
     label = classify or (
-        lambda s, d: classify_edge(
-            s, d, backend=backend, P=P, node_of=node_of
-        )
+        lambda s, d: classify_edge(s, d, backend=backend, P=P)
     )
     edges: List[dict] = []
     per_device_bytes = 0
-    round_tiers = []
-    for r, (wire_slots, edge_list, tier) in enumerate(rounds):
+    for r, (wire_slots, edge_list) in enumerate(rounds):
         per_device_bytes += wire_slots * K * itemsize
-        round_tiers.append(tier)
         for src, dst, payload in edge_list:
             edges.append(
                 {
                     "round": r,
-                    "tier": tier,
                     "src": src,
                     "dst": dst,
                     "fabric": label(src, dst),
@@ -320,21 +150,13 @@ def static_matrix(
                     "wire_bytes": wire_slots * K * itemsize,
                 }
             )
-    if twolevel:
-        kind = ("twolevel-box" if plan.layout.box_info is not None
-                else "twolevel")
-    elif isinstance(plan, BoxExchangePlan):
-        kind = "box"
-    else:
-        kind = "generic"
-    out = {
+    return {
         "comms_matrix_schema_version": COMMS_MATRIX_SCHEMA_VERSION,
-        "plan": kind,
+        "plan": "box" if isinstance(plan, BoxExchangePlan) else "generic",
         "P": int(P),
         "K": K,
         "dtype": str(np.dtype(dtype)),
         "rounds": len(rounds),
-        "round_tiers": round_tiers,
         "edges": edges,
         "fabric_summary": fabric_summary(edges),
         "static": {
@@ -342,10 +164,6 @@ def static_matrix(
             "per_device_bytes": per_device_bytes,
         },
     }
-    if twolevel:
-        out["node_of"] = list(plan.node_of)
-        out["decision"] = dict(plan.decision)
-    return out
 
 
 def reconcile_matrix(matrix: dict, dA, abft: bool = False) -> list:
@@ -466,74 +284,6 @@ def _round_chains(plan, backend, K: int):
     return chains
 
 
-def _twolevel_round_chains(plan, backend, K: int):
-    """One jitted k-step chain per WIRE round of a two-level plan —
-    same marginal protocol as `_round_chains`, but over the combined
-    frame (ghost slab + per-part stage + stage trash) the staged
-    schedule indexes into. Local copy rounds ship nothing and get no
-    chain (they are not matrix rows either)."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ..parallel.tpu import _stage
-
-    shard_map = jax.shard_map
-    layout = plan.layout
-    P, W = layout.P, layout.W
-    S = plan.stage_width
-    strash = W + S
-    o0, g0, trash = layout.o0, layout.g0, layout.trash
-    mesh = backend.mesh(P)
-    spec = backend.parts_spec()
-    Wc = W + S + 1
-    shape = (P, Wc, K) if K > 1 else (P, Wc)
-    x0 = np.zeros(shape, dtype=np.float64)
-    x0[:, o0:g0] = 1.0
-    x = jax.device_put(x0, jax.sharding.NamedSharding(mesh, spec))
-    eps = np.float64(1e-30)
-
-    chains = []
-    for rd in plan.tl_rounds:
-        if not rd.perm:
-            continue
-        si = _stage(backend, rd.snd_idx, P)
-        sm = _stage(backend, rd.snd_mask, P)
-        ri = _stage(backend, rd.rcv_idx, P)
-
-        @functools.partial(jax.jit, static_argnums=4)
-        def chain(xv, siv, smv, riv, k, _perm=rd.perm):
-            def shard_fn(xs, sis, sms, ris):
-                v, s_i, s_m, r_i = xs[0], sis[0], sms[0], ris[0]
-
-                def step(_, vv):
-                    mask = s_m.reshape(
-                        s_m.shape + (1,) * (vv.ndim - 1)
-                    )
-                    buf = jnp.where(mask, vv[s_i], 0)
-                    buf = jax.lax.ppermute(buf, "parts", perm=_perm)
-                    vv = vv.at[r_i].set(buf)
-                    vv = vv.at[trash].set(0)
-                    vv = vv.at[strash].set(0)
-                    return vv.at[o0].add(vv[g0] * eps)
-
-                return jax.lax.fori_loop(0, k, step, v)[None]
-
-            return shard_map(
-                shard_fn, mesh=mesh, in_specs=(spec,) * 4,
-                out_specs=spec, check_vma=False,
-            )(xv, siv, smv, riv).sum()
-
-        chains.append(
-            lambda k, _c=chain, _si=si, _sm=sm, _ri=ri: float(
-                _c(x, _si, _sm, _ri, k)
-            )
-        )
-    return chains
-
-
 def _full_exchange_chain(plan, dA, backend, K: int):
     """One chain running the WHOLE exchange per step (the box plan's
     rounds compile into one fused slice program — per-round programs
@@ -602,7 +352,7 @@ def measure_comms_matrix(
     full-exchange cost (``attribution="proportional"``)."""
     import numpy as np
 
-    from ..parallel.tpu import TwoLevelDeviceExchangePlan, device_matrix
+    from ..parallel.tpu import device_matrix
     from ..parallel.tpu_box import BoxExchangePlan
     from .profile import _marginal_s, prof_reps
     from .throughput import operator_fingerprint
@@ -617,12 +367,7 @@ def measure_comms_matrix(
     matrix["fingerprint"] = operator_fingerprint(A)
     matrix["trips"] = {"k1": int(k1), "k2": int(k2), "reps": int(reps)}
 
-    if isinstance(plan, TwoLevelDeviceExchangePlan):
-        chains = _twolevel_round_chains(plan, backend, K)
-        round_s = [_marginal_s(c, k1, k2, reps) for c in chains]
-        total = sum(round_s)
-        matrix["attribution"] = "measured-round"
-    elif isinstance(plan, BoxExchangePlan):
+    if isinstance(plan, BoxExchangePlan):
         total = _marginal_s(
             _full_exchange_chain(plan, dA, backend, K), k1, k2, reps
         )
@@ -675,7 +420,7 @@ def render_comms_matrix(matrix: dict) -> str:
         )
         lines.append(
             f"  round {e['round']}: {e['src']:>2} -> {e['dst']:<2} "
-            f"[{e['fabric']:>4}/{e.get('tier', 'direct'):<7}] "
+            f"[{e['fabric']:>4}] "
             f"payload {e['payload_bytes']:>8} B / "
             f"wire {e['wire_bytes']:>8} B"
             + (f"  {t * 1e6:10.2f} us" if t is not None else "")

@@ -6,9 +6,6 @@ collective inventories) and `pamon` (PR 9) made the *service* legible
 question every optimization PR starts from: of one compiled CG
 iteration's wall time, how much is SpMV compute, how much halo
 exchange, how much the dot all_gathers, how much the axpy sweeps?
-ROADMAP item 2's s-step decision (is small-N latency-bound or
-FLOP-bound?) and item 3's node-aware planning both need that split as a
-MEASURED object, not a guess.
 
 One capture method, the deterministic **split-timer**: time each phase
 as its OWN compiled k-step chain (the `bench.py` marginal-chain
@@ -57,8 +54,6 @@ from .comms import COMM_KINDS, cg_comms_profile
 __all__ = [
     "PHASE_SCHEMA_VERSION",
     "PHASES",
-    "PHASE_BOUNDARY",
-    "PHASE_HALO_SPLIT",
     "PHASE_SUM_BAND",
     "PHASE_SUM_BAND_WIDE",
     "prof_enabled",
@@ -66,17 +61,13 @@ __all__ = [
     "lowering_descriptor",
     "phase_case_name",
     "phase_case_of",
-    "profile_phases",
     "capture_phase_profile",
     "reconcile_phases",
     "phase_trace_events",
     "render_phase_profile",
 ]
 
-#: v2 (ISSUE 17): the overlap body adds the ``boundary_spmv`` phase
-#: (structural nnz-proportional split of the SpMV compute), the s-step
-#: body records per-TRIP attribution with an explicit ``unit``, and the
-#: committed PHASE_PROFILE.json became a multi-case container
+#: v2: the committed PHASE_PROFILE.json is a multi-case container
 #: ``{"phase_schema_version": 2, "profiles": {case: profile}}``.
 PHASE_SCHEMA_VERSION = 2
 
@@ -84,39 +75,6 @@ PHASE_SCHEMA_VERSION = 2
 #: operator-apply compute (full SpMV minus its embedded halo update),
 #: so the four sum to one iteration's work.
 PHASES = ("spmv_local", "halo_exchange", "dot_allgather", "axpy_sweep")
-
-#: The overlap body's extra axis: the boundary-row (A_oh) share of the
-#: SpMV compute — the part that must wait for the halo, split out of
-#: ``spmv_local`` proportionally to the interior/boundary nnz counts
-#: (a STRUCTURAL attribution, not an independent timer: the overlap
-#: schedule computes interior rows while the halo is in flight, so the
-#: boundary share is exactly the non-overlappable compute).
-PHASE_BOUNDARY = "boundary_spmv"
-
-#: The two-level (node-aware) plans' replacement of ``halo_exchange``
-#: (ISSUE 18): the fast-fabric rounds (direct neighbors + the
-#: gather/scatter staging hops) vs the aggregated slow-fabric
-#: representative-to-representative rounds — so the node-tier win is
-#: ATTRIBUTED per fabric, not asserted. Each is measured as its own
-#: tier-restricted exchange chain.
-PHASE_HALO_SPLIT = ("halo_ici", "halo_dcn_agg")
-
-
-def profile_phases(profile: dict) -> tuple:
-    """The phase keys of one profile, canonical order: the four shared
-    axes — with ``halo_exchange`` replaced by the per-fabric split when
-    a two-level profile recorded it — plus ``boundary_spmv`` when the
-    overlap body recorded it."""
-    ph = profile.get("phases", {})
-    out = []
-    for p in PHASES:
-        if p == "halo_exchange" and PHASE_HALO_SPLIT[0] in ph:
-            out.extend(PHASE_HALO_SPLIT)
-        else:
-            out.append(p)
-    if PHASE_BOUNDARY in ph:
-        out.append(PHASE_BOUNDARY)
-    return tuple(out)
 
 #: Pinned acceptance band for attributed_sum / measured_total. The
 #: split chains re-pay per-phase loop-carry and buffer-roundtrip costs
@@ -130,14 +88,10 @@ def profile_phases(profile: dict) -> tuple:
 #: stays out of this band on every attempt).
 PHASE_SUM_BAND = (0.15, 6.0)
 
-#: The looser band of the heavier bodies, introduced when the
-#: committed PHASE_PROFILE.json went multi-case (schema v2). The
-#: s-step trip carries work the four phase chains deliberately do not
-#: model — the (W, 2) pair-slab stacking, the inter-level owned-row
-#: re-embeddings, the (2s+1)-wide Gram einsum and the trip-end basis
-#: GEMVs — and the block (rhs_batch) bodies carry K-column while-carry
-#: and pfold costs the chains likewise skip (measured ~0.07-0.14 on
-#: the CPU probe, vs >= 0.15 for the scalar bodies). Same role as
+#: The looser band of the block (rhs_batch) bodies: they carry
+#: K-column while-carry and pfold costs the four phase chains
+#: deliberately do not model (measured ~0.07-0.14 on the CPU probe, vs
+#: >= 0.15 for the scalar bodies). Same role as
 #: `PHASE_SUM_BAND` (same-scale, catches orders-of-magnitude
 #: attribution breakage), looser floor; each profile records the band
 #: it was checked against.
@@ -175,35 +129,16 @@ def lowering_descriptor(dA) -> Dict[str, str]:
         a_oo = "bsr"
     else:
         a_oo = "ell"
-    cp = dA.col_plan
-    if hasattr(cp, "tl_rounds"):
-        plan = (
-            "twolevel-box" if cp.layout.box_info is not None
-            else "twolevel"
-        )
-    elif isinstance(cp, BoxExchangePlan):
-        plan = "box"
-    else:
-        plan = "generic"
+    plan = "box" if isinstance(dA.col_plan, BoxExchangePlan) else "generic"
     return {"a_oo": a_oo, "plan": plan}
 
 
 def phase_case_name(fused: bool, rhs_batch: Optional[int] = None,
-                    abft: bool = False, sstep: int = 0,
-                    overlap: bool = False,
-                    twolevel: bool = False) -> str:
+                    abft: bool = False) -> str:
     """The palint lowering-matrix case name this profile is keyed by
-    (`parallel.tpu.lowering_matrix` naming: body form + K + mode; the
-    ISSUE-17 bodies key as ``sstep{s}`` / ``overlap``, the ISSUE-18
-    node-aware plan as ``twolevel``)."""
-    if int(sstep) >= 2:
-        return f"sstep{int(sstep)}"
+    (`parallel.tpu.lowering_matrix` naming: body form + K + mode)."""
     body = "fused" if fused else "standard"
     name = f"block_k{int(rhs_batch)}_{body}" if rhs_batch else body
-    if overlap:
-        name = "overlap" if name == "standard" else name + "_overlap"
-    if twolevel:
-        name = "twolevel" if name == "standard" else name + "_twolevel"
     return name + ("_abft" if abft else "")
 
 
@@ -214,12 +149,6 @@ def phase_case_of(name: str) -> str:
     has no committed phase entry. Mode suffixes (_nobox/_abft/_f32,
     strict_) share their base body's profile: they change operands or
     rounding, not the phase structure."""
-    if name.startswith("sstep"):
-        return "sstep2"
-    if name == "twolevel" or name.endswith("_twolevel"):
-        return "twolevel"
-    if name == "overlap" or name.endswith("_overlap"):
-        return "overlap"
     for k in ("block_k1", "block_k4"):
         if k in name:
             return f"{k}_fused"
@@ -279,7 +208,6 @@ def _phase_chains(dA, rhs_batch: Optional[int]) -> Dict[str, Callable]:
     import functools
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from ..parallel.tpu import (
@@ -317,61 +245,22 @@ def _phase_chains(dA, rhs_batch: Optional[int]) -> Dict[str, Callable]:
         # on the previous step's permute, so nothing is loop-invariant
         return xv.at[o0].add(xv[g0] * eps)
 
-    def _exchange_chain(body):
-        @functools.partial(jax.jit, static_argnums=2)
-        def chain(xv, m, k):
-            def shard_fn(xs, ms):
-                mm = _shard_ops(jax, ms)
+    @functools.partial(jax.jit, static_argnums=2)
+    def exch_chain(xv, m, k):
+        def shard_fn(xs, ms):
+            mm = _shard_ops(jax, ms)
 
-                def step(_, v):
-                    return _feedback(
-                        body(v, mm["si"], mm["sm"], mm["ri"])
-                    )
-
-                return jax.lax.fori_loop(0, k, step, xs[0])[None]
-
-            return shard_map(
-                shard_fn, mesh=mesh, in_specs=(spec, specs),
-                out_specs=spec, check_vma=False,
-            )(xv, m).sum()
-
-        return chain
-
-    exch_chain = _exchange_chain(exch_body)
-
-    def _tier_body(fabric):
-        # the two-level per-fabric halo share (PHASE_HALO_SPLIT): the
-        # same staged body as `_shard_exchange`'s two-level branch, but
-        # executing only the schedule rounds whose traffic rides this
-        # fabric — node rounds are the slow-fabric aggregate, every
-        # other round (direct ppermutes, gather/scatter staging hops
-        # and the wire-free local copies) is the fast-fabric share
-        plan = dA.col_plan
-        tl = plan.tl_rounds
-        Wp, S = plan.layout.W, plan.stage_width
-        strash = Wp + S
-        idxs = [
-            r for r, rd in enumerate(tl)
-            if plan.fabric_of_round(rd) == fabric
-        ]
-
-        def body(xv, si, sm, ri):
-            pad = jnp.zeros((S + 1,) + xv.shape[1:], dtype=xv.dtype)
-            cv = jnp.concatenate([xv, pad], axis=0)
-            for r in idxs:
-                rd = tl[r]
-                mask = sm[r].reshape(
-                    sm[r].shape + (1,) * (cv.ndim - 1)
+            def step(_, v):
+                return _feedback(
+                    exch_body(v, mm["si"], mm["sm"], mm["ri"])
                 )
-                buf = jnp.where(mask, cv[si[r]], 0)
-                if rd.perm:
-                    buf = jax.lax.ppermute(buf, "parts", perm=rd.perm)
-                cv = cv.at[ri[r]].set(buf)
-                cv = cv.at[plan.layout.trash].set(0)
-                cv = cv.at[strash].set(0)
-            return cv[:Wp]
 
-        return body
+            return jax.lax.fori_loop(0, k, step, xs[0])[None]
+
+        return shard_map(
+            shard_fn, mesh=mesh, in_specs=(spec, specs),
+            out_specs=spec, check_vma=False,
+        )(xv, m).sum()
 
     spmv_body = _spmv_body(dA)
 
@@ -435,23 +324,16 @@ def _phase_chains(dA, rhs_batch: Optional[int]) -> Dict[str, Callable]:
             check_vma=False,
         )(xv).sum()
 
-    chains = {
+    return {
         "exchange": lambda k: float(exch_chain(x, ops, k)),
         "spmv": lambda k: float(spmv_chain(x, ops, k)),
         "dot": lambda k: float(dot_chain(x, k)),
         "axpy": lambda k: float(axpy_chain(x, k)),
     }
-    if hasattr(dA.col_plan, "tl_rounds"):
-        ici_chain = _exchange_chain(_tier_body("ici"))
-        dcn_chain = _exchange_chain(_tier_body("dcn"))
-        chains["halo_ici"] = lambda k: float(ici_chain(x, ops, k))
-        chains["halo_dcn"] = lambda k: float(dcn_chain(x, ops, k))
-    return chains
 
 
 def _body_chain(dA, b, x0, fused, precond, rhs_batch,
-                comms_kwargs: dict, sstep: int = 0,
-                overlap: Optional[bool] = None) -> Callable[[int], float]:
+                comms_kwargs: dict) -> Callable[[int], float]:
     """The REAL compiled CG body as a `_marginal_s` chain: one
     fixed-trip (tol=0) solve per call, programs cached per trip count
     by `_krylov_fn_for`. Side effect: fills ``comms_kwargs`` with the
@@ -463,8 +345,7 @@ def _body_chain(dA, b, x0, fused, precond, rhs_batch,
     def run_chain(k: int) -> float:
         fn = make_cg_fn(
             dA, tol=0.0, maxiter=k, fused=fused, precond=precond,
-            rhs_batch=rhs_batch, sstep=(int(sstep) or None),
-            overlap=overlap,
+            rhs_batch=rhs_batch,
         )
         comms_kwargs.update(fn.comms_kwargs)
         out = fn(b, x0, None)
@@ -487,8 +368,6 @@ def capture_phase_profile(
     k1: int = 4,
     k2: int = 24,
     reps: Optional[int] = None,
-    sstep: int = 0,
-    overlap: Optional[bool] = None,
 ) -> Optional[dict]:
     """Capture one `PhaseProfile` of the compiled CG body for ``A`` on
     ``backend`` (see module docstring). Returns the schema-versioned
@@ -499,18 +378,7 @@ def capture_phase_profile(
     per-phase comms inventories sum per kind to
     `cg_comms_profile`'s per-iteration inventory (exact), and
     ``attributed_s_per_it / measured_s_per_it`` lands in
-    `PHASE_SUM_BAND` (recorded as ``in_band``).
-
-    ``sstep >= 2`` profiles the communication-avoiding body: the comms
-    inventory is per OUTER TRIP (one trip = ``sstep`` textbook
-    iterations — `telemetry.comms`), so the whole profile records
-    per-TRIP attribution with ``"unit": sstep`` (``measured_s_per_it``
-    is seconds per trip). ``overlap=True`` profiles the
-    interior/boundary-overlap schedule and splits the ``boundary_spmv``
-    phase out of ``spmv_local`` proportionally to the operator's
-    interior/boundary nnz counts — a STRUCTURAL attribution (the two
-    shares run in one fused SpMV pass; no independent timer exists for
-    the boundary finish), marked ``boundary_attribution``."""
+    `PHASE_SUM_BAND` (recorded as ``in_band``)."""
     import numpy as np
 
     from ..parallel.pvector import PVector
@@ -527,11 +395,7 @@ def capture_phase_profile(
     reps = prof_reps() if reps is None else max(3, int(reps))
     dA = device_matrix(A, backend)
     dtype = np.float64
-    fused_resolved = _resolve_fused(fused, False)
-    # the node-aware plan (ISSUE 18) is env-selected at device_matrix
-    # time (PA_TPU_TWOLEVEL / PA_TPU_NODE_MAP); when it staged, the
-    # halo phase splits per fabric tier (PHASE_HALO_SPLIT)
-    twolevel_on = hasattr(dA.col_plan, "tl_rounds")
+    fused_resolved = _resolve_fused(fused)
 
     bvec = PVector.full(1.0, A.cols, dtype=dtype)
     zvec = PVector.full(0.0, A.cols, dtype=dtype)
@@ -544,27 +408,16 @@ def capture_phase_profile(
         b = DeviceVector.from_pvector(bvec, backend, dA.col_layout).data
         x0 = DeviceVector.from_pvector(zvec, backend, dA.col_layout).data
 
-    sstep = int(sstep)
-    unit = sstep if sstep >= 2 else 1
-    band = (
-        PHASE_SUM_BAND_WIDE if (sstep >= 2 or rhs_batch)
-        else PHASE_SUM_BAND
-    )
+    band = PHASE_SUM_BAND_WIDE if rhs_batch else PHASE_SUM_BAND
     comms_kwargs: dict = {}
     body_chain = _body_chain(
-        dA, b, x0, fused, precond, rhs_batch, comms_kwargs,
-        sstep=sstep, overlap=overlap,
+        dA, b, x0, fused, precond, rhs_batch, comms_kwargs
     )
-    # _marginal_s differences maxiter counts, so its marginal is per
-    # textbook iteration; the s-step profile's accounting unit is the
-    # TRIP (= `unit` iterations), like its comms inventory
-    measured = _marginal_s(body_chain, k1, k2, reps) * unit
+    measured = _marginal_s(body_chain, k1, k2, reps)
     if rhs_batch:
         comms_kwargs["rhs_batch"] = int(rhs_batch)
-    prof_comms = cg_comms_profile(dA, dtype, **comms_kwargs)
-    per_it = prof_comms["per_iteration"]
+    per_it = cg_comms_profile(dA, dtype, **comms_kwargs)["per_iteration"]
     n_gathers = per_it["all_gather"]["ops"]
-    overlap_on = bool(comms_kwargs.get("overlap"))
 
     method = "split-timer"
     # wall-clock timings on a shared host can still catch a load
@@ -575,35 +428,17 @@ def capture_phase_profile(
     # attribution still lands (and stays) out of band
     chains = _phase_chains(dA, rhs_batch)
     best = None
-    # the s-step trip runs `unit` basis levels, each a 2-lane pair
-    # slab (SpMV + halo), then ONE Gram gather — scale the chain
-    # marginals to the trip the same way the comms inventory scales
-    sc = unit * (2 if sstep >= 2 else 1)
     for attempts in range(1, 4):
         t_exch = _marginal_s(chains["exchange"], k1, k2, reps)
         t_spmv = _marginal_s(chains["spmv"], k1, k2, reps)
         t_dot1 = _marginal_s(chains["dot"], k1, k2, reps)
         t_axpy = _marginal_s(chains["axpy"], k1, k2, reps)
-        if twolevel_on:
-            # per-fabric halo attribution: each tier measured as
-            # its own restricted chain (the aggregation's staging
-            # hops and local copies are fast-fabric work)
-            halo = {
-                "halo_ici": sc * _marginal_s(
-                    chains["halo_ici"], k1, k2, reps
-                ),
-                "halo_dcn_agg": sc * _marginal_s(
-                    chains["halo_dcn"], k1, k2, reps
-                ),
-            }
-        else:
-            halo = {"halo_exchange": sc * t_exch}
-        cand = dict(halo)
-        cand.update({
-            "spmv_local": sc * max(t_spmv - t_exch, 0.0),
+        cand = {
+            "halo_exchange": t_exch,
+            "spmv_local": max(t_spmv - t_exch, 0.0),
             "dot_allgather": n_gathers * t_dot1,
             "axpy_sweep": t_axpy,
-        })
+        }
         r = sum(cand.values()) / measured if measured > 0 else (
             float("inf")
         )
@@ -613,24 +448,8 @@ def capture_phase_profile(
         if band[0] <= r <= band[1]:
             break
         if attempts < 3:  # the final attempt keeps `best` as-is
-            measured = _marginal_s(body_chain, k1, k2, reps) * unit
+            measured = _marginal_s(body_chain, k1, k2, reps)
     _, phase_s, measured = best
-
-    boundary_frac = None
-    if overlap_on:
-        # the overlap body's boundary_spmv phase: the A_oh share of the
-        # SpMV compute, split STRUCTURALLY by the interior/boundary nnz
-        # counts (the two shares lower into one fused pass — the split
-        # is the schedule's non-overlappable fraction, not a timer)
-        nnz_oo = int(getattr(dA, "oo_nnz", 0) or 0)
-        nnz_oh = int(dA.oh_nnz or 0)
-        total_nnz = nnz_oo + nnz_oh
-        boundary_frac = (nnz_oh / total_nnz) if total_nnz else 0.0
-        phase_s = dict(phase_s)
-        phase_s[PHASE_BOUNDARY] = boundary_frac * phase_s["spmv_local"]
-        phase_s["spmv_local"] = (1.0 - boundary_frac) * phase_s[
-            "spmv_local"
-        ]
 
     # the per-phase collective split of the per-iteration inventory:
     # permutes ride the halo update, gathers ride the dots, and any
@@ -644,54 +463,15 @@ def capture_phase_profile(
         }
 
     phase_comms = {
+        "halo_exchange": {
+            k: _entry(k, k == "collective_permute") for k in COMM_KINDS
+        },
         "dot_allgather": {
             k: _entry(k, k == "all_gather") for k in COMM_KINDS
         },
         "spmv_local": {k: _entry(k, False) for k in COMM_KINDS},
         "axpy_sweep": {k: _entry(k, False) for k in COMM_KINDS},
     }
-    if twolevel_on:
-        # split the one halo update's permute inventory per fabric:
-        # the slow-fabric share is the node-tier wire rounds' ragged
-        # lane slabs, the fast-fabric share is the exact remainder —
-        # the two sum to the per-iteration inventory by construction,
-        # so `reconcile_phases`'s per-kind sum still balances
-        plan = dA.col_plan
-        Kcols = int(rhs_batch) if rhs_batch else 1
-        isz = int(np.dtype(dtype).itemsize)
-        dcn_sizes = [
-            rd.snd_idx.shape[-1] for rd in plan.tl_rounds
-            if rd.perm and plan.fabric_of_round(rd) == "dcn"
-        ]
-        dcn_ops = len(dcn_sizes)
-        dcn_bytes = sum(s * Kcols * isz for s in dcn_sizes)
-        pi = per_it["collective_permute"]
-
-        def _permute_split(ops, nbytes):
-            return {
-                k: {
-                    "ops": ops if k == "collective_permute" else 0,
-                    "bytes": nbytes if k == "collective_permute" else 0,
-                }
-                for k in COMM_KINDS
-            }
-
-        phase_comms["halo_ici"] = _permute_split(
-            pi["ops"] - dcn_ops, pi["bytes"] - dcn_bytes
-        )
-        phase_comms["halo_dcn_agg"] = _permute_split(
-            dcn_ops, dcn_bytes
-        )
-    else:
-        phase_comms["halo_exchange"] = {
-            k: _entry(k, k == "collective_permute") for k in COMM_KINDS
-        }
-    if overlap_on:
-        # boundary compute owns no collective: the halo it waits on is
-        # already attributed to halo_exchange
-        phase_comms[PHASE_BOUNDARY] = {
-            k: _entry(k, False) for k in COMM_KINDS
-        }
     unattributed = {
         k: dict(per_it[k]) for k in COMM_KINDS
         if k not in ("collective_permute", "all_gather")
@@ -700,20 +480,10 @@ def capture_phase_profile(
 
     attributed = sum(phase_s.values())
     ratio = attributed / measured if measured > 0 else float("inf")
-    plist = []
-    for p in PHASES:
-        if p == "halo_exchange" and twolevel_on:
-            plist.extend(PHASE_HALO_SPLIT)
-        else:
-            plist.append(p)
-    if overlap_on:
-        plist.append(PHASE_BOUNDARY)
-    plist = tuple(plist)
-    profile = {
+    return {
         "phase_schema_version": PHASE_SCHEMA_VERSION,
         "case": phase_case_name(
-            fused_resolved, rhs_batch, bool(comms_kwargs.get("abft")),
-            sstep=sstep, overlap=overlap_on, twolevel=twolevel_on,
+            fused_resolved, rhs_batch, bool(comms_kwargs.get("abft"))
         ),
         "fingerprint": operator_fingerprint(A),
         "lowering": lowering_descriptor(dA),
@@ -726,7 +496,7 @@ def capture_phase_profile(
                 "s_per_it": round(phase_s[p], 9),
                 "comms": phase_comms[p],
             }
-            for p in plist
+            for p in PHASES
         },
         "unattributed_comms": unattributed,
         "per_iteration_comms": per_it,
@@ -739,14 +509,6 @@ def capture_phase_profile(
         "band": list(band),
         "in_band": bool(band[0] <= ratio <= band[1]),
     }
-    if unit > 1:
-        # s-step: everything above is per OUTER TRIP (= `unit` textbook
-        # iterations), matching the comms inventory's unit
-        profile["unit"] = unit
-    if overlap_on:
-        profile["boundary_attribution"] = "structural-nnz-split"
-        profile["boundary_nnz_fraction"] = round(boundary_frac, 6)
-    return profile
 
 
 # ---------------------------------------------------------------------------
@@ -773,12 +535,11 @@ def reconcile_phases(profile: dict, dA=None) -> list:
             f"phase_schema_version {profile.get('phase_schema_version')!r}"
             f" != {PHASE_SCHEMA_VERSION}"
         ]
-    plist = profile_phases(profile)
     per_it = profile["per_iteration_comms"]
     for kind in COMM_KINDS:
         for field in ("ops", "bytes"):
             total = sum(
-                profile["phases"][p]["comms"][kind][field] for p in plist
+                profile["phases"][p]["comms"][kind][field] for p in PHASES
             ) + profile.get("unattributed_comms", {}).get(kind, {}).get(
                 field, 0
             )
@@ -829,7 +590,7 @@ def phase_trace_events(profile: dict, pid: int = 3,
     ]
     t = 0.0
     for it in range(max(1, int(iterations))):
-        for p in profile_phases(profile):
+        for p in PHASES:
             dur = profile["phases"][p]["s_per_it"] * 1e6
             out.append(
                 {
@@ -862,7 +623,7 @@ def render_phase_profile(profile: dict) -> str:
         f"{profile['lowering']['plan']} method={profile['method']}",
     ]
     total = profile["attributed_s_per_it"]
-    for p in profile_phases(profile):
+    for p in PHASES:
         ph = profile["phases"][p]
         share = ph["s_per_it"] / total if total > 0 else 0.0
         comms = ", ".join(

@@ -34,14 +34,6 @@ the loop:
   space, weighted by sample count) with the textbook κ-bound rate
   ``(√κ−1)/(√κ+1)`` as the prior. Monotone in ``tol`` by construction
   (the blended rate does not depend on the target).
-* **s-selection.** `suggest_s` turns a stored spec into the s-step CG
-  depth the ``PA_TPU_SSTEP`` lowering should use (the PR's
-  communication-avoiding body, `parallel.tpu.make_cg_fn(sstep=s)`):
-  the largest ``s ≤ SSTEP_MAX`` whose monomial-basis growth ``κ̂^s``
-  stays inside the dtype's precision budget, with `predict_iters`
-  forecasting the collective-count win of each variant. Unmeasured
-  operators suggest the always-safe ``s = 1`` (bitwise the textbook
-  body under strict-bits).
 * **Admission.** `check_deadline_feasible` multiplies the forecast by
   the throughput model's measured ``s_per_it`` and refuses deadlines
   that cannot be met with the typed
@@ -99,9 +91,6 @@ __all__ = [
     "predict_iters",
     "admission_prediction",
     "check_deadline_feasible",
-    "SSTEP_MAX",
-    "sstep_stability_limit",
-    "suggest_s",
 ]
 
 SPECTRUM_SCHEMA_VERSION = 1
@@ -709,118 +698,6 @@ def predict_iters(spec: Optional[dict], tol: float,
         logs.append((_PRIOR_WEIGHT, math.log(_kappa_rate(kappa))))
     log_rho = sum(w * lr for w, lr in logs) / sum(w for w, _ in logs)
     return max(1, int(math.ceil(math.log(eps) / log_rho)))
-
-
-# ---------------------------------------------------------------------------
-# s-step depth selection (the PA_TPU_SSTEP policy input)
-# ---------------------------------------------------------------------------
-
-#: Depth ceiling for `suggest_s`. The s-step body's Gram payload is
-#: (2s+1)² entries and its trip recurrences unroll s deep — past ~8 the
-#: monomial basis is numerically hopeless at ANY realistic κ̂ and the
-#: unrolled body stops paying for its own compile time.
-SSTEP_MAX = 8
-
-#: Precision headroom of the stability budget: the monomial basis
-#: [p, Ap, …, A^s p] conditions like κ^s, and the trip's Gram solve
-#: squares it — we demand κ̂^s ≤ 1/(HEADROOM·eps(dtype)) so the basis
-#: keeps ~10 bits of slack above the dtype's noise floor (the classic
-#: s-step practice of staying well clear of 1/√eps per power).
-_SSTEP_HEADROOM = 2.0 ** 10
-
-
-def sstep_stability_limit(kappa: Optional[float],
-                          dtype: str = "float64") -> int:
-    """Largest ``s`` in ``[1, SSTEP_MAX]`` whose monomial-basis growth
-    ``κ̂^s`` stays inside the dtype precision budget
-    ``1/(HEADROOM·eps)``. ``s = 1`` is ALWAYS stable (it is the
-    textbook body's own conditioning), so an unmeasured or degenerate
-    κ̂ returns 1, never 0."""
-    eps = float(np.finfo(np.dtype(dtype)).eps)
-    budget = 1.0 / (_SSTEP_HEADROOM * eps)
-    if kappa is None or not math.isfinite(float(kappa)) or kappa <= 1.0:
-        # κ ≤ 1: a perfectly conditioned (or unmeasured) operator —
-        # every depth is stable, the ceiling is the compile-size cap
-        return SSTEP_MAX if kappa is not None and 0.0 < kappa <= 1.0 \
-            else 1
-    if budget <= 1.0:
-        return 1
-    # log-space: κ^s ≤ budget  ⇔  s ≤ ln budget / ln κ
-    s = int(math.floor(math.log(budget) / math.log(float(kappa))))
-    return max(1, min(SSTEP_MAX, s))
-
-
-def suggest_s(spec: Optional[dict], dtype: str = "float64",
-              tol: Optional[float] = None,
-              r0_norm: Optional[float] = None) -> dict:
-    """The ``PA_TPU_SSTEP`` depth policy for one stored spec (one
-    ``(operator fingerprint, dtype, minv-class)`` class): pick the
-    largest stability-budget-feasible ``s`` and forecast what it buys.
-
-    The s-step body replaces the textbook body's 2 scalar all_gathers
-    per iteration with ONE block all_gather per s-iteration trip (the
-    (2s+1)-wide Gram payload), so the modeled collective saving of
-    depth s is a factor ``2s`` in gather COUNT — latency-bound ICI
-    steps are where that wins (docs/performance.md). `predict_iters`
-    (when a ``tol`` is given) turns the stored rate into absolute
-    gather counts per variant so the caller sees the forecasted win,
-    not just the factor.
-
-    Returns a policy dict: ``s`` (the suggestion), ``policy``
-    (``"largest-stable"`` | ``"unmeasured-default"``), ``kappa``,
-    ``eps``/``budget`` (the stability arithmetic), per-depth
-    ``candidates`` rows (growth, stability, modeled gather factor),
-    and the forecast block when ``tol`` is given. Never raises on an
-    unmeasured spec — the policy degrades to the always-safe s=1."""
-    eps = float(np.finfo(np.dtype(dtype)).eps)
-    budget = 1.0 / (_SSTEP_HEADROOM * eps)
-    kappa = None if spec is None else spec.get("kappa")
-    measured = kappa is not None and math.isfinite(float(kappa)) \
-        and kappa > 0.0
-    s_limit = sstep_stability_limit(kappa if measured else None, dtype)
-    candidates = []
-    for s in range(1, SSTEP_MAX + 1):
-        log_growth = None if not measured else s * math.log(
-            max(float(kappa), 1.0)
-        )
-        candidates.append({
-            "s": s,
-            # growth capped representable: κ^s can overflow float64 at
-            # depths the policy would never pick anyway
-            "basis_growth": (
-                None if log_growth is None
-                else math.exp(min(log_growth, 700.0))
-            ),
-            "stable": (s == 1) or (measured and s <= s_limit),
-            "gather_factor": 2 * s,  # 2 gathers/it -> 1 gather/s its
-        })
-    chosen = s_limit if measured else 1
-    out = {
-        "s": int(chosen),
-        "policy": "largest-stable" if measured else "unmeasured-default",
-        "kappa": None if not measured else float(kappa),
-        "dtype": str(np.dtype(dtype)),
-        "eps": eps,
-        "budget": budget,
-        "sstep_max": SSTEP_MAX,
-        "candidates": candidates,
-        "gather_factor": 2 * int(chosen),
-    }
-    if tol is not None:
-        its = predict_iters(spec, tol, r0_norm=r0_norm)
-        out["forecast"] = {
-            "tol": float(tol),
-            "predicted_iters": its,
-            # the textbook body's 2 scalar gathers per iteration vs
-            # one block gather per s-trip — the absolute win the
-            # factor models
-            "standard_gathers": None if its is None else 2 * its,
-            "sstep_gathers": (
-                None if its is None
-                else int(math.ceil(its / max(1, chosen)))
-            ),
-        }
-    return out
 
 
 def admission_prediction(fingerprint: str, dtype: str, minv_class: str,

@@ -482,7 +482,7 @@ def test_block_matches_solo_collective_counts():
 # ---------------------------------------------------------------------------
 
 
-def test_block_rejects_pipelined_and_checkpoint():
+def test_block_rejects_checkpoint_and_bad_block_args():
     backend = _backend()
 
     def driver(parts):
@@ -490,11 +490,6 @@ def test_block_rejects_pipelined_and_checkpoint():
         return A, b
 
     A, b = pa.prun(driver, backend, (2, 2))
-    dA = device_matrix(A, backend)
-    with pytest.raises(ValueError, match="single-RHS"):
-        make_cg_fn(dA, tol=1e-9, maxiter=10, pipelined=True, rhs_batch=2)
-    with pytest.raises(ValueError, match="single-RHS"):
-        cg(A, B=[b, b], pipelined=True)
     with pytest.raises(ValueError, match="single-RHS"):
         cg(A, B=[b], checkpoint=object())
     with pytest.raises(Exception):

@@ -215,36 +215,59 @@ def test_env_flag_disables_box_plan():
     assert pa.prun(driver, pa.tpu, (2, 2))
 
 
-def test_box_and_generic_plans_agree_slotwise():
+@pytest.mark.parametrize("K", [1, 4])
+@pytest.mark.parametrize("combine", ["set", "add"])
+@pytest.mark.parametrize(
+    "grid", [(2, 2, 1), (4, 1, 1), (1, 2, 2), (1, 1, 4), (2, 2, 2)]
+)
+def test_box_and_generic_plans_agree_slotwise(grid, combine, K):
     """The two plans over the SAME layout must produce identical device
     arrays (not just identical PVectors): exchange is used inside
-    compiled solvers that read raw slots."""
+    compiled solvers that read raw slots. Both directions ('set'
+    owner->ghost, 'add' ghost->owner), a single vector and a K-column
+    block, on part grids that cut each axis alone and together."""
     import jax
+
+    from partitionedarrays_jl_tpu.parallel.tpu import (
+        DeviceExchangePlan, _box_dummy_operands, _shard_exchange, _stage,
+    )
 
     def driver(parts):
         rows = pa.prange(parts, (8, 8, 8), pa.with_ghost)
-        v = _ramp(rows)
-        dv = DeviceVector.from_pvector(v, parts.backend)
-        from partitionedarrays_jl_tpu.parallel.tpu import (
-            DeviceExchangePlan, _box_dummy_operands, _shard_exchange, _stage,
-        )
-
         backend = parts.backend
         plan_box = device_exchange_plan(rows, False)
         assert isinstance(plan_box, BoxExchangePlan)
         layout = plan_box.layout
-        plan_gen = DeviceExchangePlan(rows.exchanger, layout)
-        mesh = backend.mesh(layout.P)
+        P = layout.P
+        exchanger = rows.exchanger
+        if combine == "add":
+            plan_box = plan_box.reverse()
+            exchanger = exchanger.reverse()
+        plan_gen = DeviceExchangePlan(exchanger, layout)
+        # integer-valued columns: the two 'add' bodies accumulate in
+        # different orders, which only exact sums make comparable bitwise
+        cols = []
+        for k in range(K):
+            vals = pa.map_parts(
+                lambda i, k=k: (
+                    np.asarray(i.lid_to_gid, dtype=np.float64) * 2.0
+                    + 1.0 + i.part
+                ) * (k + 1),
+                rows.partition,
+            )
+            cols.append(np.asarray(DeviceVector.from_pvector(
+                pa.PVector(vals, rows), backend
+            ).data))
+        x = _stage(backend, cols[0] if K == 1 else np.stack(cols, -1), P)
+        mesh = backend.mesh(P)
         spec = backend.parts_spec()
 
         def run(plan, si, sm, ri):
-            shard_map = jax.shard_map
-
-            body = _shard_exchange(plan, "set")
+            body = _shard_exchange(plan, combine)
 
             @jax.jit
             def fn(x, a, b, c):
-                return shard_map(
+                return jax.shard_map(
                     lambda xs, as_, bs, cs: body(
                         xs[0], as_[0], bs[0], cs[0]
                     )[None],
@@ -254,10 +277,16 @@ def test_box_and_generic_plans_agree_slotwise():
                     check_vma=False,
                 )(x, a, b, c)
 
-            return np.asarray(fn(dv.data, si, sm, ri))
+            return np.asarray(fn(x, si, sm, ri))
 
-        P = layout.P
-        out_box = run(plan_box, *_box_dummy_operands(backend, P))
+        out_box = run(
+            plan_box,
+            *_box_dummy_operands(
+                backend, P,
+                plan_box.info.seg_mask if combine == "add" else None,
+                variants=plan_box.info.variants,
+            ),
+        )
         out_gen = run(
             plan_gen,
             _stage(backend, plan_gen.snd_idx, P),
@@ -267,13 +296,16 @@ def test_box_and_generic_plans_agree_slotwise():
         # orphan slots may differ (box ships whole slabs); every REAL
         # slot — owned + mapped ghosts — must agree exactly
         o0 = layout.o0
+        x_in = np.asarray(x)
+        changed = False
         for p, iset in enumerate(rows.partition.part_values()):
-            np.testing.assert_array_equal(
-                out_box[p, o0 : o0 + iset.num_oids],
-                out_gen[p, o0 : o0 + iset.num_oids],
-            )
+            own = slice(o0, o0 + iset.num_oids)
+            np.testing.assert_array_equal(out_box[p, own], out_gen[p, own])
             hs = layout.hid_slots[p]
             np.testing.assert_array_equal(out_box[p, hs], out_gen[p, hs])
+            changed |= not np.array_equal(out_gen[p, own], x_in[p, own])
+            changed |= not np.array_equal(out_gen[p, hs], x_in[p, hs])
+        assert changed  # the exchange really moved something
         return True
 
-    assert pa.prun(driver, pa.tpu, (2, 2, 2))
+    assert pa.prun(driver, pa.tpu, grid)

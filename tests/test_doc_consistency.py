@@ -479,153 +479,9 @@ def test_obs_artifact_agrees_with_guard_bands():
         assert "real TPUs" in rec["note"]
 
 
-def test_sstep_artifact_agrees_with_guard_bands():
-    """The committed s-step/overlap A/B artifact (round 17) and the
-    bench guard must agree: identical device-knee band bounds (the
-    >= 1.15x s-step acceptance), speedup rows self-consistent with the
-    per-body marginals, the suggest_s policy block reproducible from
-    the committed SPECTRUM.json through `telemetry.suggest_s`, and the
-    docs/performance.md claims tied to the artifact. Device-kind bands
-    gate only records measured on real TPUs — a cpu-platform record is
-    the structural canary (its note must say so) and carries the wide
-    canary sanity bands instead."""
-    from partitionedarrays_jl_tpu import telemetry
-
-    bench_sstep = _load_tool("bench_sstep")
-    rec = json.load(open(os.path.join(REPO, "SSTEP_BENCH.json")))
-    assert rec["methodology"] == bench_sstep.METHODOLOGY
-    assert rec["sstep"] == bench_sstep.SSTEP
-    for key, (lo, hi, kind) in bench_sstep.SSTEP_BANDS.items():
-        band = rec["bands"].get(key)
-        assert band is not None, f"artifact missing band {key}"
-        assert (band["lo"], band["hi"], band["kind"]) == (lo, hi, kind), (
-            key, band,
-        )
-    std = rec["bodies"]["standard"]["s_per_it"]
-    for body, row in rec["bodies"].items():
-        if body == "standard":
-            continue
-        ratio = std / row["s_per_it"]
-        assert abs(row["speedup_vs_standard"] - ratio) <= (
-            1e-3 * ratio
-        ), (body, row)
-    # the policy block must be what telemetry.suggest_s derives from
-    # the committed spectrum store today (artifact and policy cannot
-    # drift apart silently)
-    spec_rec = json.load(open(os.path.join(REPO, "SPECTRUM.json")))
-    by_key = {
-        (e["fingerprint"], e["dtype"], e["minv_class"]): e
-        for e in spec_rec["entries"]
-    }
-    assert rec["suggest_s"], "artifact lost its suggest_s policy block"
-    for row in rec["suggest_s"]:
-        e = by_key[(row["fingerprint"], row["dtype"], row["minv_class"])]
-        pol = telemetry.suggest_s(
-            {"kappa": e.get("kappa"), "rate": e.get("rate"),
-             "samples": e.get("samples", 1)},
-            e["dtype"], tol=1e-8,
-        )
-        assert row["suggested_s"] == pol["s"], row
-        assert row["policy"] == pol["policy"]
-        assert row["gather_factor"] == pol["gather_factor"]
-    if rec["platform"] == "tpu":
-        assert rec["bands_ok_device"] is True
-    else:
-        assert rec["bands_ok_device"] is None
-        assert "real TPUs" in rec["note"]
-        for key, (lo, hi, kind) in bench_sstep.CANARY_BANDS.items():
-            band = rec["bands"].get(key)
-            assert band is not None, f"canary record missing band {key}"
-            assert band["kind"] == kind and band["in_band"] is True
-    # the docs claim the knee the artifact enforces
-    perf = open(os.path.join(REPO, "docs", "performance.md")).read()
-    assert "SSTEP_BENCH.json" in perf
-    knee = bench_sstep.SSTEP_BANDS["sstep2_speedup_vs_standard"][0]
-    assert f"{knee:.2f}" in perf, (
-        "docs/performance.md must state the device knee the band pins"
-    )
-
-
-def test_twolevel_artifact_agrees_with_guard_bands():
-    """The committed flat-vs-two-level A/B artifact (round 18) and the
-    bench guard must agree: identical band bounds, the static
-    reductions recomputable from the recorded per-fabric summaries,
-    the synthetic-fit decision self-consistent (dcn fit engaged, both
-    modeled costs present, ``use`` true), and the docs claims tied to
-    the artifact. Static-kind bands gate on EVERY platform; the
-    device-kind exchange speedup gates only records measured on real
-    TPUs — a cpu-platform record is the structural canary."""
-    bench_twolevel = _load_tool("bench_twolevel")
-    rec = json.load(open(os.path.join(REPO, "TWOLEVEL_BENCH.json")))
-    assert rec["methodology"] == bench_twolevel.METHODOLOGY
-    assert rec["node_map"] == bench_twolevel.NODE_MAP
-    assert rec["synth_model"] == bench_twolevel.SYNTH_MODEL
-    for key, (lo, hi, kind) in bench_twolevel.TWOLEVEL_BANDS.items():
-        band = rec["bands"].get(key)
-        assert band is not None, f"artifact missing band {key}"
-        assert (band["lo"], band["hi"], band["kind"]) == (lo, hi, kind), (
-            key, band,
-        )
-        if kind == "static":
-            # deterministic plan/model structure: in band everywhere
-            assert band["in_band"] is True, (key, band)
-    # the static reductions are the per-fabric summaries' arithmetic
-    dcn_f = rec["flat"]["fabric_summary"]["dcn"]
-    dcn_t = rec["twolevel"]["fabric_summary"]["dcn"]
-    red = rec["reductions"]
-    assert red["dcn_edge_reduction"] == round(
-        dcn_f["edges"] / dcn_t["edges"], 4
-    )
-    assert red["dcn_wire_reduction"] == round(
-        dcn_f["wire_bytes"] / dcn_t["wire_bytes"], 4
-    )
-    assert red["extra_ici_wire_rounds"] == sum(
-        1 for t in rec["twolevel"]["round_tiers"]
-        if t in ("gather", "scatter")
-    )
-    # the measured-not-guessed decision: the dcn fit engaged (the
-    # synthetic matrix carries two distinct dcn payload sizes) and the
-    # modeled speedup band row is the decision's own cost ratio
-    fit = rec["synthetic_fit"]["model"]
-    dec = rec["synthetic_fit"]["decision"]
-    assert fit["dcn"]["source"] == "fit"
-    assert dec["use"] is True
-    assert dec["model_source"] != "default"
-    modeled = dec["flat_modeled_s"] / dec["twolevel_modeled_s"]
-    measured = rec["bands"]["modeled_speedup"]["measured"]
-    assert abs(measured - modeled) <= 1e-3 * modeled, (measured, modeled)
-    ratio = rec["flat"]["exchange_s"] / rec["twolevel"]["exchange_s"]
-    assert abs(rec["exchange_speedup"] - ratio) <= 1e-3 * ratio
-    # the two-level block carries the plan's OWN fabric view
-    assert rec["twolevel"]["node_of"] == [
-        int(x) for x in rec["node_map"].split(",")
-    ]
-    assert rec["twolevel"]["decision"]["use"] is True
-    assert rec["twolevel"]["decision"]["node_pair_edges"] == (
-        dcn_t["edges"]
-    )
-    if rec["platform"] == "tpu":
-        assert rec["bands_ok_device"] is True
-    else:
-        assert rec["bands_ok_device"] is None
-        assert "real TPUs" in rec["note"]
-        for key, (lo, hi, kind) in bench_twolevel.CANARY_BANDS.items():
-            band = rec["bands"].get(key)
-            assert band is not None, f"canary record missing band {key}"
-            assert band["kind"] == kind and band["in_band"] is True
-    # the docs claim what the bands enforce
-    perf = open(os.path.join(REPO, "docs", "performance.md")).read()
-    assert "TWOLEVEL_BENCH.json" in perf
-    knee = bench_twolevel.TWOLEVEL_BANDS["twolevel_exchange_speedup"][0]
-    assert f"≥ {knee:g}×" in perf, (
-        "docs/performance.md must state the device knee the band pins"
-    )
-
-
 def test_committed_comms_matrix_fabric_summaries_pin_both_ways():
     """The v2 schema's per-fabric summary is DERIVED state: for the
-    committed COMMS_MATRIX.json — the top-level flat record AND its
-    ``twolevel`` sub-record — the stored summary must equal the
+    committed COMMS_MATRIX.json the stored summary must equal the
     recomputation from the stored edge rows (stale-summary direction),
     and every fabric in the summary must be present among the edges
     (phantom-summary direction)."""
@@ -635,23 +491,11 @@ def test_committed_comms_matrix_fabric_summaries_pin_both_ways():
     assert rec["comms_matrix_schema_version"] == (
         cmx.COMMS_MATRIX_SCHEMA_VERSION
     )
-    tl = rec["twolevel"]
-    for label, m in (("flat", rec), ("twolevel", tl)):
-        assert m["fabric_summary"] == cmx.fabric_summary(m["edges"]), (
-            label
-        )
-        assert set(m["fabric_summary"]) == {
-            e["fabric"] for e in m["edges"]
-        }, label
-    # the sub-record is the node-aware fixture's own fabric view: the
-    # plan kind, its node map, a recorded decision, and slow-fabric
-    # traffic that the flat record (single-process host) cannot have
-    assert tl["plan"] == "twolevel"
-    assert tl["node_of"] == [0, 0, 1, 1]
-    assert tl["decision"]["use"] is True
-    assert tl["fabric_summary"]["dcn"]["edges"] == (
-        tl["decision"]["node_pair_edges"]
-    )
+    assert rec["fabric_summary"] == cmx.fabric_summary(rec["edges"])
+    assert set(rec["fabric_summary"]) == {
+        e["fabric"] for e in rec["edges"]
+    }
+    # a single-process host has no slow-fabric traffic to record
     assert "dcn" not in rec["fabric_summary"]
 
 

@@ -343,19 +343,6 @@ def test_fused_padded_frame_kernel_fold_parity(monkeypatch):
     )
 
 
-def test_fused_and_pipelined_mutually_exclusive():
-    backend = _backend()
-
-    def driver(parts):
-        A, b, xe, x0 = assemble_poisson(parts, (6, 6))
-        return A
-
-    A = pa.prun(driver, backend, (2, 2))
-    dA = device_matrix(A, backend)
-    with pytest.raises(ValueError):
-        make_cg_fn(dA, tol=1e-9, maxiter=10, pipelined=True, fused=True)
-
-
 def test_pcg_gmg_branch_rejects_explicit_fused():
     """The GMG-preconditioned device program compiles its own PCG body
     with no fused variant — an explicit fused flag there must raise, not

@@ -1,21 +1,14 @@
-"""Multi-host legs exercised with REAL OS processes (reference analog:
-the mpiexec suite, test/mpi/runtests.jl:1-20 — each test spawns a real
-multi-rank job and asserts clean completion).
+"""Two-process multi-host smoke test: the DCN story exercised with REAL
+processes (reference analog: the mpiexec suite, test/mpi/runtests.jl:1-20
+— each test spawns a real multi-rank job and asserts clean completion).
 
-Two tiers, split by what they actually need (ISSUE 18):
-
-* **Plan-soundness legs** — replicated planning must produce the
-  IDENTICAL exchange schedule on every controller. That is host-side
-  NumPy work, so it runs through the `tools/plan_multiproc.py` spawn
-  harness on EVERY host: K real processes each build + verify the
-  two-level plan and the parent pins cross-process digest agreement.
-  No backend capability involved — these legs never skip.
-* **Execution legs** — two `jax.distributed` CPU processes x 4 virtual
-  devices form one 8-device global mesh and run the compiled CG over
-  it. Only THESE carry the named skip for jaxlib CPU runtimes without
-  cross-process collectives (the documented backend limitation).
+Two `jax.distributed` CPU processes x 4 virtual devices each form one
+8-device global mesh; both run the identical FDM driver (replicated
+planning), the compiled CG executes over the global mesh, and each
+controller checks the solve plus cross-process agreement of the result.
+The leg carries a named skip for jaxlib CPU runtimes without
+cross-process collectives (the documented backend limitation).
 """
-import json
 import os
 import socket
 import subprocess
@@ -23,22 +16,9 @@ import sys
 
 import pytest
 
-TESTS = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(TESTS)
-WORKER = os.path.join(TESTS, "_multihost_worker.py")
-
-
-def _plan_multiproc():
-    """Import the harness as a REAL module (not an importlib shim):
-    the spawn pool pickles its worker by reference, so the children
-    must be able to ``import plan_multiproc`` — they inherit this
-    process's sys.path."""
-    tools = os.path.join(REPO, "tools")
-    if tools not in sys.path:
-        sys.path.insert(0, tools)
-    import plan_multiproc
-
-    return plan_multiproc
+WORKER = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "_multihost_worker.py"
+)
 
 #: jaxlib builds whose CPU runtime lacks cross-process collectives fail
 #: the compiled solve with exactly this error. That is a missing BACKEND
@@ -57,54 +37,10 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def test_two_process_twolevel_plan_agreement():
-    """Plan-soundness leg (never skips): two REAL spawned processes
-    each build the (2, 4)-part two-level plan from the identical
-    replicated inputs, run the full verifier battery in-process (the
-    worker asserts zero defects), and the structural digests —
-    `plan_fingerprint` + `canonical_exchange_fingerprint` — agree
-    across processes. A forked schedule would deadlock the paired
-    ppermutes on a real slice; this pins it at plan time."""
-    pm = _plan_multiproc()
-    results, agree = pm.run_twolevel(procs=2)
-    assert agree, [r["digest"] for r in results]
-    assert len(results) == 2
-    for r in results:
-        # the dcn-weighted probe's aggregation structure: 8 flat
-        # cross-node edges collapse to 2 node-pair transfers, through
-        # the staged gather/node/scatter tiers
-        assert r["slow_edges_flat"] == 8 and r["node_pairs"] == 2
-        assert r["use"] is True
-        for tier in ("gather", "node", "scatter"):
-            assert tier in r["tiers"], r["tiers"]
-        assert r["wire_rounds"] == sum(
-            1 for t in r["tiers"] if t not in ("local_out", "local_in")
-        )
-    # distinct OS processes, both distinct from this controller
-    assert len({r["pid"] for r in results} | {os.getpid()}) == 3
-
-
-def test_two_process_twolevel_plan_cli_smoke():
-    """The harness's operator surface: `plan_multiproc.py --twolevel`
-    exits zero and reports agreement — the command a multi-host
-    operator runs before committing a node map to a job config."""
-    out = subprocess.run(
-        [sys.executable,
-         os.path.join(REPO, "tools", "plan_multiproc.py"),
-         "--twolevel"],
-        capture_output=True, text=True, timeout=300,
-    )
-    assert out.returncode == 0, out.stderr or out.stdout
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["agree"] is True
-    assert rec["metric"] == "twolevel_plan_cross_process_agreement"
-
-
 def test_two_process_fdm_solve():
-    """Execution leg: the compiled CG over a true two-process global
-    mesh (named skip below when the jaxlib CPU runtime cannot execute
-    cross-process programs — plan soundness is covered unskippably
-    above)."""
+    """The compiled CG over a true two-process global mesh (named skip
+    below when the jaxlib CPU runtime cannot execute cross-process
+    programs)."""
     port = _free_port()
     env = {
         k: v

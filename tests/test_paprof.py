@@ -129,27 +129,19 @@ def test_phase_profile_sums_in_band_and_reconciles(fixture_Ab,
 def test_phase_trace_events_merge_shape(fixture_Ab):
     """The patrace merge feed: spans for every phase, synthetic
     iterations consecutive, args carrying the attribution identity.
-    The committed artifact is the schema-2 multi-case container; the
-    overlap entry additionally carries its boundary_spmv phase."""
+    The committed artifact is the schema-2 multi-case container."""
     rec = json.load(open(os.path.join(REPO, "PHASE_PROFILE.json")))
-    for case in ("standard", "overlap"):
+    for case in ("standard", "fused"):
         committed = rec["profiles"][case]
-        phases = prof.profile_phases(committed)
         events = prof.phase_trace_events(committed, iterations=2)
         spans = [e for e in events if e.get("cat") == "phase"]
-        assert len(spans) == 2 * len(phases)
-        assert {e["name"] for e in spans} == set(phases)
+        assert len(spans) == 2 * len(prof.PHASES)
+        assert {e["name"] for e in spans} == set(prof.PHASES)
         ts = [e["ts"] for e in spans]
         assert ts == sorted(ts)
         assert all(
             e["args"]["case"] == committed["case"] for e in spans
         )
-    assert prof.PHASE_BOUNDARY in prof.profile_phases(
-        rec["profiles"]["overlap"]
-    )
-    assert prof.PHASE_BOUNDARY not in prof.profile_phases(
-        rec["profiles"]["standard"]
-    )
 
 
 def test_pa_prof_off_noop_and_solver_hlo_identical(fixture_Ab,
@@ -262,32 +254,20 @@ def test_committed_phase_profile_is_reconciled():
     """PHASE_PROFILE.json (the schema-2 container): every committed
     case internally reconciled and in its own recorded band, the
     envelope on the container, and the case set covering the full
-    lowering matrix through `phase_case_of` (the ISSUE-17 bugfix: the
-    artifact used to commit only the fused body)."""
+    lowering matrix through `phase_case_of`."""
     rec = json.load(open(os.path.join(REPO, "PHASE_PROFILE.json")))
     assert rec["phase_schema_version"] == prof.PHASE_SCHEMA_VERSION
     profiles = rec["profiles"]
     assert set(profiles) == {
         "standard", "fused", "block_k1_fused", "block_k4_fused",
-        "sstep2", "overlap", "twolevel",
     }
     for case, p in profiles.items():
         assert p["case"] == case
         assert prof.reconcile_phases(p) == [], case
         assert p["in_band"] is True, case
         assert p["fingerprint"] == "g36-p4"
-    # the s-step entry is attributed per TRIP (unit = s); the overlap
-    # entry names its boundary attribution
-    assert profiles["sstep2"]["unit"] == 2
-    assert profiles["overlap"]["boundary_attribution"] == (
-        "structural-nnz-split"
-    )
-    # the twolevel entry attributes the halo per FABRIC tier (ISSUE
-    # 18): both split phases present, the merged halo_exchange absent
-    tl_phases = profiles["twolevel"]["phases"]
-    for ph in prof.PHASE_HALO_SPLIT:
-        assert ph in tl_phases, ph
-    assert "halo_exchange" not in tl_phases
+    for p in profiles.values():
+        assert set(p["phases"]) == set(prof.PHASES)
     # every lowering-matrix case must map onto a committed entry —
     # paprof --check's coverage gate, pinned here against the artifact
     from partitionedarrays_jl_tpu.parallel.tpu import lowering_matrix

@@ -16,7 +16,7 @@ The contracts pinned here:
   spectra equal the solo solves' spectra EXACTLY (the trajectories are
   bitwise, so the tridiagonals are too).
 * **Trace-ring exemption honesty** — a body that cannot carry the ring
-  (pipelined) emits the typed ``trace_unavailable`` event naming
+  (BiCGStab) emits the typed ``trace_unavailable`` event naming
   itself instead of silently returning no spectrum.
 * **Overhead** — the solver path never reads ``PA_SPEC*``: the block
   program lowers to byte-identical StableHLO with the observatory and
@@ -321,12 +321,12 @@ def test_block_per_column_spectra_match_solo_bitwise(monkeypatch):
 
 
 def test_trace_unavailable_event_names_the_body(monkeypatch):
-    """Trace-ring exemption honesty: a pipelined solve under
+    """Trace-ring exemption honesty: a BiCGStab solve under
     PA_TRACE_ITERS cannot carry the ring — it must say so typed
     (``trace_unavailable`` naming the body) instead of silently
     returning a record with no spectrum."""
     monkeypatch.setenv("PA_TRACE_ITERS", "64")
-    from partitionedarrays_jl_tpu.parallel.tpu import tpu_cg
+    from partitionedarrays_jl_tpu.parallel.tpu import tpu_bicgstab
 
     backend = _backend()
 
@@ -337,12 +337,11 @@ def test_trace_unavailable_event_names_the_body(monkeypatch):
     A, b, x0 = pa.prun(probe, backend, (2, 2, 2))
 
     def driver(parts):
-        x, info = tpu_cg(A, b, x0=x0, tol=1e-9, maxiter=100,
-                         pipelined=True)
+        x, info = tpu_bicgstab(A, b, x0=x0, tol=1e-9, maxiter=100)
         rec = info.record
-        assert rec.alpha is None  # no ring on the pipelined body
+        assert rec.alpha is None  # no ring on the BiCGStab body
         evs = rec.events_of("trace_unavailable")
-        assert evs and evs[0].label == "pipelined"
+        assert evs and evs[0].label == "bicgstab"
         assert evs[0].details["requested"] == 64
         # the spectrum layer still measured the RATE from the history
         est = telemetry.estimate_solve(

@@ -57,14 +57,6 @@ DEFECT_FIXTURES = [
     ("dead_slot.json", "dead-slot"),
 ]
 
-#: The ISSUE-18 staged-schedule corpus ("paplan-twolevel-fixture"
-#: format): mutated TWO-LEVEL plans whose flat logical-delivery view is
-#: sound — only the staged schedule is corrupted, so nothing but the
-#: schedule simulation can catch them.
-TWOLEVEL_FIXTURES = [
-    ("twolevel_rep_slot.json", "coverage"),
-]
-
 
 # ---------------------------------------------------------------------------
 # the committed negative corpus
@@ -77,26 +69,8 @@ def test_corpus_is_complete():
     names = {os.path.basename(p) for p in glob.glob(
         os.path.join(FIXDIR, "*.json")
     )}
-    assert names == (
-        {n for n, _ in DEFECT_FIXTURES}
-        | {n for n, _ in TWOLEVEL_FIXTURES}
-        | {"clean.json"}
-    )
+    assert names == {n for n, _ in DEFECT_FIXTURES} | {"clean.json"}
     assert {c for _, c in DEFECT_FIXTURES} == set(pv.PLAN_CHECKS)
-
-
-@pytest.mark.parametrize("name,check", TWOLEVEL_FIXTURES)
-def test_twolevel_defect_fixture_caught(name, check):
-    plan, ref, defect = pv.load_twolevel_fixture(
-        os.path.join(FIXDIR, name)
-    )
-    assert defect == check, "fixture self-description drifted"
-    defects = pv.verify_twolevel_plan(plan, referenced=ref)
-    assert defects, f"{name}: verifier saw nothing"
-    checks = {d.check for d in defects}
-    assert check in checks, (name, checks)
-    hit = next(d for d in defects if d.check == check)
-    assert hit.part is not None and hit.message
 
 
 def test_clean_fixture_verifies_sound():

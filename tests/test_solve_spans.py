@@ -249,7 +249,6 @@ def coded_kernel_args():
 @pytest.mark.parametrize("variant,name", [
     ("plain", "pa_dia_coded_spmv"),
     ("pfold", "pa_dia_coded_spmv_pfold"),
-    ("axpy", "pa_dia_coded_spmv_axpy"),
     ("stream", "pa_dia_stream_spmv"),
 ])
 def test_each_pallas_call_has_its_name(variant, name):
@@ -267,7 +266,6 @@ def test_each_pallas_call_has_its_name(variant, name):
     else:
         x, args = coded_kernel_args()
         one = np.zeros(1, np.float32)
-        kw = {"plain": {}, "pfold": {"pfold": (x, one)},
-              "axpy": {"axpy": (x, x, one)}}[variant]
+        kw = {"plain": {}, "pfold": {"pfold": (x, one)}}[variant]
         call = lambda: P.dia_coded_padded_pallas(*args, interpret=True, **kw)
     assert pallas_names(jax.make_jaxpr(call)().jaxpr) == [name]

@@ -238,19 +238,14 @@ EXPECTED_LOWERING_FLAGS = {
     "PA_TPU_BOX",
     "PA_TPU_BSR",
     "PA_TPU_CLASS_ACC",
-    "PA_TPU_COMMS_MATRIX",
     "PA_TPU_ELL_GUARD",
     "PA_TPU_ELL_MAX_GATHER",
     "PA_TPU_FUSED_CG",
     "PA_TPU_GMG_BOX",
     "PA_TPU_GMG_STENCIL",
-    "PA_TPU_NODE_MAP",
     "PA_TPU_OH_BUCKETS",
-    "PA_TPU_OVERLAP",
     "PA_TPU_SD",
-    "PA_TPU_SSTEP",
     "PA_TPU_STRICT_BITS",
-    "PA_TPU_TWOLEVEL",
     "PA_TRACE_ITERS",
 }
 
@@ -540,12 +535,10 @@ def test_fast_matrix_contracts_hold():
     assert "standard_f32__compiled" in reports
     assert "f64" not in reports["standard_f32__compiled"].float_dtypes
     # the plan audits are live: default-env cases verified the BOX
-    # plan, the nobox/ABFT cases the GENERIC plan, the node-aware case
-    # its TWO-LEVEL schedule, all with zero defects and the host
-    # exchanger alongside
+    # plan, the nobox/ABFT cases the GENERIC plan, all with zero defects
+    # and the host exchanger alongside
     kinds = {cases[n]["plan_audit"]["kind"] for n in cases}
-    assert kinds == {"device-box", "device-generic", "device-twolevel"}
-    assert cases["twolevel"]["plan_audit"]["kind"] == "device-twolevel"
+    assert kinds == {"device-box", "device-generic"}
     for n in cases:
         audit = cases[n]["plan_audit"]
         assert audit["n_defects"] == 0, (n, audit)

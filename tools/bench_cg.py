@@ -54,12 +54,10 @@ def main():
     K0, K1 = 100, 500
     flops = dA.flops_per_spmv  # one SpMV per CG iteration
 
-    def measure(pipelined: bool = False, fused: bool = False) -> float:
+    def measure(fused: bool = False) -> float:
         # compile each K-program ONCE; only the timed executions repeat
         solves = {
-            k: make_cg_fn(
-                dA, tol=0.0, maxiter=k, pipelined=pipelined, fused=fused
-            )
+            k: make_cg_fn(dA, tol=0.0, maxiter=k, fused=fused)
             for k in (K0, K1)
         }
         for s in solves.values():  # warm: the solve ends in host scalars
@@ -101,16 +99,6 @@ def main():
         f"spmv_equiv_gflops={flops / dtf / 1e9:.1f} "
         f"speedup_vs_standard={dt / dtf:.3f}x "
         "(fused body, PA_TPU_FUSED_CG default)"
-    )
-    dtp = measure(pipelined=True)
-    rec["bodies"]["pipelined"] = {
-        "s_per_it": round(dtp, 9),
-        "speedup_vs_standard": round(dt / dtp, 4),
-    }
-    print(
-        f"pipelined_cg_per_iteration_us={dtp * 1e6:.1f} "
-        f"spmv_equiv_gflops={flops / dtp / 1e9:.1f} "
-        f"speedup_vs_standard={dt / dtp:.3f}x"
     )
 
     # --rhs leg: block (multi-RHS) CG marginals — per-RHS cost at each

@@ -11,15 +11,10 @@ artifact writer (`telemetry.artifacts` — the same envelope every other
 committed ``*_BENCH.json`` carries and tests/test_doc_consistency.py
 checks); ``--dry-run`` prints the record without committing.
 
-ISSUE-18 port: the record also carries a schema-v2 ``comms_matrix``
-block — the static per-edge byte accounting of the mesh operator's
-exchange plan (`telemetry.commsmatrix.static_matrix`), reconciled
-against `comms._exchange_inventory` before writing. The fabric hook is
-THREADED, not duplicated: when ``PA_TPU_NODE_MAP`` is set, the same
-map reaches plan construction (``device_exchange_plan`` reads it for
-the two-level tier) AND the matrix's edge labels (`classify_edge`'s
-``node_of`` priority) — the committed record can never disagree with
-the plan the env selected (tests/test_twolevel.py pins the threading).
+The record also carries a schema-v2 ``comms_matrix`` block — the
+static per-edge byte accounting of the mesh operator's exchange plan
+(`telemetry.commsmatrix.static_matrix`), reconciled against
+`comms._exchange_inventory` before writing.
 
     python tools/bench_ici.py            # 64^3, 8 virtual CPU devices
     PA_ICI_N=96 python tools/bench_ici.py
@@ -46,13 +41,7 @@ jax.config.update("jax_platforms", "cpu")
 
 def comms_record(pa, backend, ns=(6, 6, 6), pshape=(2, 2, 2)):
     """The v2 matrix block: static per-edge accounting of the mesh
-    operator's column plan, fabric-labeled by the SAME hook plan
-    construction consumed. ``PA_TPU_NODE_MAP`` (when set) is read once
-    from the environment: `device_exchange_plan` already resolved the
-    plan through it, and the flat families label their edges through
-    `classify_edge`'s ``node_of`` priority with the identical map — a
-    two-level plan carries its own copy, so no override is passed
-    (plan and matrix views cannot fork)."""
+    operator's column plan."""
     import numpy as np
 
     from partitionedarrays_jl_tpu.models import assemble_poisson
@@ -65,14 +54,7 @@ def comms_record(pa, backend, ns=(6, 6, 6), pshape=(2, 2, 2)):
 
     A = pa.prun(driver, backend, pshape)
     dA = device_matrix(A, backend)
-    nmap = os.environ.get("PA_TPU_NODE_MAP")
-    classify = None
-    if nmap and not hasattr(dA.col_plan, "tl_rounds"):
-        node_of = [int(x) for x in nmap.split(",")]
-        classify = lambda s, d: cm.classify_edge(s, d, node_of=node_of)
-    m = cm.static_matrix(
-        dA.col_plan, np.float64, backend=backend, classify=classify
-    )
+    m = cm.static_matrix(dA.col_plan, np.float64, backend=backend)
     m["static_check"] = cm.reconcile_matrix(m, dA)
     assert m["static_check"] == [], m["static_check"]
     return m

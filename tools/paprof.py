@@ -29,22 +29,17 @@ item 3). Legs:
                           and print/write it.
 * ``--write``             regenerate the committed PHASE_PROFILE.json
                           (schema v2: ONE profile per committed body
-                          case — standard, fused, block_k1/k4, the
-                          ISSUE-17 sstep2 / overlap bodies, and the
-                          ISSUE-18 twolevel node-aware plan with its
-                          per-fabric ``halo_ici`` / ``halo_dcn_agg``
-                          split) and COMMS_MATRIX.json (schema v2: the
-                          flat comms matrix on the generic index plan
-                          — ``PA_TPU_BOX=0`` — where per-round timings
-                          are truly measured, plus the two-level
-                          schedule's per-fabric matrix under
-                          ``"twolevel"``). ``--check`` fails when any
-                          lowering-matrix CG case maps to no committed
-                          phase entry.
+                          case — standard, fused, block_k1/k4) and
+                          COMMS_MATRIX.json (schema v2: the comms
+                          matrix on the generic index plan —
+                          ``PA_TPU_BOX=0`` — where per-round timings
+                          are truly measured). ``--check`` fails when
+                          any lowering-matrix CG case maps to no
+                          committed phase entry.
 
-Options: ``--case standard|fused|block_k1_fused|block_k4_fused|
-sstep2|overlap|twolevel`` (body form; default the shipped default), ``--k K``
-(block width), ``--n N`` (grid edge, default 6).
+Options: ``--case standard|fused|block_k1_fused|block_k4_fused`` (body
+form; default the shipped default), ``--k K`` (block width), ``--n N``
+(grid edge, default 6).
 
 Usage:
     python tools/paprof.py --check
@@ -97,23 +92,12 @@ def _fixture(jax, n: int):
 
 #: The committed PHASE_PROFILE.json entries: every lowering-matrix CG
 #: case maps onto one of these via `profile.phase_case_of` (the
-#: --check coverage gate). kwargs feed `capture_phase_profile`; the
-#: optional "env" entry is scoped around the capture (the node-aware
-#: plan is env-selected at device_matrix time, not a body kwarg).
-_TWOLEVEL_ENV = {
-    "PA_TPU_TWOLEVEL": "1",
-    "PA_TPU_NODE_MAP": "0,0,1,1",
-    "PA_TPU_BOX": "0",
-}
-
+#: --check coverage gate). kwargs feed `capture_phase_profile`.
 _COMMITTED_CASES = {
     "standard": dict(fused=False),
     "fused": dict(fused=True),
     "block_k1_fused": dict(fused=True, rhs_batch=1),
     "block_k4_fused": dict(fused=True, rhs_batch=4),
-    "sstep2": dict(fused=False, sstep=2),
-    "overlap": dict(fused=False, overlap=True),
-    "twolevel": dict(fused=False, env=_TWOLEVEL_ENV),
 }
 
 
@@ -127,22 +111,17 @@ def _case_kwargs(case, k):
 
 
 def _capture(jax, args):
-    from partitionedarrays_jl_tpu.parallel.tpu import _env_overrides
     from partitionedarrays_jl_tpu.telemetry import profile as prof
 
-    kw = _case_kwargs(args.case, args.k)
-    env = kw.pop("env", None)
     A, backend = _fixture(jax, args.n)
-    with _env_overrides(env or {}):
-        return prof.capture_phase_profile(A, backend, **kw)
+    return prof.capture_phase_profile(
+        A, backend, **_case_kwargs(args.case, args.k)
+    )
 
 
 def _check(args) -> int:
     jax = _cpu_mesh()
-    from partitionedarrays_jl_tpu.parallel.tpu import (
-        _env_overrides,
-        device_matrix,
-    )
+    from partitionedarrays_jl_tpu.parallel.tpu import device_matrix
     from partitionedarrays_jl_tpu.telemetry import (
         commsmatrix as cm,
         profile as prof,
@@ -203,31 +182,14 @@ def _check(args) -> int:
             )
             if name == "COMMS_MATRIX.json":
                 # schema v2: the per-fabric summary must recompute
-                # from the committed edge rows (both the flat matrix
-                # and the two-level sub-record), and the two-level
-                # record must actually exercise the slow fabric
-                tl = rec.get("twolevel")
-                expect(
-                    isinstance(tl, dict),
-                    f"committed {name}: no 'twolevel' record "
-                    "(schema v2; run tools/paprof.py --write)",
-                )
-                for lbl, sub in (("", rec), ("twolevel", tl or {})):
-                    if not sub.get("edges"):
-                        continue
-                    got = sub.get("fabric_summary")
-                    want = cm.fabric_summary(sub["edges"])
+                # from the committed edge rows
+                if rec.get("edges"):
+                    got = rec.get("fabric_summary")
+                    want = cm.fabric_summary(rec["edges"])
                     expect(
                         got == want,
-                        f"committed {name}{lbl and f'[{lbl}]'}: "
-                        f"fabric_summary {got} != recomputed {want}",
-                    )
-                if isinstance(tl, dict):
-                    expect(
-                        any(e.get("fabric") == "dcn"
-                            for e in tl.get("edges", [])),
-                        f"committed {name}[twolevel]: no slow-fabric "
-                        "edge recorded",
+                        f"committed {name}: fabric_summary {got} != "
+                        f"recomputed {want}",
                     )
             if name == "PHASE_PROFILE.json":
                 profiles = rec.get("profiles") or {}
@@ -242,26 +204,11 @@ def _check(args) -> int:
                         f"committed {name}: entry {cname!r} records "
                         f"case {p.get('case')!r}",
                     )
-                    dA_for = None
-                    if cname == "twolevel":
-                        # the twolevel entry's inventory is re-derived
-                        # against a FRESH two-level operator (the
-                        # committed per-fabric permute split must match
-                        # the plan the env selects today)
-                        with _env_overrides(_TWOLEVEL_ENV):
-                            dA_for = device_matrix(A, backend)
-                        expect(
-                            prof.PHASE_HALO_SPLIT[0] in p.get(
-                                "phases", {}
-                            ),
-                            f"committed {name}[{cname}]: no per-fabric "
-                            "halo split recorded",
-                        )
-                    for m in prof.reconcile_phases(p, dA=dA_for):
+                    for m in prof.reconcile_phases(p):
                         expect(False, f"committed {name}[{cname}]: {m}")
                 # coverage: every lowering-matrix CG case must map onto
-                # a committed phase entry (the ISSUE-17 bugfix — the
-                # matrix can never grow a body paprof has not profiled)
+                # a committed phase entry (the matrix can never grow a
+                # body paprof has not profiled)
                 from partitionedarrays_jl_tpu.parallel.tpu import (
                     lowering_matrix,
                 )
@@ -296,15 +243,12 @@ def _write_committed() -> int:
     profiles = {}
     for cname, kw in _COMMITTED_CASES.items():
         print(f"paprof --write: capturing {cname} ...", flush=True)
-        kw = dict(kw)
-        env = kw.pop("env", None)
         # wall-clock marginals on a shared host jitter; the committed
         # artifact records a clean capture, so re-capture (fresh body
         # total AND fresh chains) up to 3 times before giving up
         p = bad = None
         for _ in range(3):
-            with _env_overrides(env or {}):
-                p = prof.capture_phase_profile(A, backend, **kw)
+            p = prof.capture_phase_profile(A, backend, **kw)
             if p is None:
                 print("paprof --write: PA_PROF=0 — nothing captured",
                       file=sys.stderr)
@@ -331,21 +275,10 @@ def _write_committed() -> int:
     )
     # the committed matrix rides the GENERIC index plan: its per-round
     # timings are individually measured (the box plan's fused slice
-    # program only supports proportional attribution), and the generic
-    # plan is the structure the node-aware tier transforms — the
-    # schema-v2 artifact carries BOTH: the flat matrix at top level
-    # and the two-level schedule's per-fabric matrix under "twolevel"
+    # program only supports proportional attribution)
     with _env_overrides({"PA_TPU_BOX": "0"}):
         A2, backend2 = _fixture(jax, 6)
         matrix = cm.measure_comms_matrix(A2, backend2)
-        with _env_overrides(_TWOLEVEL_ENV):
-            A3, backend3 = _fixture(jax, 6)
-            tl_matrix = cm.measure_comms_matrix(A3, backend3)
-    if tl_matrix["static_check"]:
-        print("paprof --write: two-level matrix does not reconcile: "
-              f"{tl_matrix['static_check']}", file=sys.stderr)
-        return 1
-    matrix["twolevel"] = tl_matrix
     artifacts.write(
         os.path.join(REPO, "COMMS_MATRIX.json"), matrix, tool="paprof"
     )
@@ -365,8 +298,7 @@ def main(argv=None):
                     help="regenerate the committed artifacts")
     ap.add_argument("--case",
                     choices=("standard", "fused", "block_k1_fused",
-                             "block_k4_fused", "sstep2", "overlap",
-                             "twolevel"),
+                             "block_k4_fused"),
                     help="CG body form (default: shipped default)")
     ap.add_argument("--k", type=int, default=0,
                     help="block width (rhs_batch; 0 = single RHS)")

@@ -18,14 +18,6 @@ The operator console of `telemetry.spectrum` (docs/observability.md,
 * ``--forecast TOL``        with ``--last``: predict
                             iterations-to-tolerance from the record's
                             own estimate.
-* ``--suggest-s``           the ``PA_TPU_SSTEP`` depth policy per
-                            stored (fingerprint, dtype, minv-class)
-                            entry: the largest stability-budget-stable
-                            s (``telemetry.spectrum.suggest_s``), κ̂
-                            and the dtype precision budget it was
-                            judged against, and the forecasted
-                            collective win at ``--forecast TOL``
-                            (default 1e-8).
 * ``--check``               tier-1 smoke: solve the conformance Poisson
                             probe on the virtual device mesh with the
                             trace ring on, reconstruct the spectrum,
@@ -43,7 +35,6 @@ The operator console of `telemetry.spectrum` (docs/observability.md,
 Usage:
     python tools/paspec.py --check
     python tools/paspec.py --write            # refresh SPECTRUM.json
-    python tools/paspec.py --suggest-s        # PA_TPU_SSTEP policy
     PA_METRICS_DIR=/tmp/rec python your_solve.py
     python tools/paspec.py --last --dir /tmp/rec --forecast 1e-8
 """
@@ -176,69 +167,6 @@ def render_store(store_rec):
             f" samples={e['samples']}"
         )
     return "\n".join(lines)
-
-
-def render_suggest_s(store_rec, tol):
-    """One policy row per stored spectrum entry: the chosen
-    ``PA_TPU_SSTEP`` depth, the κ̂/precision-budget arithmetic that
-    chose it, and the forecasted collective win at ``tol``."""
-    from partitionedarrays_jl_tpu import telemetry
-
-    lines = [
-        f"s-step depth policy (PA_TPU_SSTEP suggestion, "
-        f"s_max={telemetry.SSTEP_MAX}, forecast tol={tol:g}):"
-    ]
-    entries = store_rec.get("entries") or []
-    if not entries:
-        lines.append(
-            "  (no measured entries — unmeasured operators default to "
-            "the always-safe s=1)"
-        )
-    policies = []
-    for e in entries:
-        spec = {
-            "kappa": e.get("kappa"), "rate": e.get("rate"),
-            "samples": e.get("samples", 1),
-        }
-        pol = telemetry.suggest_s(spec, e["dtype"], tol=tol)
-        pol["fingerprint"] = e["fingerprint"]
-        pol["minv_class"] = e["minv_class"]
-        policies.append(pol)
-        kap = pol["kappa"]
-        fc = pol.get("forecast") or {}
-        win = (
-            "win unforecast (no measured rate/kappa)"
-            if fc.get("predicted_iters") is None
-            else (
-                f"forecast {fc['predicted_iters']} its: "
-                f"{fc['standard_gathers']} scalar gathers -> "
-                f"{fc['sstep_gathers']} block gathers "
-                f"({pol['gather_factor']}x fewer collectives)"
-            )
-        )
-        lines.append(
-            f"  {e['fingerprint']} [{e['dtype']}, "
-            f"minv={e['minv_class']}]: s={pol['s']} "
-            f"({pol['policy']}; "
-            f"kappa={'—' if kap is None else f'{kap:.6g}'}, "
-            f"budget kappa^s <= {pol['budget']:.3g}); {win}"
-        )
-    return "\n".join(lines), policies
-
-
-def suggest_s_cmd(tol, json_=False) -> int:
-    path = os.path.join(REPO, "SPECTRUM.json")
-    if not os.path.exists(path):
-        print("paspec: no committed SPECTRUM.json — run --write first",
-              file=sys.stderr)
-        return 2
-    rec = json.load(open(path))
-    text, policies = render_suggest_s(rec, tol)
-    if json_:
-        print(json.dumps(policies, indent=1, sort_keys=True))
-    else:
-        print(text)
-    return 0
 
 
 # ---------------------------------------------------------------------------
@@ -580,11 +508,6 @@ def main(argv=None):
                     help="one spectral-availability line per record")
     ap.add_argument("--store", action="store_true",
                     help="render the committed SPECTRUM.json store")
-    ap.add_argument("--suggest-s", action="store_true",
-                    dest="suggest_s",
-                    help="PA_TPU_SSTEP depth policy per stored "
-                         "spectrum entry (use --forecast TOL for the "
-                         "win forecast; default 1e-8)")
     ap.add_argument("--forecast", type=float, metavar="TOL",
                     help="with --last: iterations-to-TOL forecast")
     ap.add_argument("--dir", help="records directory (PA_METRICS_DIR)")
@@ -596,11 +519,6 @@ def main(argv=None):
         return check()
     if args.write is not None:
         return write_artifact(args.write, dry_run=args.dry_run)
-    if args.suggest_s:
-        return suggest_s_cmd(
-            args.forecast if args.forecast is not None else 1e-8,
-            json_=args.json_,
-        )
     if args.store:
         rec = json.load(open(os.path.join(REPO, "SPECTRUM.json")))
         if args.json_:

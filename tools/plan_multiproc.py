@@ -16,25 +16,8 @@ scales. `tests/test_multiproc_planning.py` pins the checksums to the
 in-process `assemble_poisson` fast path, so the parallel planning path
 provably computes the SAME matrices.
 
-ISSUE-18 leg (``--twolevel``): the same real-OS-process discipline
-applied to the NODE-AWARE exchange plan. Every controller in a
-multi-host job must construct the identical two-level schedule from
-the identical replicated inputs (node map + exchanger) — a forked
-schedule would deadlock the paired `ppermute`s at runtime. The harness
-makes that testable today: K spawned processes each build the
-two-level plan host-side (pure NumPy — no JAX backend, exactly like
-the planning workers), run the full plan-verifier battery (the five
-flat checks on the logical view plus the staged-schedule simulation),
-and return a structural digest (`plan_fingerprint` +
-`canonical_exchange_fingerprint`); the parent asserts all digests
-agree. `tests/test_multihost.py` routes its plan-soundness legs
-through this harness, so they RUN on every host instead of skipping on
-the jaxlib CPU-runtime collective limitation (which only the true
-execution legs need).
-
     python tools/plan_multiproc.py            # 192^3, K=2 processes
     PA_MP_N=128 PA_MP_PROCS=4 python tools/plan_multiproc.py
-    python tools/plan_multiproc.py --twolevel # cross-process plan digests
 """
 from __future__ import annotations
 
@@ -109,95 +92,7 @@ def run(ns, pshape, procs, dtype="float32", decoupled=True):
     return wall, flat
 
 
-def plan_twolevel(args):
-    """Worker: build the two-level exchange plan of the shared probe
-    under the given node map, verify it (five flat checks on the
-    logical view + the staged-schedule simulation), and return its
-    structural digest plus the schedule/decision summary. Host-side
-    NumPy planning only — no JAX backend is ever initialized."""
-    ns, pshape, nmap = args
-    os.environ["PA_TPU_BOX"] = "0"
-    os.environ["PA_TPU_TWOLEVEL"] = "1"
-    os.environ["PA_TPU_NODE_MAP"] = nmap
-    import hashlib
-
-    import partitionedarrays_jl_tpu as pa
-    from partitionedarrays_jl_tpu.analysis import plan_verifier as pv
-    from partitionedarrays_jl_tpu.models import assemble_poisson
-    from partitionedarrays_jl_tpu.parallel.tpu import device_exchange_plan
-
-    out = {}
-
-    def driver(parts):
-        A, _b, _xe, _x0 = assemble_poisson(parts, ns)
-        rows = A.cols
-        plan = device_exchange_plan(rows)
-        assert hasattr(plan, "tl_rounds"), type(plan).__name__
-        defects = pv.verify_plan(
-            plan, referenced=pv.referenced_ghosts(A)
-        )
-        assert defects == [], [str(d) for d in defects]
-        canon = pv.canonical_exchange_fingerprint(
-            rows.exchanger, rows.partition
-        )
-        fp = pv.plan_fingerprint(plan)
-        out.update(
-            pid=os.getpid(),
-            digest=hashlib.sha256(
-                repr((canon, fp)).encode()
-            ).hexdigest()[:16],
-            rounds=len(plan.tl_rounds),
-            wire_rounds=plan.wire_rounds,
-            tiers=[rd.tier for rd in plan.tl_rounds],
-            slow_edges_flat=plan.decision["slow_edges_flat"],
-            node_pairs=plan.decision["node_pair_edges"],
-            use=plan.decision["use"],
-        )
-        return True
-
-    assert pa.prun(driver, pa.sequential, pshape)
-    return out
-
-
-def run_twolevel(ns=(8, 8), pshape=(2, 4),
-                 nmap="0,0,0,0,1,1,1,1", procs=2):
-    """K >= 2 REAL OS processes each build and verify the identical
-    two-level plan; returns ``(results, agree)`` where ``agree`` is
-    cross-process digest equality (see module docstring — the
-    replicated-planning invariant a multi-host job depends on)."""
-    assert procs >= 2, "the cross-process leg needs >= 2 processes"
-    args = (tuple(ns), tuple(pshape), nmap)
-    # spawn, not fork — same rationale as `run`
-    with mp.get_context("spawn").Pool(procs) as pool:
-        results = pool.map(plan_twolevel, [args] * procs)
-    digests = {r["digest"] for r in results}
-    assert len({os.getpid()} | {r["pid"] for r in results}) == (
-        procs + 1
-    ), "workers did not run in distinct OS processes"
-    return results, len(digests) == 1
-
-
 def main():
-    if "--twolevel" in sys.argv[1:]:
-        procs = int(os.environ.get("PA_MP_PROCS", "2"))
-        results, agree = run_twolevel(procs=procs)
-        assert agree, "cross-process two-level plan digests diverged"
-        print(
-            json.dumps(
-                {
-                    "metric": "twolevel_plan_cross_process_agreement",
-                    "procs": procs,
-                    "digest": results[0]["digest"],
-                    "rounds": results[0]["rounds"],
-                    "wire_rounds": results[0]["wire_rounds"],
-                    "tiers": results[0]["tiers"],
-                    "slow_edges_flat": results[0]["slow_edges_flat"],
-                    "node_pairs": results[0]["node_pairs"],
-                    "agree": agree,
-                }
-            )
-        )
-        return
     n = int(os.environ.get("PA_MP_N", "192"))
     procs = int(os.environ.get("PA_MP_PROCS", "2"))
     px = int(os.environ.get("PA_MP_PARTS", "8"))
