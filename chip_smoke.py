@@ -145,7 +145,7 @@ def device_state(pa, A, seed: int) -> dict:
     tools/scale_check.py check), where the shards of the product live,
     what dtype they hold, and what each device has allocated."""
     from partitionedarrays_jl_tpu.parallel.tpu import (
-        DeviceVector, device_matrix, make_spmv_fn,
+        DeviceVector, _lift_on_device, device_matrix, make_spmv_fn,
     )
 
     backend = A.values.backend
@@ -153,6 +153,19 @@ def device_state(pa, A, seed: int) -> dict:
     v = seeded_vector(pa, A.cols, seed, A.dtype)
     host = pa.gather_pvector(A @ v)
     dv = DeviceVector.from_pvector(v, backend, dA.col_layout)
+    # what `tpu._as_callers_array` rests on (jax 0.9.0, no documented
+    # contract): a part fetched from the chip is an array of its own, so an
+    # answer changes hands without a copy
+    fetched = [
+        np.asarray(part)
+        for part in _lift_on_device(dv.data, dA.col_layout, backend)
+    ]
+    fetched_owned = all(f.flags.owndata and f.base is None for f in fetched)
+    require(
+        fetched_owned or not on_tpu(A),
+        "a part fetched from the chip does not own its data: every answer "
+        "is copied once more (tpu._as_callers_array)",
+    )
     y = make_spmv_fn(dA)(dv.data)
     got = pa.gather_pvector(
         DeviceVector(y, A.rows, dA.row_layout, backend).to_pvector()
@@ -182,6 +195,7 @@ def device_state(pa, A, seed: int) -> dict:
         "device_dtype": str(y.dtype),
         "shard_devices": shard_ids,
         "bytes_in_use": in_use,
+        "fetched_part_owns_its_data": fetched_owned,
     }
 
 
@@ -218,11 +232,22 @@ def on_tpu(A) -> bool:
 def solve_twice(solve):
     """First call (stages, traces, compiles) and second call (warm)."""
     (x, info), first = timed(solve)
-    (_x2, info2), second = timed(solve)
+    (x2, info2), second = timed(solve)
     require(
         info2["iterations"] == info["iterations"],
         "the warm solve took a different number of iterations",
     )
+    for a, a2 in zip(x.values.part_values(), x2.values.part_values()):
+        a, a2 = np.asarray(a), np.asarray(a2)
+        require(
+            a.flags.writeable and a.flags.owndata and a.base is None
+            and a2.flags.writeable and a2.flags.owndata and a2.base is None,
+            "a part of an answer is not a writable array of its own",
+        )
+        require(
+            not np.shares_memory(a, a2),
+            "the answers of two solves share memory",
+        )
     return x, info, {"first_call_s": first, "second_call_s": second}
 
 

@@ -110,7 +110,9 @@ class PRange:
         # everything derived from the ghost set dies with the exchanger:
         # a stale device layout / box-structure map would silently route
         # newly added ghosts nowhere
-        for attr in ("_device_layout", "_device_plan", "_box_info"):
+        for attr in (
+            "_device_layout", "_device_plan", "_box_info", "_owned_first",
+        ):
             if hasattr(self, attr):
                 delattr(self, attr)
 

@@ -75,6 +75,11 @@ SCOPE_BSR_GATHER, SCOPE_BSR_EINSUM = "bsr.gather", "bsr.einsum"
 #: Inside `SCOPE_SPMV` as well: the boundary (A_oh) rows in their face-slab
 #: form (`DeviceMatrix._detect_oh_slabs`), which `oh_rows_us` reads.
 SCOPE_OH = "oh"
+#: Outside the solve's program: the small per-part programs that write a
+#: part's values into its ``(1, W)`` frame and take them out again
+#: (`_vector_program`).
+SCOPE_PACK = "pa.stage_pack"
+SCOPE_LIFT = "pa.fetch_lift"
 
 
 def _scoped(scope: str, fn: Callable) -> Callable:
@@ -113,6 +118,7 @@ class TPUBackend(AbstractBackend):
         self._devices = devices
         self._meshes = {}
         self._mesh_grid = {}  # nparts -> part-grid shape the mesh was ordered for
+        self._part_devices = {}  # nparts -> the device of each part's row
         # stable cache identity: id(backend) can be recycled after GC,
         # which would hand back device buffers staged for a dead backend
         self._token = next(_backend_tokens)
@@ -197,6 +203,19 @@ class TPUBackend(AbstractBackend):
     def sharding(self, nparts: int):
         jax = _jax()
         return jax.sharding.NamedSharding(self.mesh(nparts), self.parts_spec())
+
+    def part_devices(self, nparts: int) -> list:
+        """The device that holds part p's row of a ``(P, ...)`` array under
+        `sharding`, for every p, read from the sharding's own index map:
+        the mesh may order the devices otherwise than `devices()` does
+        (`_topology_order`)."""
+        if nparts not in self._part_devices:
+            devs = [None] * nparts
+            index_map = self.sharding(nparts).devices_indices_map((nparts, 1))
+            for d, idx in index_map.items():
+                devs[idx[0].start or 0] = d
+            self._part_devices[nparts] = devs
+        return self._part_devices[nparts]
 
     def get_part_ids(self, nparts: PartShape) -> "TPUData":
         shape = _as_shape(nparts)
@@ -328,7 +347,8 @@ class DeviceLayout:
 
     __slots__ = (
         "P", "W", "no_max", "nh_max", "noids", "nhids", "lid_slots",
-        "hid_slots", "o0", "g0", "padded", "box_info",
+        "hid_slots", "o0", "g0", "padded", "box_info", "programs",
+        "dev_hid_slots", "part_shapes",
     )
 
     def __init__(self, rows: PRange, padded: bool = False, box_info=None):
@@ -340,6 +360,10 @@ class DeviceLayout:
         self.nh_max = int(self.nhids.max()) if self.P else 0
         self.padded = bool(padded)
         self.box_info = box_info
+        # the jitted pack and lift of the parts' values (`_vector_program`),
+        # and their ghost slots on the devices (`_ghost_operands`)
+        self.programs = {}
+        self.dev_hid_slots = {}
         # the box layout reorders the ghost region into per-direction
         # segments (slot maps only — see tpu_box.py); the segment frame
         # can be wider than nh_max (missing-neighbor segments stay zero)
@@ -381,6 +405,12 @@ class DeviceLayout:
             hs = np.empty(int(self.nhids[p]), dtype=INDEX_DTYPE)
             hs[-ohid[h] - 1] = slots[h]
             self.hid_slots.append(hs)
+        # per part: owned entries, ghosts, and where the ghost slots are
+        # one run (`_ghost_run`), else None
+        self.part_shapes = [
+            (int(no), int(nh), _ghost_run(hs))
+            for no, nh, hs in zip(self.noids, self.nhids, self.hid_slots)
+        ]
 
     @property
     def trash(self) -> int:
@@ -542,6 +572,349 @@ def _shard_exchange(plan, combine: str, abft: bool = False):
     return _scoped(SCOPE_HALO, body_abft)
 
 
+def _count_vector(kind: str, on_device: bool) -> None:
+    """One vector packed (``kind='packs'``) or lifted (``'lifts'``):
+    ``solve.device_<kind>`` or ``solve.host_<kind>`` grows by one, and
+    the other is there to be read as unchanged."""
+    from .. import telemetry
+
+    telemetry.bump(f"solve.device_{kind}", int(on_device))
+    telemetry.bump(f"solve.host_{kind}", int(not on_device))
+
+
+def _parts_on_device_path(rows: PRange) -> bool:
+    """Whether a vector over ``rows`` is packed and lifted on the device
+    (`_pack_on_device`, `_lift_on_device`): every part numbers its owned
+    lids first, so a part's values ARE ``[owned | ghosts in hid order]``
+    as the caller holds them, and one process addresses every shard. Any
+    other vector keeps the host frame (`_pack_on_host`,
+    `_host_frame_to_pvector`)."""
+    owned_first = getattr(rows, "_owned_first", None)
+    if owned_first is None:
+        # kept on the rows (as `_device_layout` is, and dropped with it):
+        # the test walks every owned lid of every part
+        owned_first = rows._owned_first = all(
+            i.owned_first for i in rows.partition.part_values()
+        )
+    return owned_first and _jax().process_count() == 1
+
+
+def _ghost_run(slots: np.ndarray) -> Optional[int]:
+    """The first of ``slots`` where they are one run of consecutive
+    slots (the ghost region of a layout without box segments), so that
+    they are read and written as a slice; else None."""
+    if slots.size and np.array_equal(
+        slots, np.arange(slots[0], slots[0] + slots.size, dtype=slots.dtype)
+    ):
+        return int(slots[0])
+    return None
+
+
+def _vector_program(layout: DeviceLayout, kind: str, no: int, nh: int,
+                    dtype, run: Optional[int], mesh=None):
+    """The jitted pack (``kind='pack'``: a part's values -> its ``(1, W)``
+    frame) or lift (``'lift'``: the frame -> the part's values) of a part
+    with ``no`` owned and ``nh`` ghost entries, built once per layout and
+    shape. The owned run is a static slice at ``o0``; the ghosts are a
+    static slice at ``run`` where their slots are consecutive, else they
+    go through the operands `_ghost_operands` keeps on the device (box
+    layouts: the ghost region is direction segments): the lift gathers
+    them at their slots; the pack permutes them into slot order and
+    scatters them sorted and unique, the one form of a scatter XLA does
+    not put a sort in front of (at 72,580 slots the sort alone took 15 s
+    to compile for a v5e, a device, against 0.4 s). ``nh=0`` moves owned
+    entries only (b, and every vector of a single part).
+
+    With ``mesh`` the program is the one of all parts, which then share
+    this shape: it runs over the mesh (`shard_map`), on the parts' values
+    and ghost operands laid end to end in 1-D arrays sharded over the
+    parts, and compiles once where a program of a part compiles once a
+    device."""
+    key = (kind, no, nh, np.dtype(dtype).str, run, mesh)
+    if key in layout.programs:
+        return layout.programs[key]
+    jax = _jax()
+    import jax.numpy as jnp
+
+    W, o0 = layout.W, layout.o0
+
+    def pack(vals, order=None, sorted_slots=None):
+        vals = vals.astype(dtype)
+        frame = jax.lax.dynamic_update_slice(
+            jnp.zeros((W,), dtype), vals[:no], (o0,)
+        )
+        ghosts = vals[no : no + nh]
+        if nh and run is not None:
+            frame = jax.lax.dynamic_update_slice(frame, ghosts, (run,))
+        elif nh:
+            frame = frame.at[sorted_slots].set(
+                ghosts[order], indices_are_sorted=True, unique_indices=True
+            )
+        return frame[None, :]
+
+    def lift(frame, slots=None):
+        owned = frame[0, o0 : o0 + no]
+        if not nh:
+            return owned
+        at = slice(run, run + nh) if run is not None else slots
+        return jnp.concatenate([owned, frame[0, at]])
+
+    fn, scope = (pack, SCOPE_PACK) if kind == "pack" else (lift, SCOPE_LIFT)
+    of_a_part = _scoped(scope, fn)
+    if mesh is None:
+        layout.programs[key] = jax.jit(of_a_part)
+        return layout.programs[key]
+    spec = jax.sharding.PartitionSpec("parts")
+
+    def of_all_parts(*operands):
+        return jax.shard_map(
+            of_a_part, mesh=mesh, in_specs=(spec,) * len(operands),
+            out_specs=spec, check_vma=False,
+        )(*operands)
+
+    # the sharding named: of one part JAX would else call it replicated,
+    # another sharding to the compiled solve than a staged frame's
+    layout.programs[key] = jax.jit(
+        of_all_parts, out_shardings=jax.sharding.NamedSharding(mesh, spec)
+    )
+    return layout.programs[key]
+
+
+def _ghost_operands(layout: DeviceLayout, kind: str, parts: tuple, where):
+    """The operands a `_vector_program` of ``kind`` takes for the ghosts
+    of ``parts`` (one part, or all of them end to end), put on ``where``
+    (the part's device, or the parts' sharding) once per layout and kept
+    there: the slots in hid order for a lift, the permutation into slot
+    order and the sorted slots for a pack. None where the ghosts are a
+    run and read as a slice."""
+    if layout.part_shapes[parts[0]][2] is not None:
+        return ()
+    key = (parts, where)
+    if key not in layout.dev_hid_slots:
+        slots = [layout.hid_slots[p] for p in parts]
+        order = [
+            np.argsort(hs, kind="stable").astype(INDEX_DTYPE) for hs in slots
+        ]
+        sorted_slots = [hs[o] for hs, o in zip(slots, order)]
+        check(
+            all(bool(np.all(np.diff(ss) > 0)) for ss in sorted_slots),
+            "device layout: two ghosts of a part share a slot",
+        )
+        slots, order, sorted_slots = _jax().device_put(
+            [np.concatenate(a) for a in (slots, order, sorted_slots)], where
+        )
+        layout.dev_hid_slots[key] = {
+            "lift": (slots,), "pack": (order, sorted_slots),
+        }
+    return layout.dev_hid_slots[key][kind]
+
+
+def _pack_on_device(v: PVector, layout: DeviceLayout, backend: TPUBackend,
+                    with_ghosts: bool):
+    """The ``(P, W)`` frame of ``v`` made on the devices: each part's
+    values go to the part's device as the caller holds them (a view, no
+    host copy), a `_vector_program` there writes them into the part's
+    ``(1, W)`` row (one program over the mesh where the parts share a
+    shape, else one a part and the rows assembled), under
+    `backend.sharding`, the sharding the compiled solve takes. Nothing is
+    waited for: the transfers read the caller's arrays after this
+    returns, so they must stay as they are until the frame (or what was
+    computed from it) is ready. `_run_krylov` waits for its solve;
+    `DeviceVector.from_pvector` waits for the frame."""
+    from .. import telemetry
+
+    jax = _jax()
+    P = layout.P
+    devices, sharding = backend.part_devices(P), backend.sharding(P)
+    dtype = _device_dtype(v.dtype)
+    _note_narrowing(jax, v.dtype)
+    isets = v.rows.partition.part_values()
+    shapes = layout.part_shapes
+    for iset, (no, nh, _run) in zip(isets, shapes):
+        # the slots are the layout's: a vector of other index sets would
+        # be written to wrong slots, and silently (a gather clamps)
+        check(
+            iset.num_oids == no and (not with_ghosts or iset.num_hids == nh),
+            "device pack: the vector's parts are not the layout's",
+        )
+    if not with_ghosts:
+        shapes = [(no, 0, None) for no, _nh, _run in shapes]
+    with telemetry.annotate("pa:stage:put"):
+        vals = jax.device_put(
+            [
+                np.asarray(vals)[: no + nh]
+                for vals, (no, nh, _run) in zip(v.values.part_values(), shapes)
+            ],
+            devices,
+        )
+    with telemetry.annotate("pa:stage:pack"):
+        if len(set(shapes)) == 1:
+            no, nh, run = shapes[0]
+            pack = _vector_program(
+                layout, "pack", no, nh, dtype, run, backend.mesh(P)
+            )
+            data = pack(
+                jax.make_array_from_single_device_arrays(
+                    (P * (no + nh),), sharding, vals
+                ),
+                *(_ghost_operands(layout, "pack", tuple(range(P)), sharding)
+                  if nh else ()),
+            )
+        else:
+            rows = [
+                _vector_program(layout, "pack", no, nh, dtype, run)(
+                    vals[p],
+                    *(_ghost_operands(layout, "pack", (p,), devices[p])
+                      if nh else ()),
+                )
+                for p, (no, nh, run) in enumerate(shapes)
+            ]
+            data = jax.make_array_from_single_device_arrays(
+                (P, layout.W), sharding, rows
+            )
+    _count_vector("packs", on_device=True)
+    return data
+
+
+def _pack_on_host(v: PVector, layout: DeviceLayout, backend: TPUBackend,
+                  with_ghosts: bool):
+    """The ``(P, W)`` frame of ``v`` filled on the host and staged whole:
+    the path of parts that do not number their owned lids first, and of
+    a run of several processes (`_stage` hands each its own rows)."""
+    from .. import telemetry
+
+    o0 = layout.o0
+    with telemetry.annotate("pa:stage:pack"):
+        stacked = np.zeros((layout.P, layout.W), dtype=v.dtype)
+        for p, (iset, vals) in enumerate(
+            zip(v.rows.partition.part_values(), v.values.part_values())
+        ):
+            vals = np.asarray(vals)
+            stacked[p, o0 : o0 + iset.num_oids] = _owned(iset, vals)
+            if with_ghosts:
+                # hid_slots, not g0+hid: the box layout reorders the ghost
+                # region into direction segments
+                stacked[p, layout.hid_slots[p]] = _ghost(iset, vals)
+    _count_vector("packs", on_device=False)
+    return _stage(backend, stacked, layout.P)
+
+
+def _pack(v: PVector, layout: DeviceLayout, backend: TPUBackend,
+          with_ghosts: bool = True):
+    """``v`` as a ``(P, W)`` device frame of ``layout``: owned entries at
+    ``o0``, ghosts (where ``with_ghosts``) at the layout's ghost slots,
+    zero elsewhere. On the device where `_parts_on_device_path`."""
+    pack = _pack_on_device if _parts_on_device_path(v.rows) else _pack_on_host
+    return pack(v, layout, backend, with_ghosts)
+
+
+def _zero_frame(layout: DeviceLayout, backend: TPUBackend, dtype):
+    """The frame of an all-zero vector, made on the devices."""
+    from .. import telemetry
+    import jax.numpy as jnp
+
+    with telemetry.annotate("pa:stage:pack"):
+        data = jnp.zeros(
+            (layout.P, layout.W), _device_dtype(dtype),
+            device=backend.sharding(layout.P),
+        )
+    _count_vector("packs", on_device=True)
+    return data
+
+
+def _lifts_on_device(data, rows: PRange, layout: DeviceLayout) -> bool:
+    """Whether the frame ``data`` is lifted on the device: its vector is
+    on the device path (`_parts_on_device_path`) and ``data`` is one
+    ``(1, W)`` row a part."""
+    shards = getattr(data, "addressable_shards", ())
+    return (
+        _parts_on_device_path(rows)
+        and len(shards) == layout.P
+        and all(s.data.shape == (1, layout.W) for s in shards)
+    )
+
+
+def _lift_on_device(data, layout: DeviceLayout, backend: TPUBackend) -> list:
+    """Each part's values ``[owned | ghosts in hid order]`` taken out of
+    its row of the frame ``data`` by a `_vector_program` on the row's
+    device (one over the mesh where the parts share a shape): P device
+    arrays in part order, dispatched and not waited for."""
+    P = layout.P
+    shapes = layout.part_shapes
+    if len(set(shapes)) == 1:
+        no, nh, run = shapes[0]
+        lift = _vector_program(
+            layout, "lift", no, nh, data.dtype, run, backend.mesh(P)
+        )
+        lifted = lift(
+            data,
+            *(_ghost_operands(
+                layout, "lift", tuple(range(P)), backend.sharding(P)
+            ) if nh else ()),
+        )
+        return [
+            s.data for s in sorted(
+                lifted.addressable_shards, key=lambda s: s.index[0].start or 0
+            )
+        ]
+    lifted = [None] * P
+    for s in data.addressable_shards:
+        p = s.index[0].start or 0
+        no, nh, run = shapes[p]
+        lift = _vector_program(layout, "lift", no, nh, data.dtype, run)
+        lifted[p] = lift(
+            s.data,
+            *(_ghost_operands(layout, "lift", (p,), s.device) if nh else ()),
+        )
+    return lifted
+
+
+def _answer_to_host(out, rows: PRange, layout: DeviceLayout,
+                    backend: TPUBackend):
+    """A compiled solve's outputs on the host: the answer frame ``out[0]``
+    as a PVector over ``rows``, and the rest as arrays. Where
+    `_lifts_on_device`, the parts' values only are fetched, every
+    transfer started before the first is read, and each fetched array
+    becomes the caller's (`_as_callers_array`). Else the whole frame is
+    fetched and lifted on the host (`_outputs_to_host`,
+    `_host_frame_to_pvector`)."""
+    from .. import telemetry
+
+    on_device = _lifts_on_device(out[0], rows, layout)
+    _count_vector("lifts", on_device)
+    if not on_device:
+        host = _outputs_to_host(out)
+        return _host_frame_to_pvector(host[0], rows, layout), host[1:]
+    with telemetry.annotate("pa:fetch:d2h"):
+        arrays = _lift_on_device(out[0], layout, backend) + list(out[1:])
+        for a in arrays:
+            a.copy_to_host_async()
+        host = [np.asarray(a) for a in arrays]
+        # JAX keeps a fetched array on the `jax.Array` it came from: the
+        # lifted parts go here, before their arrays change hands
+        del arrays, a
+    telemetry.bump("solve.fetched_bytes", sum(h.nbytes for h in host))
+    with telemetry.annotate("pa:fetch:lift"):
+        vals = [_as_callers_array(h) for h in host[: layout.P]]
+        return PVector(rows.partition._like(vals), rows), host[layout.P :]
+
+
+def _as_callers_array(fetched: np.ndarray) -> np.ndarray:
+    """A fetched part as the array the caller gets: writable, owning its
+    data, shared with nobody. Where the fetch made a fresh NumPy array of
+    its own (the TPU runtime's transfer: ``owndata``), that array itself,
+    made writable: JAX marks it read-only only to guard the copy it keeps
+    on the `jax.Array`, and `_answer_to_host` has dropped that one (the
+    lift's own temporary). This is what jax 0.9.0 does and no documented
+    contract: `chip_smoke.py` holds a real fetched answer to it on the
+    chip. Else (the CPU backend hands out a view of the device buffer)
+    one copy."""
+    if fetched.flags.owndata:
+        fetched.flags.writeable = True
+        return fetched
+    return np.array(fetched)
+
+
 class DeviceVector:
     """A PVector lowered to one (P, W) array sharded over the mesh."""
 
@@ -555,34 +928,22 @@ class DeviceVector:
 
     @classmethod
     def from_pvector(cls, v: PVector, backend: TPUBackend, layout=None) -> "DeviceVector":
-        from .. import telemetry
-
         layout = layout or device_layout(v.rows, _padded_for(backend))
-        o0, g0 = layout.o0, layout.g0
-        with telemetry.annotate("pa:stage:pack"):
-            stacked = np.zeros((layout.P, layout.W), dtype=v.dtype)
-            for p, (iset, vals) in enumerate(
-                zip(v.rows.partition.part_values(), v.values.part_values())
-            ):
-                vals = np.asarray(vals)
-                stacked[p, o0 : o0 + iset.num_oids] = _owned(iset, vals)
-                # hid_slots, not g0+hid: the box layout reorders the ghost
-                # region into direction segments
-                stacked[p, layout.hid_slots[p]] = _ghost(iset, vals)
-        data = _stage(backend, stacked, layout.P)
+        # waited for: a put reads the caller's arrays after it returns,
+        # and who stages a vector by hand may change it next
+        data = _jax().block_until_ready(_pack(v, layout, backend))
         return cls(data, v.rows, layout, backend)
 
     def to_pvector(self) -> PVector:
-        from .multihost import fetch_global
-
-        host = fetch_global(self.data)
-        return _host_frame_to_pvector(host, self.rows, self.layout)
+        return _answer_to_host(
+            [self.data], self.rows, self.layout, self.backend
+        )[0]
 
 
 def _host_frame_to_pvector(host: np.ndarray, rows: PRange, layout) -> PVector:
-    """A fetched (P, W) host frame lifted back to a PVector (shared by
-    DeviceVector.to_pvector and the multi-RHS block unstaging, which
-    fetches one (P, W, K) slab and lifts each column)."""
+    """A fetched (P, W) host frame lifted back to a PVector: the host
+    path of `_answer_to_host`, and the multi-RHS block unstaging, which
+    fetches one (P, W, K) slab and lifts each column."""
     from .. import telemetry
 
     o0 = layout.o0
@@ -5100,10 +5461,12 @@ def tpu_chebyshev(
     if key not in dA._cg_cache:
         dA._cg_cache[key] = make_chebyshev_fn(dA, lmin, lmax, tol, maxiter)
     solve = dA._cg_cache[key]
-    x0 = x0 if x0 is not None else PVector.full(0.0, A.cols, dtype=b.dtype)
-    db = _b_on_cols_layout(b, dA)
-    dx0 = DeviceVector.from_pvector(x0, backend, dA.col_layout)
-    x_data, rs, rs0, it, hist = solve(db.data, dx0.data)
+    layout = dA.col_layout
+    x_data, rs, rs0, it, hist = solve(
+        _pack(b, layout, backend, with_ghosts=False),
+        _pack(x0, layout, backend) if x0 is not None
+        else _zero_frame(layout, backend, b.dtype),
+    )
     x = DeviceVector(x_data, A.cols, dA.col_layout, backend).to_pvector()
     rs, rs0, it = float(rs), float(rs0), int(it)
     # hist is per 16-iteration leg (reductions happen once per leg);
@@ -5266,34 +5629,26 @@ def _run_krylov(A, b, x0, tol, verbose, solve, minv=None, name="cg",
     with telemetry.annotate(f"pa:{name}:stage"):
         with telemetry.annotate("pa:stage:operator"):
             dA = device_matrix(A, backend)
-        x0 = x0 if x0 is not None else PVector.full(
-            0.0, A.cols, dtype=b.dtype
-        )
-        db = _b_on_cols_layout(b, dA)
-        dx0 = DeviceVector.from_pvector(x0, backend, dA.col_layout)
-        dmv = (
-            DeviceVector.from_pvector(minv, backend, dA.col_layout)
-            if minv is not None
-            else None
-        )
-        _count_staged(
-            db.data, dx0.data, None if dmv is None else dmv.data
-        )
+        layout = dA.col_layout
+        frames = [
+            _pack(b, layout, backend, with_ghosts=False),
+            _pack(x0, layout, backend) if x0 is not None
+            else _zero_frame(layout, backend, b.dtype),
+        ]
+        if minv is not None:
+            frames.append(_pack(minv, layout, backend))
+        _count_staged(*frames)
     with telemetry.annotate(f"pa:{name}:solve"):
-        if dmv is not None:
-            out = solve(db.data, dx0.data, dmv.data)
-        else:
-            out = solve(db.data, dx0.data)
+        out = solve(*frames)
     with telemetry.annotate(f"pa:{name}:wait"):
         # the host waits here while the device works, so that the fetch
         # below times the copy and not the solve
         out = _jax().block_until_ready(list(out))
     with telemetry.annotate(f"pa:{name}:fetch"):
-        out = _outputs_to_host(out)
-        x = _host_frame_to_pvector(out[0], A.cols, dA.col_layout)
+        x, out = _answer_to_host(out, A.cols, layout, backend)
     with telemetry.annotate(f"pa:{name}:finish"):
-        rs, rs0, it, hist = out[1:5]
-        k = 5
+        rs, rs0, it, hist = out[:4]
+        k = 4
         sdcvec = None
         if getattr(solve, "has_sdc", False):
             sdcvec = out[k]
@@ -5804,19 +6159,11 @@ def _krylov_fn_for(
 
 def _b_on_cols_layout(b: PVector, dA: DeviceMatrix) -> DeviceVector:
     """b lives on A.rows (no ghosts); the compiled CG keeps every vector in
-    the cols layout (same owned gids). Restack b's owned values there."""
-    from .. import telemetry
-
+    the cols layout (same owned gids). Its owned values go there."""
     layout = dA.col_layout
-    with telemetry.annotate("pa:stage:pack"):
-        stacked = np.zeros((layout.P, layout.W), dtype=b.dtype)
-        for p, (iset, vals) in enumerate(
-            zip(b.rows.partition.part_values(), b.values.part_values())
-        ):
-            stacked[p, layout.o0 : layout.o0 + iset.num_oids] = _owned(
-                iset, np.asarray(vals)
-            )
-    data = _stage(dA.backend, stacked, layout.P)
+    data = _jax().block_until_ready(
+        _pack(b, layout, dA.backend, with_ghosts=False)
+    )
     return DeviceVector(data, dA.cols, layout, dA.backend)
 
 

@@ -147,8 +147,25 @@ CATALOG: Dict[str, MetricSpec] = {
               "bytes of the device frames the solves' vectors were "
               "staged into"),
         _spec("solve.fetched_bytes", "counter", "bytes",
-              "parallel/tpu.py:_outputs_to_host",
-              "bytes of the solves' outputs copied back to the host"),
+              "parallel/tpu.py:_answer_to_host, _outputs_to_host",
+              "bytes of the solves' outputs that crossed to the host: "
+              "the parts' values and the scalars, or a whole frame "
+              "where the answer is lifted on the host"),
+        _spec("solve.device_packs", "counter", "1",
+              "parallel/tpu.py:_count_vector",
+              "vectors whose device frame a program on the parts' "
+              "devices made from the parts' values"),
+        _spec("solve.host_packs", "counter", "1",
+              "parallel/tpu.py:_count_vector",
+              "vectors whose frame was filled on the host and staged "
+              "whole (lids not owned-first, or several processes)"),
+        _spec("solve.device_lifts", "counter", "1",
+              "parallel/tpu.py:_count_vector",
+              "vectors whose parts' values a program on the parts' "
+              "devices took out of the frame before the fetch"),
+        _spec("solve.host_lifts", "counter", "1",
+              "parallel/tpu.py:_count_vector",
+              "vectors fetched as a whole frame and lifted on the host"),
         # -- the supernode-dense lowering, where an operator is staged --
         _spec("lowering.sd.nnz", "counter", "1",
               "parallel/tpu.py:_count_sd_lowering",

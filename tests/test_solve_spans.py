@@ -88,10 +88,21 @@ def test_solve_counters_grow_by_one_call_and_its_frames(system, which):
     after = telemetry.counters("solve")
     grew = {k: after[k] - before.get(k, 0) for k in after}
     assert grew["solve.calls"] == 1
-    assert grew["solve.staged_bytes"] == 2 * frame  # b and x0
-    assert grew["solve.fetched_bytes"] >= frame  # the answer, and scalars
+    # the device frames of b and x0, however they were made
+    assert grew["solve.staged_bytes"] == 2 * frame
+    # what crossed to the host: the parts' values and the scalars (the
+    # residual history among them), no longer a whole frame
+    parts = 4 * sum(
+        i.num_lids for i in system["A"].cols.partition.part_values()
+    )
+    assert grew["solve.fetched_bytes"] >= parts
+    # the path each vector took: packed and lifted on the devices
+    assert grew["solve.device_packs"] == 2 and grew["solve.host_packs"] == 0
+    assert grew["solve.device_lifts"] == 1 and grew["solve.host_lifts"] == 0
     assert set(grew) == {
         "solve.calls", "solve.staged_bytes", "solve.fetched_bytes",
+        "solve.device_packs", "solve.host_packs",
+        "solve.device_lifts", "solve.host_lifts",
     }
 
 
