@@ -72,9 +72,13 @@ SCOPE_AXPY = "pa.axpy_sweep"
 #: `SCOPE_SPMV` (benchmark/layer_metrics/_scoped.py `phase_of`).
 SCOPE_SD_GATHER, SCOPE_SD_EINSUM = "sd.gather", "sd.einsum"
 SCOPE_BSR_GATHER, SCOPE_BSR_EINSUM = "bsr.gather", "bsr.einsum"
-#: Inside `SCOPE_SPMV` as well: the boundary (A_oh) rows in their face-slab
-#: form (`DeviceMatrix._detect_oh_slabs`), which `oh_rows_us` reads.
+#: Inside `SCOPE_SPMV` as well: the boundary (A_oh) rows in whichever of
+#: their three forms (face slabs, node blocks, ELL), which `oh_rows_us` reads.
 SCOPE_OH = "oh"
+#: Inside `SCOPE_HALO`, in the generic (index-vector) exchange body: a
+#: round's gather of its send slots and its scatter into its receive
+#: slots, which `halo_index_share` reads; the permutes stay the phase's own.
+SCOPE_EX_PACK, SCOPE_EX_UNPACK = "ex.pack", "ex.unpack"
 #: Outside the solve's program: the small per-part programs that write a
 #: part's values into its ``(1, W)`` frame and take them out again
 #: (`_vector_program`).
@@ -528,20 +532,35 @@ def _shard_exchange(plan, combine: str, abft: bool = False):
     g0 = plan.layout.g0
     L = plan.snd_idx.shape[-1]
 
+    def pack(xv, si, sm, r):
+        with jax.named_scope(SCOPE_EX_PACK):
+            mask = sm[r].reshape(sm[r].shape + (1,) * (xv.ndim - 1))
+            return jnp.where(mask, xv[si[r]], 0)
+
+    def unpack(xv, ri, r, buf):
+        with jax.named_scope(SCOPE_EX_UNPACK):
+            if combine == "add":
+                return xv.at[ri[r]].add(buf)
+            return xv.at[ri[r]].set(buf)
+
+    def tidy(xv):
+        """Once an exchange, behind its last round: the pads of every
+        round landed in the trash slot, which is kept clean so padding
+        invariants hold (no round reads it: send pads are masked)."""
+        with jax.named_scope(SCOPE_EX_UNPACK):
+            if combine == "add":
+                # ghost contributions now live on owners; the trash slot
+                # lies behind the ghosts
+                return xv.at[g0:].set(0)
+            return xv.at[plan.layout.trash].set(0) if R else xv
+
     def body(xv, si, sm, ri):
         for r in range(R):
-            mask = sm[r].reshape(sm[r].shape + (1,) * (xv.ndim - 1))
-            buf = jnp.where(mask, xv[si[r]], 0)
-            buf = jax.lax.ppermute(buf, "parts", perm=perms[r])
-            if combine == "add":
-                xv = xv.at[ri[r]].add(buf)
-            else:
-                xv = xv.at[ri[r]].set(buf)
-            # keep the trash slot clean so padding invariants hold
-            xv = xv.at[plan.layout.trash].set(0)
-        if combine == "add":
-            xv = xv.at[g0:].set(0)  # ghost contributions now live on owners
-        return xv
+            buf = jax.lax.ppermute(
+                pack(xv, si, sm, r), "parts", perm=perms[r]
+            )
+            xv = unpack(xv, ri, r, buf)
+        return tidy(xv)
 
     if not abft:
         return _scoped(SCOPE_HALO, body)
@@ -551,8 +570,7 @@ def _shard_exchange(plan, combine: str, abft: bool = False):
         delta = jnp.zeros(xv.shape[1:], dtype=xv.dtype)
         scale = jnp.zeros(xv.shape[1:], dtype=xv.dtype)
         for r in range(R):
-            mask = sm[r].reshape(sm[r].shape + (1,) * (xv.ndim - 1))
-            buf = jnp.where(mask, xv[si[r]], 0)
+            buf = pack(xv, si, sm, r)
             cs = jnp.sum(buf, axis=0, keepdims=True)
             payload = jax.lax.ppermute(
                 jnp.concatenate([buf, cs], axis=0), "parts", perm=perms[r]
@@ -560,14 +578,8 @@ def _shard_exchange(plan, combine: str, abft: bool = False):
             buf, rcs = payload[:L], payload[L]
             delta = delta + jnp.abs(jnp.sum(buf, axis=0) - rcs)
             scale = scale + jnp.sum(jnp.abs(buf), axis=0) + jnp.abs(rcs)
-            if combine == "add":
-                xv = xv.at[ri[r]].add(buf)
-            else:
-                xv = xv.at[ri[r]].set(buf)
-            xv = xv.at[plan.layout.trash].set(0)
-        if combine == "add":
-            xv = xv.at[g0:].set(0)
-        return xv, delta, scale
+            xv = unpack(xv, ri, r, buf)
+        return tidy(xv), delta, scale
 
     return _scoped(SCOPE_HALO, body_abft)
 
@@ -1380,6 +1392,7 @@ class DeviceMatrix:
         self.rows, self.cols = A.rows, A.cols
         self.row_layout, self.col_layout = row_layout, col_layout
         self.col_plan = device_exchange_plan(A.cols, self.padded)
+        _count_exchange_plan(self.col_plan)
         self.backend = backend
         L_oh = max((int(m.row_lengths().max()) if m.nnz else 0 for m in oh), default=0)
         L_oh = max(L_oh, 1)
@@ -1469,6 +1482,10 @@ class DeviceMatrix:
             )
             self.ohb_vals = tuple(
                 _stage(backend, c["vals"], P) for c in ohb["chunks"]
+            )
+            _count_oh_lowering(
+                self.oh_nnz,
+                block_entries=sum(c["vals"].size for c in ohb["chunks"]),
             )
         # a box layout keeps the ghosts of a direction in the sender's
         # scan order: where the boundary block is made of face slabs its
@@ -3218,6 +3235,40 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
             )
         return y
 
+    def _oh_rows(y, xv, m):
+        """The ghost (A_oh) contribution, added on the boundary rows only
+        (padded rows target the trash slot with exact-zero values), in
+        the form the operator was staged in; every form under `SCOPE_OH`."""
+        tail = xv.shape[1:]
+        if dA.ohb_bs is not None:
+            # node-block boundary path (directive 7): one gather per
+            # ghost NODE, block products as a batched einsum — same
+            # structure as the A_oo SD/BSR paths. BUCKETED like the
+            # owned SD groups: each contiguous chunk of boundary
+            # nodes is padded to its own block-row maximum, one
+            # einsum per bucket (round-4 directive 7 leftover).
+            bs_ = dA.ohb_bs
+            cl2 = dA.col_plan.layout
+            nhn = (cl2.W - cl2.g0 - 1) // bs_
+            gh = xv[cl2.g0 : cl2.g0 + nhn * bs_].reshape((-1, bs_) + tail)
+            for rows_c, cols_c, vals_c in zip(
+                m["ohb_r"], m["ohb_c"], m["ohb_v"]
+            ):
+                xb = gh[cols_c]
+                yb = jnp.einsum(
+                    "nlij,nljk->nik" if tail else "nlij,nlj->ni",
+                    vals_c, xb,
+                    preferred_element_type=xv.dtype,
+                    precision=jax.lax.Precision.HIGHEST,
+                )
+                y = y.at[rows_c].add(yb.reshape(rows_c.shape + tail))
+            return y
+        if dA.ohs_geo is not None:
+            return _oh_slabs(y, xv, m["ohs_v"])
+        return y.at[m["oh_r"]].add(_ell_rowsum(m["oh_v"], m["oh_c"], xv))
+
+    _oh_rows = _scoped(SCOPE_OH, _oh_rows)
+
     def _finish(full, partial_, xv, m):
         """Shared SpMV tail: halo-exchange the operand, embed the A_oo
         product in the row frame, add the boundary (A_oh) contribution.
@@ -3239,41 +3290,7 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
                 o0 : o0 + no_max
             ].set(partial_)
         if dA.oh_nnz:
-            # ghost contribution only on the boundary rows (padded rows
-            # target the trash slot with exact-zero values)
-            if dA.ohb_bs is not None:
-                # node-block boundary path (directive 7): one gather per
-                # ghost NODE, block products as a batched einsum — same
-                # structure as the A_oo SD/BSR paths. BUCKETED like the
-                # owned SD groups: each contiguous chunk of boundary
-                # nodes is padded to its own block-row maximum, one
-                # einsum per bucket (round-4 directive 7 leftover).
-                bs_ = dA.ohb_bs
-                cl2 = dA.col_plan.layout
-                nhn = (cl2.W - cl2.g0 - 1) // bs_
-                gh = xv[cl2.g0 : cl2.g0 + nhn * bs_].reshape(
-                    (-1, bs_) + tail
-                )
-                for rows_c, cols_c, vals_c in zip(
-                    m["ohb_r"], m["ohb_c"], m["ohb_v"]
-                ):
-                    xb = gh[cols_c]
-                    yb = jnp.einsum(
-                        "nlij,nljk->nik" if tail else "nlij,nlj->ni",
-                        vals_c, xb,
-                        preferred_element_type=xv.dtype,
-                        precision=jax.lax.Precision.HIGHEST,
-                    )
-                    y = y.at[rows_c].add(
-                        yb.reshape(rows_c.shape + tail)
-                    )
-            elif dA.ohs_geo is not None:
-                with jax.named_scope(SCOPE_OH):
-                    y = _oh_slabs(y, xv, m["ohs_v"])
-            else:
-                y = y.at[m["oh_r"]].add(
-                    _ell_rowsum(m["oh_v"], m["oh_c"], xv)
-                )
+            y = _oh_rows(y, xv, m)
             y = y.at[g0:].set(0)
         return y, xv, exd, exs
 
@@ -5561,12 +5578,38 @@ def _count_sd_lowering(sd: dict, nnz: int) -> None:
     )
 
 
-def _count_oh_lowering(nnz: int, slabs=None, ell_entries=None) -> None:
+def _count_exchange_plan(plan) -> None:
+    """The ``exchange.plan.*`` counters of one operator staged with the
+    generic plan: its rounds, its directed edges, the real slots they send
+    (all parts), the ``P x R x L`` slots its padded rounds gather, ship
+    and scatter, and its longest and shortest edge. ``slots /
+    padded_slots`` is the fill. A box plan, or a plan with no edge (one
+    part), counts nothing."""
+    if not isinstance(plan, DeviceExchangePlan) or not plan.R:
+        return
+    from .. import telemetry
+
+    sent = plan.snd_mask.sum(axis=-1)  # (P, R): what a part sends in a round
+    edges = [
+        int(sent[src, r])
+        for r, perm in enumerate(plan.perms) for src, _dst in perm
+    ]
+    telemetry.bump("exchange.plan.rounds", plan.R)
+    telemetry.bump("exchange.plan.edges", len(edges))
+    telemetry.bump("exchange.plan.slots", sum(edges))
+    telemetry.bump("exchange.plan.padded_slots", int(plan.snd_mask.size))
+    telemetry.bump("exchange.plan.max_edge", max(edges))
+    telemetry.bump("exchange.plan.min_edge", min(edges))
+
+
+def _count_oh_lowering(nnz: int, slabs=None, ell_entries=None,
+                       block_entries=None) -> None:
     """The ``lowering.oh.*`` counters of one operator's boundary block:
     its stored entries, and what they were staged as: the classes and
     dense entries of the face-slab form (``slabs`` as
-    `DeviceMatrix._detect_oh_slabs` returned them), or the padded
-    entries of the ELL form."""
+    `DeviceMatrix._detect_oh_slabs` returned them), the padded entries
+    of the node-block form's ``bs x bs`` blocks (all parts), or the
+    padded entries of the ELL form."""
     from .. import telemetry
 
     telemetry.bump("lowering.oh.nnz", int(nnz))
@@ -5576,6 +5619,8 @@ def _count_oh_lowering(nnz: int, slabs=None, ell_entries=None) -> None:
             "lowering.oh.slab_entries",
             sum(math.prod(s.shape) for s in slabs),
         )
+    elif block_entries is not None:
+        telemetry.bump("lowering.oh.block_entries", int(block_entries))
     else:
         telemetry.bump("lowering.oh.ell_entries", int(ell_entries))
 

@@ -188,8 +188,8 @@ CATALOG: Dict[str, MetricSpec] = {
         # -- the boundary (A_oh) block, where an operator is staged ---
         _spec("lowering.oh.nnz", "counter", "1",
               "parallel/tpu.py:_count_oh_lowering",
-              "stored entries of the boundary blocks staged in the "
-              "face-slab or the ELL form (ghost-coupled entries, all "
+              "stored entries of the staged boundary blocks, whichever "
+              "of the three forms they took (ghost-coupled entries, all "
               "parts)"),
         _spec("lowering.oh.slab_classes", "counter", "1",
               "parallel/tpu.py:_count_oh_lowering",
@@ -202,6 +202,31 @@ CATALOG: Dict[str, MetricSpec] = {
               "parallel/tpu.py:_count_oh_lowering",
               "padded entries of the boundary blocks kept in the ELL "
               "form (all parts): one gathered element each"),
+        _spec("lowering.oh.block_entries", "counter", "1",
+              "parallel/tpu.py:_count_oh_lowering",
+              "padded entries of the bs x bs blocks of the boundary "
+              "blocks staged in the node-block form (all parts): one "
+              "gathered ghost node a block"),
+        # -- the generic exchange plan, where an operator takes it -----
+        _spec("exchange.plan.rounds", "counter", "1",
+              "parallel/tpu.py:_count_exchange_plan",
+              "edge-coloured ppermute rounds of the generic plans"),
+        _spec("exchange.plan.edges", "counter", "1",
+              "parallel/tpu.py:_count_exchange_plan",
+              "directed neighbour edges of those plans"),
+        _spec("exchange.plan.slots", "counter", "1",
+              "parallel/tpu.py:_count_exchange_plan",
+              "real slots the edges send, all parts (the ghosts)"),
+        _spec("exchange.plan.padded_slots", "counter", "1",
+              "parallel/tpu.py:_count_exchange_plan",
+              "P x R x L: slots the padded rounds gather, ship and "
+              "scatter (slots over this is the fill)"),
+        _spec("exchange.plan.max_edge", "counter", "1",
+              "parallel/tpu.py:_count_exchange_plan",
+              "slots of the longest edge, to which every round is padded"),
+        _spec("exchange.plan.min_edge", "counter", "1",
+              "parallel/tpu.py:_count_exchange_plan",
+              "slots of the shortest edge"),
         # -- service lifecycle counters -------------------------------
         _spec("service.admitted", "counter", "1",
               "service/service.py:submit",

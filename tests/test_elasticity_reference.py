@@ -4,8 +4,11 @@ elasticity_tet.py`, which imports nothing of the program), the float32
 path, the faster node-pair assembly against the raw triplet path it
 replaced, Jacobi-`pa.pcg` through the SD lowering against the reference
 PCG, the comparison that decides `correct`, and the new scopes and
-counters. Small sizes, CPU devices.
+counters. Since PR 32 the cell's path on one part and on four (the
+partitioned deployment: an irregular ghost graph, the generic exchange
+plan, node-block boundary rows). Small sizes, CPU devices.
 """
+import functools
 import importlib
 import json
 import os
@@ -32,6 +35,9 @@ ref = importlib.import_module("benchmark.builders.elasticity_tet")
 MIX = json.load(open(os.path.join(ROOT, "benchmark", "traffic", "jacobi_pcg_closed.json")))
 CFG8 = json.load(
     open(os.path.join(ROOT, "benchmark", "tests", "configs", "elasticity_tet_8.json"))
+)
+CFG8_X4 = json.load(
+    open(os.path.join(ROOT, "benchmark", "tests", "configs", "elasticity_tet_8_x4.json"))
 )
 
 
@@ -207,14 +213,17 @@ def test_the_node_pair_assembly_is_the_triplet_assembly(parts):
 # -- (c), (d), (e): the cell's path at 8^3 nodes on CPU devices -------------------
 
 
-@pytest.fixture(scope="module")
-def system():
-    backend = pa.TPUBackend(devices=jax.devices()[:1])
+@functools.lru_cache(maxsize=None)
+def built(parts: int) -> dict:
+    """The rehearsal configuration on ``parts`` parts, one a CPU device:
+    built, its pool solved, its operator staged, the counters read."""
+    cfg = {1: CFG8, 4: CFG8_X4}[parts]
+    backend = pa.TPUBackend(devices=jax.devices()[:parts])
     telemetry.reset_counters()
-    out = {}
+    out = {"parts": parts}
 
-    def body(parts):
-        s = ref.build(pa, parts, CFG8, MIX)
+    def body(p):
+        s = ref.build(pa, p, cfg, MIX)
         pool = s.make_pool(2**31 + 77)
         out.update(
             system=s, pool=pool,
@@ -223,9 +232,21 @@ def system():
         )
         return True
 
-    assert pa.prun(body, backend, (1,))
+    assert pa.prun(body, backend, tuple(cfg["part_grid"]))
     out["counters"] = telemetry.counters("lowering.sd")
+    out["oh_counters"] = telemetry.counters("lowering.oh")
+    out["plan_counters"] = telemetry.counters("exchange.plan")
     return out
+
+
+@pytest.fixture(scope="module", params=[1, 4], ids=["1part", "4parts"])
+def system(request):
+    return built(request.param)
+
+
+@pytest.fixture(scope="module")
+def system_x4():
+    return built(4)
 
 
 def judged(s, req, x) -> float:
@@ -253,7 +274,11 @@ def test_pcg_through_the_sd_lowering_agrees_with_the_reference_pcg(system):
         assert abs(rinfo["iterations"] - info["iterations"]) <= 2
         xp = pa.gather_pvector(x)
         assert np.abs(xp - xr).max() <= 1e-5 * np.abs(xr).max()
-        assert judged(s, req, x) <= limit
+        got = judged(s, req, x)
+        assert got <= limit
+        # beside the witness's: both stop on the same recurrence
+        witness = judged(s, req, pa.scatter_pvector_values(xr, s.A.cols))
+        assert abs(got - witness) <= 0.1 * witness
     its = [info["iterations"] for _x, info in system["answers"]]
     assert max(its) - min(its) <= 2  # scaling a system leaves its Krylov work
 
@@ -289,13 +314,17 @@ def test_the_control_and_a_scaled_answer_fail_the_check(system):
     assert judged(s, req, scaled) > limit
 
 
-def test_the_program_text_holds_the_sd_scopes(system):
-    dA = system["dA"]
+def op_names(dA) -> set:
+    """The `op_name`s of the compiled preconditioned CG program of ``dA``."""
     fn = T.make_cg_fn(dA, 1e-5, 50, precond=True)
     L = dA.col_plan.layout
     z = np.zeros((L.P, L.W), dtype=np.float32)
     text = fn.jit_fn.lower(z, z, z, T._matrix_operands(dA)).compile().as_text()
-    names = set(re.findall(r'op_name="([^"]+)"', text))
+    return set(re.findall(r'op_name="([^"]+)"', text))
+
+
+def test_the_program_text_holds_the_sd_scopes(system):
+    names = op_names(system["dA"])
     for sub in (T.SCOPE_SD_GATHER, T.SCOPE_SD_EINSUM):
         assert any(f"{T.SCOPE_SPMV}/{sub}" in n for n in names), sub
     # the Jacobi scaling inside the loop stays an update, not an SpMV part
@@ -308,9 +337,8 @@ def test_the_program_text_holds_the_sd_scopes(system):
 def test_the_lowering_counters_are_what_detect_sd_returned(system):
     s, dA = system["system"], system["dA"]
     oo = s.A.owned_owned_values.part_values()
-    sd = T.DeviceMatrix._detect_sd(
-        oo, 1, np.array([oo[0].shape[0]]), oo[0].shape[0], np.float32
-    )
+    noids = np.array([m.shape[0] for m in oo])
+    sd = T.DeviceMatrix._detect_sd(oo, len(oo), noids, int(noids.max()), np.float32)
     vals = [c["vals"] for c in sd["chunks"]]
     assert system["counters"] == {
         "lowering.sd.nnz": sum(m.nnz for m in oo),
@@ -322,4 +350,79 @@ def test_the_lowering_counters_are_what_detect_sd_returned(system):
     assert [tuple(v.shape[1:]) for v in dA.sd_vals] == [
         tuple(v.shape[1:]) for v in vals
     ]
-    assert system["counters"]["lowering.sd.nnz"] == CFG8["nnz"]
+    oh_nnz = sum(m.nnz for m in s.A.owned_ghost_values.part_values())
+    assert (oh_nnz > 0) == (system["parts"] > 1)
+    assert system["counters"]["lowering.sd.nnz"] + oh_nnz == CFG8["nnz"]
+
+
+# -- PR 32: what four parts add (the ghost graph, the plan, the boundary rows) ------
+
+
+def test_four_parts_are_all_neighbours_over_edges_of_unequal_size(system_x4):
+    """Morton runs of a cube: every part touches every other, two across a
+    face and one across an edge, so the edges differ in size by a factor."""
+    A = system_x4["system"].A
+    for p, iset in enumerate(A.cols.partition.part_values()):
+        owners = np.asarray(iset.lid_to_part)[iset.num_oids :]
+        by_owner = np.bincount(owners, minlength=4)
+        assert by_owner[p] == 0 and (np.delete(by_owner, p) > 0).all()
+        assert by_owner.max() >= 4 * np.delete(by_owner, p).min()
+        assert iset.num_hids % 3 == 0  # a ghost node brings its three DOFs
+
+
+def test_the_plan_counters_are_what_the_plan_holds(system_x4):
+    plan = system_x4["dA"].col_plan
+    assert type(plan) is T.DeviceExchangePlan
+    isets = system_x4["system"].A.cols.partition.part_values()
+    ghosts = sum(i.num_hids for i in isets)
+    edges = [e for perm in plan.perms for e in perm]
+    assert len(set(edges)) == len(edges) == 12  # K4, both directions
+    sizes = plan.snd_mask.sum(axis=-1)
+    assert system_x4["plan_counters"] == {
+        "exchange.plan.rounds": plan.R,
+        "exchange.plan.edges": 12,
+        "exchange.plan.slots": ghosts,
+        "exchange.plan.padded_slots": 4 * plan.R * plan.L,
+        "exchange.plan.max_edge": plan.L,
+        "exchange.plan.min_edge": int(sizes[sizes > 0].min()),
+    }
+    assert plan.R >= 3 and int(plan.snd_mask.sum()) == ghosts
+    assert plan.L > system_x4["plan_counters"]["exchange.plan.min_edge"]
+    exchange_fill = importlib.import_module("benchmark.layer_metrics.exchange_fill")
+    assert exchange_fill.fill(system_x4["plan_counters"]) == pytest.approx(
+        100.0 * ghosts / (4 * plan.R * plan.L)
+    )
+    # one part has a plan with no edge, and counts nothing
+    assert built(1)["plan_counters"] == {} and built(1)["oh_counters"] == {}
+
+
+def test_the_boundary_rows_lower_to_node_blocks(system_x4):
+    s, dA = system_x4["system"], system_x4["dA"]
+    assert dA.sd_bs == 3 and dA.ohb_bs == 3
+    assert dA.ohs_geo is None and dA.oh_vals is None
+    oh = s.A.owned_ghost_values.part_values()
+    assert system_x4["oh_counters"] == {
+        "lowering.oh.nnz": sum(m.nnz for m in oh),
+        "lowering.oh.block_entries": sum(int(np.prod(v.shape)) for v in dA.ohb_vals),
+    }
+    assert system_x4["oh_counters"]["lowering.oh.nnz"] == dA.oh_nnz > 0
+
+
+def test_the_program_text_holds_the_boundary_and_exchange_scopes(system_x4):
+    names = op_names(system_x4["dA"])
+    halo = f"{T.SCOPE_SPMV}/{T.SCOPE_HALO}"
+    for scope, op in (
+        (f"{T.SCOPE_SPMV}/{T.SCOPE_OH}", "gather"),
+        (f"{T.SCOPE_SPMV}/{T.SCOPE_OH}", "scatter-add"),
+        (f"{halo}/{T.SCOPE_EX_PACK}", "gather"),
+        (f"{halo}/{T.SCOPE_EX_UNPACK}", "scatter"),
+    ):
+        assert any(f"{scope}/{op}" in n for n in names), (scope, op)
+    # the permutes stay the phase's own; no sub-scope opens a phase
+    assert any(n.endswith(f"{halo}/ppermute") for n in names)
+    assert not any(
+        c.startswith("pa.") for c in (T.SCOPE_OH, T.SCOPE_EX_PACK, T.SCOPE_EX_UNPACK)
+    )
+    # one part exchanges nothing and has no boundary rows
+    alone = op_names(built(1)["dA"])
+    assert not any(T.SCOPE_EX_PACK in n or f"/{T.SCOPE_OH}/" in n for n in alone)

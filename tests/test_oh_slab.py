@@ -236,7 +236,11 @@ def test_irregular_ghost_graph_keeps_the_node_block_form():
     dA, counted = _lower(A, backend)
     assert dA.col_layout.box_info is None and dA.oh_nnz > 0
     assert dA.ohs_geo is None and dA.ohb_bs == 3 and dA.oh_vals is None
-    assert counted == {}
+    # the node-block form counts its stored entries and its padded blocks
+    assert counted == {
+        "lowering.oh.nnz": dA.oh_nnz,
+        "lowering.oh.block_entries": sum(int(v.size) for v in dA.ohb_vals),
+    }
     (y,) = _device_product(dA, A, [xh], backend)
     np.testing.assert_allclose(
         y, gather_pvector(A @ xh), rtol=1e-10, atol=1e-10
