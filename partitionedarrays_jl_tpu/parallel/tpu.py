@@ -1393,6 +1393,7 @@ class DeviceMatrix:
         self.row_layout, self.col_layout = row_layout, col_layout
         self.col_plan = device_exchange_plan(A.cols, self.padded)
         _count_exchange_plan(self.col_plan)
+        _count_box_plan(self.col_plan)
         self.backend = backend
         L_oh = max((int(m.row_lengths().max()) if m.nnz else 0 for m in oh), default=0)
         L_oh = max(L_oh, 1)
@@ -3161,6 +3162,7 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
         the whole block there, in and out), and a run widens to the
         lane rows it touches."""
         from ..ops.pallas_dia import LANES
+        from .tpu_box import slab_split_axis
 
         cl = dA.col_layout
         box = cl.box_info.box_shape
@@ -3168,13 +3170,6 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
         no = math.prod(box)
         tail = xv.shape[1:]
         keep = [(0, 0)] * len(tail)
-
-        def _split(s):
-            return next(
-                a for a in range(dim)
-                if s.shape[a] * math.prod(box[a + 1 :])
-                <= DeviceMatrix.OH_SLAB_MAX_FILL * math.prod(s.shape[a:])
-            )
 
         def _slice_add(view, starts, upd):
             starts = tuple(starts) + (0,) * (view.ndim - len(starts))
@@ -3185,7 +3180,7 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
 
         by_split = {}
         for s in dA.ohs_geo:
-            by_split.setdefault(_split(s), []).append(s)
+            by_split.setdefault(slab_split_axis(box, s.shape), []).append(s)
         for a, classes in sorted(by_split.items()):
             step = math.prod(box[a + 1 :])  # elements a step of axis a spans
             run = box[a] * step
@@ -5583,8 +5578,8 @@ def _count_exchange_plan(plan) -> None:
     generic plan: its rounds, its directed edges, the real slots they send
     (all parts), the ``P x R x L`` slots its padded rounds gather, ship
     and scatter, and its longest and shortest edge. ``slots /
-    padded_slots`` is the fill. A box plan, or a plan with no edge (one
-    part), counts nothing."""
+    padded_slots`` is the fill. A box plan (`_count_box_plan` counts
+    it), or a plan with no edge (one part), counts nothing."""
     if not isinstance(plan, DeviceExchangePlan) or not plan.R:
         return
     from .. import telemetry
@@ -5600,6 +5595,25 @@ def _count_exchange_plan(plan) -> None:
     telemetry.bump("exchange.plan.padded_slots", int(plan.snd_mask.size))
     telemetry.bump("exchange.plan.max_edge", max(edges))
     telemetry.bump("exchange.plan.min_edge", min(edges))
+
+
+def _count_box_plan(plan) -> None:
+    """The ``exchange.box.*`` counters of one operator staged with a box
+    plan that has an edge: its directions (one `ppermute` each), and how
+    the forward body addresses the face each packs
+    (`tpu_box.face_form`), one count a direction and box-shape variant,
+    so that the three forms add up to the directions on an equal-box
+    plan. A generic plan, or a box plan of one part, counts nothing."""
+    from .tpu_box import FACE_FORMS, BoxExchangePlan
+
+    if not isinstance(plan, BoxExchangePlan) or not plan.R:
+        return
+    from .. import telemetry
+
+    forms = plan.pack_forms()
+    telemetry.bump("exchange.box.dirs", plan.R)
+    for form in FACE_FORMS:
+        telemetry.bump(f"exchange.box.{form}_dirs", forms.count(form))
 
 
 def _count_oh_lowering(nnz: int, slabs=None, ell_entries=None,

@@ -11,8 +11,12 @@ C-order box scan (reference: the N-D block constructors,
 src/Interfaces.jl:1114-1231, and the FDM ghost discovery of
 test/test_fdm.jl:82-100) — and lowers the same Exchanger plan to:
 
-* pack: a static strided slice of the part's owned box (a
-  bandwidth-speed tiled copy on TPU — no gather),
+* pack: a static slice of the part's owned box (no gather), every
+  direction's taken from the operand as it arrives and addressed in
+  the cheapest form its geometry allows (`face_form`: a run of the flat
+  frame, a block of 128-lane rows, or the box's own view taken once —
+  the rule of `_spmv_body._oh_slabs` in tpu.py, which has what each
+  form read on the chip),
 * wire: one `ppermute` per geometric direction (the same partial
   permutation per round the generic plan's edge coloring produces),
 * unpack: a static contiguous store into a per-direction ghost SEGMENT.
@@ -334,6 +338,56 @@ def box_structure(rows: PRange) -> Optional[BoxInfo]:
     return cache[0]
 
 
+def slab_split_axis(box, shape) -> int:
+    """The axis a sub-box of ``shape`` is addressed from inside an owned
+    block ``box`` (C-order scan). Flattened from axis ``a`` on, the
+    block is ``box[:a] + (prod(box[a:]),)``, and the sub-box padded out
+    to whole steps of ``a`` is ONE run of that last axis: the smallest
+    ``a`` whose run stays within `DeviceMatrix.OH_SLAB_MAX_FILL` times
+    the sub-box. 0 for a face normal to the slowest axis, 1 for the
+    next, and so on; the boundary rows (`_spmv_body._oh_slabs`) and the
+    exchange's pack (`face_form`) split by this one rule."""
+    from .tpu import DeviceMatrix
+
+    return next(
+        a for a in range(len(box))
+        if shape[a] * math.prod(box[a + 1 :])
+        <= DeviceMatrix.OH_SLAB_MAX_FILL * math.prod(shape[a:])
+    )
+
+
+#: how `shard_box_exchange` addresses the face a direction packs
+FACE_FORMS = ("flat", "lane", "boxview")
+
+
+def face_form(box, shape) -> Tuple[str, int]:
+    """``(form, a)`` of the pack of a sub-box of ``shape`` out of the
+    owned block ``box``, ``a`` its split axis (`slab_split_axis`, as the
+    boundary rows split):
+
+    * ``'flat'`` (``a == 0``): the covering run is a slice of the flat
+      frame itself;
+    * ``'lane'`` (an inner ``a`` whose flattened axis is whole 128-lane
+      rows): the lane rows the run touches, out of the view
+      ``box[:a] + (rows, LANES)``, which on the padded frame is the flat
+      order itself;
+    * ``'boxview'`` (anything else: a face normal to the fastest axis,
+      an edge or corner whose covering run would pass the fill, planes
+      that are no whole lane rows): a slice of the box's own shape.
+
+    Shapes only: every shard, rank of operand and frame takes the same
+    form, and the compact frame (nothing aligned) takes it as a plain
+    reshape."""
+    from ..ops.pallas_dia import LANES
+
+    a = slab_split_axis(box, shape)
+    if a == 0:
+        return "flat", a
+    if a < len(box) - 1 and math.prod(box[a:]) % LANES == 0:
+        return "lane", a
+    return "boxview", a
+
+
 class BoxExchangePlan:
     """Slice-based halo program over a box layout: one `ppermute` per
     direction, static pack slices, static unpack segments. Drop-in for
@@ -354,14 +408,27 @@ class BoxExchangePlan:
     def reverse(self) -> "BoxExchangePlan":
         return BoxExchangePlan(self.layout, self.info, not self.reverse_mode)
 
+    def pack_forms(self) -> list:
+        """The `face_form` of every forward pack the body compiles: one a
+        direction and box-shape variant, directions outermost."""
+        return [
+            face_form(box, shape)[0]
+            for d in self.info.dirs
+            for box, (_start, shape) in zip(self.info.box_shapes, d.geo)
+        ]
+
 
 def shard_box_exchange(plan: BoxExchangePlan, combine: str):
     """Per-shard exchange body with the SAME signature as tpu.py's
     `_shard_exchange` bodies: body(xv, si, sm, ri) — the three index
     operands are ignored (dummies keep the operand pytree uniform).
 
-    Forward (owner->ghost, combine='set'): pack = static strided slice of
-    the owned box, unpack = static contiguous segment store.
+    Forward (owner->ghost, combine='set'): every direction's face is
+    taken from the operand AS IT ARRIVES (a pack reads owned slots only
+    and a store writes ghost slots only, so no pack waits for a store),
+    each in its `face_form`, the addressing rule of the boundary rows
+    (`_spmv_body._oh_slabs` in tpu.py); then one `ppermute` a direction;
+    unpack = static contiguous segment store.
     Reverse (ghost->owner, combine='add'): pack = the contiguous segment,
     unpack = static strided `.add` into the owned box; ghosts zeroed
     after, like the generic plan and the host `assemble`.
@@ -374,7 +441,9 @@ def shard_box_exchange(plan: BoxExchangePlan, combine: str):
     import jax
     import jax.numpy as jnp
 
+    from ..ops.pallas_dia import LANES
     from ..utils.helpers import check
+    from .tpu import SCOPE_EX_PACK, SCOPE_EX_UNPACK
 
     # reversal is explicit for box plans (no reversed index vectors to
     # encode it in): forward plans pair with 'set', reversed with 'add'
@@ -392,21 +461,62 @@ def shard_box_exchange(plan: BoxExchangePlan, combine: str):
     def _tail(xv):
         return tuple(xv.shape[1:])  # () or (K,)
 
-    def _pack(xv, d, v):
-        """Variant v's static pack: slice the owned box, pad the slab to
-        the direction's segment size."""
-        bs_v = shapes[v]
-        no_v = int(math.prod(bs_v))
-        start, shape = d.geo[v]
-        own = xv[o0 : o0 + no_v].reshape(bs_v + _tail(xv))
-        sl = tuple(slice(a, a + s) for a, s in zip(start, shape))
-        buf = own[sl].reshape((-1,) + _tail(xv))
-        pad = d.size - buf.shape[0]
-        if pad:
-            buf = jnp.pad(
-                buf, ((0, pad),) + ((0, 0),) * (buf.ndim - 1)
+    def _pack_all(xv, v):
+        """Variant v's static packs, one a direction, all out of the one
+        operand: each face in its `face_form`, trimmed to its slab,
+        flattened to the segment's order and padded to its size. The
+        directions of one lane-row split share that view of the owned
+        block, the box-view ones the box's own."""
+        box = shapes[v]
+        dim = len(box)
+        no_v = int(math.prod(box))
+        tail = _tail(xv)
+        views = {}
+
+        def view(shape):
+            if shape not in views:
+                views[shape] = xv[o0 : o0 + no_v].reshape(shape + tail)
+            return views[shape]
+
+        def cut(start, shape, axes):
+            return tuple(
+                slice(start[j], start[j] + shape[j]) for j in axes
             )
-        return buf
+
+        bufs = []
+        for d in info.dirs:
+            start, shape = d.geo[v]
+            form, a = face_form(box, shape)
+            if form == "boxview":
+                buf = view(box)[cut(start, shape, range(dim))]
+            else:
+                step = math.prod(box[a + 1 :])  # a step of axis a
+                lo = start[a] * step
+                hi = lo + shape[a] * step
+                if form == "flat":
+                    run = xv[o0 + lo : o0 + hi]
+                else:
+                    r0, r1 = lo // LANES, -(-hi // LANES)
+                    rows = view(box[:a] + (box[a] * step // LANES, LANES))
+                    run = jax.lax.slice_in_dim(
+                        rows[
+                            cut(start, shape, range(a)) + (slice(r0, r1),)
+                        ].reshape(shape[:a] + ((r1 - r0) * LANES,) + tail),
+                        lo - r0 * LANES, hi - r0 * LANES, axis=a,
+                    )
+                # whole steps of axis a; the axes behind it cut to the slab
+                buf = run.reshape(shape[: a + 1] + box[a + 1 :] + tail)[
+                    (slice(None),) * (a + 1)
+                    + cut(start, shape, range(a + 1, dim))
+                ]
+            buf = buf.reshape((-1,) + tail)
+            pad = d.size - buf.shape[0]
+            if pad:
+                buf = jnp.pad(
+                    buf, ((0, pad),) + ((0, 0),) * (buf.ndim - 1)
+                )
+            bufs.append(buf)
+        return tuple(bufs)
 
     def _unpack_add(xv, buf, d, v):
         """Variant v's static reverse unpack: accumulate the (sender-
@@ -428,20 +538,25 @@ def shard_box_exchange(plan: BoxExchangePlan, combine: str):
             # `si` carries the shard's box-shape VARIANT index (a single
             # int32; equal-box plans have V == 1 and never read it)
             del sm, ri
-            for d in info.dirs:
+            with jax.named_scope(SCOPE_EX_PACK):
                 if V == 1:
-                    buf = _pack(xv, d, 0)
+                    bufs = _pack_all(xv, 0)
                 else:
-                    buf = jax.lax.switch(
+                    bufs = jax.lax.switch(
                         si[0].astype(jnp.int32),
                         [
-                            (lambda x, d=d, v=v: _pack(x, d, v))
+                            (lambda x, v=v: _pack_all(x, v))
                             for v in range(V)
                         ],
                         xv,
                     )
-                buf = jax.lax.ppermute(buf, "parts", perm=d.perm)
-                xv = xv.at[g0 + d.off : g0 + d.off + d.size].set(buf)
+            bufs = [
+                jax.lax.ppermute(buf, "parts", perm=d.perm)
+                for d, buf in zip(info.dirs, bufs)
+            ]
+            with jax.named_scope(SCOPE_EX_UNPACK):
+                for d, buf in zip(info.dirs, bufs):
+                    xv = xv.at[g0 + d.off : g0 + d.off + d.size].set(buf)
             return xv
 
         return body

@@ -227,6 +227,23 @@ CATALOG: Dict[str, MetricSpec] = {
         _spec("exchange.plan.min_edge", "counter", "1",
               "parallel/tpu.py:_count_exchange_plan",
               "slots of the shortest edge"),
+        # -- the box exchange plan, where an operator takes it ---------
+        _spec("exchange.box.dirs", "counter", "1",
+              "parallel/tpu.py:_count_box_plan",
+              "directions of the box plans: one ppermute each"),
+        _spec("exchange.box.flat_dirs", "counter", "1",
+              "parallel/tpu.py:_count_box_plan",
+              "forward packs (one a direction and box-shape variant) "
+              "taken as a slice of the flat frame: faces normal to the "
+              "slowest axis"),
+        _spec("exchange.box.lane_dirs", "counter", "1",
+              "parallel/tpu.py:_count_box_plan",
+              "forward packs taken as a block of 128-lane rows: faces "
+              "normal to an inner axis whose planes are whole lane rows"),
+        _spec("exchange.box.boxview_dirs", "counter", "1",
+              "parallel/tpu.py:_count_box_plan",
+              "forward packs taken from the box's own view of the owned "
+              "block (one view an exchange): every other sub-box"),
         # -- service lifecycle counters -------------------------------
         _spec("service.admitted", "counter", "1",
               "service/service.py:submit",
