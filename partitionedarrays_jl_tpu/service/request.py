@@ -12,7 +12,10 @@ A request moves through::
 the handle: ``req.result()`` returns ``(x, info)`` for a finished
 request and re-raises the retained TYPED error for a failed one (the
 same `SolverHealthError` subclass a solo solve would have raised, so
-callers keep one error vocabulary whether they batched or not). Every
+callers keep one error vocabulary whether they batched or not);
+``req.wait(timeout)`` blocks until the request is terminal and then
+behaves as ``result()`` — what a client of a service with its worker
+thread running (`SolveService.start`) calls. Every
 request carries its own `SolveRecord` (``req.record``): the queue /
 admission / slab / ejection events of its life, plus everything the
 slab solves emitted while it was active — the PR 6 observability
@@ -20,6 +23,7 @@ contract extended to the request level.
 """
 from __future__ import annotations
 
+import threading
 from typing import Optional
 
 __all__ = ["SolveRequest"]
@@ -76,11 +80,16 @@ class SolveRequest:
         self._x = None
         self._info = None
         self._error: Optional[BaseException] = None
+        #: Set at every terminal transition (`_set_state`), after the
+        #: result or the error is in place: what `wait` blocks on.
+        self._terminal = threading.Event()
 
     # -- state transitions (service-internal) ----------------------------
     def _set_state(self, state: str) -> None:
         assert state in _STATES, state
         self.state = state
+        if state not in ("queued", "running"):
+            self._terminal.set()
 
     def _resolve(self, x, info) -> None:
         self._x, self._info = x, info
@@ -103,7 +112,8 @@ class SolveRequest:
         """``(x, info)`` of a finished request; re-raises the retained
         typed error for a failed one. Raises `RuntimeError` while the
         request is still queued/running (the service is pull-driven:
-        call `SolveService.drain` / `step`, or run the worker thread)
+        call `SolveService.drain` / `step`, or run the worker thread
+        and `wait`)
         and for shutdown-terminated requests (checkpointed/suspended —
         resubmit from the checkpointed iterate instead)."""
         if self.state == "done":
@@ -125,6 +135,22 @@ class SolveRequest:
             f"request {self.id} is still {self.state} — drive the "
             "service (drain()/step()) before asking for the result"
         )
+
+    def wait(self, timeout: Optional[float] = None):
+        """Block until the request is terminal (done, failed,
+        checkpointed or suspended), then behave as `result`: ``(x,
+        info)``, or the retained typed error, or the shutdown
+        `RuntimeError`. Somebody else has to drive the service
+        meanwhile (the worker thread of `SolveService.start`, or
+        another thread's ``drain()``). ``timeout`` is in seconds of
+        wall clock; past it `TimeoutError` is raised and the request
+        stays what it was. No lock is held while waiting."""
+        if not self._terminal.wait(timeout):
+            raise TimeoutError(
+                f"request {self.id} is still {self.state} after "
+                f"{timeout} s"
+            )
+        return self.result()
 
     def __repr__(self):
         return (

@@ -50,6 +50,7 @@ from ..telemetry import spectrum, tracing
 from ..telemetry.registry import monitoring_enabled, registry
 from ..telemetry.throughput import model as throughput_model
 from ..telemetry.throughput import operator_fingerprint
+from ..telemetry.trace import profiler_span
 from ..utils.helpers import check
 from ..utils.locksan import sanitized
 from .admission import (
@@ -449,6 +450,7 @@ class SolveService:
             ragged = reg.counter("service.slabs_ragged").inc()
         mon = monitoring_enabled()
         formed = self.clock()
+        self._count_columns(slab, formed, reg)
         if mon:
             reg.gauge("service.slab_utilization").set(
                 len(slab) / self.kmax
@@ -479,22 +481,47 @@ class SolveService:
         first_dispatch = True
         if mon:
             reg.gauge("service.inflight_slabs").inc()
+        # the slab's whole run as ONE profiler span: the block solves'
+        # own ``pa:block-cg:*`` spans nest under it. ``k`` is the width
+        # at formation; ``trips`` (block iterations of all its block
+        # solves) is known at the end and joins the span's stats there
+        span = profiler_span("pa:service:slab", k=len(slab))
         try:
-            done = self._slab_loop(
-                active, X, tol, key, budget, chunked, targets,
-                formed, first_dispatch, mon, reg, done,
-            )
+            with span:
+                done, trips = self._slab_loop(
+                    active, X, tol, key, budget, chunked, targets,
+                    formed, first_dispatch, mon, reg, done,
+                )
+                if hasattr(span, "set_metadata"):  # a null context off jax
+                    span.set_metadata(trips=trips)
         finally:
             if mon:
                 reg.gauge("service.inflight_slabs").dec()
         return done
 
+    def _count_columns(self, reqs, now: float, reg) -> None:
+        """The always-on counters of requests that join a slab, at its
+        formation or at a chunk boundary's top-up: one column each, and
+        each one's wait since submission in whole microseconds of the
+        service clock."""
+        reg.counter("service.slab_columns").inc(len(reqs))
+        reg.counter("service.queue_wait_us").inc(
+            sum(
+                int(round(1e6 * max(0.0, now - r.submitted_at)))
+                for r in reqs
+            )
+        )
+
     def _slab_loop(self, active, X, tol, key, budget, chunked, targets,
                    formed, first_dispatch, mon, reg, done):
+        """Returns ``(done, trips)``: requests terminated, and the block
+        iterations of the slab's block solves (each solve's largest
+        column)."""
         from .. import telemetry
         from ..parallel.pvector import PVector
 
         _, key_maxiter, key_dtype = key
+        slab_trips = 0
         while active:
             remaining = min(budget - r.iterations for r in active)
             step = min(self.chunk, remaining) if chunked else remaining
@@ -541,6 +568,8 @@ class SolveService:
                 (int(c["iterations"]) for c in info["columns"]),
                 default=0,
             )
+            slab_trips += trips
+            reg.counter("service.slab_trips").inc(trips)
             if mon:
                 reg.histogram("service.solve_s").observe(solve_wall)
                 if trips > 0:
@@ -618,8 +647,9 @@ class SolveService:
                 self._open_solve_span(r, len(active) + len(added))
                 X[r.id] = r.x0
             if added:
+                join = self.clock()
+                self._count_columns(added, join, reg)
                 if mon:
-                    join = self.clock()
                     qw = reg.histogram("service.queue_wait_s")
                     for r in added:
                         qw.observe(max(0.0, join - r.submitted_at))
@@ -632,7 +662,7 @@ class SolveService:
                     tol=tol, maxiter=key_maxiter, topped_up=True,
                 )
             active = active + added
-        return done
+        return done, slab_trips
 
     def _chunk_verdict(self, req, col, tol, targets):
         """Chunk continuation must NOT re-baseline the convergence

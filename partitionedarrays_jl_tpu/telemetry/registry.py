@@ -282,6 +282,19 @@ CATALOG: Dict[str, MetricSpec] = {
         _spec("service.slabs_ragged", "counter", "1",
               "service/service.py:_run_slab",
               "slabs narrower than kmax (ragged leftovers)"),
+        _spec("service.slab_columns", "counter", "1",
+              "service/service.py:_count_columns",
+              "columns of every slab run: requests that joined a slab "
+              "at its formation or at a top-up (over service.slabs x "
+              "kmax: how full the slabs ran)"),
+        _spec("service.slab_trips", "counter", "1",
+              "service/service.py:_slab_loop",
+              "block iterations: the largest column's count of each "
+              "block solve, summed"),
+        _spec("service.queue_wait_us", "counter", "us",
+              "service/service.py:_count_columns",
+              "submission to slab formation (or top-up), summed over "
+              "requests, in whole microseconds of the service clock"),
         # -- service gauges (PA_MON-gated) ----------------------------
         _spec("service.queue_depth", "gauge", "requests",
               "service/service.py:submit/_pop_slab",
