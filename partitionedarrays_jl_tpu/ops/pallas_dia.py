@@ -216,12 +216,19 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
                    n_blocks: int, block_rows: int, halo_rows: int,
                    n_coded: int,
                    cls_pattern: Tuple[Tuple[bool, ...], ...] = None,
-                   has_pfold: bool = False):
+                   has_pfold: bool = False, columns: bool = False):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if columns:
+        # K vectors, one after the other, on a leading grid axis: the
+        # block walk below is a column's own from its first block to its
+        # last (every DMA it starts it also waits for), so a column is
+        # the single-vector call and the codes are read once a column
+        col = pl.program_id(0)
+        xw_ref = xw_ref.at[col]
     if has_pfold:
         # leading-edge direction fold (fused CG): the SpMV operand is
         # p = r + beta*p_prev, built IN the window pass — the kernel
@@ -233,10 +240,12 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
         # xw_ref is the r window source here.
         (pw_ref, beta_ref, y_ref, po_ref,
          xs_ref, ps_ref, comb_ref, cs_ref, xsem, psem, csem) = refs
+        if columns:
+            pw_ref = pw_ref.at[col]
     else:
         y_ref, xs_ref, cs_ref, xsem, csem = refs
 
-    j = pl.program_id(0)
+    j = pl.program_id(1 if columns else 0)
     BR = block_rows
     win_rows = _win_rows(BR, halo_rows)
 
@@ -289,7 +298,9 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
             # one in-VMEM pass builds the combined operand window; every
             # shifted diagonal read then hits the combined copy, so the
             # fold costs ONE add per element instead of one per diagonal
-            comb_ref[:] = xs_ref[slot] + beta_ref[0] * ps_ref[slot]
+            comb_ref[:] = (
+                xs_ref[slot] + beta_ref[col if columns else 0] * ps_ref[slot]
+            )
         if n_coded:
             codes_dma(slot, j).wait()
 
@@ -426,7 +437,13 @@ def dia_coded_padded_pallas(
     ``y = A_oo p`` and ``p`` masked to the owned band (every other slot
     exactly zero) — the standard loop's standalone direction-update
     sweep is absorbed by the SpMV pass (tpu.py:make_cg_fn fused body).
-    Callers must first check `pfold_vmem_ok(plan)`."""
+    Callers must first check `pfold_vmem_ok(plan)`.
+
+    ``x`` may be K such vectors, ``(K, total_rows, 128)`` (with ``pprev``
+    alike and ``beta`` of K entries): a leading grid axis walks them one
+    after the other, each as the single-vector call would (the codes are
+    read once a column), and the results keep the leading axis. The
+    single-vector call is built exactly as before."""
     import jax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -439,7 +456,8 @@ def dia_coded_padded_pallas(
         assert all(len(p) == D for p in cls_pattern)
     BR, H, nB = plan["block_rows"], plan["halo_rows"], plan["n_blocks"]
     qr = tuple(divmod(H * LANES + off, LANES) for off in offsets)
-    assert x.shape[0] == total_rows and total_rows % BR == 0
+    columns = x.ndim == 3
+    assert x.shape[-2] == total_rows and total_rows % BR == 0
     assert total_rows >= (nB + 2) * BR
     win_rows = _win_rows(BR, H)
     kernel = functools.partial(
@@ -447,6 +465,7 @@ def dia_coded_padded_pallas(
         code_row=tuple(int(c) for c in code_row), n_blocks=nB,
         block_rows=BR, halo_rows=H, n_coded=Dc,
         cls_pattern=cls_pattern, has_pfold=pfold is not None,
+        columns=columns,
     )
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),  # codebook
@@ -454,10 +473,20 @@ def dia_coded_padded_pallas(
         pl.BlockSpec(memory_space=pl.ANY),  # codes: manual DMA
         pl.BlockSpec(memory_space=pl.ANY),  # x: manual DMA
     ]
-    y_spec = pl.BlockSpec(
-        (BR, LANES), lambda j: (j, 0), memory_space=pltpu.VMEM
+    if columns:
+        grid = (x.shape[0], total_rows // BR)
+        y_spec = pl.BlockSpec(
+            (None, BR, LANES), lambda c, j: (c, j, 0),
+            memory_space=pltpu.VMEM,
+        )
+    else:
+        grid = (total_rows // BR,)
+        y_spec = pl.BlockSpec(
+            (BR, LANES), lambda j: (j, 0), memory_space=pltpu.VMEM
+        )
+    y_shape = jax.ShapeDtypeStruct(
+        x.shape[:-2] + (total_rows, LANES), codebook.dtype
     )
-    y_shape = jax.ShapeDtypeStruct((total_rows, LANES), codebook.dtype)
     params = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
     scratch = [
         pltpu.VMEM((2, win_rows, LANES), codebook.dtype),
@@ -470,7 +499,7 @@ def dia_coded_padded_pallas(
         assert pprev.shape == x.shape
         return pl.pallas_call(
             kernel,
-            grid=(total_rows // BR,),
+            grid=grid,
             in_specs=in_specs + [
                 pl.BlockSpec(memory_space=pl.ANY),  # pprev: manual DMA
                 pl.BlockSpec(memory_space=pltpu.SMEM),  # beta
@@ -494,7 +523,7 @@ def dia_coded_padded_pallas(
         )(codebook, no, codes, x, pprev, beta)
     return pl.pallas_call(
         kernel,
-        grid=(total_rows // BR,),
+        grid=grid,
         in_specs=in_specs,
         out_specs=y_spec,
         out_shape=y_shape,

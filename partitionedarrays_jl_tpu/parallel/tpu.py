@@ -79,6 +79,11 @@ SCOPE_OH = "oh"
 #: round's gather of its send slots and its scatter into its receive
 #: slots, which `halo_index_share` reads; the permutes stay the phase's own.
 SCOPE_EX_PACK, SCOPE_EX_UNPACK = "ex.pack", "ex.unpack"
+#: What `jax.vmap` over the columns of a lane-major block operand traces
+#: under (`make_block_cg_fn`): the transform renames the FIRST scope it
+#: meets to ``vmap(<name>)``, which no reader would find under ``pa.``,
+#: so it is given this one to rename and the phases inside keep theirs.
+SCOPE_COLUMN = "column"
 #: Outside the solve's program: the small per-part programs that write a
 #: part's values into its ``(1, W)`` frame and take them out again
 #: (`_vector_program`).
@@ -2851,7 +2856,8 @@ def _matrix_operands(dA: DeviceMatrix) -> dict:
 
 
 def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
-               abft: bool = False, audit: bool = False):
+               abft: bool = False, audit: bool = False,
+               columns: bool = False):
     """Per-shard overlapped SpMV: pack+permute the halo, compute the A_oo
     partial on pre-exchange owned values (independent of the collective —
     XLA overlaps them), then unpack and add the A_oh ghost contribution
@@ -2873,9 +2879,16 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
     einsum, and the halo exchange ships ``(…, K)`` slabs per wire round
     (JITSPMM, arxiv 2312.05639 — amortize the operand stream across
     columns and feed the MXU). The Pallas kernels (coded padded frame,
-    streaming DIA, in-kernel pfold) keep a K=1-only guard and the
-    block path falls back to the equivalent XLA forms of the same
-    arithmetic.
+    streaming DIA, in-kernel pfold) take a 1-D frame only, and a
+    ``(W, K)`` operand falls back to the equivalent XLA forms of the
+    same arithmetic.
+
+    ``columns=True`` (the lane-major block body of `make_block_cg_fn`)
+    builds the body for `jax.vmap` over K 1-D frames instead: the two
+    callers of the coded kernel then take the K frames as ``(K, W)`` and
+    the kernel walks them on a leading grid axis, one after the other;
+    everything else batches by itself. Without it the body, and the
+    text it lowers to, is what it was.
 
     ``abft=True`` builds the checksummed variant: the halo exchange runs
     with per-round slab checksums (`_shard_exchange(abft=True)`) and the
@@ -2978,17 +2991,23 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
     code_row = dA.dia_code_row
     interpret = dA.backend.devices()[0].platform != "tpu"
 
+    def _lane_rows(xv):
+        # the kernel's view of a frame, or of K of them as (K, W)
+        from ..ops.pallas_dia import LANES
+
+        return xv.reshape(xv.shape[:-1] + (-1, LANES))
+
     def _dia_coded_full(cb, no, codes, xv):
         # zero-copy hot path: xv IS the kernel frame (padded layout); the
         # result is a full vector with every non-owned slot exactly zero
         from ..ops.pallas_dia import LANES, dia_coded_padded_pallas
 
         y = dia_coded_padded_pallas(
-            cb, no.astype(jnp.int32), codes, xv.reshape(-1, LANES), offsets,
-            kk, code_row, pplan, xv.shape[0] // LANES, interpret=interpret,
+            cb, no.astype(jnp.int32), codes, _lane_rows(xv), offsets,
+            kk, code_row, pplan, xv.shape[-1] // LANES, interpret=interpret,
             cls_pattern=dA.dia_cls_pattern,
         )
-        return y.reshape(-1)
+        return y.reshape(xv.shape)
 
     def _codes_stream(codes, j):
         """Stream ``j`` of the staged codes as (no_max,) int32: unpacked
@@ -3042,15 +3061,40 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
         from ..ops.pallas_dia import LANES, dia_coded_padded_pallas
 
         y, pnew = dia_coded_padded_pallas(
-            cb, no.astype(jnp.int32), codes, rv.reshape(-1, LANES),
-            offsets, kk, code_row, pplan, rv.shape[0] // LANES,
+            cb, no.astype(jnp.int32), codes, _lane_rows(rv),
+            offsets, kk, code_row, pplan, rv.shape[-1] // LANES,
             interpret=interpret, cls_pattern=dA.dia_cls_pattern,
             pfold=(
-                pv.reshape(-1, LANES),
-                jnp.reshape(beta, (1,)).astype(rv.dtype),
+                _lane_rows(pv),
+                jnp.reshape(beta, (-1,)).astype(rv.dtype),
             ),
         )
-        return y.reshape(-1), pnew.reshape(-1)
+        return y.reshape(rv.shape), pnew.reshape(rv.shape)
+
+    if columns:
+        # `jax.vmap` over the columns of a lane-major block operand
+        # (`make_block_cg_fn`): Pallas on the TPU cannot batch an operand
+        # it leaves in HBM for the kernel's own DMAs, so the two kernel
+        # callers take the K frames as they are, (K, W), and the kernel
+        # walks them on a leading grid axis; everything around them (the
+        # exchange, the boundary rows, the jnp fold) batches by itself
+        def _over_columns(caller):
+            batched = jax.custom_batching.custom_vmap(caller)
+
+            @batched.def_vmap
+            def _(axis_size, in_batched, cb, no, codes, *frames):
+                assert not any(in_batched[:3]), "one operator, K columns"
+                frames = [
+                    f if b else jnp.broadcast_to(f, (axis_size,) + f.shape)
+                    for f, b in zip(frames, in_batched[3:])
+                ]
+                out = caller(cb, no, codes, *frames)
+                return out, jax.tree.map(lambda _: True, out)
+
+            return batched
+
+        _dia_coded_full = _over_columns(_dia_coded_full)
+        _dia_coded_full_pfold = _over_columns(_dia_coded_full_pfold)
 
     def _aoo(xv, m):
         """The A_oo block applied to xv: ``(full, partial_)`` with
@@ -3060,8 +3104,9 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
             # coded-diagonal path: 1 byte/element per non-constant
             # diagonal, decoded against the SMEM codebook — independent of
             # the wire, so it still overlaps the halo collective. The
-            # Pallas kernel is K=1-only; a block operand decodes the same
-            # codebooks through the XLA shifted-broadcast form.
+            # Pallas kernel takes 1-D frames; a (W, K) block operand
+            # decodes the same codebooks through the XLA
+            # shifted-broadcast form.
             if pplan is not None and xv.ndim == 1:
                 return _dia_coded_full(m["cb"], m["no"], m["codes"], xv), None
             return None, _dia_coded_xla(m["cb"], m["no"], m["codes"], xv)
@@ -3324,7 +3369,7 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
         colL = dA.col_plan.layout
         cs = slice(colL.o0, colL.o0 + colL.no_max)
         if _pfold_in_kernel and mvv is None and rv.ndim == 1:
-            # has_pfold Pallas kernel: K=1-only this round — a block
+            # has_pfold Pallas kernel, 1-D frames only: a (W, K) block
             # operand takes the fused jnp fold below instead
             full, pnew = _dia_coded_full_pfold(
                 m["cb"], m["no"], m["codes"], rv, pv, beta
@@ -4084,39 +4129,73 @@ def make_cg_fn(
     return run
 
 
+def _block_lane_major(dA: DeviceMatrix, fused: bool, sdc: bool) -> bool:
+    """Whether the block CG program holds its operands lane-major,
+    ``(K, W)`` with a column a row, and not ``(W, K)``. Read from the
+    operator's lowering, the body and the SDC mode, and from nothing
+    else: the A_oo block runs the coded Mosaic kernel on the padded frame
+    (K=1-only: `_spmv_body._aoo`), the body is the fused one, and no SDC
+    mode is on. `make_block_cg_fn` has which lowering wins what."""
+    return bool(
+        fused and not sdc
+        and dA.dia_mode == "coded" and dA.pallas_plan is not None
+    )
+
+
 def make_block_cg_fn(
     dA: DeviceMatrix, tol: float, maxiter: int, rhs_batch: int,
     precond: bool = False, fused: Optional[bool] = None,
 ) -> Callable:
     """Block (multi-RHS) CG: ONE compiled shard_map program solving
     ``A X = B`` for K = ``rhs_batch`` right-hand sides against the SAME
-    operator. The per-iteration operator stream — DIA values/codebooks,
-    SD group blocks, BSR blocks, halo slabs — is read ONCE per K
-    columns (`_spmv_body`'s rank-polymorphic lowerings turn SpMV into
-    SpMM), which is what makes the HBM-roofline-bound large-N iteration
-    cheaper PER RHS as K grows (docs/performance.md, Multi-RHS).
+    operator. Inside the program the block takes one of two layouts,
+    chosen by what the operator lowered to (`_block_lane_major`; no flag
+    or argument chooses, and ``run.block_layout`` says which):
 
-    Semantics contract: every column follows the TEXTBOOK single-vector
-    recurrence exactly — per-column α/β from per-column dots (identical
-    partial-sum trees, identical part-order folds), so column k's
-    trajectory is the trajectory `make_cg_fn` at K=1 would produce for
-    (b_k, x0_k), bit-for-bit under strict-bits arithmetic (pinned by
-    tests/test_block_cg.py on the 4-part conformance fixture).
+    * ``"columns"``, operands ``(W, K)``: `_spmv_body`'s rank-polymorphic
+      lowerings turn SpMV into SpMM, and the per-iteration operator
+      stream is read ONCE per K columns. That holds for the SD group
+      blocks and BSR blocks (one ``(rows, U) @ (U, K)`` product), for
+      streamed DIA values and ELL arrays (broadcast over the trailing
+      axis) and for the halo slabs, which is what makes the
+      HBM-bound large-N iteration cheaper PER RHS as K grows there
+      (docs/performance.md, Multi-RHS). Every operator but the one
+      below, the standard body, strict-bits and the SDC-defended loops.
+    * ``"lanes"``, operands ``(K, W)`` with a column a row (held as the
+      kernel reads a frame, ``(K, W // 128, 128)``): where the A_oo
+      block runs the coded Mosaic kernel on the padded frame and the body
+      is the fused one. The operator there is a codebook and a byte a
+      row, so there is next to nothing to amortize, and K minor would
+      leave 4 lanes of 128 to every sweep, select and dot (PERF.md,
+      PR 34: a K=4 iteration 6.16 ms where four solo ones take 1.59).
+      Each column goes through the solo solve's own kernel and direction
+      fold on its 1-D frame (the codes are read once a COLUMN), the
+      sweeps and dots have full lanes, and K=1 is `make_cg_fn`'s fused
+      program plus the freeze selects.
+
+    Semantics contract, in both layouts: every column follows the
+    TEXTBOOK single-vector recurrence exactly, with per-column α/β from
+    per-column dots, so column k's trajectory is the trajectory
+    `make_cg_fn` at K=1 would produce for (b_k, x0_k): bit-for-bit under
+    strict-bits arithmetic (which lowers to ELL and so to the columns
+    layout; pinned by tests/test_block_cg.py on the 4-part conformance
+    fixture), to rounding in the lane-major body, whose row reductions
+    sum in another order than the solo frame's.
     Converged (or broken-down / non-finite) columns FREEZE — their α is
     zeroed and their state re-selected unchanged — rather than exiting,
     keeping the loop shape static; the loop ends when every column is
     frozen or maxiter hits. Collective count per iteration is
     K-INDEPENDENT: the dot payloads widen from scalars to (K,) /
-    (K, 2) stacks riding the same all_gathers (`_pdot_owned_factory`),
-    and the halo ppermutes ship (…, K) slabs — pinned by the HLO A/B in
-    tests/test_block_cg.py.
+    (K, 2) stacks riding the same all_gathers, and the halo ppermutes
+    ship the K columns' faces as one payload — pinned by the HLO A/B in
+    tests/test_block_cg.py, for both layouts.
 
     ``fused`` selects the fused streaming body exactly as in
     `make_cg_fn` (default: env-resolved): one update+dot sweep, the
-    direction fold riding the SpMV pass (jnp fold on every lowering —
-    the Pallas has_pfold kernel keeps its K=1-only guard), and the
-    preconditioned reduction pair sharing ONE all_gather as a (K, 2)
-    payload.
+    direction fold riding the SpMV pass (the Pallas has_pfold kernel in
+    the lane-major body; the jnp fold on every other lowering, and
+    under a preconditioner row), and the preconditioned reduction pair
+    sharing ONE all_gather as a (K, 2) payload.
 
     Returns ``run(b, x0, mv=None) -> (x, rs, rs0, iters, hist)`` with
     b/x0/x of shape (P, W, K), per-column ``rs``/``rs0``/``iters`` of
@@ -4125,6 +4204,8 @@ def make_block_cg_fn(
     import jax
     import jax.numpy as jnp
     shard_map = jax.shard_map
+
+    from ..ops.pallas_dia import LANES
 
     K = int(rhs_batch)
     check(K >= 1, "make_block_cg_fn: rhs_batch must be >= 1")
@@ -4143,9 +4224,13 @@ def make_block_cg_fn(
     # round (its per-column freeze/rollback bookkeeping has no committed
     # α/β slot per trip), noted in docs/observability.md.
     Ht = 0 if sdccfg is not None else int(min(_trace_config(), maxiter))
-    body_spmv = _spmv_body(dA, abft=abft_on)
+    lanes = _block_lane_major(dA, fused, sdccfg is not None)
+    body_spmv = _spmv_body(dA, abft=abft_on, columns=lanes)
     body_pfold = (
-        _spmv_body(dA, pfold=True, abft=abft_on, audit=sdccfg is not None)
+        _spmv_body(
+            dA, pfold=True, abft=abft_on, audit=sdccfg is not None,
+            columns=lanes,
+        )
         if fused
         else None
     )
@@ -4162,6 +4247,148 @@ def make_block_cg_fn(
         return _strict_rounded_product(t) if strict else t
 
     H = int(min(maxiter + 1, 4096))
+
+    def still_active(rs, rz, rs0):
+        # the SAME per-column predicate the K=1 cond tests: a column
+        # below tol, non-finite, or (preconditioned) broken down is
+        # permanently inactive — its state is frozen, so the predicate
+        # stays False once it trips
+        go = jnp.sqrt(rs) > tol * jnp.maximum(1.0, jnp.sqrt(rs0))
+        go = jnp.logical_and(go, jnp.isfinite(rs))
+        if precond:
+            go = jnp.logical_and(go, rz != 0)
+        return go
+
+    def per_column(f):
+        """``f`` of 1-D frames over K of them, taken and returned in the
+        kernel's view ``(K, W // LANES, LANES)`` (scalars a column as
+        ``(K,)``): the kernel's call gains a leading grid axis and takes
+        that view as it is (`_spmv_body` ``columns``), a ppermute ships
+        the K columns' payload at once."""
+
+        def column(*args):
+            with jax.named_scope(SCOPE_COLUMN):
+                return f(*args)
+
+        def over(*args):
+            out = jax.vmap(column)(
+                *[a.reshape((K, -1)) if a.ndim == 3 else a for a in args]
+            )
+            return jax.tree.map(lambda y: y.reshape((K, -1, LANES)), out)
+
+        return over
+
+    def shard_lanes(bs, x0s, mvs, ms):
+        """The lane-major fused body (`_block_lane_major`): `make_cg_fn`'s
+        ``step_fused`` with three carries of K frames each and the
+        per-column freeze. A frame is held as the kernel reads it,
+        ``(W // LANES, LANES)``, so that K of them, ``(K, W)`` with a
+        column a row, are the kernel's operand with no relayout between
+        the sweeps and the product (a 2-D ``(K, W)`` array of K <= 4 rows
+        is tiled K rows deep on the chip, which interleaves the columns).
+        The sweeps and dots run over the lane rows that hold the owned
+        band; the slots of its last row past ``no_max`` are pads, zero in
+        every operand (`body_pfold`'s contract for p and A p)."""
+        lo, hi = o0 // LANES, -(-(o0 + no_max) // LANES)
+        own = slice(lo, hi)
+
+        def lane_rows(t):  # (W, K) -> (K, W // LANES, LANES)
+            return t.T.reshape((K, -1, LANES))
+
+        bv, xv = lane_rows(bs[0]), lane_rows(x0s[0])
+        mats = _shard_ops(jax, ms)
+        mvo = mvs[0].reshape((-1, LANES))[own] if precond else None
+
+        def gathered(partials):
+            # (K,) or (K, 2) partials of this part: ONE all_gather, the
+            # parts folded in their order
+            return jnp.sum(jax.lax.all_gather(partials, "parts"), axis=0)
+
+        def dot(a, b):
+            return gathered(jnp.sum(a[:, own] * b[:, own], axis=(1, 2)))
+
+        def dots(ro):
+            # r.z and r.r of the owned rows, on one all_gather
+            rr = jnp.sum(ro * ro, axis=(1, 2))
+            if not precond:
+                rs = gathered(rr)
+                return rs, rs
+            s = gathered(
+                jnp.stack([jnp.sum(ro * (mvo * ro), axis=(1, 2)), rr], axis=-1)
+            )
+            return s[:, 0], s[:, 1]
+
+        dot, dots = _scoped(SCOPE_DOTS, dot), _scoped(SCOPE_DOTS, dots)
+        q = per_column(lambda c: body_spmv(c, mats)[0])(xv)
+        ro = bv[:, own] - q[:, own]
+        r = jnp.zeros_like(xv).at[:, own].set(ro)
+        rz0, rs0 = dots(ro)
+        hist = (
+            jnp.full((H, K), jnp.nan, dtype=bv.dtype).at[0].set(jnp.sqrt(rs0))
+        )
+
+        def active(rs, rz):
+            return still_active(rs, rz, rs0)
+
+        def cond_l(state):
+            rz, rs, _beta, _itk, it = state[3:8]
+            return jnp.logical_and(jnp.any(active(rs, rz)), it < maxiter)
+
+        fold = per_column(
+            lambda rk, pk, bk: body_pfold(
+                rk, pk, bk, mats, mvs[0] if precond else None
+            )
+        )
+
+        def step_l(state):
+            x, r_, p_prev, rz, rs, beta, itk, it, hist = state[:9]
+            act = active(rs, rz)
+            # every column's fold and product are the solo solve's own. A
+            # frozen column folds with beta 0: its direction is then its
+            # residual, finite, and never read again (its alpha is 0 and
+            # its x and r are re-selected), so the direction needs no
+            # select pass of its own.
+            q, p = fold(r_, p_prev, jnp.where(act, beta, 0))
+            alpha = jnp.where(act, rz / dot(p, q), 0)
+            # one sweep: both updates where the carries lie, and the dot
+            # partials. The freeze is a select, not `+ 0 * p`: a frozen
+            # column's x and r never move a bit.
+            a_, al = act[:, None, None], alpha[:, None, None]
+            x = x.at[:, own].set(
+                jnp.where(a_, x[:, own] + al * p[:, own], x[:, own])
+            )
+            r_ = r_.at[:, own].set(
+                jnp.where(a_, r_[:, own] - al * q[:, own], r_[:, own])
+            )
+            rz_new, rs_new = dots(r_[:, own])
+            rz2 = jnp.where(act, rz_new, rz)
+            rs2 = jnp.where(act, rs_new, rs)
+            beta2 = jnp.where(act, rz_new / rz, beta)
+            idx = jnp.minimum(it + 1, H - 1)
+            hist2 = hist.at[idx].set(
+                jnp.where(act, jnp.sqrt(rs2), hist[idx])
+            )
+            out = (
+                x, r_, p, rz2, rs2, beta2, itk + act.astype(jnp.int32),
+                it + 1, hist2,
+            )
+            if Ht:
+                out = out + (state[9].at[it % Ht].set(
+                    jnp.stack([alpha, beta2])
+                ),)
+            return out
+
+        init_l = (
+            xv, r, jnp.zeros_like(xv), rz0, rs0,
+            jnp.zeros((K,), bv.dtype), jnp.zeros((K,), jnp.int32),
+            jnp.int32(0), hist,
+        )
+        if Ht:
+            init_l = init_l + (jnp.zeros((Ht, 2, K), dtype=bv.dtype),)
+        fin = _krylov_loop(cond_l, step_l, init_l)
+        x, rs, itk, hist = fin[0], fin[4], fin[6], fin[8]
+        out = (x.reshape((K, -1)).T[None], rs, rs0, itk, hist)
+        return out + ((fin[9],) if Ht else ())
 
     @jax.jit
     def fn(b, x0, mv, m):
@@ -4199,15 +4426,7 @@ def make_block_cg_fn(
             it0 = jnp.zeros((K,), jnp.int32)
 
             def active(rs, rz):
-                # the SAME per-column predicate the K=1 cond tests: a
-                # column below tol, non-finite, or (preconditioned)
-                # broken down is permanently inactive — its state is
-                # frozen, so the predicate stays False once it trips
-                go = jnp.sqrt(rs) > tol * jnp.maximum(1.0, jnp.sqrt(rs0))
-                go = jnp.logical_and(go, jnp.isfinite(rs))
-                if precond:
-                    go = jnp.logical_and(go, rz != 0)
-                return go
+                return still_active(rs, rz, rs0)
 
             def _sel(act, new, old):
                 # per-column freeze: re-select the OLD value so a frozen
@@ -4692,7 +4911,7 @@ def make_block_cg_fn(
 
         nouts = 4 + (1 if sdccfg is not None else 0) + (1 if Ht else 0)
         return shard_map(
-            shard_fn,
+            shard_lanes if lanes else shard_fn,
             mesh=mesh,
             in_specs=(spec, spec, spec, specs),
             out_specs=(spec,) + (none_spec,) * nouts,
@@ -4727,6 +4946,7 @@ def make_block_cg_fn(
     run.operands = ops
     run.fused = bool(fused)
     run.rhs_batch = K
+    run.block_layout = "lanes" if lanes else "columns"
     run.has_sdc = sdccfg is not None
     run.trace_iters = Ht
     run.comms_kwargs = dict(
@@ -5913,6 +6133,16 @@ def tpu_block_cg(
             A, B, X0, tol, maxiter, verbose, minv, fused, K, backend,
             dt, name, rec, column_errors=column_errors,
         )
+        # which layout the program held the block in (`_block_lane_major`;
+        # the operator is staged by now, so this is a look-up)
+        lanes = _block_lane_major(
+            device_matrix(A, backend), fused,
+            _sdc_config(int(maxiter)) is not None,
+        )
+        info["block_layout"] = rec.config["block_layout"] = (
+            "lanes" if lanes else "columns"
+        )
+        telemetry.bump("solve.block_lane_major", int(lanes))
         return xs, rec.finish(info)
 
 

@@ -166,6 +166,12 @@ CATALOG: Dict[str, MetricSpec] = {
         _spec("solve.host_lifts", "counter", "1",
               "parallel/tpu.py:_count_vector",
               "vectors fetched as a whole frame and lifted on the host"),
+        _spec("solve.block_lane_major", "counter", "1",
+              "parallel/tpu.py:tpu_block_cg",
+              "block solves whose program held the K columns lane-major, "
+              "(K, W), each through the solo solve's coded kernel (the "
+              "record's block_layout says 'lanes'; every other block "
+              "solve says 'columns' and leaves this unchanged)"),
         # -- the supernode-dense lowering, where an operator is staged --
         _spec("lowering.sd.nnz", "counter", "1",
               "parallel/tpu.py:_count_sd_lowering",

@@ -19,6 +19,12 @@ The block program's three contracts, each pinned here:
   XLA-DIA, SD, BSR, ELL) accepts the (P, W, K) block operand and agrees
   with K separate SpMVs (bitwise under strict-bits, where the ELL path
   is the oracle).
+* **One recurrence, two layouts.** Where the operator's A_oo block runs
+  the coded Mosaic kernel on the padded frame, the fused block body
+  holds its K columns lane-major and sends each through the solo
+  solve's kernel (`_block_lane_major`; PR 35): the same three contracts,
+  `info["block_layout"] == "lanes"`; every other operator, body and
+  mode keeps the (W, K) body and says ``"columns"``.
 """
 import numpy as np
 import pytest
@@ -43,7 +49,7 @@ from partitionedarrays_jl_tpu.parallel.tpu import (
     tpu_cg,
 )
 
-from test_fused_cg import _fixture_spd_system
+from test_fused_cg import _fixture_spd_system, _padded_frame
 
 
 def _backend(n=8):
@@ -52,8 +58,8 @@ def _backend(n=8):
     return TPUBackend(devices=jax.devices()[:n])
 
 
-def _rand_rhs(A, seed):
-    v = pa.PVector.full(0.0, A.cols)
+def _rand_rhs(A, seed, dtype=np.float64):
+    v = pa.PVector.full(0.0, A.cols, dtype=dtype)
 
     def fill(i, vals):
         rng = np.random.default_rng(seed + int(i.part))
@@ -412,11 +418,19 @@ from partitionedarrays_jl_tpu.analysis import collective_counts  # noqa: E402
 
 @pytest.mark.parametrize("fused", [False, True])
 @pytest.mark.parametrize("precond", [False, True])
-def test_block_collective_count_k_independent(fused, precond):
+@pytest.mark.parametrize("frame", ["compact", "padded"])
+def test_block_collective_count_k_independent(fused, precond, frame, monkeypatch):
+    """``frame='padded'`` is the real-TPU frame with the interpret-mode
+    kernel: its fused body is the lane-major one, whose columns ride ONE
+    ppermute a direction as a stacked payload and one all_gather a dot."""
+    dtype = np.float64
+    if frame == "padded":
+        _padded_frame(monkeypatch)
+        dtype = np.float32
     backend = _backend()
 
     def driver(parts):
-        A, b, xe, x0 = assemble_poisson(parts, (6, 6, 6))
+        A, b, xe, x0 = assemble_poisson(parts, (6, 6, 6), dtype=dtype)
         return A, b
 
     A, b = pa.prun(driver, backend, (2, 2, 2))
@@ -433,12 +447,15 @@ def test_block_collective_count_k_independent(fused, precond):
         Bs = [b] * K
         db = _block_on_cols_layout(Bs, dA)
         dx0 = _block_on_cols_layout(
-            [pa.PVector.full(0.0, A.cols) for _ in range(K)],
+            [pa.PVector.full(0.0, A.cols, dtype=dtype) for _ in range(K)],
             dA, with_ghosts=True,
         )
         fn = make_cg_fn(
             dA, tol=1e-9, maxiter=50, fused=fused, precond=precond,
             rhs_batch=K,
+        )
+        assert fn.block_layout == (
+            "lanes" if fused and frame == "padded" else "columns"
         )
         counts[K] = collective_counts(
             fn, db, dx0, db[..., 0] if mv is None else mv, ops
@@ -447,27 +464,30 @@ def test_block_collective_count_k_independent(fused, precond):
     assert counts[1] == counts[4] == counts[8], counts
 
 
-def test_block_matches_solo_collective_counts():
+@pytest.mark.parametrize("frame", ["compact", "padded"])
+def test_block_matches_solo_collective_counts(frame, monkeypatch):
     """The K=1 block program must not pay MORE collectives than the solo
     program of the same body — widening payloads is free, extra rounds
-    are not."""
+    are not. On the padded frame the fused K=1 block program is the
+    lane-major body: the solo body plus selects."""
+    dtype = np.float64
+    if frame == "padded":
+        _padded_frame(monkeypatch)
+        dtype = np.float32
     backend = _backend()
 
     def driver(parts):
-        A, b, xe, x0 = assemble_poisson(parts, (6, 6, 6))
+        A, b, xe, x0 = assemble_poisson(parts, (6, 6, 6), dtype=dtype)
         return A, b
 
     A, b = pa.prun(driver, backend, (2, 2, 2))
     dA = device_matrix(A, backend)
     ops = _matrix_operands(dA)
+    zero = pa.PVector.full(0.0, A.cols, dtype=dtype)
     db1 = _block_on_cols_layout([b], dA)
-    dx01 = _block_on_cols_layout(
-        [pa.PVector.full(0.0, A.cols)], dA, with_ghosts=True
-    )
+    dx01 = _block_on_cols_layout([zero], dA, with_ghosts=True)
     db = DeviceVector.from_pvector(b, backend, dA.col_layout)
-    dx0 = DeviceVector.from_pvector(
-        pa.PVector.full(0.0, A.cols), backend, dA.col_layout
-    )
+    dx0 = DeviceVector.from_pvector(zero, backend, dA.col_layout)
     for fused in (False, True):
         blk = make_cg_fn(dA, tol=1e-9, maxiter=50, fused=fused, rhs_batch=1)
         solo = make_cg_fn(dA, tol=1e-9, maxiter=50, fused=fused)
@@ -475,6 +495,249 @@ def test_block_matches_solo_collective_counts():
         cs = collective_counts(solo, db.data, dx0.data, db.data, ops)
         for kind in cs:
             assert cb[kind] <= cs[kind], (fused, kind, cb, cs)
+
+
+# ---------------------------------------------------------------------------
+# the lane-major body (PR 35): the columns ride the lanes, each through
+# the solo solve's coded kernel
+# ---------------------------------------------------------------------------
+
+
+def _lanes_grew():
+    from partitionedarrays_jl_tpu import telemetry
+
+    before = telemetry.counters("solve").get("solve.block_lane_major", 0)
+    return lambda: (
+        telemetry.counters("solve").get("solve.block_lane_major", 0) - before
+    )
+
+
+_LANES_SYSTEMS = {}
+
+
+def _lanes_system(grid, precond):
+    """float32 Poisson on the padded frame, its four columns (the
+    assembled system from its own start, and three random right-hand
+    sides from zero) and every column's solo `tpu_cg` solve: built once
+    a ``(grid, precond)`` and shared by the widths."""
+    key = (grid, precond)
+    if key not in _LANES_SYSTEMS:
+        backend = _backend(int(np.prod(grid)))
+
+        def driver(parts):
+            A, b, xe, x0 = assemble_poisson(parts, (8, 8, 8), dtype=np.float32)
+            return A, b, x0
+
+        A, b, x0 = pa.prun(driver, backend, grid)
+        dA = device_matrix(A, backend)
+        assert dA.padded and dA.dia_mode == "coded"
+        assert dA.pallas_plan is not None
+        B = [b] + [_rand_rhs(A, 7 * k, np.float32) for k in (1, 2, 3)]
+        X0 = [x0] + [pa.PVector.full(0.0, A.cols, dtype=np.float32)] * 3
+        mv = jacobi_preconditioner(A) if precond else None
+        solo = [
+            tpu_cg(A, bk, x0=xk, tol=1e-5, maxiter=80, minv=mv)
+            for bk, xk in zip(B, X0)
+        ]
+        _LANES_SYSTEMS[key] = (A, B, X0, mv, solo)
+    return _LANES_SYSTEMS[key]
+
+
+@pytest.mark.parametrize("precond", [False, True], ids=["cg", "jacobi"])
+@pytest.mark.parametrize("K", [1, 2, 3, 4])
+@pytest.mark.parametrize("grid", [(1, 1, 1), (2, 2, 1)], ids=["1part", "4parts"])
+def test_lane_major_block_matches_solo(grid, K, precond, monkeypatch):
+    """On the padded frame (interpret-mode kernel) the fused block body
+    is lane-major: every column takes its solo `tpu_cg` iteration count
+    and agrees with its solo answer to float32 rounding, with and without
+    the Jacobi row (under it the fold is the jnp one and the product the
+    plain coded kernel), on one part and on four; the record and the
+    counter say which body ran."""
+    _padded_frame(monkeypatch)
+    A, B, X0, mv, solo = _lanes_system(grid, precond)
+    grew = _lanes_grew()
+    xs, info = tpu_block_cg(
+        A, B[:K], X0=X0[:K], tol=1e-5, maxiter=80, minv=mv
+    )
+    assert info["cg_body"] == "fused" and info["rhs_batch"] == K
+    assert info["block_layout"] == "lanes"
+    assert info.record.config["block_layout"] == "lanes"
+    assert grew() == 1
+    its = info["iterations_per_column"]
+    assert its == [ik["iterations"] for _x, ik in solo[:K]], its
+    if K > 1:
+        assert len(set(its)) > 1, f"block is not ragged: {its}"
+    for k in range(K):
+        xk, ik = solo[k]
+        ref = gather_pvector(xk)
+        np.testing.assert_allclose(
+            gather_pvector(xs[k]), ref, rtol=0,
+            atol=2e-5 * max(1.0, float(np.abs(ref).max())),
+        )
+        n = ik["iterations"] + 1
+        hist_k = np.asarray(info["columns"][k]["residuals"])
+        assert len(hist_k) == n  # nothing logged past the freeze point
+        np.testing.assert_allclose(
+            hist_k, np.asarray(ik["residuals"])[:n], rtol=2e-3
+        )
+
+
+def test_lane_major_frozen_columns_stay_bitwise_still(monkeypatch):
+    """The per-column freeze of the lane-major body: a column that
+    converges tens of trips before the others and a column given a zero
+    right-hand side never move a bit of x or r once frozen. The early
+    column's answer and residual after the whole block has finished are
+    bitwise what they were when the loop was cut at its own last trip;
+    the zero column stays exactly zero and counts no iteration."""
+    _padded_frame(monkeypatch)
+    A, B, X0, _mv, solo = _lanes_system((2, 2, 1), False)
+    zero = pa.PVector.full(0.0, A.cols, dtype=np.float32)
+    counts = [ik["iterations"] for _x, ik in solo]
+    slow, early = int(np.argmax(counts)), int(np.argmin(counts))
+    B3, X3 = [B[slow], B[early], zero], [X0[slow], X0[early], zero]
+    xs, info = tpu_block_cg(A, B3, X0=X3, tol=1e-5, maxiter=80)
+    assert info["block_layout"] == "lanes"
+    its = info["iterations_per_column"]
+    assert its == [counts[slow], counts[early], 0], (its, counts)
+    assert its[0] - its[1] >= 20, its
+    # cut the same block at the early column's last trip
+    xs_cut, info_cut = tpu_block_cg(A, B3, X0=X3, tol=1e-5, maxiter=its[1])
+    assert info_cut["iterations_per_column"] == [its[1], its[1], 0]
+    np.testing.assert_array_equal(
+        gather_pvector(xs[1]), gather_pvector(xs_cut[1])
+    )
+    np.testing.assert_array_equal(
+        np.asarray(info["columns"][1]["residuals"]),
+        np.asarray(info_cut["columns"][1]["residuals"]),
+    )
+    np.testing.assert_array_equal(
+        gather_pvector(xs[2]), np.zeros_like(gather_pvector(xs[2]))
+    )
+    assert info["columns"][2]["converged"]
+    assert np.all(np.isfinite(gather_pvector(xs[0])))
+
+
+def test_served_slabs_run_the_lane_major_body(monkeypatch):
+    """A `SolveService` over the padded-frame coded operator: every slab
+    is one block solve of the lane-major body, so `solve.block_lane_major`
+    grows with `service.slabs`, every block solve's record says
+    ``block_layout: "lanes"``, and every request gets the answer of its
+    own right-hand side whatever slab and row it rode in."""
+    from partitionedarrays_jl_tpu import telemetry
+    from partitionedarrays_jl_tpu.service import SolveService
+
+    _padded_frame(monkeypatch)
+    A, B, X0, _mv, solo = _lanes_system((2, 2, 1), False)
+    before = telemetry.counters("service").get("service.slabs", 0)
+    grew = _lanes_grew()
+    seq0 = max((r.seq for r in telemetry.record_history()), default=0)
+    svc = SolveService(A, kmax=4)
+    order = [2, 0, 3, 1, 0]  # a slab of four, then one alone
+    hs = [svc.submit(B[k], x0=X0[k], tol=1e-5, maxiter=80) for k in order]
+    svc.drain()
+    slabs = telemetry.counters("service").get("service.slabs", 0) - before
+    assert slabs == 2 and grew() == slabs
+    for h, k in zip(hs, order):
+        x, info = h.wait(0.0)
+        assert info["converged"]
+        assert info["iterations"] == solo[k][1]["iterations"]
+        ref = gather_pvector(solo[k][0])
+        np.testing.assert_allclose(
+            gather_pvector(x), ref, rtol=0,
+            atol=2e-5 * max(1.0, float(np.abs(ref).max())),
+        )
+    blocks = [
+        r for r in telemetry.record_history()
+        if r.seq > seq0 and r.solver == "block-cg"
+    ]
+    assert [r.config["rhs_batch"] for r in blocks] == [4, 1]
+    assert all(r.config["block_layout"] == "lanes" for r in blocks)
+
+
+def _columns_case(case, monkeypatch):
+    """A block system whose program must keep the (W, K) body, and why."""
+    fused = True
+    if case in ("sd", "ell"):
+        from partitionedarrays_jl_tpu.models.elasticity_tet import (
+            assemble_elasticity_tet,
+        )
+
+        if case == "ell":
+            monkeypatch.setenv("PA_TPU_SD", "0")
+            monkeypatch.setenv("PA_TPU_BSR", "0")
+        backend = _backend(4)
+        A, b = pa.prun(
+            lambda parts: assemble_elasticity_tet(parts, (4, 4, 4))[:2],
+            backend, 4,
+        )
+        dA = device_matrix(A, backend)
+        assert (dA.sd_bs == 3) if case == "sd" else (dA.oo_vals is not None)
+    elif case == "coded-compact":
+        # the coded operator off the padded frame: no kernel, the XLA form
+        backend = _backend(4)
+        A, b = pa.prun(_fixture_spd_system, backend, 4)
+        dA = device_matrix(A, backend)
+        assert dA.dia_mode == "coded" and dA.pallas_plan is None
+    else:
+        # the padded-frame coded operator, which alone would run lane-major
+        _padded_frame(monkeypatch)
+        if case == "strict-bits":
+            monkeypatch.setenv("PA_TPU_STRICT_BITS", "1")
+        elif case == "sdc-audit":
+            monkeypatch.setenv("PA_HEALTH_AUDIT_EVERY", "5")
+        elif case == "sdc-abft":
+            monkeypatch.setenv("PA_TPU_ABFT", "1")
+        elif case == "standard-body":
+            fused = False
+        backend = _backend(4)
+
+        def driver(parts):
+            A, b, xe, x0 = assemble_poisson(parts, (8, 8, 8), dtype=np.float32)
+            return A, b
+
+        A, b = pa.prun(driver, backend, (2, 2, 1))
+        dA = device_matrix(A, backend)
+        if case == "strict-bits":
+            assert dA.oo_vals is not None  # pure ELL, off the padded frame
+        else:
+            assert dA.padded and dA.dia_mode == "coded"
+            assert dA.pallas_plan is not None
+    return A, b, fused
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["sd", "ell", "coded-compact", "strict-bits", "sdc-audit", "sdc-abft",
+     "standard-body"],
+)
+def test_other_lowerings_bodies_and_modes_keep_the_columns_layout(
+    case, monkeypatch
+):
+    """The layout follows from the operator's lowering, the body and the
+    SDC mode alone. An SD operator (its ``grc,gck->grk`` products stream
+    the dense blocks once for K columns and want K minor), an ELL
+    operator, the coded operator off the padded frame, strict-bits,
+    either SDC mode and the standard body keep the (W, K) program: the record says ``"columns"``, the counter stands,
+    and the lowered program is the (W, K) one (its loop carries frames of
+    ``W x K`` and none of ``K x W/128 x 128``)."""
+    from partitionedarrays_jl_tpu.ops.pallas_dia import LANES
+
+    A, b, fused = _columns_case(case, monkeypatch)
+    B = [b, _rand_rhs(A, 5, b.dtype)]
+    grew = _lanes_grew()
+    xs, info = tpu_block_cg(A, B, tol=1e-5, maxiter=40, fused=fused)
+    assert info["block_layout"] == "columns"
+    assert info.record.config["block_layout"] == "columns"
+    assert grew() == 0
+    dA = device_matrix(A, xs[0].values.backend)
+    fn = make_cg_fn(dA, tol=1e-5, maxiter=40, fused=fused, rhs_batch=2)
+    assert fn.block_layout == "columns"
+    W = dA.col_layout.W
+    dt = np.asarray(_block_on_cols_layout(B, dA)).dtype
+    z = np.zeros((dA.col_layout.P, W, 2), dtype=dt)
+    text = fn.jit_fn.lower(z, z, z[..., 0], fn.operands).as_text()
+    assert f"tensor<{W}x2x" in text
+    assert f"tensor<2x{W // LANES}x{LANES}x" not in text
 
 
 # ---------------------------------------------------------------------------
