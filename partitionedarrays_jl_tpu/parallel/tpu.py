@@ -32,6 +32,7 @@ import contextlib
 import itertools
 import math
 import os
+import time
 from functools import partial
 from typing import Callable, NamedTuple, Optional, Tuple
 
@@ -1324,6 +1325,12 @@ class DeviceMatrix:
     _CLS_CAP = 64
 
     def __init__(self, A: PSparseMatrix, backend: TPUBackend, padded=None):
+        # the whole lowering under `pa:lower` and its three leaves, timed
+        # for the record of the solve that pays it and counted always
+        with _LowerSpans() as low:
+            self._lower(A, backend, padded, low)
+
+    def _lower(self, A, backend, padded, low) -> None:
         from ..ops.sparse import CSRMatrix, ELLMatrix
         from .. import native
 
@@ -1355,10 +1362,11 @@ class DeviceMatrix:
             # owned/ghost block split it avoids materializes a second
             # full copy of the operator in fresh pages (~65 s of the
             # 1e8-DOF assembly+lowering on the slow-fault bench host).
-            det = self._detect_dia(
-                A, full, P, noids, no_max, np.dtype(dt).itemsize,
-                col_limits=noids, fused_only=True,
-            )
+            with low.leaf("detect"):
+                det = self._detect_dia(
+                    A, full, P, noids, no_max, np.dtype(dt).itemsize,
+                    col_limits=noids, fused_only=True,
+                )
             if det is not None:
                 oh = []
                 for p in range(P):
@@ -1383,9 +1391,10 @@ class DeviceMatrix:
             oo = A.owned_owned_values.part_values()
             oh = A.owned_ghost_values.part_values()
             if not strict_bits():
-                det = self._detect_dia(
-                    A, oo, P, noids, no_max, np.dtype(dt).itemsize
-                )
+                with low.leaf("detect"):
+                    det = self._detect_dia(
+                        A, oo, P, noids, no_max, np.dtype(dt).itemsize
+                    )
         if padded is None:
             # the padded vector frame only pays off when the in-frame coded
             # kernel can actually run; otherwise stay compact even on TPU
@@ -1410,24 +1419,26 @@ class DeviceMatrix:
         self.bsr_cols = self.bsr_vals = self.bsr_bs = None
         self.sd_idx = self.sd_vals = self.sd_g = self.sd_bs = None
         if det is None:
-            sd = self._detect_sd(oo, P, noids, no_max, dt)
+            with low.leaf("detect"):
+                sd = self._detect_sd(oo, P, noids, no_max, dt)
             if sd is not None:
                 self.sd_bs = sd["bs"]
                 self.sd_g = sd["G"]
                 # one staged (idx, vals) pair per width bucket
                 self.sd_idx = tuple(
-                    _stage(backend, c["idx"], P) for c in sd["chunks"]
+                    low.upload(backend, c["idx"], P) for c in sd["chunks"]
                 )
                 self.sd_vals = tuple(
-                    _stage(backend, c["vals"], P) for c in sd["chunks"]
+                    low.upload(backend, c["vals"], P) for c in sd["chunks"]
                 )
                 _count_sd_lowering(sd, sum(m.nnz for m in oo))
             else:
-                bsr = self._detect_bsr(oo, P, noids, no_max, dt)
+                with low.leaf("detect"):
+                    bsr = self._detect_bsr(oo, P, noids, no_max, dt)
                 if bsr is not None:
                     self.bsr_bs = bsr["bs"]
-                    self.bsr_cols = _stage(backend, bsr["cols"], P)
-                    self.bsr_vals = _stage(backend, bsr["vals"], P)
+                    self.bsr_cols = low.upload(backend, bsr["cols"], P)
+                    self.bsr_vals = low.upload(backend, bsr["vals"], P)
         if det is None and self.bsr_bs is None and self.sd_bs is None:
             # pure-ELL path: the only mode whose compiled program reads
             # the O(N x row_width) oo value/col arrays — banded operators
@@ -1452,8 +1463,8 @@ class DeviceMatrix:
                 oo_vals[p, :m] = Eoo.vals
                 # ELL pad cols are 0 with val 0 — safe: o0 is a real slot
                 oo_cols[p, :m] = col_layout.o0 + Eoo.cols
-            self.oo_vals = _stage(backend, oo_vals.astype(dt), P)
-            self.oo_cols = _stage(backend, oo_cols, P)
+            self.oo_vals = low.upload(backend, oo_vals.astype(dt), P)
+            self.oo_cols = low.upload(backend, oo_cols, P)
         else:
             self.oo_vals = self.oo_cols = None
         # A_oh, compact boundary-row form. Only rows touching the ghost
@@ -1472,22 +1483,23 @@ class DeviceMatrix:
         if self.oh_nnz and (self.sd_bs or self.bsr_bs):
             # round-4 directive 7: the boundary block blocks the same
             # way as A_oo — ghost dofs arrive node-triple-contiguous
-            ohb = self._detect_oh_blocks(
-                A, oh, P, self.sd_bs or self.bsr_bs, row_layout, col_layout,
-                dt,
-            )
+            with low.leaf("detect"):
+                ohb = self._detect_oh_blocks(
+                    A, oh, P, self.sd_bs or self.bsr_bs, row_layout,
+                    col_layout, dt,
+                )
         if ohb is not None:
             # one staged (rows, cols, vals) triple per width bucket —
             # the same per-bucket padding the owned SD groups get
             self.ohb_bs = ohb["bs"]
             self.ohb_rows = tuple(
-                _stage(backend, c["rows"], P) for c in ohb["chunks"]
+                low.upload(backend, c["rows"], P) for c in ohb["chunks"]
             )
             self.ohb_cols = tuple(
-                _stage(backend, c["cols"], P) for c in ohb["chunks"]
+                low.upload(backend, c["cols"], P) for c in ohb["chunks"]
             )
             self.ohb_vals = tuple(
-                _stage(backend, c["vals"], P) for c in ohb["chunks"]
+                low.upload(backend, c["vals"], P) for c in ohb["chunks"]
             )
             _count_oh_lowering(
                 self.oh_nnz,
@@ -1496,14 +1508,13 @@ class DeviceMatrix:
         # a box layout keeps the ghosts of a direction in the sender's
         # scan order: where the boundary block is made of face slabs its
         # rows need no index at all
-        ohs = (
-            self._detect_oh_slabs(A, oh, P, col_layout, dt)
-            if self.oh_nnz and ohb is None
-            else None
-        )
+        ohs = None
+        if self.oh_nnz and ohb is None:
+            with low.leaf("detect"):
+                ohs = self._detect_oh_slabs(A, oh, P, col_layout, dt)
         if ohs is not None:
             self.ohs_geo = ohs["geo"]
-            self.ohs_vals = _stage(backend, ohs["vals"], P)
+            self.ohs_vals = low.upload(backend, ohs["vals"], P)
             _count_oh_lowering(self.oh_nnz, slabs=ohs["geo"])
         elif ohb is None and self.oh_nnz:
             nb_max = max(
@@ -1533,9 +1544,9 @@ class DeviceMatrix:
                     oh_cols[p, : len(br)] = col_layout.hid_slots[p][
                         Eoh.cols[br]
                     ]
-            self.oh_vals = _stage(backend, oh_vals.astype(dt), P)
-            self.oh_cols = _stage(backend, oh_cols, P)
-            self.oh_rows = _stage(backend, oh_rows, P)
+            self.oh_vals = low.upload(backend, oh_vals.astype(dt), P)
+            self.oh_cols = low.upload(backend, oh_cols, P)
+            self.oh_rows = low.upload(backend, oh_rows, P)
             _count_oh_lowering(self.oh_nnz, ell_entries=oh_vals.size)
 
         # ABFT checksum row: w = 1ᵀA per part over the local COLUMN
@@ -1547,7 +1558,7 @@ class DeviceMatrix:
         self.abft_w = None
         if _abft_enabled():
             wdt = np.float64 if jax.config.jax_enable_x64 else dt
-            self.abft_w = _stage(
+            self.abft_w = low.upload(
                 backend,
                 self._abft_checksum_row(
                     A, oo, oh, full, P, noids, col_layout
@@ -1661,11 +1672,11 @@ class DeviceMatrix:
                     tuple(bool(np.any(cb[:, d, k] != 0)) for d in range(D))
                     for k in range(kmax)
                 )
-            self.dia_cb = _stage(backend, cb.astype(dt), P)
-            self.dia_no = _stage(
+            self.dia_cb = low.upload(backend, cb.astype(dt), P)
+            self.dia_no = low.upload(
                 backend, noids.astype(np.int32).reshape(P, 1), P
             )
-            self.dia_codes = _stage(backend, codes, P)
+            self.dia_codes = low.upload(backend, codes, P)
         else:
             self.dia_mode = "stream"
             if dia is None:
@@ -1704,7 +1715,7 @@ class DeviceMatrix:
                 dia_stage = dia_stage.reshape(P, D, R, LANES)
             else:
                 dia_stage = dia
-            self.dia_vals = _stage(backend, dia_stage.astype(dt), P)
+            self.dia_vals = low.upload(backend, dia_stage.astype(dt), P)
 
     @staticmethod
     def _abft_checksum_row(A, oo, oh, full, P, noids, col_layout):
@@ -5770,6 +5781,65 @@ def _decode_sdc_outputs(name: str, sdcvec, it=None) -> dict:
             diagnostics=diag,
         )
     return sdc_info
+
+
+class _LowerSpans:
+    """The spans and counters of one `DeviceMatrix.__init__`: ``pa:lower``
+    around it and, under it, exactly one leaf open at any time:
+    ``pa:lower:layout`` (layouts, plans, codebooks: what is neither of
+    the two others) except inside ``leaf("detect")`` (every `_detect_*`)
+    and `upload` (every `_stage` of an operand; its ``pa:stage:put`` nests
+    there). Through `telemetry.annotate`, so the solve that pays the
+    lowering reads them as ``timings["lower"]`` / ``["detect"]`` /
+    ``["layout"]`` / ``["upload"]``. Always-on counters beside them, in
+    whole microseconds: ``lowering.wall_us``, ``.detect_us``,
+    ``.upload_us``, and ``.upload_bytes`` (the host operands' bytes)."""
+
+    def __enter__(self):
+        from .. import telemetry
+        from ..telemetry.metrics import whole_us
+
+        self._annotate = telemetry.annotate
+        self._bump, self._whole_us = telemetry.bump, whole_us
+        self._t0 = time.perf_counter()
+        self._root = self._annotate("pa:lower")
+        self._root.__enter__()
+        self._open_layout()
+        return self
+
+    def _open_layout(self) -> None:
+        self._layout = self._annotate("pa:lower:layout")
+        self._layout.__enter__()
+
+    def _count_us(self, name: str, t0: float) -> None:
+        self._bump(
+            f"lowering.{name}_us",
+            self._whole_us(time.perf_counter() - t0),
+        )
+
+    @contextlib.contextmanager
+    def leaf(self, name: str):
+        """``pa:lower:<name>`` in the layout leaf's place."""
+        self._layout.__exit__(None, None, None)
+        t0 = time.perf_counter()
+        try:
+            with self._annotate(f"pa:lower:{name}"):
+                yield
+        finally:
+            self._count_us(name, t0)
+            self._open_layout()
+
+    def upload(self, backend, arr, nparts: int):
+        """`_stage` of one operand under ``pa:lower:upload``."""
+        with self.leaf("upload"):
+            self._bump("lowering.upload_bytes", int(arr.nbytes))
+            return _stage(backend, arr, nparts)
+
+    def __exit__(self, *exc):
+        self._layout.__exit__(None, None, None)
+        self._root.__exit__(None, None, None)
+        self._count_us("wall", self._t0)
+        return False
 
 
 def _count_sd_lowering(sd: dict, nnz: int) -> None:

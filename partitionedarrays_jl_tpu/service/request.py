@@ -26,6 +26,10 @@ from __future__ import annotations
 import threading
 from typing import Optional
 
+from ..telemetry.metrics import whole_us
+from ..telemetry.registry import registry
+from ..telemetry.trace import profiler_span
+
 __all__ = ["SolveRequest"]
 
 #: Lifecycle states (strings, not an enum: they serialize into events
@@ -68,6 +72,9 @@ class SolveRequest:
         #: total latency, the `service.total_s` histogram's unit of
         #: account and the span `tools/patrace.py --service` renders.
         self.finished_at: Optional[float] = None
+        #: The service's clock, handed over at admission: what `wait`
+        #: reads ``finished_at`` against (``service.handoff_us``).
+        self._clock = None
         self.iterations = 0  # committed across chunks
         self.record = None  # SolveRecord, opened by the service
         #: Distributed-tracing context (`telemetry.tracing.TraceContext`)
@@ -144,13 +151,30 @@ class SolveRequest:
         meanwhile (the worker thread of `SolveService.start`, or
         another thread's ``drain()``). ``timeout`` is in seconds of
         wall clock; past it `TimeoutError` is raised and the request
-        stays what it was. No lock is held while waiting."""
-        if not self._terminal.wait(timeout):
-            raise TimeoutError(
-                f"request {self.id} is still {self.state} after "
-                f"{timeout} s"
-            )
-        return self.result()
+        stays what it was. No lock is held while waiting.
+
+        Entry to return is the span ``pa:service:wait`` on the caller's
+        thread (stat ``request``, the id). A wait that returns an answer
+        counts one ``service.answers`` and adds to
+        ``service.handoff_us`` the service clock now minus
+        ``finished_at``, in whole microseconds: what lies between the
+        request's terminal stamp on the thread that drove its slab and
+        the answer in this caller's hands (for a wait begun after the
+        request had ended, the time the answer lay unclaimed)."""
+        with profiler_span("pa:service:wait", request=self.id):
+            if not self._terminal.wait(timeout):
+                raise TimeoutError(
+                    f"request {self.id} is still {self.state} after "
+                    f"{timeout} s"
+                )
+            answer = self.result()
+            if self._clock is not None and self.finished_at is not None:
+                reg = registry()
+                reg.counter("service.handoff_us").inc(
+                    whole_us(self._clock() - self.finished_at)
+                )
+                reg.counter("service.answers").inc()
+            return answer
 
     def __repr__(self):
         return (

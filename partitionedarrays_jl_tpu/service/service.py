@@ -44,13 +44,15 @@ from __future__ import annotations
 import os
 import threading
 import time
+from contextlib import contextmanager
 from typing import Callable, Optional
 
 from ..telemetry import spectrum, tracing
+from ..telemetry.metrics import whole_us
 from ..telemetry.registry import monitoring_enabled, registry
 from ..telemetry.throughput import model as throughput_model
 from ..telemetry.throughput import operator_fingerprint
-from ..telemetry.trace import profiler_span
+from ..telemetry.trace import annotate, profiler_span
 from ..utils.helpers import check
 from ..utils.locksan import sanitized
 from .admission import (
@@ -190,7 +192,37 @@ class SolveService:
         ``r0_norm`` is an optional precomputed ``‖b‖`` for the paspec
         forecast (the gate's own feasibility check passes it through,
         so the O(n) reduction is paid once per request, not per
-        layer)."""
+        layer).
+
+        The whole call, rejected or admitted, is the span
+        ``pa:service:submit`` on the caller's thread (stat ``request``,
+        the id, once there is one) and counts into
+        ``service.submit_us``: the forecast runs here, BEFORE the
+        submission stamp that ``service.queue_wait_us`` starts from."""
+        with self._timed(
+            profiler_span("pa:service:submit"), "service.submit_us"
+        ) as span:
+            req = self._admit(
+                b, x0, tol, maxiter, deadline, retries, tag, trace, r0_norm
+            )
+            if hasattr(span, "set_metadata"):  # a null context off jax
+                span.set_metadata(request=req.id)
+            return req
+
+    @contextmanager
+    def _timed(self, span, counter: str):
+        """``span`` (a `profiler_span` or `annotate`) and, beside it, its
+        wall time on the service clock added to the always-on counter
+        ``counter`` in whole microseconds, however the body ends."""
+        t0 = self.clock()
+        try:
+            with span:
+                yield span
+        finally:
+            registry().counter(counter).inc(whole_us(self.clock() - t0))
+
+    def _admit(self, b, x0, tol, maxiter, deadline, retries, tag, trace,
+               r0_norm) -> SolveRequest:
         from .. import telemetry
 
         check(tol > 0.0, "service: tol must be positive")
@@ -208,9 +240,12 @@ class SolveService:
         # infeasible deadline is refused typed HERE, before any
         # iteration burns; otherwise the forecast only stamps the
         # record. Unmeasured operators always pass.
-        forecast = self._forecast(
-            b, x0, tol, deadline, tag, r0_norm=r0_norm
-        )
+        with self._timed(
+            annotate("pa:submit:forecast"), "service.forecast_us"
+        ):
+            forecast = self._forecast(
+                b, x0, tol, deadline, tag, r0_norm=r0_norm
+            )
         with self._lock:
             tag = tag or f"req-{self._next_id}"
             try:
@@ -225,6 +260,7 @@ class SolveService:
                 tag=tag,
             )
             self._next_id += 1
+            req._clock = self.clock
             req.submitted_at = self.clock()
             req.trace = trace
             req.forecast = forecast
@@ -273,15 +309,18 @@ class SolveService:
         dt = str(_np.dtype(b.dtype))
         # lazy: one cached O(nnz) digest per operator, paid at the
         # first forecast rather than at service construction
-        spec_fp = spectrum.spectrum_fingerprint(self.A)
+        with annotate("pa:forecast:fingerprint"):
+            spec_fp = spectrum.spectrum_fingerprint(self.A)
         # the common case — an unmeasured operator — must cost nothing:
         # only a measured spec is worth the O(n) norm below
         if not spectrum.has_spec(spec_fp, dt, self._minv_class):
             return None
-        r0 = (
-            float(r0_norm) if r0_norm is not None
-            else spectrum.residual_norm(self.A, b, x0)
-        )
+        registry().counter("service.forecasts").inc()
+        if r0_norm is not None:
+            r0 = float(r0_norm)
+        else:
+            with annotate("pa:forecast:norm"):
+                r0 = spectrum.residual_norm(self.A, b, x0)
         if deadline is not None and spectrum.spec_admit_enabled():
             try:
                 return spectrum.check_deadline_feasible(
@@ -374,10 +413,17 @@ class SolveService:
     def _work(self) -> None:
         while True:
             with self._lock:
-                while not self._queue and not self._stop and not (
-                    self._draining
-                ):
-                    self._cv.wait(timeout=0.05)
+                if not (self._queue or self._stop or self._draining):
+                    # ONE span from the empty queue to the next slab (or
+                    # the stop), however many polls pass: device idle
+                    # under it is "no request in the service"
+                    with self._timed(
+                        profiler_span("pa:service:idle"), "service.idle_us"
+                    ):
+                        while not (
+                            self._queue or self._stop or self._draining
+                        ):
+                            self._cv.wait(timeout=0.05)
                 if self._stop or (self._draining and not self._queue):
                     return
                 slab = self._pop_slab()
@@ -483,9 +529,14 @@ class SolveService:
             reg.gauge("service.inflight_slabs").inc()
         # the slab's whole run as ONE profiler span: the block solves'
         # own ``pa:block-cg:*`` spans nest under it. ``k`` is the width
-        # at formation; ``trips`` (block iterations of all its block
-        # solves) is known at the end and joins the span's stats there
-        span = profiler_span("pa:service:slab", k=len(slab))
+        # at formation and ``requests`` the ids riding then; ``trips``
+        # (block iterations of all its block solves) is known at the end
+        # and joins the span's stats there
+        span = profiler_span(
+            "pa:service:slab", k=len(slab),
+            # "+": a comma ends a stat in the profiler's encoding
+            requests="+".join(str(r.id) for r in slab),
+        )
         try:
             with span:
                 done, trips = self._slab_loop(
@@ -506,10 +557,7 @@ class SolveService:
         service clock."""
         reg.counter("service.slab_columns").inc(len(reqs))
         reg.counter("service.queue_wait_us").inc(
-            sum(
-                int(round(1e6 * max(0.0, now - r.submitted_at)))
-                for r in reqs
-            )
+            sum(whole_us(now - r.submitted_at) for r in reqs)
         )
 
     def _slab_loop(self, active, X, tol, key, budget, chunked, targets,

@@ -26,12 +26,14 @@ docs/observability.md catalog table).
 """
 from __future__ import annotations
 
+import threading
 from typing import Dict, Optional
 
 from .registry import registry
 
 __all__ = [
     "bump",
+    "whole_us",
     "get",
     "snapshot",
     "reset",
@@ -42,6 +44,12 @@ __all__ = [
 def bump(name: str, n: int = 1) -> int:
     """Increment counter ``name`` by ``n`` and return the new value."""
     return registry().counter(name).inc(n)
+
+
+def whole_us(seconds: float) -> int:
+    """A stretch of time as the ``*_us`` counters keep it: whole
+    microseconds, never negative."""
+    return int(round(1e6 * max(0.0, seconds)))
 
 
 def get(name: str) -> int:
@@ -73,11 +81,35 @@ _JAX_EVENT_COUNTERS = {
     "/jax/compilation_cache/cache_misses": "persistent_cache.miss",
 }
 
+#: jax.monitoring events (jax 0.9.0) -> the ``compile.*_us`` counters:
+#: the TIME SPANS JAX reports (`record_event_time_span`) around the
+#: tracing of a jitted function to a jaxpr (`pjit.py`, `pxla.py`), the
+#: lowering of a jaxpr to an MLIR module (`pxla.py`) and the backend's
+#: compilation (`pxla.py`, around `compile_or_get_cached`), and the
+#: DURATION it reports, inside that last one, for the retrieval of an
+#: executable from the persistent cache (`compiler.py`, on a hit only).
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_JAX_SPAN_COUNTERS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace_us",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower_us",
+    _BACKEND_EVENT: "compile.backend_us",
+}
+
 
 def install_jax_cache_listeners() -> bool:
-    """Bridge JAX's persistent-compilation-cache monitoring events into
-    ``persistent_cache.{hit,miss}``. Idempotent (a second registration
-    would double-count every hit); returns True."""
+    """Bridge JAX's monitoring events into the registry: the
+    persistent-compilation-cache events into
+    ``persistent_cache.{hit,miss}``, and what JAX reports around
+    tracing, lowering to MLIR, backend compilation and a cache retrieval
+    into ``compile.{trace,lower,backend,cache_load}_us`` (whole
+    microseconds, summed over the process) with ``compile.programs``
+    (backend compile events, loads included). JAX's spans NEST (a jit
+    traced inside a jit reports inside the outer trace, a retrieval
+    inside the backend event that made it): each counter takes a span's
+    SELF time, what no span inside it has counted already, so that the
+    four add up to the time spent. Idempotent (a second registration
+    would double-count); returns True."""
     global _jax_listeners_installed
     if _jax_listeners_installed:
         return True
@@ -89,5 +121,35 @@ def install_jax_cache_listeners() -> bool:
         if name:
             bump(name)
 
+    # a compile runs on the thread that asked for it, and so do its
+    # events, each as it ENDS: ``mine.spans`` holds the disjoint
+    # ``(start, end)`` counted so far on this thread, in time order (one
+    # entry a top-level event: as many as the process compiles programs),
+    # ``mine.loaded`` the retrieval waiting for its backend event
+    mine = threading.local()
+
+    def _on_load(event: str, secs: float, **kw) -> None:
+        if event == _CACHE_LOAD_EVENT:
+            mine.loaded = getattr(mine, "loaded", 0.0) + secs
+            bump("compile.cache_load_us", whole_us(secs))
+
+    def _on_span(event: str, start: float, end: float, **kw) -> None:
+        name = _JAX_SPAN_COUNTERS.get(event)
+        if name is None:
+            return
+        spans = mine.__dict__.setdefault("spans", [])
+        secs = end - start
+        while spans and spans[-1][0] >= start:  # ended inside this one
+            s, e = spans.pop()
+            secs -= e - s
+        spans.append((start, end))
+        if event == _BACKEND_EVENT:
+            secs -= getattr(mine, "loaded", 0.0)
+            mine.loaded = 0.0
+            bump("compile.programs")
+        bump(name, whole_us(secs))
+
     jm.register_event_listener(_on_event)
+    jm.register_event_duration_secs_listener(_on_load)
+    jm.register_event_time_span_listener(_on_span)
     return True

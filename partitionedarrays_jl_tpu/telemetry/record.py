@@ -16,7 +16,13 @@ attribute away (``info.record``).
 
 Scoping: records nest (``solve_with_recovery`` wraps the records of its
 inner attempts), and `emit_event` appends to EVERY active record so the
-outer record sees the whole story. A record is finalized exactly once —
+outer record sees the whole story. `current_record` is the calling
+thread's record: the innermost one a `solve_scope` opened ON THAT
+THREAD, which is where `telemetry.trace.annotate` adds a span's time —
+a record opened with a bare `begin_record` (the service's
+``"service-request"``) hears every event and is on no thread's stack,
+so a block solve on the worker's thread keeps its own ``timings``
+while clients submit. A record is finalized exactly once —
 on `finish` (success) or by the `solve_scope` context manager on an
 exception (the aborted record still lands in the history ring with its
 events: that is what `tools/patrace.py` post-mortems read).
@@ -44,6 +50,7 @@ from __future__ import annotations
 
 import json
 import os
+import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
@@ -155,7 +162,7 @@ def _pa_env_snapshot() -> Dict[str, str]:
 
 class SolveRecord:
     """One solve's telemetry. Create via `begin_record` / `solve_scope`
-    so the active-record stack stays consistent."""
+    so the active set and the opening thread's stack stay consistent."""
 
     def __init__(self, solver: str, config: Optional[dict] = None,
                  enabled: Optional[bool] = None):
@@ -200,6 +207,9 @@ class SolveRecord:
         self.error: Optional[dict] = None
         self.wall_s: Optional[float] = None
         self.finished = False
+        #: The thread stack a `solve_scope` put this record on (None for
+        #: a bare `begin_record`): where `_retire` takes it out.
+        self._scope: Optional[List["SolveRecord"]] = None
 
     # -- event log -------------------------------------------------------
     def event(self, kind: str, label: str = "",
@@ -309,40 +319,49 @@ class SolveRecord:
 
 
 # ---------------------------------------------------------------------------
-# active-record stack + finished-record ring
+# active records, the threads' scope stacks, the finished-record ring
 # ---------------------------------------------------------------------------
 
-#: The stack and ring share the REGISTRY lock (an RLock): the service
-#: background worker mutates counters, records, and the ring from its
-#: thread while the submitting thread does the same — one lock means
+#: The active set and the ring share the REGISTRY lock (an RLock): the
+#: service background worker mutates counters, records, and the ring from
+#: its thread while the submitting thread does the same — one lock means
 #: one ordering (the PR 9 thread-safety satellite; hammer-tested in
-#: tests/test_pamon.py). Previously this module carried its own lock
-#: and `SolveRecord.event` appended with none at all.
+#: tests/test_pamon.py).
 _lock = registry().lock
-_stack: List[SolveRecord] = []
+#: Every open record, whichever thread began it: what `emit_event` reaches.
+_active: List[SolveRecord] = []
+#: ``_tls.stack``: the records `solve_scope` opened on THIS thread and has
+#: not closed, innermost last: what `current_record` (and so `annotate`)
+#: reads, with no lock.
+_tls = threading.local()
 _history: List[SolveRecord] = []
 _seq = 0
 _begun = 0
 
 
 def begin_record(solver: str, **config) -> SolveRecord:
-    """Open a record and push it onto the active stack. Always returns
-    a record object (inert when ``PA_METRICS=0``) so call sites never
-    branch."""
+    """Open a record and add it to the active set (`emit_event` reaches
+    it from every thread; it is on no thread's `current_record` stack:
+    `solve_scope` does that). Always returns a record object (inert when
+    ``PA_METRICS=0``) so call sites never branch."""
     global _begun
     rec = SolveRecord(solver, config=config)
     with _lock:
         _begun += 1
         rec.seq = _begun
         if rec.enabled:
-            _stack.append(rec)
+            _active.append(rec)
     return rec
 
 
 def _retire(rec: SolveRecord) -> None:
+    """Take ``rec`` out wherever it is (the active set, and the stack of
+    the thread whose `solve_scope` opened it) and archive it."""
     with _lock:
-        if rec in _stack:
-            _stack.remove(rec)
+        if rec in _active:
+            _active.remove(rec)
+        if rec._scope is not None and rec in rec._scope:
+            rec._scope.remove(rec)
         if rec.enabled:
             _history.append(rec)
             del _history[: max(0, len(_history) - history_depth())]
@@ -366,7 +385,7 @@ def emit_event(kind: str, label: str = "", iteration: Optional[int] = None,
             details.setdefault("trace_id", ctx.trace_id)
             details.setdefault("span_id", ctx.span_id)
         with _lock:
-            recs = list(_stack)
+            recs = list(_active)
         for rec in recs:
             rec.event(kind, label=label, iteration=iteration, **details)
     except Exception:
@@ -374,8 +393,11 @@ def emit_event(kind: str, label: str = "", iteration: Optional[int] = None,
 
 
 def current_record() -> Optional[SolveRecord]:
-    with _lock:
-        return _stack[-1] if _stack else None
+    """The calling thread's record: the innermost one a `solve_scope`
+    opened on THIS thread and that is not finished, or None. No lock:
+    the stack is the thread's own."""
+    stack = getattr(_tls, "stack", None)
+    return stack[-1] if stack else None
 
 
 def last_record(solver: Optional[str] = None) -> Optional[SolveRecord]:
@@ -399,8 +421,9 @@ def clear_history() -> None:
 
 @contextmanager
 def solve_scope(solver: str, **config):
-    """``with solve_scope("cg", tol=...) as rec:`` — opens a record; a
-    raising body finalizes it as an aborted record (events retained), a
+    """``with solve_scope("cg", tol=...) as rec:`` — opens a record and
+    makes it the calling thread's `current_record` until it is finished;
+    a raising body finalizes it as an aborted record (events retained), a
     clean body is expected to call ``rec.finish(info)`` itself (the
     scope closes it empty otherwise). The whole scope is one ``pa:solve``
     profiler span carrying ``solver`` and the record's ``seq`` as
@@ -409,6 +432,11 @@ def solve_scope(solver: str, **config):
     from .trace import profiler_span
 
     rec = begin_record(solver, **config)
+    if rec.enabled:
+        if not hasattr(_tls, "stack"):
+            _tls.stack = []
+        rec._scope = _tls.stack
+        rec._scope.append(rec)
     with profiler_span("pa:solve", solver=solver, seq=rec.seq):
         try:
             yield rec

@@ -134,6 +134,29 @@ CATALOG: Dict[str, MetricSpec] = {
         _spec("persistent_cache.miss", "counter", "1",
               "telemetry/metrics.py:install_jax_cache_listeners",
               "JAX on-disk XLA executable cache miss"),
+        # -- what JAX reports of its own compile path, summed (PR 36) --
+        _spec("compile.trace_us", "counter", "us",
+              "telemetry/metrics.py:install_jax_cache_listeners",
+              "tracing of jitted functions to jaxprs, as JAX reports it "
+              "(/jax/core/compile/jaxpr_trace_duration), each span's "
+              "self time: a jit traced inside another counts once"),
+        _spec("compile.lower_us", "counter", "us",
+              "telemetry/metrics.py:install_jax_cache_listeners",
+              "lowering of jaxprs to MLIR modules "
+              "(/jax/core/compile/jaxpr_to_mlir_module_duration)"),
+        _spec("compile.backend_us", "counter", "us",
+              "telemetry/metrics.py:install_jax_cache_listeners",
+              "backend compilation "
+              "(/jax/core/compile/backend_compile_duration), less the "
+              "cache retrievals JAX times inside it: XLA and Mosaic at "
+              "work, and a miss's look in the cache"),
+        _spec("compile.cache_load_us", "counter", "us",
+              "telemetry/metrics.py:install_jax_cache_listeners",
+              "retrieval of executables from the persistent cache, hits "
+              "only (/jax/compilation_cache/cache_retrieval_time_sec)"),
+        _spec("compile.programs", "counter", "1",
+              "telemetry/metrics.py:install_jax_cache_listeners",
+              "backend compile events: programs compiled or loaded"),
         _spec("events.*", "counter", "1",
               "telemetry/record.py:emit_event",
               "one counter per telemetry event kind emitted"),
@@ -172,6 +195,22 @@ CATALOG: Dict[str, MetricSpec] = {
               "(K, W), each through the solo solve's coded kernel (the "
               "record's block_layout says 'lanes'; every other block "
               "solve says 'columns' and leaves this unchanged)"),
+        # -- one operator's lowering, DeviceMatrix.__init__ (PR 36) ------
+        _spec("lowering.wall_us", "counter", "us",
+              "parallel/tpu.py:_LowerSpans",
+              "wall time of DeviceMatrix.__init__ (span pa:lower), "
+              "summed over the operators lowered"),
+        _spec("lowering.detect_us", "counter", "us",
+              "parallel/tpu.py:_LowerSpans",
+              "of it, inside the _detect_* analyses (span "
+              "pa:lower:detect)"),
+        _spec("lowering.upload_us", "counter", "us",
+              "parallel/tpu.py:_LowerSpans",
+              "of it, inside _stage of the operands (span "
+              "pa:lower:upload); the rest is pa:lower:layout"),
+        _spec("lowering.upload_bytes", "counter", "bytes",
+              "parallel/tpu.py:_LowerSpans",
+              "bytes of the host operands handed to _stage there"),
         # -- the supernode-dense lowering, where an operator is staged --
         _spec("lowering.sd.nnz", "counter", "1",
               "parallel/tpu.py:_count_sd_lowering",
@@ -252,7 +291,7 @@ CATALOG: Dict[str, MetricSpec] = {
               "block (one view an exchange): every other sub-box"),
         # -- service lifecycle counters -------------------------------
         _spec("service.admitted", "counter", "1",
-              "service/service.py:submit",
+              "service/service.py:_admit",
               "requests admitted past the bounded queue"),
         _spec("service.rejected", "counter", "1",
               "service/admission.py:AdmissionRejected",
@@ -301,9 +340,36 @@ CATALOG: Dict[str, MetricSpec] = {
               "service/service.py:_count_columns",
               "submission to slab formation (or top-up), summed over "
               "requests, in whole microseconds of the service clock"),
+        # -- a request's path outside its slab (PR 36; service clock) --
+        _spec("service.submit_us", "counter", "us",
+              "service/service.py:submit",
+              "wall time of submit calls, rejected or admitted (span "
+              "pa:service:submit), in whole microseconds; the forecast "
+              "is inside, the queue wait starts after"),
+        _spec("service.forecast_us", "counter", "us",
+              "service/service.py:_admit",
+              "of it, inside the paspec forecast (span "
+              "pa:submit:forecast)"),
+        _spec("service.forecasts", "counter", "1",
+              "service/service.py:_forecast",
+              "forecasts that got as far as the residual norm (a "
+              "measured operator)"),
+        _spec("service.idle_us", "counter", "us",
+              "service/service.py:_work",
+              "the worker thread with an empty queue, from finding it "
+              "empty to the next slab or the stop (span "
+              "pa:service:idle)"),
+        _spec("service.handoff_us", "counter", "us",
+              "service/request.py:wait",
+              "a request's terminal stamp (finished_at) to its answer "
+              "in the waiting caller's hands, summed over the waits "
+              "that returned an answer"),
+        _spec("service.answers", "counter", "1",
+              "service/request.py:wait",
+              "waits that returned an answer"),
         # -- service gauges (PA_MON-gated) ----------------------------
         _spec("service.queue_depth", "gauge", "requests",
-              "service/service.py:submit/_pop_slab",
+              "service/service.py:_admit/_pop_slab",
               "queued requests right now"),
         _spec("service.inflight_slabs", "gauge", "slabs",
               "service/service.py:_run_slab",

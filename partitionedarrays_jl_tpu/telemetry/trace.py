@@ -16,9 +16,12 @@ and timer sections from the same process land on one coherent timeline.
 `annotate` is the one span helper of the device solve path: it opens a
 ``jax.profiler.TraceAnnotation`` (so the span lands in a captured
 profile on the same clock as the device ops) AND adds the span's
-``perf_counter`` duration to the current record's ``timings`` under the
-span's last component, so an operator without a profiler reads the same
-split from ``info.record.timings``. The spans a device solve opens
+``perf_counter`` duration to the calling thread's record
+(`record.current_record`: the innermost `solve_scope` open on the
+thread that opens the span, never another thread's and never a
+request's), under the span's last component of ``timings``, so an
+operator without a profiler reads the same split from
+``info.record.timings``. The spans a device solve opens
 (``pa:solve`` root from `solve_scope`; ``pa:<solver>:stage|solve|wait|
 fetch|finish`` and the ``pa:stage:*`` / ``pa:fetch:*`` leaves from
 ``parallel/tpu.py`` `_run_krylov` / `_tpu_block_cg_impl`) are listed in
@@ -60,11 +63,13 @@ def profiler_span(name: str, **stats):
 @contextmanager
 def annotate(name: str):
     """``with annotate("pa:cg:stage"): ...`` — a profiler span (see
-    `profiler_span`) whose wall time is also added to the current
-    record's ``timings[<last component of name>]`` when that record is
-    enabled: ``pa:cg:stage`` -> ``timings["stage"]``, ``pa:stage:pack``
-    -> ``timings["pack"]`` (spans that repeat inside one solve add up).
-    Inactive, a span costs well under a microsecond."""
+    `profiler_span`) whose wall time is also added to the calling
+    thread's record, ``timings[<last component of name>]``, when the
+    thread has one and it is enabled: ``pa:cg:stage`` ->
+    ``timings["stage"]``, ``pa:stage:pack`` -> ``timings["pack"]``
+    (spans that repeat inside one solve add up). Outside any
+    `solve_scope` of the thread it is the profiler span alone. Inactive,
+    a span costs well under a microsecond."""
     rec = current_record()
     timed = rec is not None and rec.enabled
     t0 = time.perf_counter() if timed else 0.0

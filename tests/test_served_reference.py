@@ -277,9 +277,17 @@ def test_one_slab_span_a_slab_with_its_width_and_trips(monkeypatch):
     svc = SolveService(A, kmax=4)
     hs = [svc.submit(b, x0=x0, tol=1e-9) for _ in range(5)]
     svc.drain()
-    assert [s.name for s in opened] == ["pa:service:slab"] * 2
-    assert [s.stats["k"] for s in opened] == [4, 1]
-    assert [s.stats["trips"] for s in opened] == [
+    # five submits each opened their own span, with the id they were given
+    assert [
+        s.stats["request"] for s in opened if s.name == "pa:service:submit"
+    ] == [h.id for h in hs]
+    slabs = [s for s in opened if s.name == "pa:service:slab"]
+    assert len(slabs) == 2 and len(opened) == 5 + 2
+    assert [s.stats["k"] for s in slabs] == [4, 1]
+    assert [s.stats["requests"] for s in slabs] == [
+        "+".join(str(h.id) for h in hs[:4]), str(hs[4].id)
+    ]
+    assert [s.stats["trips"] for s in slabs] == [
         hs[0].result()[1]["iterations"], hs[4].result()[1]["iterations"]
     ]
     assert all(s.closed for s in opened)

@@ -99,7 +99,10 @@ def test_solve_counters_grow_by_one_call_and_its_frames(system, which):
     # the path each vector took: packed and lifted on the devices
     assert grew["solve.device_packs"] == 2 and grew["solve.host_packs"] == 0
     assert grew["solve.device_lifts"] == 1 and grew["solve.host_lifts"] == 0
-    assert set(grew) == {
+    # the names that GREW, against the seven a solo solve may move:
+    # whatever ran earlier in the process (a block solve's
+    # `solve.block_lane_major`, say) exists and stands still
+    assert {k for k, v in grew.items() if v} <= {
         "solve.calls", "solve.staged_bytes", "solve.fetched_bytes",
         "solve.device_packs", "solve.host_packs",
         "solve.device_lifts", "solve.host_lifts",

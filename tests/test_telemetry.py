@@ -240,7 +240,16 @@ def test_event_nesting_and_kill_switch(monkeypatch):
     outer = telemetry.begin_record("outer")
     inner = telemetry.begin_record("inner")
     telemetry.emit_event("checkpoint_save", label="x", iteration=3, n=1)
-    assert telemetry.current_record() is inner
+    # a bare begin_record hears every event and is on no thread's stack;
+    # the calling thread's record is the innermost solve_scope it opened
+    assert telemetry.current_record() is None
+    with telemetry.solve_scope("scoped-outer") as so:
+        with telemetry.solve_scope("scoped-inner") as si:
+            assert telemetry.current_record() is si
+            si.finish(None)
+            assert telemetry.current_record() is so
+        assert telemetry.current_record() is so
+    assert telemetry.current_record() is None
     inner.finish(None)
     telemetry.emit_event("restart", label="y")
     outer.finish(None)
