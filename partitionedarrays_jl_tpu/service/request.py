@@ -77,6 +77,12 @@ class SolveRequest:
         self._clock = None
         self.iterations = 0  # committed across chunks
         self.record = None  # SolveRecord, opened by the service
+        #: The paspec prediction of the request's cost, or None: set at
+        #: `submit` where the norm it needs was at hand there, else
+        #: OWED (the arguments `_forecast_from_report` keeps for it)
+        #: until the request's first column reports.
+        self.forecast: Optional[dict] = None
+        self._forecast_owed: Optional[tuple] = None
         #: Distributed-tracing context (`telemetry.tracing.TraceContext`)
         #: propagated by the submitter (the gate stamps its root span's
         #: context here); None = untraced request. The service opens

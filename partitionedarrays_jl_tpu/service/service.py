@@ -7,7 +7,10 @@ Lifecycle of a request:
 
 1. **submit** — admission control (`service.admission`): bounded queue
    + draining check, typed `AdmissionRejected` backpressure. Admitted
-   requests get a `SolveRecord` and a ``request_queued`` event.
+   requests get a `SolveRecord` and a ``request_queued`` event. The
+   paspec forecast takes its O(n) norm here only for a request that is
+   gated on it (`_forecast`); every other request is queued at once and
+   forecast from its slab's first residual (`_forecast_from_report`).
 2. **coalesce** — `service.batcher.next_slab` groups FIFO-compatible
    requests (same tol/maxiter/dtype) into one (P, W, K) slab, K ≤
    ``PA_SERVE_KMAX``; ragged leftovers run as-is and are topped back up
@@ -197,8 +200,9 @@ class SolveService:
         The whole call, rejected or admitted, is the span
         ``pa:service:submit`` on the caller's thread (stat ``request``,
         the id, once there is one) and counts into
-        ``service.submit_us``: the forecast runs here, BEFORE the
-        submission stamp that ``service.queue_wait_us`` starts from."""
+        ``service.submit_us``: whatever of the forecast runs here runs
+        BEFORE the submission stamp that ``service.queue_wait_us``
+        starts from (`_forecast` says which requests pay a norm here)."""
         with self._timed(
             profiler_span("pa:service:submit"), "service.submit_us"
         ) as span:
@@ -239,11 +243,13 @@ class SolveService:
         # can touch a compiled program). Under PA_SPEC_ADMIT=1 an
         # infeasible deadline is refused typed HERE, before any
         # iteration burns; otherwise the forecast only stamps the
-        # record. Unmeasured operators always pass.
+        # record, and is OWED to the request's first column report
+        # (`_forecast_from_report`) unless the caller brought the norm.
+        # Unmeasured operators always pass.
         with self._timed(
             annotate("pa:submit:forecast"), "service.forecast_us"
         ):
-            forecast = self._forecast(
+            forecast, owed = self._forecast(
                 b, x0, tol, deadline, tag, r0_norm=r0_norm
             )
         with self._lock:
@@ -263,18 +269,14 @@ class SolveService:
             req._clock = self.clock
             req.submitted_at = self.clock()
             req.trace = trace
-            req.forecast = forecast
+            req._forecast_owed = owed
             with tracing.ambient(trace):
                 req.record = telemetry.begin_record(
                     "service-request", request=req.tag, tol=float(tol),
                     maxiter=maxiter, deadline=deadline,
                 )
                 if forecast is not None:
-                    # the prediction rides the record: realized error
-                    # is stamped at the terminal state (_slo_account)
-                    req.record.config["forecast"] = dict(forecast)
-                    self.stats["predicted"] += 1
-                    registry().counter("spec.predictions").inc()
+                    self._stamp_forecast(req, forecast)
                 self.stats["admitted"] += 1
                 registry().counter("service.admitted").inc()
                 telemetry.emit_event(
@@ -290,20 +292,32 @@ class SolveService:
             return req
 
     def _forecast(self, b, x0, tol, deadline, tag,
-                  r0_norm: Optional[float] = None) -> Optional[dict]:
-        """The paspec admission forecast for one request (host-side):
-        predicted iterations + seconds from the spectrum store and the
-        throughput model, or ``None`` while the operator is unmeasured
-        (or ``PA_SPEC=0``). Warm starts forecast their REMAINING work
-        (``‖b − A·x0‖`` — a checkpointed near-converged resubmission
-        must not be cold-forecast). Under ``PA_SPEC_ADMIT=1`` a
-        deadline-carrying request whose predicted cost exceeds its
-        deadline raises the typed `DeadlineInfeasible` — counted in
-        ``stats["infeasible"]``/``spec.infeasible``, never dispatched."""
+                  r0_norm: Optional[float] = None) -> tuple:
+        """The paspec admission forecast for one request (host-side), as
+        ``(forecast, owed)``: predicted iterations + seconds from the
+        spectrum store and the throughput model, or ``(None, None)``
+        while the operator is unmeasured (or ``PA_SPEC=0``). Warm starts
+        forecast their REMAINING work (``‖b − A·x0‖`` — a checkpointed
+        near-converged resubmission must not be cold-forecast).
+
+        Where that norm is taken follows from who reads the prediction
+        before the solve. A caller that brings ``r0_norm`` is forecast
+        here from it. Under ``PA_SPEC_ADMIT=1`` a deadline-carrying
+        request is refused BEFORE any iteration burns, so its norm is
+        taken here, on the host and on the caller's thread
+        (``service.forecasts``), and a predicted cost over its deadline
+        raises the typed `DeadlineInfeasible` — counted in
+        ``stats["infeasible"]``/``spec.infeasible``, never dispatched.
+        Every other request's prediction only rides its record to the
+        terminal state, and its slab's first residual IS that norm: it
+        pays no O(n) work here and is returned as OWED a forecast,
+        ``(None, (spectrum fingerprint, dtype))``, which
+        `_forecast_from_report` makes good when its first column
+        reports."""
         from ..parallel.health import DeadlineInfeasible
 
         if not spectrum.spec_enabled():
-            return None
+            return None, None
         import numpy as _np
 
         dt = str(_np.dtype(b.dtype))
@@ -311,24 +325,26 @@ class SolveService:
         # first forecast rather than at service construction
         with annotate("pa:forecast:fingerprint"):
             spec_fp = spectrum.spectrum_fingerprint(self.A)
-        # the common case — an unmeasured operator — must cost nothing:
-        # only a measured spec is worth the O(n) norm below
+        # the common case — an unmeasured operator — must cost nothing
         if not spectrum.has_spec(spec_fp, dt, self._minv_class):
-            return None
-        registry().counter("service.forecasts").inc()
+            return None, None
+        gated = deadline is not None and spectrum.spec_admit_enabled()
         if r0_norm is not None:
             r0 = float(r0_norm)
-        else:
+        elif gated:
+            registry().counter("service.forecasts").inc()
             with annotate("pa:forecast:norm"):
                 r0 = spectrum.residual_norm(self.A, b, x0)
-        if deadline is not None and spectrum.spec_admit_enabled():
+        else:
+            return None, (spec_fp, dt)
+        if gated:
             try:
                 return spectrum.check_deadline_feasible(
                     spec_fp, dt, self._minv_class, tol,
                     float(deadline), r0_norm=r0, tag=tag,
                     where="service",
                     cost_fingerprint=self.fingerprint,
-                )
+                ), None
             except DeadlineInfeasible:
                 with self._lock:
                     self.stats["infeasible"] += 1
@@ -336,7 +352,42 @@ class SolveService:
         return spectrum.admission_prediction(
             spec_fp, dt, self._minv_class, tol,
             r0_norm=r0, cost_fingerprint=self.fingerprint,
-        )
+        ), None
+
+    def _forecast_from_report(self, req, col) -> None:
+        """Make good the forecast `_forecast` left owed, on the worker's
+        thread, from the request's FIRST column report: ``residuals[0]``
+        of a block solve's column is ``‖b − A·x0‖`` of the start it was
+        given (`_chunk_verdict` fixes the request's target from the same
+        entry), so the first report's is the ORIGINAL start's and no
+        later chunk or solo retry re-baselines it. The same formula over
+        the same quantity as at `submit`, taken in the solve's precision
+        on the device. A report with no history (a column the host
+        oracle contained before it ran) leaves the request unforecast,
+        as a request of an unmeasured operator is."""
+        spec_fp, dt = req._forecast_owed
+        req._forecast_owed = None
+        hist = col.get("residuals", ())
+        if not len(hist):
+            return
+        with self._timed(
+            annotate("pa:forecast:deferred"), "service.forecast_us"
+        ):
+            forecast = spectrum.admission_prediction(
+                spec_fp, dt, self._minv_class, req.tol,
+                r0_norm=float(hist[0]), cost_fingerprint=self.fingerprint,
+            )
+        if forecast is not None:
+            registry().counter("service.forecasts_deferred").inc()
+            self._stamp_forecast(req, forecast)
+
+    def _stamp_forecast(self, req, forecast: dict) -> None:
+        """The prediction rides the request and its record: the realized
+        error is stamped at the terminal state (`_forecast_account`)."""
+        req.forecast = forecast
+        req.record.config["forecast"] = dict(forecast)
+        self._bump("predicted")
+        registry().counter("spec.predictions").inc()
 
     def pending(self) -> int:
         with self._lock:
@@ -633,6 +684,8 @@ class SolveService:
                 col = info["columns"][k]
                 verdict = info["column_health"][k]
                 r.iterations += int(col["iterations"])
+                if r._forecast_owed is not None:
+                    self._forecast_from_report(r, col)
                 if chunked:
                     col = self._chunk_verdict(r, col, tol, targets)
                 if verdict["status"] != "ok":
@@ -793,7 +846,7 @@ class SolveService:
         unforecast requests or zero-iteration outcomes."""
         from .. import telemetry
 
-        forecast = getattr(req, "forecast", None)
+        forecast = req.forecast
         if forecast is None or req.iterations <= 0:
             return
         predicted = int(forecast["predicted_iters"])

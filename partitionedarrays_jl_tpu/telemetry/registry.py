@@ -347,13 +347,20 @@ CATALOG: Dict[str, MetricSpec] = {
               "pa:service:submit), in whole microseconds; the forecast "
               "is inside, the queue wait starts after"),
         _spec("service.forecast_us", "counter", "us",
-              "service/service.py:_admit",
-              "of it, inside the paspec forecast (span "
-              "pa:submit:forecast)"),
+              "service/service.py:_admit/_forecast_from_report",
+              "inside the paspec forecast: the part of a submit under "
+              "span pa:submit:forecast, and a deferred prediction on "
+              "the worker's thread (span pa:forecast:deferred)"),
         _spec("service.forecasts", "counter", "1",
               "service/service.py:_forecast",
-              "forecasts that got as far as the residual norm (a "
-              "measured operator)"),
+              "forecasts that took the residual norm on the host "
+              "inside submit (a measured operator, a deadline under "
+              "PA_SPEC_ADMIT=1, no r0_norm given)"),
+        _spec("service.forecasts_deferred", "counter", "1",
+              "service/service.py:_forecast_from_report",
+              "requests whose prediction was made from their first "
+              "column report, residuals[0] of the slab's block solve, "
+              "and not from a norm taken inside submit"),
         _spec("service.idle_us", "counter", "us",
               "service/service.py:_work",
               "the worker thread with an empty queue, from finding it "
@@ -487,10 +494,11 @@ CATALOG: Dict[str, MetricSpec] = {
               "hostile header can never 500 a submit)"),
         # -- PR 16 convergence observatory (paspec) -------------------
         _spec("spec.predictions", "counter", "1",
-              "service/service.py:submit",
-              "requests admitted with an iterations-to-tolerance "
-              "forecast stamped on their record (the operator was "
-              "spectrally measured at submit)"),
+              "service/service.py:_stamp_forecast",
+              "requests with an iterations-to-tolerance forecast "
+              "stamped on their record, at submit or when their first "
+              "column reported (the operator was spectrally measured "
+              "at submit)"),
         _spec("spec.infeasible", "counter", "1",
               "telemetry/spectrum.py:check_deadline_feasible",
               "deadline-carrying requests refused typed at admission "
