@@ -76,6 +76,16 @@ SCOPE_BSR_GATHER, SCOPE_BSR_EINSUM = "bsr.gather", "bsr.einsum"
 #: Inside `SCOPE_SPMV` as well: the boundary (A_oh) rows in whichever of
 #: their three forms (face slabs, node blocks, ELL), which `oh_rows_us` reads.
 SCOPE_OH = "oh"
+#: Inside `SCOPE_SPMV` too, where the operator's diagonals are streamed
+#: (``dia_mode == "stream"``): the Mosaic kernel; the copies around it (the
+#: owned region cut out of the frame and padded into the kernel's
+#: ``(x_rows, 128)`` operand, the product cut to its owned length and
+#: embedded in a frame again), which `stream_embed_share` reads; and the
+#: XLA shifted-slice form the same operator takes off the chip and for a
+#: ``(W, K)`` block.
+SCOPE_DIA_STREAM, SCOPE_DIA_EMBED, SCOPE_DIA_XLA = (
+    "dia.stream", "dia.embed", "dia.xla"
+)
 #: Inside `SCOPE_HALO`, in the generic (index-vector) exchange body: a
 #: round's gather of its send slots and its scatter into its receive
 #: slots, which `halo_index_share` reads; the permutes stay the phase's own.
@@ -987,6 +997,13 @@ def _padded_for(backend: TPUBackend) -> bool:
     return backend.devices()[0].platform == "tpu"
 
 
+def _stream_kernel_for(backend: TPUBackend) -> bool:
+    """Real TPUs stream an operator's stored diagonals through the Mosaic
+    kernel (`ops/pallas_dia.py:dia_spmv_pallas`); host/CPU meshes take the
+    XLA shifted-slice form of the same sum."""
+    return backend.devices()[0].platform == "tpu"
+
+
 def _box_exchange_enabled() -> bool:
     """The slice-based box exchange (tpu_box.py), default ON. Strict-bits
     keeps the generic plan: the box 'add' path accumulates ghost
@@ -1702,10 +1719,9 @@ class DeviceMatrix:
                             off_arr, M.indices.astype(np.int64) - r
                         )
                         dia[p, d_, r] = M.data
-            on_tpu = backend.devices()[0].platform == "tpu"
             self.pallas_plan = (
                 plan_dia_pallas(offsets, no_max, itemsize=np.dtype(dt).itemsize)
-                if on_tpu
+                if _stream_kernel_for(backend)
                 else None
             )
             if self.pallas_plan is not None:
@@ -1715,7 +1731,9 @@ class DeviceMatrix:
                 dia_stage = dia_stage.reshape(P, D, R, LANES)
             else:
                 dia_stage = dia
-            self.dia_vals = low.upload(backend, dia_stage.astype(dt), P)
+            dia_stage = dia_stage.astype(dt)
+            _count_stream_lowering(dia_stage, self.pallas_plan)
+            self.dia_vals = low.upload(backend, dia_stage, P)
 
     @staticmethod
     def _abft_checksum_row(A, oo, oh, full, P, noids, col_layout):
@@ -2967,11 +2985,15 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
         # shifted-slice form instead (`_dia_vals_dense`).
         from ..ops.pallas_dia import dia_spmv_pallas
 
-        y = dia_spmv_pallas(
-            vals, _pad_lanes(xv), offsets, pplan["n_rows"], pplan["halo_rows"],
-            pplan["block_rows"],
-        )
-        return y.reshape(-1)[:no_max]
+        with jax.named_scope(SCOPE_DIA_EMBED):
+            xw = _pad_lanes(xv)
+        with jax.named_scope(SCOPE_DIA_STREAM):
+            y = dia_spmv_pallas(
+                vals, xw, offsets, pplan["n_rows"], pplan["halo_rows"],
+                pplan["block_rows"], interpret=interpret,
+            )
+        with jax.named_scope(SCOPE_DIA_EMBED):
+            return y.reshape(-1)[:no_max]
 
     def _dia_vals_dense(vals):
         # the streaming-DIA staging is lane-tiled (D, R, LANES) when a
@@ -3124,7 +3146,8 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
         if offsets is not None:  # owned block first: overlaps the wire
             if pplan is not None and xv.ndim == 1:
                 return None, _dia_rowsum_pallas(m["oo_v"], xv)
-            return None, _dia_rowsum(_dia_vals_dense(m["oo_v"]), xv)
+            with jax.named_scope(SCOPE_DIA_XLA):
+                return None, _dia_rowsum(_dia_vals_dense(m["oo_v"]), xv)
         if dA.sd_bs is not None:
             # supernode-dense path: self blocks arrive by RESHAPE of the
             # owned region (no gather), only the per-group external
@@ -3320,6 +3343,13 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
 
     _oh_rows = _scoped(SCOPE_OH, _oh_rows)
 
+    def _embed_scope():
+        # the streamed kernel's product embedded in a frame again is one of
+        # its copies; every other lowering's embedding keeps the phase's name
+        if mode == "stream" and pplan is not None:
+            return jax.named_scope(SCOPE_DIA_EMBED)
+        return contextlib.nullcontext()
+
     def _finish(full, partial_, xv, m):
         """Shared SpMV tail: halo-exchange the operand, embed the A_oo
         product in the row frame, add the boundary (A_oh) contribution.
@@ -3337,9 +3367,10 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
             # the product lives in the ROW-layout frame: for rectangular
             # operators (restriction/prolongation transfers) the column
             # frame can be narrower than the row count
-            y = jnp.zeros((layout.W,) + tail, dtype=xv.dtype).at[
-                o0 : o0 + no_max
-            ].set(partial_)
+            with _embed_scope():
+                y = jnp.zeros((layout.W,) + tail, dtype=xv.dtype).at[
+                    o0 : o0 + no_max
+                ].set(partial_)
         if dA.oh_nnz:
             y = _oh_rows(y, xv, m)
             y = y.at[g0:].set(0)
@@ -5861,6 +5892,29 @@ def _count_sd_lowering(sd: dict, nnz: int) -> None:
         "lowering.sd.gather_slots",
         sum(int(c["idx"].size) for c in sd["chunks"]),
     )
+
+
+def _count_stream_lowering(vals: np.ndarray, plan: Optional[dict]) -> None:
+    """The ``lowering.stream.*`` counters of one operator staged as
+    streamed diagonals: how many, the bytes uploaded for them (all parts,
+    the kernel's padding in), whether the Mosaic kernel takes them
+    (``plan`` as `plan_dia_pallas` returned it) or the XLA form; and with a
+    plan the kernel's block, the rows of x it fetches for each block (the
+    block and its halo on both sides: ``x_window_rows / block_rows`` is how
+    often x is read) and its blocks."""
+    from .. import telemetry
+    from ..ops.pallas_dia import _win_rows
+
+    telemetry.bump("lowering.stream.diagonals", int(vals.shape[1]))
+    telemetry.bump("lowering.stream.value_bytes", int(vals.nbytes))
+    telemetry.bump("lowering.stream.pallas", int(plan is not None))
+    if plan is not None:
+        br = plan["block_rows"]
+        telemetry.bump("lowering.stream.block_rows", br)
+        telemetry.bump(
+            "lowering.stream.x_window_rows", _win_rows(br, plan["halo_rows"])
+        )
+        telemetry.bump("lowering.stream.blocks", plan["n_rows"] // br)
 
 
 def _count_exchange_plan(plan) -> None:

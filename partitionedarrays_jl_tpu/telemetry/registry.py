@@ -230,6 +230,30 @@ CATALOG: Dict[str, MetricSpec] = {
         _spec("lowering.sd.gather_slots", "counter", "1",
               "parallel/tpu.py:_count_sd_lowering",
               "padded external node slots the products gather"),
+        # -- streamed diagonals, where an operator is staged ------------
+        _spec("lowering.stream.diagonals", "counter", "1",
+              "parallel/tpu.py:_count_stream_lowering",
+              "stored diagonals of the operators staged as streamed "
+              "diagonals (dia_mode 'stream')"),
+        _spec("lowering.stream.value_bytes", "counter", "bytes",
+              "parallel/tpu.py:_count_stream_lowering",
+              "bytes uploaded for them, all parts, the kernel's padding "
+              "in: what one product streams"),
+        _spec("lowering.stream.pallas", "counter", "1",
+              "parallel/tpu.py:_count_stream_lowering",
+              "of those operators, the ones the Mosaic kernel takes (the "
+              "others take the XLA shifted-slice form)"),
+        _spec("lowering.stream.block_rows", "counter", "1",
+              "parallel/tpu.py:_count_stream_lowering",
+              "lane rows of a block of the kernel's plan"),
+        _spec("lowering.stream.x_window_rows", "counter", "1",
+              "parallel/tpu.py:_count_stream_lowering",
+              "lane rows of x the kernel fetches for each block: the "
+              "block and the halo on both sides (over block_rows: how "
+              "often x is read)"),
+        _spec("lowering.stream.blocks", "counter", "1",
+              "parallel/tpu.py:_count_stream_lowering",
+              "blocks of the kernel's plan"),
         # -- the boundary (A_oh) block, where an operator is staged ---
         _spec("lowering.oh.nnz", "counter", "1",
               "parallel/tpu.py:_count_oh_lowering",
