@@ -11,7 +11,9 @@ This kernel makes the memory schedule explicit:
 * all operands are viewed as ``(rows, 128)`` lane-major tiles;
 * the diagonal values ``(D, R, 128)`` and the output stream through VMEM
   via the grid pipeline (auto double-buffered);
-* the x window (block rows + halo rows) is DMA'd HBM→VMEM once per block;
+* the x window (block rows + halo rows) is DMA'd HBM→VMEM once per block,
+  double-buffered by hand: block i+1's window is in flight while block i
+  computes (`WINDOW_SLOTS` slots, the grid walked in order);
 * each diagonal offset ``s = q*128 + r`` becomes a *row shift* (q) plus a
   *lane rotation* (r) computed entirely in VMEM: two shifted row views
   concatenated at lane boundary r.
@@ -46,9 +48,17 @@ LANES = 128
 #: streams) needs ~40 MiB by the same arithmetic; 64 MiB holds it with
 #: room and is half of a v5e core's 128 MiB of VMEM.
 VMEM_LIMIT_BYTES = 64 * 2**20
-#: block rows per grid step (tuned: vals block = D * BR * 128 * 4B in VMEM,
-#: double-buffered by the pipeline; 512 rows -> 1.8 MB per diagonal-7 block)
-DEF_BLOCK_ROWS = 512
+#: block rows per grid step (vals block = D * BR * 128 * 4B in VMEM,
+#: double-buffered by the pipeline; 1,024 rows -> 3.7 MB per diagonal-7
+#: block). Tuned on a v5e with the window double-buffered (PR 39): one call
+#: at 192^3 and seven diagonals reads 451 / 408 / 387 us at 256 / 512 /
+#: 1,024 rows. A band too wide for the VMEM gate at this size is planned at
+#: MIN_BLOCK_ROWS, where up to 20 diagonals of a 288-row halo fit.
+DEF_BLOCK_ROWS = 1024
+MIN_BLOCK_ROWS = 512
+#: VMEM slots of the streamed kernel's x window: block i+1's window is
+#: fetched into one while block i's band sum reads the other
+WINDOW_SLOTS = 2
 
 
 def _win_rows(block_rows: int, halo_rows: int) -> int:
@@ -59,30 +69,48 @@ def _win_rows(block_rows: int, halo_rows: int) -> int:
 
 
 def _kernel(vals_ref, xw_ref, y_ref, xs_ref, sem, *, qr: Tuple[Tuple[int, int], ...],
-            block_rows: int, halo_rows: int):
+            block_rows: int, halo_rows: int, n_blocks: int):
+    import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     i = pl.program_id(0)
-    # x window for this block: rows [i*BR, i*BR + win_rows) of the padded
+    # x window of block blk: rows [blk*BR, blk*BR + win_rows) of the padded
     # x — one DMA, reused by every diagonal. The window is rounded up to a
     # multiple of 8 rows: a DMA whose sublane count is not 8-aligned
     # faults the chip.
     win_rows = _win_rows(block_rows, halo_rows)
-    dma = pltpu.make_async_copy(
-        xw_ref.at[pl.ds(i * block_rows, win_rows), :], xs_ref, sem
-    )
-    dma.start()
-    dma.wait()
+
+    def x_dma(slot, blk):
+        return pltpu.make_async_copy(
+            xw_ref.at[pl.ds(blk * block_rows, win_rows), :],
+            xs_ref.at[slot],
+            sem.at[slot],
+        )
+
+    slot = jax.lax.rem(i, jnp.int32(WINDOW_SLOTS))
+
+    @pl.when(i == 0)
+    def _():
+        x_dma(0, 0).start()
+
+    # block i+1's window goes into the other slot before block i's is
+    # waited on: the fetch overlaps this block's band sum (the grid runs
+    # in order, so the slot's last reader, block i-1, is done)
+    @pl.when(i + 1 < n_blocks)
+    def _():
+        x_dma(1 - slot, i + 1).start()
+
+    x_dma(slot, i).wait()
 
     acc = None
     for d, (q, r) in enumerate(qr):
-        a = xs_ref[pl.ds(q, block_rows), :]
+        a = xs_ref[slot, pl.ds(q, block_rows), :]
         if r == 0:
             shifted = a
         else:
-            b = xs_ref[pl.ds(q + 1, block_rows), :]
+            b = xs_ref[slot, pl.ds(q + 1, block_rows), :]
             # lane rotation: lanes [r:] of row q  ++  lanes [:r] of row q+1
             shifted = jnp.concatenate([a[:, r:], b[:, :r]], axis=1)
         term = vals_ref[d] * shifted
@@ -115,15 +143,16 @@ def dia_spmv_pallas(
     D, R, _ = vals.shape
     assert R == n_rows and n_rows % block_rows == 0
     qr = tuple(divmod(halo_rows * LANES + off, LANES) for off in offsets)
-    grid = (n_rows // block_rows,)
+    n_blocks = n_rows // block_rows
     win_rows = _win_rows(block_rows, halo_rows)
     assert x.shape[0] >= n_rows + win_rows - block_rows, (x.shape, n_rows, win_rows)
     kernel = functools.partial(
-        _kernel, qr=qr, block_rows=block_rows, halo_rows=halo_rows
+        _kernel, qr=qr, block_rows=block_rows, halo_rows=halo_rows,
+        n_blocks=n_blocks,
     )
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec(
                 (D, block_rows, LANES), lambda i: (0, i, 0), memory_space=pltpu.VMEM
@@ -135,11 +164,13 @@ def dia_spmv_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((n_rows, LANES), vals.dtype),
         scratch_shapes=[
-            pltpu.VMEM((win_rows, LANES), vals.dtype),
-            pltpu.SemaphoreType.DMA(()),
+            pltpu.VMEM((WINDOW_SLOTS, win_rows, LANES), vals.dtype),
+            pltpu.SemaphoreType.DMA((WINDOW_SLOTS,)),
         ],
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=VMEM_LIMIT_BYTES
+            # the window prefetch crosses grid steps: they run in order
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
         name="pa_dia_stream_spmv",
@@ -563,14 +594,25 @@ def plan_dia_pallas(
     # block at the (8-sublane-aligned) tiled row count of the data itself
     tiled_rows = -(-no_max // LANES)
     block_rows = int(min(block_rows, max(8, -(-tiled_rows // 8) * 8)))
+    d = len(offsets)
+
+    def vmem_of(br):
+        # VMEM budget: vals block (double-buffered) + out (x2) + the
+        # window's slots
+        return (
+            (2 * d + 2) * br + WINDOW_SLOTS * _win_rows(br, halo_rows)
+        ) * LANES * itemsize
+
+    budget = 12 * 2**20
+    if vmem_of(block_rows) > budget and block_rows > MIN_BLOCK_ROWS:
+        block_rows = MIN_BLOCK_ROWS
+    vmem = vmem_of(block_rows)
+    if vmem > budget:
+        return None
     n_rows = -(-no_max // (LANES * block_rows)) * block_rows
     win_rows = _win_rows(block_rows, halo_rows)
-    # VMEM budget check: vals block (double-buffered) + out (x2) + window
-    d = len(offsets)
-    vmem = ((2 * d + 2) * block_rows * LANES + win_rows * LANES) * itemsize
-    if vmem > 12 * 2**20:
-        return None
     return {
+        "vmem": int(vmem),
         "n_rows": int(n_rows),
         "halo_rows": int(halo_rows),
         "block_rows": int(block_rows),

@@ -5901,9 +5901,10 @@ def _count_stream_lowering(vals: np.ndarray, plan: Optional[dict]) -> None:
     (``plan`` as `plan_dia_pallas` returned it) or the XLA form; and with a
     plan the kernel's block, the rows of x it fetches for each block (the
     block and its halo on both sides: ``x_window_rows / block_rows`` is how
-    often x is read) and its blocks."""
+    often x is read), its blocks and the VMEM slots of its x window (two:
+    the next block's window is fetched while this one computes)."""
     from .. import telemetry
-    from ..ops.pallas_dia import _win_rows
+    from ..ops.pallas_dia import WINDOW_SLOTS, _win_rows
 
     telemetry.bump("lowering.stream.diagonals", int(vals.shape[1]))
     telemetry.bump("lowering.stream.value_bytes", int(vals.nbytes))
@@ -5915,6 +5916,7 @@ def _count_stream_lowering(vals: np.ndarray, plan: Optional[dict]) -> None:
             "lowering.stream.x_window_rows", _win_rows(br, plan["halo_rows"])
         )
         telemetry.bump("lowering.stream.blocks", plan["n_rows"] // br)
+        telemetry.bump("lowering.stream.window_slots", WINDOW_SLOTS)
 
 
 def _count_exchange_plan(plan) -> None:

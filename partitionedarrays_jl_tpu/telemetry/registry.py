@@ -254,6 +254,10 @@ CATALOG: Dict[str, MetricSpec] = {
         _spec("lowering.stream.blocks", "counter", "1",
               "parallel/tpu.py:_count_stream_lowering",
               "blocks of the kernel's plan"),
+        _spec("lowering.stream.window_slots", "counter", "1",
+              "parallel/tpu.py:_count_stream_lowering",
+              "VMEM slots of the kernel's x window: 2, block i+1's "
+              "window in flight while block i computes"),
         # -- the boundary (A_oh) block, where an operator is staged ---
         _spec("lowering.oh.nnz", "counter", "1",
               "parallel/tpu.py:_count_oh_lowering",

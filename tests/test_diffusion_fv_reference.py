@@ -341,6 +341,7 @@ def test_the_kernel_path_names_its_parts_and_counts_its_plan(kernel_system):
         "lowering.stream.block_rows": rows,
         "lowering.stream.x_window_rows": window,
         "lowering.stream.blocks": 1,
+        "lowering.stream.window_slots": 2,
     }
     reread = importlib.import_module("benchmark.layer_metrics.stream_window_reread")
     assert reread.reread(kernel_system["counters"]) == pytest.approx(100.0 * window / rows)
@@ -367,3 +368,28 @@ def test_the_kernel_is_the_xla_form_at_an_offset_over_one_lane_row(kernel_system
         assert ia["iterations"] == ib["iterations"] and ia["converged"]
         a, b = pa.gather_pvector(xa), pa.gather_pvector(xb)
         assert np.abs(a - b).max() <= 1e-5 * np.abs(b).max()
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "xla"])
+def test_a_kernel_plan_counts_the_two_window_slots(kernel):
+    """PR 39: the Mosaic kernel fetches block i+1's x window into the second
+    of two VMEM slots while block i computes. Staging an operator with a
+    kernel plan bumps `lowering.stream.window_slots` by 2; the XLA form
+    fetches no window and bumps it by nothing."""
+    key = "lowering.stream.window_slots"
+    backend = pa.TPUBackend(devices=jax.devices()[:1])
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(T, "_stream_kernel_for", lambda backend: kernel)
+
+        def body(p):
+            A = pa.assemble_diffusion_fv(p, (6, 6, 6), BETA, dtype=np.float32)
+            before = telemetry.counters("lowering.stream").get(key, 0)
+            out["dA"] = T.device_matrix(A, backend)
+            out["slots"] = telemetry.counters("lowering.stream").get(key, 0) - before
+            return True
+
+        assert pa.prun(body, backend, (1, 1, 1))
+    assert out["dA"].dia_mode == "stream"
+    assert (out["dA"].pallas_plan is not None) is kernel
+    assert out["slots"] == (2 if kernel else 0)

@@ -28,6 +28,15 @@ def _band_reference(vals, x, offsets, n):
         (6 * LANES * 8, (-LANES * 8, -1, 0, 1, LANES * 8)),  # 2-D-ish stencil
         (4 * LANES * 8, (-3, 0, 5)),                          # asymmetric band
         (2 * LANES * 8, (0,)),                                # pure diagonal
+        # the x window's two slots (PR 39): one block (only the first
+        # fetch), two blocks (one prefetch), an odd count with a ragged
+        # tail (both slots used, the last block in slot 0)
+        (LANES * 8, (-LANES - 1, -1, 0, 1, LANES + 1)),
+        (2 * LANES * 8, (-LANES * 2, -1, 0, 1, LANES * 2)),
+        (7 * LANES * 8 - 37, (-LANES * 3 - 5, -1, 0, 1, LANES * 3 + 5)),
+        # a halo wider than a block: consecutive windows overlap, so each
+        # row of x is fetched into both slots
+        (5 * LANES * 8, (-LANES * 20 - 1, -1, 0, 1, LANES * 20 + 1)),
     ],
 )
 def test_pallas_matches_band_reference(n, offsets):
@@ -36,6 +45,8 @@ def test_pallas_matches_band_reference(n, offsets):
     plan = plan_dia_pallas(offsets, n, block_rows=block_rows)
     assert plan is not None
     R, H = plan["n_rows"], plan["halo_rows"]
+    assert plan["block_rows"] == block_rows
+    assert R == -(-n // (LANES * block_rows)) * block_rows
     vals = np.zeros((len(offsets), plan["padded_len"]), dtype=np.float32)
     vals[:, :n] = rng.standard_normal((len(offsets), n)).astype(np.float32)
     # zero out entries whose shifted read would fall outside [0, n): the
@@ -72,6 +83,29 @@ def test_plan_geometry():
     # the x operand row count is 8-aligned relative to the block grid: the
     # DMA window (x_rows - n_rows + block_rows) must be a multiple of 8
     assert (plan["x_rows"] - plan["n_rows"] + plan["block_rows"]) % 8 == 0
+    # VMEM: 3 value blocks and the output, double-buffered by the grid
+    # pipeline, and the x window in both of its slots (8 + 2*2 + 1 rows,
+    # rounded up to 16)
+    assert plan["vmem"] == ((2 * 3 + 2) * 8 + 2 * 16) * LANES * 4
+
+
+@pytest.mark.parametrize(
+    "n_diagonals,block_rows",
+    [(7, 1024), (9, 1024), (10, 512), (20, 512), (21, None)],
+)
+def test_plan_block_for_the_band_width(n_diagonals, block_rows):
+    """At 192^3 (a 288-row halo) the plan takes 1,024-row blocks where the
+    VMEM gate admits them, else 512, else none (the XLA form)."""
+    offsets = tuple(
+        int(o) for o in np.linspace(-192 * 192, 192 * 192, n_diagonals)
+    )
+    plan = plan_dia_pallas(offsets, 192**3)
+    if block_rows is None:
+        assert plan is None
+        return
+    assert plan["block_rows"] == block_rows and plan["halo_rows"] == 288
+    assert plan["vmem"] <= 12 * 2**20
+    assert plan["n_rows"] == 192**3 // LANES  # 54 or 108 whole blocks
 
 
 def test_padded_kernel_matches_band_reference():
