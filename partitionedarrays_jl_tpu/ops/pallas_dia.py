@@ -36,7 +36,8 @@ import numpy as np
 LANES = 128
 
 #: Scoped-VMEM limit handed to Mosaic with both kernels. The plans below
-#: budget the DMA buffers a kernel declares (<= 12-13 MiB); the kernel
+#: budget the DMA buffers a kernel declares (<= 12-13 MiB; the coded
+#: kernel's fold variant <= PFOLD_VMEM_BYTES); the kernel
 #: body's own temporaries — the int32 upcast of every packed code stream,
 #: the shifted windows, the class accumulators — come on top of that, and
 #: the compiler's default limit does not hold them. Found on device_kind
@@ -565,15 +566,32 @@ def dia_coded_padded_pallas(
     )(codebook, no, codes, x)
 
 
+#: Budget of the direction-fold variant's declared VMEM buffers: the
+#: plan's, a second double-buffered operand window, the combined-window
+#: copy and the double-buffered p output block. The kernel body's
+#: temporaries (shifted windows, the int32 upcast of the code streams,
+#: the accumulators) come on top. Compiled for device_kind "TPU v5 lite"
+#: (v5e; jax 0.9.0, libtpu 0.0.34), the least VMEM_LIMIT_BYTES under
+#: which the fold kernel builds is 1.22 to 1.9 times its declared
+#: buffers: 16.75 MiB for 12.43 declared (7-point Poisson at 192^3, four
+#: code streams), 22.4 for 14.46 (300^3), 19.6 for 14.93 (320^3), 33.25
+#: for 17.47 (a 27-point band at 192^3, 14 streams). 16 MiB declared so
+#: stays within half of VMEM_LIMIT_BYTES, the headroom the plain kernels
+#: keep, and admits the 7-point Poisson operator (four streams) up to
+#: 360^3 and no float64 plan. On that chip the fold kernel is 497 us an
+#: iteration faster than the plain kernel and the fold in XLA at 320^3.
+PFOLD_VMEM_BYTES = 16 * 2**20
+
+
 def pfold_vmem_ok(plan: dict, itemsize: int = 4) -> bool:
-    """Whether the direction-fold variant's extra VMEM — a second
-    double-buffered operand window, the combined-window copy, and the
-    double-buffered p output block — still fits the budget the plan was
-    gated on."""
+    """Whether the direction-fold variant's declared VMEM — the plan's
+    buffers, a second double-buffered operand window, the combined-window
+    copy, and the double-buffered p output block — fits
+    `PFOLD_VMEM_BYTES`."""
     BR, H = plan["block_rows"], plan["halo_rows"]
     win = _win_rows(BR, H)
     extra = (3 * win + 2 * BR) * LANES * itemsize
-    return plan.get("vmem", 0) + extra <= 13 * 2**20
+    return plan.get("vmem", 0) + extra <= PFOLD_VMEM_BYTES
 
 
 def plan_dia_pallas(

@@ -1694,6 +1694,8 @@ class DeviceMatrix:
                 backend, noids.astype(np.int32).reshape(P, 1), P
             )
             self.dia_codes = low.upload(backend, codes, P)
+            if pplan is not None:
+                _count_coded_lowering(pplan, _pfold_fits(self))
         else:
             self.dia_mode = "stream"
             if dia is None:
@@ -3071,24 +3073,17 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
             acc = term if acc is None else acc + term
         return jnp.where(_bc(jnp.arange(no_max) < no[0], xv), acc, 0)
 
-    if (
+    # the plan's VMEM gate did not include the direction-fold variant's
+    # extra window / combined-copy / p-output blocks: `_pfold_fits`
+    # re-checks them and the body falls back to the jnp fold where they
+    # do not fit. The SDC modes (abft/audit) keep this kernel OFF: the
+    # audit's operand switch and the checksum's exchanged-operand capture
+    # both live in the XLA fold — the ABFT-off guard with XLA fallback,
+    # mirroring the K>1 precedent
+    _pfold_in_kernel = (
         pfold and pplan is not None and dA.dia_cb is not None
-        and not abft and not audit
-    ):
-        from ..ops.pallas_dia import pfold_vmem_ok
-
-        # the plan's VMEM gate did not include the direction-fold
-        # variant's extra window / combined-copy / p-output blocks:
-        # re-check headroom and fall back to the jnp fold when it is
-        # gone. The SDC modes (abft/audit) keep this kernel OFF: the
-        # audit's operand switch and the checksum's exchanged-operand
-        # capture both live in the XLA fold — the ABFT-off guard with
-        # XLA fallback, mirroring the K>1 precedent
-        _pfold_in_kernel = pfold_vmem_ok(
-            pplan, itemsize=np.dtype(dA.dia_cb.dtype).itemsize
-        )
-    else:
-        _pfold_in_kernel = False
+        and not abft and not audit and _pfold_fits(dA)
+    )
 
     def _dia_coded_full_pfold(cb, no, codes, rv, pv, beta):
         from ..ops.pallas_dia import LANES, dia_coded_padded_pallas
@@ -5892,6 +5887,38 @@ def _count_sd_lowering(sd: dict, nnz: int) -> None:
         "lowering.sd.gather_slots",
         sum(int(c["idx"].size) for c in sd["chunks"]),
     )
+
+
+def _pfold_fits(dA: DeviceMatrix) -> bool:
+    """Whether the coded kernel's direction-fold variant fits the VMEM
+    gate for ``dA``'s padded plan (`ops/pallas_dia.py:pfold_vmem_ok`):
+    the one answer from which `_spmv_body` builds the fused CG body and
+    the ``lowering.coded.pfold`` counter records."""
+    from ..ops.pallas_dia import pfold_vmem_ok
+
+    return pfold_vmem_ok(
+        dA.pallas_plan, itemsize=np.dtype(dA.dia_cb.dtype).itemsize
+    )
+
+
+def _count_coded_lowering(plan: dict, pfold: bool) -> None:
+    """The ``lowering.coded.*`` counters of one coded operator staged on
+    the padded frame (``plan`` as `plan_dia_padded` returned it): the
+    operator, the kernel's block and halo, the rows of the operand it
+    fetches for each block (the block and the halo on both sides:
+    ``x_window_rows / block_rows`` is how often the operand is read), the
+    VMEM the plan declares, and whether the fused CG body's direction
+    fold runs inside the kernel (``pfold``, from `_pfold_fits`)."""
+    from .. import telemetry
+    from ..ops.pallas_dia import _win_rows
+
+    br, halo = plan["block_rows"], plan["halo_rows"]
+    telemetry.bump("lowering.coded.operators", 1)
+    telemetry.bump("lowering.coded.block_rows", br)
+    telemetry.bump("lowering.coded.halo_rows", halo)
+    telemetry.bump("lowering.coded.x_window_rows", _win_rows(br, halo))
+    telemetry.bump("lowering.coded.plan_vmem_bytes", plan["vmem"])
+    telemetry.bump("lowering.coded.pfold", int(pfold))
 
 
 def _count_stream_lowering(vals: np.ndarray, plan: Optional[dict]) -> None:

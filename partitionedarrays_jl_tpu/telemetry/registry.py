@@ -258,6 +258,31 @@ CATALOG: Dict[str, MetricSpec] = {
               "parallel/tpu.py:_count_stream_lowering",
               "VMEM slots of the kernel's x window: 2, block i+1's "
               "window in flight while block i computes"),
+        # -- coded diagonals on the padded frame, where staged ----------
+        _spec("lowering.coded.operators", "counter", "1",
+              "parallel/tpu.py:_count_coded_lowering",
+              "coded operators staged on the padded frame (the coded "
+              "Mosaic kernel's plan made)"),
+        _spec("lowering.coded.block_rows", "counter", "1",
+              "parallel/tpu.py:_count_coded_lowering",
+              "lane rows of a block of the kernel's plan"),
+        _spec("lowering.coded.halo_rows", "counter", "1",
+              "parallel/tpu.py:_count_coded_lowering",
+              "lane rows of the operator's halo on either side of a "
+              "block"),
+        _spec("lowering.coded.x_window_rows", "counter", "1",
+              "parallel/tpu.py:_count_coded_lowering",
+              "lane rows of the operand the kernel fetches for each "
+              "block: the block and the halo on both sides (over "
+              "block_rows: how often the operand is read)"),
+        _spec("lowering.coded.plan_vmem_bytes", "counter", "bytes",
+              "parallel/tpu.py:_count_coded_lowering",
+              "VMEM the plan declares for the plain kernel's buffers"),
+        _spec("lowering.coded.pfold", "counter", "1",
+              "parallel/tpu.py:_count_coded_lowering",
+              "of those operators, the ones whose fused CG direction "
+              "fold runs inside the kernel (pfold_vmem_ok admits the "
+              "plan); the others fold in XLA"),
         # -- the boundary (A_oh) block, where an operator is staged ---
         _spec("lowering.oh.nnz", "counter", "1",
               "parallel/tpu.py:_count_oh_lowering",

@@ -513,3 +513,78 @@ def test_body_pfold_frames_zero_off_owned_band(monkeypatch, fold):
             rtol=1e-5, atol=1e-6,
         )
     assert q.any()
+
+
+# ---------------------------------------------------------------------------
+# the fold at 320^3's halo, and the counters that say which fold a solve ran
+# ---------------------------------------------------------------------------
+
+
+def _decoupled_dA(backend, ns, dtype, grid=(1, 1, 1)):
+    """The benchmark's operator: Dirichlet rows decoupled, so its 28 row
+    classes give four nibble code streams, as at 192^3 and 320^3."""
+
+    def driver(parts):
+        A, _b, _xe, _x0 = assemble_poisson(
+            parts, ns, dtype=dtype, decoupled=True
+        )
+        return A
+
+    A = pa.prun(driver, backend, grid)
+    return device_matrix(A, backend)
+
+
+def test_pfold_kernel_at_the_320_cubed_halo_matches_the_jnp_fold(monkeypatch):
+    """A slab of 6 x 320 x 320 cells on the forced padded frame: its
+    slowest offset is 320^3's 102,400, so the plan has 320^3's 800-row
+    halo, four code streams and plan VMEM, and the fold gate's verdict
+    there. One `_spmv_body(pfold=True)` application folds in the kernel
+    (interpret mode), and its frames agree with the jnp fold's to
+    rounding."""
+    from partitionedarrays_jl_tpu.ops import pallas_dia
+    from partitionedarrays_jl_tpu.parallel.tpu import _pfold_fits
+
+    _padded_frame(monkeypatch)
+    dA = _decoupled_dA(_backend(1), (6, 320, 320), np.float32)
+    plan = dA.pallas_plan
+    assert dA.dia_offsets[-1] == 320**2
+    assert plan["halo_rows"] == 800 and plan["vmem"] == 7_938_048
+    assert _pfold_fits(dA)  # the branch 320^3 takes on the chip
+    L = dA.col_layout
+    r = _owned_random(L, np.float32, 1)
+    pv = _owned_random(L, np.float32, 2)
+    q_kernel, p_kernel = _pfold_frames(dA, r, pv, np.float32(0.75))
+    monkeypatch.setattr(
+        pallas_dia, "pfold_vmem_ok", lambda plan, itemsize=4: False
+    )
+    assert not _pfold_fits(dA)
+    q_jnp, p_jnp = _pfold_frames(dA, r, pv, np.float32(0.75))
+    np.testing.assert_allclose(p_kernel, p_jnp, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(q_kernel, q_jnp, rtol=1e-5, atol=1e-5)
+    assert np.abs(q_kernel).max() > 1.0
+
+
+@pytest.mark.parametrize("dtype,pfold", [(np.float32, 1), (np.float64, 0)])
+def test_coded_lowering_counters(monkeypatch, dtype, pfold):
+    """Staging a coded operator on the padded frame counts it once, with
+    its plan; ``.pfold`` is the fold gate's verdict: 1 in float32, 0 in
+    float64, whose doubled windows fail the gate."""
+    from partitionedarrays_jl_tpu import telemetry
+    from partitionedarrays_jl_tpu.ops.pallas_dia import _win_rows
+
+    _padded_frame(monkeypatch)
+    before = telemetry.counters("lowering.coded")
+    dA = _decoupled_dA(_backend(), (8, 8, 8), dtype, (2, 2, 2))
+    after = telemetry.counters("lowering.coded")
+    got = {k: after[k] - before.get(k, 0) for k in after}
+    plan = dA.pallas_plan
+    assert got == {
+        "lowering.coded.operators": 1,
+        "lowering.coded.block_rows": plan["block_rows"],
+        "lowering.coded.halo_rows": plan["halo_rows"],
+        "lowering.coded.x_window_rows": _win_rows(
+            plan["block_rows"], plan["halo_rows"]
+        ),
+        "lowering.coded.plan_vmem_bytes": plan["vmem"],
+        "lowering.coded.pfold": pfold,
+    }

@@ -108,6 +108,81 @@ def test_plan_block_for_the_band_width(n_diagonals, block_rows):
     assert plan["n_rows"] == 192**3 // LANES  # 54 or 108 whole blocks
 
 
+def _poisson7(n):
+    """The A_oo offsets of the 7-point Poisson operator on an n^3 block: a
+    part of poisson7_192_x4 (192^3 cells a chip) has those of 192^3."""
+    return (-n * n, -n, -1, 0, 1, n, n * n)
+
+
+def _declared(plan, itemsize=4):
+    """The fold variant's declared VMEM: the plan's, a second operand
+    window in two slots, the combined window, the p output block in two."""
+    from partitionedarrays_jl_tpu.ops.pallas_dia import _win_rows
+
+    win = _win_rows(plan["block_rows"], plan["halo_rows"])
+    extra = (3 * win + 2 * plan["block_rows"]) * LANES * itemsize
+    return plan["vmem"] + extra
+
+
+@pytest.mark.parametrize(
+    "n_coded,vmem", [(1, 5_316_608), (4, 6_889_472)]
+)
+def test_padded_plan_at_192_cubed_is_unchanged(n_coded, vmem):
+    """poisson7_192 and a chip of poisson7_192_x4 (four code streams: the
+    28 row classes are over the class mode's cap), pure Python: the plan
+    and the fold verdict are what they were under the 13 MiB gate."""
+    from partitionedarrays_jl_tpu.ops.pallas_dia import (
+        plan_dia_padded,
+        pfold_vmem_ok,
+    )
+
+    plan = plan_dia_padded(_poisson7(192), 192**3, n_coded)
+    assert plan == {
+        "vmem": vmem, "block_rows": 2048, "halo_rows": 288, "n_blocks": 27,
+        "o0": 262_144, "g0": 29 * 262_144, "code_len": 27 * 262_144,
+    }
+    assert _declared(plan) <= 13 * 2**20
+    assert pfold_vmem_ok(plan)
+
+
+@pytest.mark.parametrize("n_coded", [1, 4])
+def test_padded_plan_at_320_cubed_admits_the_fold(n_coded):
+    """poisson7_320: an 800-row halo, 125 blocks; the fold variant's
+    declared buffers (13.4 / 14.9 MiB) are over the old 13 MiB gate and
+    within PFOLD_VMEM_BYTES, so the fused body folds in the kernel."""
+    from partitionedarrays_jl_tpu.ops.pallas_dia import (
+        PFOLD_VMEM_BYTES,
+        plan_dia_padded,
+        pfold_vmem_ok,
+    )
+
+    plan = plan_dia_padded(_poisson7(320), 320**3, n_coded)
+    assert plan["halo_rows"] == 800 and plan["n_blocks"] == 125
+    assert 13 * 2**20 < _declared(plan) <= PFOLD_VMEM_BYTES
+    assert pfold_vmem_ok(plan)
+
+
+@pytest.mark.parametrize(
+    "n,n_coded,itemsize",
+    [(510, 4, 4), (510, 8, 4), (8, 4, 8)],
+    ids=["halo-2040-rows", "halo-2040-rows-8-streams", "float64"],
+)
+def test_a_fold_over_the_budget_falls_back(n, n_coded, itemsize):
+    """A band the plain kernel takes but whose fold variant declares more
+    than PFOLD_VMEM_BYTES (the widest halo the padded frame holds, or
+    float64's doubled windows) keeps the jnp fold."""
+    from partitionedarrays_jl_tpu.ops.pallas_dia import (
+        PFOLD_VMEM_BYTES,
+        plan_dia_padded,
+        pfold_vmem_ok,
+    )
+
+    plan = plan_dia_padded(_poisson7(n), n**3, n_coded, itemsize=itemsize)
+    assert plan is not None
+    assert _declared(plan, itemsize) > PFOLD_VMEM_BYTES
+    assert not pfold_vmem_ok(plan, itemsize=itemsize)
+
+
 def test_padded_kernel_matches_band_reference():
     """Direct check of the padded-frame coded kernel (the real-TPU hot
     path) via the Pallas interpreter: full padded vector in, full padded
