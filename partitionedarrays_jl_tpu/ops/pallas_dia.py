@@ -269,7 +269,8 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
         # and emits the center rows as the materialized new direction.
         # The standalone p-update sweep (read r, read p, write p) of the
         # standard loop disappears into the SpMV's own streaming pass;
-        # xw_ref is the r window source here.
+        # xw_ref is the r window source here. The p output is p_prev's
+        # own buffer: see the wait at the end of the step.
         (pw_ref, beta_ref, y_ref, po_ref,
          xs_ref, ps_ref, comb_ref, cs_ref, xsem, psem, csem) = refs
         if columns:
@@ -326,7 +327,7 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
     def _compute():
         x_dma(slot, j).wait()
         if has_pfold:
-            p_dma(slot, j).wait()
+            # p_dma(slot, j) was waited for at the end of the last step
             # one in-VMEM pass builds the combined operand window; every
             # shifted diagonal read then hits the combined copy, so the
             # fold costs ONE add per element instead of one per diagonal
@@ -437,6 +438,16 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
         def _pfold_zero():
             po_ref[:] = jnp.zeros_like(po_ref)
 
+        # p block j goes back to HBM behind this step, over p_prev's
+        # rows: block j+1's window, in flight since the start of the
+        # step, reads the last halo_rows of them and must land first.
+        # Step j+1 would wait for it first thing, so waiting here costs
+        # no overlap. With halo_rows <= block_rows no later window
+        # reaches back to block j.
+        @pl.when(j < n_blocks)
+        def _p_landed():
+            p_dma(jax.lax.rem(j + 1, two), j + 1).wait()
+
 
 def dia_coded_padded_pallas(
     codebook: "jax.Array",  # noqa: F821
@@ -470,6 +481,15 @@ def dia_coded_padded_pallas(
     exactly zero) — the standard loop's standalone direction-update
     sweep is absorbed by the SpMV pass (tpu.py:make_cg_fn fused body).
     Callers must first check `pfold_vmem_ok(plan)`.
+
+    The call updates the direction in place: ``p`` is declared aliased
+    to ``pprev`` (``input_output_aliases``), so a loop that carries p
+    needs no copy of it. Block j's p rows are stored over p_prev behind
+    step j, and the one later window that reads them, block j+1's lower
+    halo, is waited for at the end of step j, before that store. That
+    needs ``halo_rows <= block_rows`` (a wider halo would let block
+    j+2's window reach them too), which every `plan_dia_padded` plan
+    holds and the call asserts.
 
     ``x`` may be K such vectors, ``(K, total_rows, 128)`` (with ``pprev``
     alike and ``beta`` of K entries): a leading grid axis walks them one
@@ -529,6 +549,7 @@ def dia_coded_padded_pallas(
     if pfold is not None:
         pprev, beta = pfold
         assert pprev.shape == x.shape
+        assert H <= BR, "p in place: only block j+1's window reads block j"
         return pl.pallas_call(
             kernel,
             grid=grid,
@@ -549,6 +570,8 @@ def dia_coded_padded_pallas(
                 pltpu.SemaphoreType.DMA((2,)),  # p window sem
                 pltpu.SemaphoreType.DMA((2,)),  # codes sem
             ],
+            # pprev (input 4) is the p output's buffer
+            input_output_aliases={4: 1},
             compiler_params=params,
             interpret=interpret,
             name="pa_dia_coded_spmv_pfold",
@@ -580,6 +603,10 @@ def dia_coded_padded_pallas(
 #: keep, and admits the 7-point Poisson operator (four streams) up to
 #: 360^3 and no float64 plan. On that chip the fold kernel is 497 us an
 #: iteration faster than the plain kernel and the fold in XLA at 320^3.
+#: The variant writes p over p_prev in place, and its p output block
+#: stays a pipelined VMEM block (the store of block j waits for block
+#: j+1's window, which needs halo_rows <= block_rows), so the alias adds
+#: nothing to this count.
 PFOLD_VMEM_BYTES = 16 * 2**20
 
 

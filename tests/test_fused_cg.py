@@ -564,6 +564,41 @@ def test_pfold_kernel_at_the_320_cubed_halo_matches_the_jnp_fold(monkeypatch):
     assert np.abs(q_kernel).max() > 1.0
 
 
+@pytest.mark.parametrize(
+    "ns,grid", [((6, 320, 320), (1, 1, 1)), ((13, 200, 200), (2, 1, 1))],
+    ids=["320-cubed-halo", "uneven-multi-block"],
+)
+def test_pfold_kernel_in_place_gives_the_bits_of_its_own_buffer(
+    monkeypatch, ns, grid
+):
+    """The fold kernel, which writes p over p_prev, against the fold in
+    XLA, whose p is a buffer of its own that the plain kernel then reads:
+    ``(A p, p)`` bit for bit through `_spmv_body`. On 320^3's slab (an
+    800-row halo, three blocks, a ragged tail) and on two parts of 7 and
+    6 planes of 200 x 200 (a 320-row halo, two blocks, parts of uneven
+    length). Interpret mode runs a DMA at its start and cannot show a
+    race: the store ordering itself is checked on the chip."""
+    from partitionedarrays_jl_tpu.ops import pallas_dia
+    from partitionedarrays_jl_tpu.parallel.tpu import _pfold_fits
+
+    _padded_frame(monkeypatch)
+    dA = _decoupled_dA(_backend(int(np.prod(grid))), ns, np.float32, grid)
+    plan = dA.pallas_plan
+    assert plan["n_blocks"] >= 2 and _pfold_fits(dA)
+    L = dA.col_layout
+    r = _owned_random(L, np.float32, 1)
+    pv = _owned_random(L, np.float32, 2)
+    q_in, p_in = _pfold_frames(dA, r, pv, np.float32(0.75))
+    monkeypatch.setattr(
+        pallas_dia, "pfold_vmem_ok", lambda plan, itemsize=4: False
+    )
+    assert not _pfold_fits(dA)
+    q_own, p_own = _pfold_frames(dA, r, pv, np.float32(0.75))
+    np.testing.assert_array_equal(p_in, p_own)
+    np.testing.assert_array_equal(q_in, q_own)
+    assert np.abs(q_in).max() > 1.0
+
+
 @pytest.mark.parametrize("dtype,pfold", [(np.float32, 1), (np.float64, 0)])
 def test_coded_lowering_counters(monkeypatch, dtype, pfold):
     """Staging a coded operator on the padded frame counts it once, with

@@ -317,3 +317,108 @@ def test_padded_kernel_class_accumulator_path():
     rest = y_fast.copy()
     rest[o0 : o0 + no] = 0
     assert not rest.any()
+
+
+def _fold_call(halo_lanes, n_blocks_owned, k=None, seed=3):
+    """The fold variant's arguments on random operands: a band of
+    ``halo_lanes`` lane rows either side, ``n_blocks_owned`` owned blocks
+    with a ragged tail, two coded diagonals; ``k`` vectors on the
+    leading grid axis (the columns form) or one."""
+    from partitionedarrays_jl_tpu.ops.pallas_dia import (
+        PAD_BLOCK_ROWS,
+        pack_nibble_codes,
+        plan_dia_padded,
+    )
+
+    rng = np.random.default_rng(seed)
+    offsets = (-LANES * halo_lanes, -1, 0, 1, LANES * halo_lanes)
+    kk, code_row = (1, 3, 2, 3, 1), (-1, 0, 1, 0, -1)
+    BRL = PAD_BLOCK_ROWS * LANES
+    no = (n_blocks_owned - 1) * BRL + 5 * LANES + 29
+    plan = plan_dia_padded(offsets, no, n_coded=2)
+    o0 = plan["o0"]
+    codes = np.zeros((2, plan["code_len"]), dtype=np.uint8)
+    codes[0, :no] = rng.integers(0, 3, no)
+    codes[1, :no] = rng.integers(0, 2, no)
+    packed = pack_nibble_codes(codes)
+    total = (plan["n_blocks"] + 3) * PAD_BLOCK_ROWS
+    lead = () if k is None else (k,)
+
+    def frame():
+        f = np.zeros(lead + (total * LANES,), dtype=np.float32)
+        f[..., o0 : o0 + no] = rng.standard_normal(lead + (no,))
+        return f.reshape(lead + (total, LANES))
+
+    r, pprev = frame(), frame()
+    beta = rng.standard_normal(k or 1).astype(np.float32)
+    args = (
+        rng.standard_normal((5, 3)).astype(np.float32),
+        np.array([no], dtype=np.int32),
+        packed.reshape(packed.shape[0], -1, LANES), r, offsets, kk,
+        code_row, plan, total,
+    )
+    return plan, args, (pprev, beta)
+
+
+@pytest.mark.parametrize(
+    "halo_lanes,n_blocks,k",
+    [(800, 3, None), (800, 3, 3), (16, 2, 3)],
+    ids=["320-cubed-halo", "320-cubed-halo-3-columns", "3-columns"],
+)
+def test_fold_in_place_gives_the_bits_of_its_own_buffer(halo_lanes, n_blocks, k):
+    """The fold kernel, which writes p over p_prev, on random operands:
+    its ``y`` holds the bits the plain kernel gives on the p it returned,
+    held in a buffer of its own, and that p is ``r + beta p_prev`` on the
+    owned band; one vector and K = 3 columns on the leading grid axis.
+    Interpret mode runs a DMA at its start and cannot show a race: the
+    store ordering itself is checked on the chip, against the kernel that
+    kept p apart (`benchmark/tests/pfold_inplace_bits.py --parent`)."""
+    from partitionedarrays_jl_tpu.ops import pallas_dia
+
+    plan, args, pfold = _fold_call(halo_lanes, n_blocks, k)
+    assert plan["n_blocks"] == n_blocks and plan["halo_rows"] == halo_lanes
+    y_in, p_in = pallas_dia.dia_coded_padded_pallas(
+        *args, interpret=True, pfold=pfold
+    )
+    p_own = np.array(p_in)
+    y_own = pallas_dia.dia_coded_padded_pallas(
+        *args[:3], p_own, *args[4:], interpret=True
+    )
+    np.testing.assert_array_equal(np.asarray(y_in), np.asarray(y_own))
+    r, (pprev, beta) = args[3], pfold
+    bk = beta.reshape((-1,) + (1,) * (r.ndim - 1))
+    want = np.where(r != 0, r + bk * pprev, 0)
+    np.testing.assert_allclose(p_own, want, rtol=1e-6, atol=1e-6)
+    assert np.abs(np.asarray(y_in)).max() > 1.0
+
+
+@pytest.mark.parametrize(
+    "halo_over_block", [False, True], ids=["halo-under-block", "halo-over-block"]
+)
+def test_fold_kernel_declares_p_prev_as_its_p(halo_over_block):
+    """The fold variant's `pallas_call` declares p_prev (its fifth
+    operand) aliased to p (its second result). A plan forced to a halo
+    wider than its block is refused: block j+2's window would read rows
+    block j had already stored over p_prev. `plan_dia_padded` makes no
+    such plan (its halo stays under `PAD_BLOCK_ROWS`)."""
+    import jax
+
+    from partitionedarrays_jl_tpu.ops import pallas_dia
+
+    plan, args, (pprev, beta) = _fold_call(16, 2)
+    if halo_over_block:
+        args = args[:7] + (dict(plan, block_rows=8, halo_rows=16),) + args[8:]
+
+    def trace():
+        return jax.make_jaxpr(
+            lambda x, pp: pallas_dia.dia_coded_padded_pallas(
+                *args[:3], x, *args[4:], interpret=True, pfold=(pp, beta)
+            )
+        )(args[3], pprev).jaxpr
+
+    if halo_over_block:
+        with pytest.raises(AssertionError, match="in place"):
+            trace()
+        return
+    (call,) = [e for e in trace().eqns if e.primitive.name == "pallas_call"]
+    assert tuple(call.params["input_output_aliases"]) == ((4, 1),)
