@@ -60,6 +60,10 @@ MIN_BLOCK_ROWS = 512
 #: VMEM slots of the streamed kernel's x window: block i+1's window is
 #: fetched into one while block i's band sum reads the other
 WINDOW_SLOTS = 2
+#: block slots of each operand ring of the coded kernel's direction-fold
+#: variant: blocks j-1, j and j+1 make step j's window, and block j+2 is
+#: fetched into block j-1's slot once step j has combined its rows
+PFOLD_RING_SLOTS = 3
 
 
 def _win_rows(block_rows: int, halo_rows: int) -> int:
@@ -264,13 +268,16 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
     if has_pfold:
         # leading-edge direction fold (fused CG): the SpMV operand is
         # p = r + beta*p_prev, built IN the window pass — the kernel
-        # DMAs one window each of r and p_prev, combines them once in
-        # VMEM, runs the shifted-read band sum on the combined window,
-        # and emits the center rows as the materialized new direction.
-        # The standalone p-update sweep (read r, read p, write p) of the
-        # standard loop disappears into the SpMV's own streaming pass;
-        # xw_ref is the r window source here. The p output is p_prev's
-        # own buffer: see the wait at the end of the step.
+        # streams r and p_prev each through a ring of PFOLD_RING_SLOTS
+        # block slots (every block fetched once: a window's halo rows
+        # are the neighbouring blocks', already in the ring), combines
+        # the window of blocks j-1 to j+1 once in VMEM, runs the
+        # shifted-read band sum on the combined window, and emits the
+        # center rows as the materialized new direction. The standalone
+        # p-update sweep (read r, read p, write p) of the standard loop
+        # disappears into the SpMV's own streaming pass; xw_ref is the r
+        # source here. The p output is p_prev's own buffer: block j is
+        # stored over it behind step j, when no fetch of it is left.
         (pw_ref, beta_ref, y_ref, po_ref,
          xs_ref, ps_ref, comb_ref, cs_ref, xsem, psem, csem) = refs
         if columns:
@@ -289,12 +296,22 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
             xsem.at[slot],
         )
 
-    def p_dma(slot, blk):
-        return pltpu.make_async_copy(
-            pw_ref.at[pl.ds(blk * BR - halo_rows, win_rows), :],
-            ps_ref.at[slot],
-            psem.at[slot],
-        )
+    def ring_slot(blk):
+        if isinstance(blk, int):
+            return blk % PFOLD_RING_SLOTS
+        return jax.lax.rem(blk, jnp.int32(PFOLD_RING_SLOTS))
+
+    def fetch(blk):
+        """block blk of r and of p_prev into its ring slot"""
+        slot = ring_slot(blk)
+        return [
+            pltpu.make_async_copy(
+                src.at[pl.ds(blk * BR, BR), :], ring.at[slot], sem.at[slot]
+            )
+            for src, ring, sem in (
+                (xw_ref, xs_ref, xsem), (pw_ref, ps_ref, psem)
+            )
+        ]
 
     def codes_dma(slot, blk):
         return pltpu.make_async_copy(
@@ -308,32 +325,64 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
 
     @pl.when(j == 0)
     def _():
-        x_dma(1, 1).start()
         if has_pfold:
-            p_dma(1, 1).start()
+            # the ring's first three blocks: the leading zero block, 1, 2
+            for blk in range(PFOLD_RING_SLOTS):
+                for c in fetch(blk):
+                    c.start()
+        else:
+            x_dma(1, 1).start()
         if n_coded:
             codes_dma(1, 1).start()
+        if has_pfold:
+            # block 0's p_prev is stored over behind this step; step j
+            # waits for block j+1
+            for blk in (0, 1):
+                for c in fetch(blk):
+                    c.wait()
 
     @pl.when((j >= 1) & (j < n_blocks))
     def _():
         nxt = jax.lax.rem(j + 1, two)
-        x_dma(nxt, j + 1).start()
-        if has_pfold:
-            p_dma(nxt, j + 1).start()
+        if not has_pfold:
+            x_dma(nxt, j + 1).start()
         if n_coded:
             codes_dma(nxt, j + 1).start()
 
     @pl.when((j >= 1) & (j <= n_blocks))
     def _compute():
-        x_dma(slot, j).wait()
         if has_pfold:
-            # p_dma(slot, j) was waited for at the end of the last step
-            # one in-VMEM pass builds the combined operand window; every
-            # shifted diagonal read then hits the combined copy, so the
-            # fold costs ONE add per element instead of one per diagonal
-            comb_ref[:] = (
-                xs_ref[slot] + beta_ref[col if columns else 0] * ps_ref[slot]
-            )
+            # one in-VMEM pass builds the combined operand window from
+            # the ring, a segment at a time: the last halo_rows of block
+            # j-1, block j, the first rows of block j+1; every shifted
+            # diagonal read then hits the combined copy, so the fold
+            # costs ONE add per element instead of one per diagonal
+            beta = beta_ref[col if columns else 0]
+
+            def combine(dst, blk, src, n):
+                if n:
+                    s = ring_slot(blk)
+                    comb_ref[pl.ds(dst, n), :] = (
+                        xs_ref[s, pl.ds(src, n), :]
+                        + beta * ps_ref[s, pl.ds(src, n), :]
+                    )
+
+            combine(0, j - 1, BR - halo_rows, halo_rows)
+
+            # block j-1's slot is read: block j+2 goes there at once and
+            # lands behind the rest of this step (the reserve block
+            # n_blocks+1 is the last one fetched)
+            @pl.when(j < n_blocks)
+            def _():
+                for c in fetch(j + 2):
+                    c.start()
+
+            combine(halo_rows, j, 0, BR)
+            for c in fetch(j + 1):
+                c.wait()
+            combine(halo_rows + BR, j + 1, 0, win_rows - BR - halo_rows)
+        else:
+            x_dma(slot, j).wait()
         if n_coded:
             codes_dma(slot, j).wait()
 
@@ -438,16 +487,6 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
         def _pfold_zero():
             po_ref[:] = jnp.zeros_like(po_ref)
 
-        # p block j goes back to HBM behind this step, over p_prev's
-        # rows: block j+1's window, in flight since the start of the
-        # step, reads the last halo_rows of them and must land first.
-        # Step j+1 would wait for it first thing, so waiting here costs
-        # no overlap. With halo_rows <= block_rows no later window
-        # reaches back to block j.
-        @pl.when(j < n_blocks)
-        def _p_landed():
-            p_dma(jax.lax.rem(j + 1, two), j + 1).wait()
-
 
 def dia_coded_padded_pallas(
     codebook: "jax.Array",  # noqa: F821
@@ -474,9 +513,11 @@ def dia_coded_padded_pallas(
 
     ``pfold=(pprev, beta)`` (fused CG) instead treats ``x`` as the
     RESIDUAL vector and computes the SpMV of the combined direction
-    ``p = x + beta*pprev`` without ever reading a materialized p: both
-    windows are DMA'd, combined once in VMEM, and the band sum runs on
-    the combined copy. Returns ``(y, p)`` with
+    ``p = x + beta*pprev`` without ever reading a materialized p: each
+    block of both operands is DMA'd once into a ring of
+    `PFOLD_RING_SLOTS` block slots, the window of blocks j-1 to j+1 is
+    combined once in VMEM, and the band sum runs on the combined copy.
+    Returns ``(y, p)`` with
     ``y = A_oo p`` and ``p`` masked to the owned band (every other slot
     exactly zero) — the standard loop's standalone direction-update
     sweep is absorbed by the SpMV pass (tpu.py:make_cg_fn fused body).
@@ -485,11 +526,12 @@ def dia_coded_padded_pallas(
     The call updates the direction in place: ``p`` is declared aliased
     to ``pprev`` (``input_output_aliases``), so a loop that carries p
     needs no copy of it. Block j's p rows are stored over p_prev behind
-    step j, and the one later window that reads them, block j+1's lower
-    halo, is waited for at the end of step j, before that store. That
-    needs ``halo_rows <= block_rows`` (a wider halo would let block
-    j+2's window reach them too), which every `plan_dia_padded` plan
-    holds and the call asserts.
+    step j; block j's fetch landed a step before, and step j+1 reads
+    those rows as its lower halo from the ring. That needs a window
+    that lies within blocks j-1 to j+1 (``halo_rows <= block_rows``,
+    and the upper edge as `_win_rows` rounds it), which every
+    `plan_dia_padded` plan holds (``halo_rows + 8 <= block_rows``) and
+    the call asserts.
 
     ``x`` may be K such vectors, ``(K, total_rows, 128)`` (with ``pprev``
     alike and ``beta`` of K entries): a leading grid axis walks them one
@@ -549,7 +591,11 @@ def dia_coded_padded_pallas(
     if pfold is not None:
         pprev, beta = pfold
         assert pprev.shape == x.shape
-        assert H <= BR, "p in place: only block j+1's window reads block j"
+        assert H <= BR and win_rows <= H + 2 * BR, (
+            "p in place from a ring: a window lies within blocks j-1 to j+1"
+        )
+        ring = pltpu.VMEM((PFOLD_RING_SLOTS, BR, LANES), codebook.dtype)
+        ring_sem = pltpu.SemaphoreType.DMA((PFOLD_RING_SLOTS,))
         return pl.pallas_call(
             kernel,
             grid=grid,
@@ -562,12 +608,12 @@ def dia_coded_padded_pallas(
                 y_shape, jax.ShapeDtypeStruct(x.shape, x.dtype),
             ],
             scratch_shapes=[
-                scratch[0],  # r window (xs slot)
-                pltpu.VMEM((2, win_rows, LANES), codebook.dtype),  # p win
+                ring,  # r (xs)
+                ring,  # p_prev
                 pltpu.VMEM((win_rows, LANES), codebook.dtype),  # combined
                 scratch[1],  # codes
-                pltpu.SemaphoreType.DMA((2,)),  # r window sem
-                pltpu.SemaphoreType.DMA((2,)),  # p window sem
+                ring_sem,  # r ring sem
+                ring_sem,  # p_prev ring sem
                 pltpu.SemaphoreType.DMA((2,)),  # codes sem
             ],
             # pprev (input 4) is the p output's buffer
@@ -590,35 +636,37 @@ def dia_coded_padded_pallas(
 
 
 #: Budget of the direction-fold variant's declared VMEM buffers: the
-#: plan's, a second double-buffered operand window, the combined-window
-#: copy and the double-buffered p output block. The kernel body's
-#: temporaries (shifted windows, the int32 upcast of the code streams,
-#: the accumulators) come on top. Compiled for device_kind "TPU v5 lite"
-#: (v5e; jax 0.9.0, libtpu 0.0.34), the least VMEM_LIMIT_BYTES under
-#: which the fold kernel builds is 1.22 to 1.9 times its declared
-#: buffers: 16.75 MiB for 12.43 declared (7-point Poisson at 192^3, four
-#: code streams), 22.4 for 14.46 (300^3), 19.6 for 14.93 (320^3), 33.25
-#: for 17.47 (a 27-point band at 192^3, 14 streams). 16 MiB declared so
-#: stays within half of VMEM_LIMIT_BYTES, the headroom the plain kernels
-#: keep, and admits the 7-point Poisson operator (four streams) up to
-#: 360^3 and no float64 plan. On that chip the fold kernel is 497 us an
-#: iteration faster than the plain kernel and the fold in XLA at 320^3.
+#: codes and y blocks of the plan, a ring of PFOLD_RING_SLOTS blocks each
+#: for r and p_prev, the combined-window copy and the double-buffered p
+#: output block. The kernel body's temporaries (shifted windows, the
+#: int32 upcast of the code streams, the accumulators) come on top.
+#: Compiled for device_kind "TPU v5 lite" (v5e; jax 0.9.0, libtpu
+#: 0.0.34), the least VMEM_LIMIT_BYTES under which the fold kernel builds
+#: is 1.26 to 1.51 times its declared buffers: 16.75 MiB for 13.29
+#: declared (7-point Poisson at 192^3, four code streams), 17.84 for 11.79
+#: (192^3, one stream), 19.81 for 13.69 (300^3), 17.84 for 13.79 (320^3),
+#: 19.16 for 14.0 (360^3), 20.25 for 15.0 (the widest halo, 2,040 rows).
+#: 16 MiB declared so stays within half of VMEM_LIMIT_BYTES, the headroom
+#: the plain kernels keep, and admits the 7-point Poisson operator (four
+#: streams) at every halo the padded frame holds and no float64 plan.
 #: The variant writes p over p_prev in place, and its p output block
-#: stays a pipelined VMEM block (the store of block j waits for block
-#: j+1's window, which needs halo_rows <= block_rows), so the alias adds
-#: nothing to this count.
+#: stays a pipelined VMEM block (block j's rows are fetched once, a step
+#: before they are stored over), so the alias adds nothing to this count.
 PFOLD_VMEM_BYTES = 16 * 2**20
 
 
 def pfold_vmem_ok(plan: dict, itemsize: int = 4) -> bool:
-    """Whether the direction-fold variant's declared VMEM — the plan's
-    buffers, a second double-buffered operand window, the combined-window
-    copy, and the double-buffered p output block — fits
-    `PFOLD_VMEM_BYTES`."""
+    """Whether the direction-fold variant's declared VMEM fits
+    `PFOLD_VMEM_BYTES`: the plan's buffers less the plain kernel's two
+    window slots, which the variant does not declare, and in their place
+    a ring of `PFOLD_RING_SLOTS` blocks each for r and p_prev, the
+    combined window, and the double-buffered p output block."""
     BR, H = plan["block_rows"], plan["halo_rows"]
     win = _win_rows(BR, H)
-    extra = (3 * win + 2 * BR) * LANES * itemsize
-    return plan.get("vmem", 0) + extra <= PFOLD_VMEM_BYTES
+    # in: two rings and the p block's two slots; the combined window in
+    # place of the plain kernel's two window slots
+    rows = (2 * PFOLD_RING_SLOTS + 2) * BR - win
+    return plan.get("vmem", 0) + rows * LANES * itemsize <= PFOLD_VMEM_BYTES
 
 
 def plan_dia_pallas(

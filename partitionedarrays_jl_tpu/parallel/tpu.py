@@ -1695,7 +1695,9 @@ class DeviceMatrix:
             )
             self.dia_codes = low.upload(backend, codes, P)
             if pplan is not None:
-                _count_coded_lowering(pplan, _pfold_fits(self))
+                _count_coded_lowering(
+                    pplan, _pfold_fits(self), _fused_cg_enabled()
+                )
         else:
             self.dia_mode = "stream"
             if dia is None:
@@ -3074,7 +3076,7 @@ def _spmv_body(dA: DeviceMatrix, pfold: bool = False,
         return jnp.where(_bc(jnp.arange(no_max) < no[0], xv), acc, 0)
 
     # the plan's VMEM gate did not include the direction-fold variant's
-    # extra window / combined-copy / p-output blocks: `_pfold_fits`
+    # operand rings / combined-copy / p-output blocks: `_pfold_fits`
     # re-checks them and the body falls back to the jnp fold where they
     # do not fit. The SDC modes (abft/audit) keep this kernel OFF: the
     # audit's operand switch and the checksum's exchanged-operand capture
@@ -5901,14 +5903,17 @@ def _pfold_fits(dA: DeviceMatrix) -> bool:
     )
 
 
-def _count_coded_lowering(plan: dict, pfold: bool) -> None:
+def _count_coded_lowering(plan: dict, pfold: bool, fused: bool) -> None:
     """The ``lowering.coded.*`` counters of one coded operator staged on
     the padded frame (``plan`` as `plan_dia_padded` returned it): the
     operator, the kernel's block and halo, the rows of the operand it
-    fetches for each block (the block and the halo on both sides:
-    ``x_window_rows / block_rows`` is how often the operand is read), the
-    VMEM the plan declares, and whether the fused CG body's direction
-    fold runs inside the kernel (``pfold``, from `_pfold_fits`)."""
+    fetches for each block in a CG solve of the default body
+    (``x_window_rows / block_rows`` is how often the operand is read:
+    the block alone where the fused body, ``fused``, folds the direction
+    in the kernel, whose rings fetch every block once; else the plain
+    kernel's window, the block and the halo on both sides), the VMEM the
+    plan declares, and whether the fused CG body's direction fold runs
+    inside the kernel (``pfold``, from `_pfold_fits`)."""
     from .. import telemetry
     from ..ops.pallas_dia import _win_rows
 
@@ -5916,7 +5921,10 @@ def _count_coded_lowering(plan: dict, pfold: bool) -> None:
     telemetry.bump("lowering.coded.operators", 1)
     telemetry.bump("lowering.coded.block_rows", br)
     telemetry.bump("lowering.coded.halo_rows", halo)
-    telemetry.bump("lowering.coded.x_window_rows", _win_rows(br, halo))
+    telemetry.bump(
+        "lowering.coded.x_window_rows",
+        br if pfold and fused else _win_rows(br, halo),
+    )
     telemetry.bump("lowering.coded.plan_vmem_bytes", plan["vmem"])
     telemetry.bump("lowering.coded.pfold", int(pfold))
 

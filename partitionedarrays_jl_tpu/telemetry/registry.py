@@ -273,8 +273,10 @@ CATALOG: Dict[str, MetricSpec] = {
         _spec("lowering.coded.x_window_rows", "counter", "1",
               "parallel/tpu.py:_count_coded_lowering",
               "lane rows of the operand the kernel fetches for each "
-              "block: the block and the halo on both sides (over "
-              "block_rows: how often the operand is read)"),
+              "block in a CG solve of the default body (over "
+              "block_rows: how often the operand is read): the block "
+              "where the fused body folds in the kernel, else the "
+              "block and the halo on both sides"),
         _spec("lowering.coded.plan_vmem_bytes", "counter", "bytes",
               "parallel/tpu.py:_count_coded_lowering",
               "VMEM the plan declares for the plain kernel's buffers"),

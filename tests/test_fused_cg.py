@@ -599,26 +599,40 @@ def test_pfold_kernel_in_place_gives_the_bits_of_its_own_buffer(
     assert np.abs(q_in).max() > 1.0
 
 
-@pytest.mark.parametrize("dtype,pfold", [(np.float32, 1), (np.float64, 0)])
-def test_coded_lowering_counters(monkeypatch, dtype, pfold):
+@pytest.mark.parametrize(
+    "dtype,pfold,fused",
+    [(np.float32, 1, True), (np.float64, 0, True), (np.float32, 1, False)],
+    ids=["float32", "float64", "float32-standard-body"],
+)
+def test_coded_lowering_counters(monkeypatch, dtype, pfold, fused):
     """Staging a coded operator on the padded frame counts it once, with
     its plan; ``.pfold`` is the fold gate's verdict: 1 in float32, 0 in
-    float64, whose doubled windows fail the gate."""
+    float64, whose doubled buffers fail the gate. ``.x_window_rows`` over
+    ``.block_rows`` reads 1 where the fused body folds in the kernel,
+    which fetches every block of its operands once, and the plain
+    kernel's window (the block and the halo on both sides) where it does
+    not: a plan the gate refuses, or the standard body
+    (``PA_TPU_FUSED_CG=0``). Differences of the process's counters, which
+    the other tests of the process bump too."""
     from partitionedarrays_jl_tpu import telemetry
     from partitionedarrays_jl_tpu.ops.pallas_dia import _win_rows
 
     _padded_frame(monkeypatch)
+    if not fused:
+        monkeypatch.setenv("PA_TPU_FUSED_CG", "0")
     before = telemetry.counters("lowering.coded")
     dA = _decoupled_dA(_backend(), (8, 8, 8), dtype, (2, 2, 2))
     after = telemetry.counters("lowering.coded")
     got = {k: after[k] - before.get(k, 0) for k in after}
     plan = dA.pallas_plan
+    window = _win_rows(plan["block_rows"], plan["halo_rows"])
+    assert window > plan["block_rows"]
     assert got == {
         "lowering.coded.operators": 1,
         "lowering.coded.block_rows": plan["block_rows"],
         "lowering.coded.halo_rows": plan["halo_rows"],
-        "lowering.coded.x_window_rows": _win_rows(
-            plan["block_rows"], plan["halo_rows"]
+        "lowering.coded.x_window_rows": (
+            plan["block_rows"] if pfold and fused else window
         ),
         "lowering.coded.plan_vmem_bytes": plan["vmem"],
         "lowering.coded.pfold": pfold,

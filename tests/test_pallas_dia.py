@@ -115,8 +115,24 @@ def _poisson7(n):
 
 
 def _declared(plan, itemsize=4):
-    """The fold variant's declared VMEM: the plan's, a second operand
-    window in two slots, the combined window, the p output block in two."""
+    """The fold variant's declared VMEM: the plan's (the codes, the y
+    block in two, the plain kernel's window in two slots) without that
+    window, a ring of three blocks each for r and p_prev, the combined
+    window, the p output block in two."""
+    from partitionedarrays_jl_tpu.ops.pallas_dia import _win_rows
+
+    BR = plan["block_rows"]
+    win = _win_rows(BR, plan["halo_rows"])
+    # out: the plan's two window slots; in: two rings, the combined
+    # window, the p block's two slots
+    rows = -2 * win + 2 * 3 * BR + win + 2 * BR
+    return plan["vmem"] + rows * LANES * itemsize
+
+
+def _declared_two_windows(plan, itemsize=4):
+    """What the fold variant declared while it fetched a window of r and
+    one of p_prev for every block: the plan's, a second window in two
+    slots, the combined window, the p output block in two."""
     from partitionedarrays_jl_tpu.ops.pallas_dia import _win_rows
 
     win = _win_rows(plan["block_rows"], plan["halo_rows"])
@@ -132,6 +148,7 @@ def test_padded_plan_at_192_cubed_is_unchanged(n_coded, vmem):
     28 row classes are over the class mode's cap), pure Python: the plan
     and the fold verdict are what they were under the 13 MiB gate."""
     from partitionedarrays_jl_tpu.ops.pallas_dia import (
+        PFOLD_VMEM_BYTES,
         plan_dia_padded,
         pfold_vmem_ok,
     )
@@ -141,15 +158,18 @@ def test_padded_plan_at_192_cubed_is_unchanged(n_coded, vmem):
         "vmem": vmem, "block_rows": 2048, "halo_rows": 288, "n_blocks": 27,
         "o0": 262_144, "g0": 29 * 262_144, "code_len": 27 * 262_144,
     }
-    assert _declared(plan) <= 13 * 2**20
+    assert _declared_two_windows(plan) <= 13 * 2**20
+    assert _declared(plan) <= PFOLD_VMEM_BYTES
     assert pfold_vmem_ok(plan)
 
 
 @pytest.mark.parametrize("n_coded", [1, 4])
 def test_padded_plan_at_320_cubed_admits_the_fold(n_coded):
     """poisson7_320: an 800-row halo, 125 blocks; the fold variant's
-    declared buffers (13.4 / 14.9 MiB) are over the old 13 MiB gate and
-    within PFOLD_VMEM_BYTES, so the fused body folds in the kernel."""
+    declared buffers were 13.4 / 14.9 MiB with a window of each operand,
+    over the old 13 MiB gate, and are 12.3 / 13.8 MiB with the rings of
+    three blocks: within PFOLD_VMEM_BYTES, so the fused body folds in the
+    kernel."""
     from partitionedarrays_jl_tpu.ops.pallas_dia import (
         PFOLD_VMEM_BYTES,
         plan_dia_padded,
@@ -158,19 +178,50 @@ def test_padded_plan_at_320_cubed_admits_the_fold(n_coded):
 
     plan = plan_dia_padded(_poisson7(320), 320**3, n_coded)
     assert plan["halo_rows"] == 800 and plan["n_blocks"] == 125
-    assert 13 * 2**20 < _declared(plan) <= PFOLD_VMEM_BYTES
+    assert 13 * 2**20 < _declared_two_windows(plan)
+    assert _declared(plan) < _declared_two_windows(plan)
+    assert _declared(plan) <= PFOLD_VMEM_BYTES
+    assert pfold_vmem_ok(plan)
+
+
+@pytest.mark.parametrize(
+    "n,n_coded",
+    [(192, 4), (300, 4), (320, 4), (360, 4), (192, 1), (510, 4)],
+    ids=["192-cubed", "300-cubed", "320-cubed", "360-cubed",
+         "192-cubed-1-stream", "halo-2040-rows"],
+)
+def test_the_ring_keeps_every_plan_the_windows_admitted(n, n_coded):
+    """Every plan the gate admitted while the fold variant fetched a
+    window of each operand (the 7-point Poisson operator with four code
+    streams up to 360^3, one stream at 192^3) is admitted with the rings
+    of three blocks. The widest halo the padded frame holds (2,040 rows,
+    four streams: 16.9 MiB with the windows, refused) declares 15.0 MiB
+    with the rings and is admitted: compiled for a described v5e it
+    builds under VMEM_LIMIT_BYTES, as the 320^3 plan does."""
+    from partitionedarrays_jl_tpu.ops.pallas_dia import (
+        PFOLD_VMEM_BYTES,
+        plan_dia_padded,
+        pfold_vmem_ok,
+    )
+
+    plan = plan_dia_padded(_poisson7(n), n**3, n_coded)
+    assert (_declared_two_windows(plan) <= PFOLD_VMEM_BYTES) == (n != 510)
+    assert _declared(plan) <= PFOLD_VMEM_BYTES
     assert pfold_vmem_ok(plan)
 
 
 @pytest.mark.parametrize(
     "n,n_coded,itemsize",
-    [(510, 4, 4), (510, 8, 4), (8, 4, 8)],
-    ids=["halo-2040-rows", "halo-2040-rows-8-streams", "float64"],
+    [(510, 8, 4), (8, 4, 8)],
+    ids=["halo-2040-rows-8-streams", "float64"],
 )
 def test_a_fold_over_the_budget_falls_back(n, n_coded, itemsize):
     """A band the plain kernel takes but whose fold variant declares more
-    than PFOLD_VMEM_BYTES (the widest halo the padded frame holds, or
-    float64's doubled windows) keeps the jnp fold."""
+    than PFOLD_VMEM_BYTES (the widest halo the padded frame holds with
+    eight code streams, or float64's doubled rings) keeps the jnp fold.
+    With four streams that halo is admitted since the fold variant reads
+    its operands through rings of blocks
+    (`test_the_ring_keeps_every_plan_the_windows_admitted`)."""
     from partitionedarrays_jl_tpu.ops.pallas_dia import (
         PFOLD_VMEM_BYTES,
         plan_dia_padded,
@@ -362,17 +413,26 @@ def _fold_call(halo_lanes, n_blocks_owned, k=None, seed=3):
 
 @pytest.mark.parametrize(
     "halo_lanes,n_blocks,k",
-    [(800, 3, None), (800, 3, 3), (16, 2, 3)],
-    ids=["320-cubed-halo", "320-cubed-halo-3-columns", "3-columns"],
+    [(800, 3, None), (800, 3, 3), (16, 2, 3), (16, 1, None), (16, 1, 3),
+     (16, 2, None), (2040, 3, None), (2040, 2, 3)],
+    ids=["320-cubed-halo", "320-cubed-halo-3-columns", "3-columns",
+         "one-block", "one-block-3-columns", "two-blocks", "widest-halo",
+         "widest-halo-3-columns"],
 )
 def test_fold_in_place_gives_the_bits_of_its_own_buffer(halo_lanes, n_blocks, k):
     """The fold kernel, which writes p over p_prev, on random operands:
     its ``y`` holds the bits the plain kernel gives on the p it returned,
     held in a buffer of its own, and that p is ``r + beta p_prev`` on the
-    owned band; one vector and K = 3 columns on the leading grid axis.
+    owned band and exactly zero in every other slot; one vector and K = 3
+    columns on the leading grid axis. The kernel reads each block of r
+    and p_prev once through a ring of three, so one, two and three owned
+    blocks walk the ring's start, its turn and its end, and the widest
+    halo a plan allows (``PAD_BLOCK_ROWS - 8`` rows) takes all of block
+    j-1 but eight rows and all of block j+1 into a window.
     Interpret mode runs a DMA at its start and cannot show a race: the
-    store ordering itself is checked on the chip, against the kernel that
-    kept p apart (`benchmark/tests/pfold_inplace_bits.py --parent`)."""
+    store ordering itself is checked on the chip, against the kernel of
+    a checkout that fetched a window a block
+    (`benchmark/tests/pfold_inplace_bits.py --parent`)."""
     from partitionedarrays_jl_tpu.ops import pallas_dia
 
     plan, args, pfold = _fold_call(halo_lanes, n_blocks, k)
@@ -387,8 +447,13 @@ def test_fold_in_place_gives_the_bits_of_its_own_buffer(halo_lanes, n_blocks, k)
     np.testing.assert_array_equal(np.asarray(y_in), np.asarray(y_own))
     r, (pprev, beta) = args[3], pfold
     bk = beta.reshape((-1,) + (1,) * (r.ndim - 1))
-    want = np.where(r != 0, r + bk * pprev, 0)
-    np.testing.assert_allclose(p_own, want, rtol=1e-6, atol=1e-6)
+    flat = np.arange(r.shape[-2] * LANES).reshape(r.shape[-2:])
+    o0, no = plan["o0"], int(args[1][0])
+    band = np.broadcast_to((flat >= o0) & (flat < o0 + no), r.shape)
+    assert not p_own[~band].any()
+    np.testing.assert_allclose(
+        p_own[band], (r + bk * pprev)[band], rtol=1e-6, atol=1e-6
+    )
     assert np.abs(np.asarray(y_in)).max() > 1.0
 
 
