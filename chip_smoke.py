@@ -14,7 +14,7 @@ Legs, in order:
 1. coded-DIA CG        Poisson, `pa.cg`, fused body, Mosaic coded kernel
 2. compiled GMG-PCG    same operator, `pa.gmg_hierarchy` -> `pa.pcg`
 3. served solves       `SolveService(A, kmax=4)`, 8 requests, block body
-4. streaming-DIA CG    variable-coefficient diffusion, Mosaic streaming kernel
+4. streaming-DIA CG    `pa.assemble_diffusion_fv`, Mosaic streaming kernel
 5. irregular graph     tet elasticity at 64^3 nodes, SD lowering, Jacobi-PCG
 
 Exits non-zero, printing no result, unless `jax.devices()[0].platform` is
@@ -29,7 +29,6 @@ reports it. Every second printed here is a smoke's, not a benchmark's.
 from __future__ import annotations
 
 import gc
-import importlib.util
 import json
 import os
 import sys
@@ -75,14 +74,12 @@ def timed(f):
     return out, round(time.perf_counter() - t0, 3)
 
 
-def load_tool(name: str):
-    """A sibling under tools/, loaded the way the tools load each other."""
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(HERE, "tools", f"{name}.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def hpgmg_beta(x, y, z):
+    """HPGMG-FV's coefficient, as the `varcoef7_192` cell sets it: 1
+    inside a sphere of radius 0.25 about the cube's centre, 10 outside,
+    a smooth tanh jump between."""
+    r = np.sqrt((x - 0.5) ** 2 + (y - 0.5) ** 2 + (z - 0.5) ** 2)
+    return 5.5 + 4.5 * np.tanh(10.0 * (r - 0.25))
 
 
 # ---------------------------------------------------------------------------
@@ -383,8 +380,9 @@ def leg_served(pa, system) -> dict:
 
 
 def leg_stream_dia(pa, parts, ns) -> dict:
-    assemble = load_tool("bench_multirhs").assemble_varcoef_poisson
-    A, setup = timed(lambda: assemble(parts, ns, pa, np.float32))
+    A, setup = timed(
+        lambda: pa.assemble_diffusion_fv(parts, ns, hpgmg_beta, np.float32)
+    )
     rec = device_state(pa, A, seed=2)
     want = "stream-dia/pallas" if on_tpu(A) else "stream-dia/xla"
     require(

@@ -45,8 +45,7 @@ budgets, PR 9 pamon metrics/SLO accounting, PR 10 adaptive K):
 CLI: ``tools/pagate.py serve|submit|loadgen`` (``--check`` is the
 tier-1 smoke); durability drills: ``tools/padur.py`` (``--check``
 tier-1, ``--drill`` the SIGKILL harness under ``-m slow``); fleet:
-``tools/pafleet.py serve|kill|--check|--drill``; bench:
-``tools/bench_gate.py`` -> ``GATE_BENCH.json``.
+``tools/pafleet.py serve|kill|--check|--drill``.
 Protocol docs: docs/service.md (Front door, Gate fleet),
 docs/resilience.md (Durability).
 """

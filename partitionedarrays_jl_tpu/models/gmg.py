@@ -419,7 +419,7 @@ def galerkin_cartesian(
     owned-box interior is emitted straight to per-part CSR by
     planning.cpp:galerkin_emit_dim (`_galerkin_fused`). This removed
     the extraction+migration+compression passes that were 98% of the
-    398 s hierarchy setup at 1e8 DOFs (SCALE_BENCH r3)."""
+    398 s hierarchy setup at 1e8 DOFs (a round-3 record, since deleted)."""
     from scipy.sparse import csr_matrix
 
     from .. import native

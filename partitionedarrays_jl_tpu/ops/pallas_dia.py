@@ -409,8 +409,7 @@ def _padded_kernel(cb_ref, no_ref, codes_ref, xw_ref, *refs,
             # host CSR kernel over that class's stored entries (the
             # skipped terms are the host's absent entries), so agreement
             # with the select path and the host oracle holds to
-            # FMA-contraction rounding — the documented determinism
-            # contract (docs/performance.md).
+            # FMA-contraction rounding — the determinism contract.
             sh = [shift_of(q, r) for (q, r) in qr]
             c = (cs_ref[slot, 0].astype(jnp.int32)) & 15
             accs = []

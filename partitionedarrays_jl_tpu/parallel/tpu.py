@@ -1465,7 +1465,7 @@ class DeviceMatrix:
                 default=0,
             )
             L_oo = max(L_oo, 1)
-            # device-fault guard (moved here from tools/bench_irregular):
+            # device-fault guard:
             # the library must never stage an ELL gather program past the
             # footprint that faults real TPU workers — neither by
             # auto-selection nor forced by strict-bits
@@ -1952,15 +1952,14 @@ class DeviceMatrix:
         A_oo block already gets. Returns None whenever any precondition
         fails; callers keep the per-element ELL boundary path.
 
-        BUCKETED widths (the round-4 directive-7 leftover, closing the
-        docs/roadmap.md §4 note): boundary rows are padded per
-        contiguous BUCKET of boundary nodes to that bucket's own
-        blocks-per-row maximum, not the global one — corner/edge nodes
-        with deep ghost coupling no longer inflate the padded gather
-        count of every face node (the same treatment `_detect_sd` gives
-        the owned groups). ``PA_TPU_OH_BUCKETS=0`` collapses to one
-        global-width bucket (the pre-bucketing program) for A/B runs —
-        tools/bench_irregular.py records both legs."""
+        BUCKETED widths (the round-4 directive-7 leftover): boundary
+        rows are padded per contiguous BUCKET of boundary nodes to that
+        bucket's own blocks-per-row maximum, not the global one —
+        corner/edge nodes with deep ghost coupling no longer inflate the
+        padded gather count of every face node (the same treatment
+        `_detect_sd` gives the owned groups). ``PA_TPU_OH_BUCKETS=0``
+        collapses to one global-width bucket (the pre-bucketing program)
+        for A/B runs."""
         from scipy.sparse import csr_matrix
 
         if col_layout.box_info is not None:
@@ -2177,12 +2176,10 @@ class DeviceMatrix:
     def _detect_bsr(cls, oo, P, noids, no_max, dt):
         """Node-block (BSR) lowering for irregular vector-dof operators:
         one gather index per bs×bs block instead of per element cuts the
-        TPU's element-at-a-time gather count ~bs²× (measured 23.9x over
-        the ELL lowering on the Morton-partitioned tet-elasticity system
-        — tools/bench_irregular.py), and the block products become
-        vectorized einsum fmas. Chosen when the blocks are dense enough
-        (`BSR_MIN_FILL`); strict-bits mode keeps the fold-order-matching
-        ELL path, and `PA_TPU_BSR=0` disables."""
+        TPU's element-at-a-time gather count ~bs²×, and the block
+        products become vectorized einsum fmas. Chosen when the blocks
+        are dense enough (`BSR_MIN_FILL`); strict-bits mode keeps the
+        fold-order-matching ELL path, and `PA_TPU_BSR=0` disables."""
         if strict_bits() or os.environ.get("PA_TPU_BSR", "1") == "0":
             return None
         from scipy.sparse import csr_matrix
@@ -4197,9 +4194,9 @@ def make_block_cg_fn(
       blocks and BSR blocks (one ``(rows, U) @ (U, K)`` product), for
       streamed DIA values and ELL arrays (broadcast over the trailing
       axis) and for the halo slabs, which is what makes the
-      HBM-bound large-N iteration cheaper PER RHS as K grows there
-      (docs/performance.md, Multi-RHS). Every operator but the one
-      below, the standard body, strict-bits and the SDC-defended loops.
+      HBM-bound large-N iteration cheaper PER RHS as K grows there.
+      Every operator but the one below, the standard body, strict-bits
+      and the SDC-defended loops.
     * ``"lanes"``, operands ``(K, W)`` with a column a row (held as the
       kernel reads a frame, ``(K, W // 128, 128)``): where the A_oo
       block runs the coded Mosaic kernel on the padded frame and the body

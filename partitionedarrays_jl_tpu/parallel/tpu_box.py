@@ -165,8 +165,8 @@ def analyze_box_structure(rows: PRange) -> Optional[BoxInfo]:
     # part_stride parks whole parts): an INACTIVE part — no owned ids
     # AND no ghosts — is admitted as a degenerate variant that never
     # sends or receives, so slab-shaped transfer ghost sets on the
-    # active parts still get the slice plan (docs/roadmap.md §4: the
-    # matrix-S fallback used to drop to the generic gather plan here).
+    # active parts still get the slice plan (the matrix-S fallback used
+    # to drop to the generic gather plan here).
     # An empty box WITH ghosts is not that case — decline.
     for i in isets:
         if math.prod(i.box_shape) == 0 and i.num_hids:

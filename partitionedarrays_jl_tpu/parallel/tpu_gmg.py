@@ -108,7 +108,7 @@ def _device_hierarchy(h, backend: TPUBackend):
             entry.update(st)
         else:
             # fallback: the assembled rectangular transfers (gather-bound
-            # on real TPUs — see docs/performance.md)
+            # on real TPUs)
             entry["dR"] = device_matrix(lvl.R, backend)
             entry["dP"] = device_matrix(lvl.P, backend)
         levels.append(entry)
@@ -330,7 +330,7 @@ def _stage_structured_transfer(h, li: int, backend: TPUBackend):
 
     Why: the assembled rectangular transfers lower to per-row column
     gathers, which run element-at-a-time on TPU and dominated the
-    measured V-cycle cost 100:1 (docs/performance.md); the factored form
+    measured V-cycle cost 100:1 (round 1); the factored form
     replaces 8N gathered elements with one stencil SpMV plus N/8
     scatter/gather elements."""
     from ..models.gmg import interp_stencil_cartesian
@@ -344,7 +344,7 @@ def _stage_structured_transfer(h, li: int, backend: TPUBackend):
     )
     # S inherits the level dtype: an f32 hierarchy stages f32 transfer
     # operators end-to-end (the stencil weights — powers of 1/2 — are
-    # exact in both widths), closing the docs/roadmap.md §4 f64 detour
+    # exact in both widths), with no f64 detour
     S = interp_stencil_cartesian(lvl.nfs, lvl.A.rows, dtype=lvl.A.dtype)
     dS = device_matrix(S, backend)
     LS = dS.col_plan.layout

@@ -16,7 +16,7 @@ records them in ``analysis.env_lint.NON_LOWERING``):
 * ``PA_SERVE_QUEUE_DEPTH`` (default 64) — admission bound: queued
   requests allowed before `AdmissionRejected` backpressure.
 * ``PA_SERVE_KMAX`` (default 8) — widest slab the batcher coalesces
-  (the measured K=8–16 per-RHS sweet spot; MULTIRHS_BENCH.json).
+  (K=8–16 was the per-RHS sweet spot of an early multi-RHS sweep).
 * ``PA_SERVE_CHUNK`` (default 25) — chunk length in solver iterations
   for deadline-carrying slabs: the compiled program cannot stop
   mid-loop, so deadlines are enforced at chunk boundaries. Slabs with

@@ -18,7 +18,7 @@ full catalog; `tools/patrace.py` is the CLI):
   runtime contract).
 * `telemetry.trace` / `telemetry.artifacts` — Chrome-trace/Perfetto
   export of records + PTimer sections, and the shared schema-versioned
-  bench-artifact writer.
+  record writer.
 
 Hard contract (same discipline as ABFT): telemetry OFF is HLO-identical
 to the pre-telemetry programs; telemetry ON adds ZERO collectives — the
@@ -135,14 +135,6 @@ from .tracing import (  # noqa: F401
     tracing_enabled,
     verify_trace,
 )
-from .ledger import (  # noqa: F401
-    LEDGER_SCHEMA_VERSION,
-    build_ledger,
-    check_artifact,
-    check_repo,
-    extract_metrics,
-    update_ledger,
-)
 
 __all__ = [
     "ANOMALY_KINDS",
@@ -168,7 +160,6 @@ __all__ = [
     "CATALOG",
     "COMMS_MATRIX_SCHEMA_VERSION",
     "COMM_KINDS",
-    "LEDGER_SCHEMA_VERSION",
     "PHASES",
     "PHASE_SCHEMA_VERSION",
     "PHASE_SUM_BAND",
@@ -191,16 +182,12 @@ __all__ = [
     "annotate",
     "apply_delta",
     "begin_record",
-    "build_ledger",
     "bump",
     "capture_phase_profile",
     "cg_comms_profile",
-    "check_artifact",
-    "check_repo",
     "chrome_trace",
     "classify_edge",
     "clear_history",
-    "extract_metrics",
     "measure_comms_matrix",
     "phase_trace_events",
     "reconcile_matrix",
@@ -208,7 +195,6 @@ __all__ = [
     "render_comms_matrix",
     "render_phase_profile",
     "static_matrix",
-    "update_ledger",
     "counter",
     "counters",
     "current_record",

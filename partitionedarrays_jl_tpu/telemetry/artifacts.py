@@ -1,18 +1,18 @@
-"""The ONE schema-versioned bench-artifact writer.
+"""The ONE schema-versioned record writer.
 
-Every committed ``*_BENCH.json`` record used to be hand-rolled by its
-bench tool (five slightly different ``json.dump`` blocks); this module
-is their shared writer. `stamp` adds the provenance envelope —
-``schema_version``, the generating tool, the accelerator platform, and
-the ``PA_*`` environment snapshot — WITHOUT overwriting anything the
-tool already recorded (the committed artifacts' existing keys are the
-contract `tests/test_doc_consistency.py` pins). `write` serializes with
-one canonical format (indent=1, sorted keys — byte-stable diffs) and
-honors the benches' shared ``--dry-run`` convention.
+The tools that commit a record (palint's memory report, paspec,
+paprof's profile and comms matrix, paelastic) write it through this
+module. `stamp` adds the provenance envelope — ``schema_version``, the
+generating tool, the accelerator platform, and the ``PA_*`` environment
+snapshot — WITHOUT overwriting anything the tool already recorded (the
+committed records' existing keys are the contract
+`tests/test_doc_consistency.py` pins). `write` serializes with one
+canonical format (indent=1, sorted keys — byte-stable diffs) and honors
+the tools' shared ``--dry-run`` convention.
 
 ``ARTIFACT_SCHEMA_VERSION`` history:
 
-* **1** — the envelope above; adopted by every committed ``*_BENCH.json``
+* **1** — the envelope above; carried by every committed record
   (test_doc_consistency asserts presence on each).
 """
 from __future__ import annotations
@@ -36,9 +36,9 @@ def _platform() -> str:
 
 
 def stamp(rec: dict, tool: Optional[str] = None) -> dict:
-    """Add the provenance envelope to a bench record, in place and
-    returned. ``setdefault`` throughout: a tool that records its own
-    ``platform`` (bench_abft's cpu-canary gating) keeps it."""
+    """Add the provenance envelope to a record, in place and returned.
+    ``setdefault`` throughout: a tool that records its own ``platform``
+    keeps it."""
     rec.setdefault("schema_version", ARTIFACT_SCHEMA_VERSION)
     if tool:
         rec.setdefault("generated_by", tool)
@@ -55,7 +55,7 @@ def stamp(rec: dict, tool: Optional[str] = None) -> dict:
 def write(path: str, rec: dict, tool: Optional[str] = None,
           dry_run: bool = False, echo: bool = True) -> dict:
     """Stamp and serialize one artifact. ``dry_run`` prints the record
-    (the benches' shared convention) without touching ``path``."""
+    (the tools' shared convention) without touching ``path``."""
     rec = stamp(rec, tool=tool)
     out = json.dumps(rec, indent=1, sort_keys=True)
     if dry_run:

@@ -228,8 +228,8 @@ def reconcile_matrix(matrix: dict, dA, abft: bool = False) -> list:
 
 def _round_chains(plan, backend, K: int):
     """One jitted k-step chain per GENERIC-plan round: that round's
-    pack + `ppermute` + unpack, with the bench_halo owned<-ghost
-    feedback so the pack stays inside the loop."""
+    pack + `ppermute` + unpack, with an owned<-ghost feedback so the
+    pack stays inside the loop."""
     import functools
 
     import jax

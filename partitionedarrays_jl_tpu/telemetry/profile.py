@@ -8,13 +8,13 @@ iteration's wall time, how much is SpMV compute, how much halo
 exchange, how much the dot all_gathers, how much the axpy sweeps?
 
 One capture method, the deterministic **split-timer**: time each phase
-as its OWN compiled k-step chain (the `bench.py` marginal-chain
-protocol: warm, median-of-reps, difference two trip counts so dispatch
-cancels) built from the same `DeviceMatrix` the solver lowers from: the
-halo exchange body, the full SpMV (halo included — the local share is
-the difference), one deterministic dot all_gather, and the three-update
-axpy sweep. Op-level truth from a captured profile is read elsewhere:
-the compiled programs carry the SAME four phases as `jax.named_scope`s
+as its OWN compiled k-step chain (`_marginal_s`: warm, min-of-reps,
+difference two trip counts so dispatch cancels) built from the same
+`DeviceMatrix` the solver lowers from: the halo exchange body, the full
+SpMV (halo included — the local share is the difference), one
+deterministic dot all_gather, and the three-update axpy sweep.
+Op-level truth from a captured profile is read elsewhere: the compiled
+programs carry the SAME four phases as `jax.named_scope`s
 (``pa.spmv_local`` ... — `parallel/tpu.py` ``SCOPE_*``), and
 `benchmark/layer_metrics/_scoped.py` reduces a chip trace by them.
 
@@ -166,12 +166,11 @@ def _marginal_s(run_chain: Callable[[int], float], k1: int, k2: int,
                 reps: int) -> float:
     """Marginal per-step cost of a compiled chain: warm both trip
     counts, MIN-of-reps each, difference so dispatch/fetch overhead
-    cancels (the bench.py protocol, compacted). Min, not median: on a
-    shared/loaded host, contention only ever INFLATES a run, so the
-    min of each side is the least-contended estimate and the
-    difference is far more stable under load than median-of-reps. One
-    doubling retry absorbs timer-noise inversions on very cheap
-    chains."""
+    cancels. Min, not median: on a shared/loaded host, contention only
+    ever INFLATES a run, so the min of each side is the least-contended
+    estimate and the difference is far more stable under load than
+    median-of-reps. One doubling retry absorbs timer-noise inversions
+    on very cheap chains."""
     def timed(k: int) -> float:
         run_chain(k)
         run_chain(k)
@@ -204,7 +203,7 @@ def _phase_chains(dA, rhs_batch: Optional[int]) -> Dict[str, Callable]:
     building blocks the CG bodies compile from, each wrapped in a
     jitted k-step ``fori_loop`` ending in a scalar fetch. Every chain
     carries a tiny owned<-ghost / state feedback so XLA cannot hoist
-    the phase work out of the loop (the bench_halo precedent)."""
+    the phase work out of the loop."""
     import functools
 
     import jax

@@ -31,8 +31,7 @@ Design rules:
 * **Zero device impact.** Nothing here can reach a traced program:
   the registry is host-side Python; the overhead pin (service slab is
   a program-cache HIT with the registry fully enabled) lives in
-  tests/test_pamon.py, and the measured metrics-on/off throughput
-  marginal is banded in SERVICE_BENCH.json.
+  tests/test_pamon.py.
 
 Env knobs (host-side, NON_LOWERING-exempt with reasons):
 

@@ -1,12 +1,10 @@
 """The online per-RHS throughput model — the measured input of
 adaptive K.
 
-ROADMAP item 1's adaptive-K policy wants "queue depth × the MEASURED
-per-RHS curve", but until PR 9 the per-RHS curve existed only as a
-hand-run bench artifact (MULTIRHS_BENCH.json / SERVICE_BENCH.json).
-This module keeps the curve ALIVE: every finished service slab reports
-its measured seconds-per-iteration, and the model EWMAs them into a
-table keyed by ``(operator fingerprint, dtype, K)`` — the same
+The adaptive-K policy wants "queue depth × the MEASURED per-RHS
+curve". This module keeps that curve: every finished service slab
+reports its measured seconds-per-iteration, and the model EWMAs them
+into a table keyed by ``(operator fingerprint, dtype, K)`` — the same
 measured-over-assumed principle as Node-Aware SpMV's per-link cost
 models (arXiv:1612.08060) and the adaptive-collectives runtime
 statistics (arXiv:2607.04676).
@@ -30,10 +28,8 @@ What the model answers:
 Updates are EWMA (``PA_MON_EWMA``, default 0.25) so the model tracks
 drift (thermal throttling, co-tenant load) without forgetting history,
 and are gated by ``PA_MON`` like the rest of the instrumentation.
-``export()`` emits the schema-versioned table that
-``tools/bench_service.py`` writes as ``THROUGHPUT_MODEL.json`` through
-the shared artifacts writer — `tests/test_doc_consistency.py` ties the
-committed record to the MULTIRHS per-RHS curve at overlapping K.
+``export()`` emits the model as a schema-versioned table, which
+``tools/pamon.py --model`` renders.
 """
 from __future__ import annotations
 

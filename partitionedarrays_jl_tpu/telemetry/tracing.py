@@ -52,8 +52,7 @@ The overhead contract (the PR 6/9/10 convention): the solver path
 never reads a ``PA_TX*`` flag — compiled programs are byte-identical
 StableHLO tracing on or off (pinned in tests/test_patx.py) — and span
 capture is host-side behind ``PA_TX`` (default on) with an inert fast
-path like `SolveRecord.event`; the measured tracing-on/off drained
-requests/s marginal is banded in SERVICE_BENCH.json.
+path like `SolveRecord.event`.
 
 Env knobs (host-side, NON_LOWERING-exempt with reasons):
 

@@ -78,7 +78,7 @@ def pairwise_sum(v):
 #: Krylov residual estimate in dtype d cannot reliably resolve below
 #: ~TOL_FLOOR_EPS_MULTIPLE x eps(d) x problem scale (round-3 finding:
 #: an f32 FGMRES with tol=1e-8 oscillates at the floor with an accurate
-#: solution and converged=False — docs/roadmap.md §5, now implemented)
+#: solution and converged=False)
 TOL_FLOOR_EPS_MULTIPLE = 50.0
 
 

@@ -1,21 +1,21 @@
-"""Doc/artifact traceability guard (round-5 rule: every number in the
-docs traces to a committed artifact or carries its round tag).
+"""Doc/record traceability guard (round-5 rule: every number in the
+docs traces to a committed record or carries its round tag).
 
-Two stale-doc classes have actually shipped in this repo's history —
-a capability claim that code had already obsoleted (docs/roadmap.md §1
-"still require equal per-part boxes", contradicted by the shape-variant
-`lax.switch` transfers in tpu_gmg.py and GMG_BENCH.json), and
-historical bench numbers quoted without their round tag (the round-4
-"11.1 GFLOP/s" lived only in a commit message). This file makes the
+Stale-doc classes that have actually shipped in this repo's history —
+a capability claim that code had already obsoleted ("still require
+equal per-part boxes", contradicted by the shape-variant `lax.switch`
+transfers in tpu_gmg.py), historical numbers quoted without their round
+tag (the round-4 "11.1 GFLOP/s" lived only in a commit message), and
+instructions naming a tool or record that is gone. This file makes the
 traceability rule enforce itself:
 
 * known-stale claim patterns must not reappear in committed docs;
 * superseded historical figures may only appear in a paragraph that
   carries a round/era tag;
-* the committed artifacts and the bench guards that gate them must
-  agree (band bounds in the artifact == the guard tables in tools/).
+* every repo path a document names exists;
+* the committed records carry the shared envelope and agree with the
+  code that derives them (budgets, fabric summaries, the analytic κ).
 """
-import importlib.util
 import json
 import os
 import re
@@ -26,8 +26,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 DOC_FILES = [
     "README.md",
-    "docs/performance.md",
-    "docs/roadmap.md",
     "docs/design.md",
     "docs/api.md",
     "docs/migration.md",
@@ -43,7 +41,7 @@ BANNED_PATTERNS = [
     (
         r"still require equal per-part boxes",
         "obsoleted by the shape-variant lax.switch transfers "
-        "(tpu_gmg.py, round 5; GMG_BENCH.json records the paths)",
+        "(tpu_gmg.py, round 5)",
     ),
     (
         r"practical floor under current XLA\s+while-loop semantics",
@@ -75,15 +73,6 @@ def _doc_paragraphs():
             yield rel, para
 
 
-def _load_tool(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO, "tools", f"{name}.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def test_no_banned_stale_claims():
     hits = []
     for rel, para in _doc_paragraphs():
@@ -108,55 +97,55 @@ def test_historical_figures_carry_their_round_tag():
     )
 
 
-def test_scale_bench_artifact_agrees_with_guard_bands():
-    """The committed flagship artifact and the bench guard must agree:
-    identical band bounds, and the recorded device metrics inside them
-    (a lowered band with a stale artifact — or vice versa — is exactly
-    the drift this file exists to catch)."""
-    bench_scale = _load_tool("bench_scale")
-    rec = json.load(open(os.path.join(REPO, "SCALE_BENCH.json")))
-    for key, (lo, hi, kind) in bench_scale.SCALE_BANDS.items():
-        band = rec["bands"].get(key)
-        assert band is not None, f"artifact missing band {key}"
-        assert (band["lo"], band["hi"]) == (lo, hi), (
-            f"band bounds for {key} drifted: guard ({lo}, {hi}) vs "
-            f"artifact ({band['lo']}, {band['hi']})"
-        )
-        if kind == "device":
-            assert band["in_band"], (key, band)
-    assert rec["bands_ok_device"] is True
+#: ``docs/<name>.md|json``, ``tools/<name>.py``, ``benchmark/<path>`` or
+#: an upper-case top-level ``<NAME>.json``; a name with a wildcard or a
+#: placeholder (``*``, ``<``) does not match and is skipped.
+REPO_PATH = re.compile(
+    r"(?<![\w/.-])("
+    r"tools/[\w-]+\.py"
+    r"|docs/[\w-]+\.(?:md|json)"
+    r"|benchmark/[\w./-]*\w"
+    r"|[A-Z][A-Z0-9_]*\.json"
+    r")(?![\w*<])"
+)
+#: The documents that describe the system as it is. CHANGES.md, PERF.md
+#: and ROADMAP.md hold history, which may name a deleted file.
+PATH_CHECKED_DOCS = [
+    "README.md",
+    "docs/api.md",
+    "docs/design.md",
+    "docs/migration.md",
+    "docs/observability.md",
+    "docs/resilience.md",
+    "docs/service.md",
+    "docs/static_analysis.md",
+]
 
 
-def test_multirhs_artifact_agrees_with_guard_bands():
-    """The committed multi-RHS flagship artifact and the bench guard
-    must agree: identical band bounds, recorded device metrics inside
-    them, and the curve rows the bands were derived from actually
-    present and self-consistent (per_rhs = block / K; the K=8 speedup
-    claim in the docs traces to THIS record)."""
-    bench_mr = _load_tool("bench_multirhs")
-    rec = json.load(open(os.path.join(REPO, "MULTIRHS_BENCH.json")))
-    assert rec["methodology"] == bench_mr.METHODOLOGY
-    assert rec["ks"] == list(bench_mr.KS)
-    by_k = {row["K"]: row for row in rec["curve"]}
-    assert set(by_k) == set(rec["ks"])
-    for row in rec["curve"]:
-        assert abs(
-            row["per_rhs_s_per_it"] - row["block_s_per_it"] / row["K"]
-        ) <= 1e-4 * row["per_rhs_s_per_it"], row  # artifact rounding
-    for key, (lo, hi, kind) in bench_mr.MULTIRHS_BANDS.items():
-        band = rec["bands"].get(key)
-        assert band is not None, f"artifact missing band {key}"
-        assert (band["lo"], band["hi"]) == (lo, hi), (key, band)
-        k = int(key.rsplit("k", 1)[-1])
-        assert band["measured"] == by_k[k]["per_rhs_speedup_vs_k1"], (
-            key, band, by_k[k],
-        )
-        if kind == "device":
-            assert band["in_band"], (key, band)
-    # the acceptance floor: >= 1.5x per-RHS at K=8 on a >= 320^3 size
-    assert rec["n"] >= 320 and rec["dofs"] == rec["n"] ** 3
-    assert by_k[8]["per_rhs_speedup_vs_k1"] >= 1.5
-    assert rec["bands_ok_device"] is True
+def _code_spans(text):
+    """The text of every fenced block and every inline code span."""
+    fenced = re.compile(r"^```[^\n]*\n(.*?)^```", re.S | re.M)
+    for m in fenced.finditer(text):
+        yield m.group(1)
+    for m in re.finditer(r"`([^`\n]+)`", fenced.sub("", text)):
+        yield m.group(1)
+
+
+@pytest.mark.parametrize("doc", PATH_CHECKED_DOCS)
+def test_every_repo_path_a_document_names_exists(doc):
+    """A tool, record or document that is deleted must not live on as a
+    dangling instruction: every repo path named in code in ``doc``
+    exists in the tree."""
+    text = open(os.path.join(REPO, doc), encoding="utf-8").read()
+    named = {
+        m.group(1)
+        for span in _code_spans(text)
+        for m in REPO_PATH.finditer(span)
+    }
+    missing = sorted(
+        p for p in named if not os.path.exists(os.path.join(REPO, p))
+    )
+    assert not missing, f"{doc} names paths that do not exist: {missing}"
 
 
 def test_metric_catalog_agrees_with_registry_both_directions():
@@ -203,189 +192,6 @@ def test_metric_catalog_agrees_with_registry_both_directions():
             else tuple(s.strip() for s in labels.split(","))
         )
         assert doc_labels == spec.labels, (name, doc_labels, spec.labels)
-
-
-def test_throughput_model_ties_to_multirhs():
-    """The committed THROUGHPUT_MODEL.json (round 12 — the adaptive-K
-    input) must be the real thing: schema-versioned under the shared
-    artifact envelope, its online-measured entries internally
-    consistent (per_rhs = s_per_it/K, EWMA fed by >= 2 samples — a
-    one-shot value is a bench row, not an online model), measured at
-    every K the SERVICE_BENCH sweep ran, and its reference curve EQUAL
-    to the committed MULTIRHS device record at every overlapping K —
-    the committed model can never drift from the device curve it
-    converges to."""
-    from partitionedarrays_jl_tpu import telemetry
-
-    bench_svc = _load_tool("bench_service")
-    rec = json.load(open(os.path.join(REPO, "THROUGHPUT_MODEL.json")))
-    mr = json.load(open(os.path.join(REPO, "MULTIRHS_BENCH.json")))
-    assert rec["throughput_schema_version"] == (
-        telemetry.THROUGHPUT_SCHEMA_VERSION
-    )
-    # the shared artifact envelope
-    assert rec.get("schema_version") == telemetry.ARTIFACT_SCHEMA_VERSION
-    assert rec.get("generated_by") == "bench_service"
-    assert rec.get("platform") and isinstance(rec.get("pa_env"), dict)
-    assert 0.0 < rec["ewma_alpha"] <= 1.0
-    # online-measured entries: loadable, consistent, covering the sweep
-    model = telemetry.ThroughputModel.load(rec)
-    entries = rec["entries"]
-    assert entries, "committed model must hold measured entries"
-    for e in entries:
-        assert abs(
-            e["per_rhs_s_per_it"] - e["s_per_it"] / e["K"]
-        ) <= 1e-6 * e["per_rhs_s_per_it"], e
-        assert e["samples"] >= 2, (e, "an online EWMA needs >= 2 samples")
-        assert e["iterations"] >= e["samples"], e
-    fp = rec["operator_fingerprint"]
-    dtype = rec["dtype"]
-    measured_ks = set(model.curve(fp, dtype))
-    assert measured_ks == set(bench_svc.KS), (measured_ks, bench_svc.KS)
-    # suggest_k reads the committed curve coherently: never wider than
-    # the queue, and the argmin of the measured per-RHS curve when wide
-    curve = model.curve(fp, dtype)
-    best = min(curve, key=lambda k: (curve[k], -k))
-    assert model.suggest_k(fp, dtype, queue_depth=64, kmax=64) == best
-    assert model.suggest_k(fp, dtype, queue_depth=1, kmax=64) == 1
-    # the reference curve IS the MULTIRHS device record
-    ref = rec["reference_curve"]
-    assert ref["source"] == "MULTIRHS_BENCH.json"
-    assert (ref["n"], ref["dtype"]) == (mr["n"], mr["dtype"])
-    mr_by_k = {str(r["K"]): r for r in mr["curve"]}
-    assert set(ref["per_rhs_s_per_it"]) == set(mr_by_k)
-    for k, row in mr_by_k.items():
-        assert ref["per_rhs_s_per_it"][k] == row["per_rhs_s_per_it"], k
-        assert ref["per_rhs_speedup_vs_k1"][k] == (
-            row["per_rhs_speedup_vs_k1"]
-        ), k
-
-
-def test_service_artifact_inherits_multirhs_floor():
-    """The committed solve-service artifact (round 10) and its bench
-    guard must agree — and the artifact's device claim must be
-    TRACEABLE: the per-RHS gains it records are inherited from the
-    committed MULTIRHS_BENCH.json record (the service feeds the
-    identical compiled block program — tests/test_service.py pins the
-    program-cache hit), so the two artifacts must carry EQUAL values,
-    with the K=8 ≥ 1.5x acceptance floor intact. The locally measured
-    service rows must be internally consistent (requests/s = K / wall,
-    ratio = solo/service)."""
-    bench_svc = _load_tool("bench_service")
-    rec = json.load(open(os.path.join(REPO, "SERVICE_BENCH.json")))
-    mr = json.load(open(os.path.join(REPO, "MULTIRHS_BENCH.json")))
-    assert rec["methodology"] == bench_svc.METHODOLOGY
-    assert rec["ks"] == list(bench_svc.KS)
-    mr_by_k = {row["K"]: row for row in mr["curve"]}
-    inh = rec["inherited"]
-    assert inh["source"] == "MULTIRHS_BENCH.json"
-    assert inh["per_rhs_gain_k8"] == mr_by_k[8]["per_rhs_speedup_vs_k1"]
-    assert inh["per_rhs_gain_k16"] == mr_by_k[16]["per_rhs_speedup_vs_k1"]
-    for key, (lo, hi, kind) in bench_svc.SERVICE_BANDS.items():
-        band = rec["bands"].get(key)
-        assert band is not None, f"artifact missing band {key}"
-        assert (band["lo"], band["hi"], band["kind"]) == (lo, hi, kind), (
-            key, band,
-        )
-        assert band["measured"] == inh[key]
-        if kind == "device":
-            assert band["in_band"], (key, band)
-    # the acceptance floor, traceable to the MULTIRHS device record
-    assert inh["per_rhs_gain_k8"] >= 1.5
-    assert rec["bands_ok_device"] is True
-    by_k = {row["K"]: row for row in rec["service_rows"]}
-    assert set(by_k) == set(rec["ks"])
-    for row in rec["service_rows"]:
-        for leg in ("service", "solo"):
-            rps = row[f"{leg}_requests_per_s"]
-            assert abs(rps - row["K"] / row[f"{leg}_wall_s"]) <= 1e-3 * rps
-        ratio = row["solo_wall_s"] / row["service_wall_s"]
-        assert abs(row["service_vs_solo"] - ratio) <= 1e-2 * ratio, row
-    # round 12: the metrics-on/off marginal — the drained requests/s
-    # with the observability plane on vs killed must be recorded,
-    # internally consistent, and inside its committed canary band (the
-    # PR 9 acceptance criterion: metrics are measurably ~free)
-    marg = rec["metrics_marginal"]
-    ratio = marg["on_requests_per_s"] / marg["off_requests_per_s"]
-    assert abs(marg["ratio_on_off"] - ratio) <= 1e-2 * ratio, marg
-    for key, (lo, hi, kind) in bench_svc.METRICS_BANDS.items():
-        band = rec["bands"][key]
-        assert (band["lo"], band["hi"], band["kind"]) == (lo, hi, kind)
-        assert band["measured"] == marg["ratio_on_off"]
-        assert band["in_band"] and lo <= band["measured"] <= hi, band
-    # round 16: the tracing-on/off marginal (patx) — same canary
-    # convention; the ledger sentinel picks the band up like every
-    # other (test_perf_ledger_covers_every_bench_artifact below)
-    tx = rec["tracing_marginal"]
-    ratio = tx["on_requests_per_s"] / tx["off_requests_per_s"]
-    assert abs(tx["ratio_on_off"] - ratio) <= 1e-2 * ratio, tx
-    for key, (lo, hi, kind) in bench_svc.TRACING_BANDS.items():
-        band = rec["bands"][key]
-        assert (band["lo"], band["hi"], band["kind"]) == (lo, hi, kind)
-        assert band["measured"] == tx["ratio_on_off"]
-        assert band["in_band"] and lo <= band["measured"] <= hi, band
-    # the locally measured per-RHS table agrees with itself and covers
-    # the sweep (its committed twin is THROUGHPUT_MODEL.json, checked
-    # in test_throughput_model_ties_to_multirhs)
-    per_rhs = {r["K"]: r for r in rec["measured_per_rhs"]}
-    assert set(per_rhs) == set(rec["ks"])
-    for r in rec["measured_per_rhs"]:
-        assert abs(
-            r["per_rhs_s_per_it"] - r["s_per_it"] / r["K"]
-        ) <= 1e-6 * r["per_rhs_s_per_it"], r
-
-
-def test_scale_curve_fused_headline_consistent_with_bench():
-    """SCALE_CURVE's 464^3 fused marginal and SCALE_BENCH's full-solve
-    per-iteration must describe the same kernel: marginal <= full-solve
-    (the full solve carries dispatch overhead) and within ~15%."""
-    curve = json.load(open(os.path.join(REPO, "SCALE_CURVE.json")))
-    rec = json.load(open(os.path.join(REPO, "SCALE_BENCH.json")))
-    row = next(r for r in curve["sizes"] if r["n"] == rec["n"])
-    marginal_ms = row["cg_s_per_it"] * 1e3
-    full_ms = rec["per_iteration_ms"]
-    assert marginal_ms <= full_ms <= 1.15 * marginal_ms, (
-        marginal_ms, full_ms,
-    )
-    # the A/B leg is present wherever the fused default is the headline
-    assert "cg_unfused_s_per_it" in row and "cg_fused_speedup" in row
-
-
-def test_abft_artifact_agrees_with_guard_bands():
-    """The committed ABFT clean-path artifact (round 8) and the bench
-    guard must agree: identical band bounds, the recorded
-    collective-count parity (the zero-extra-collectives claim) actually
-    TRUE with identical per-kind counts, and the overhead rows
-    self-consistent. Device-kind bands gate only records measured on
-    real TPUs — a cpu-platform record is the structural canary (its
-    note must say so), never silently passed off as the acceptance
-    number."""
-    bench_abft = _load_tool("bench_abft")
-    rec = json.load(open(os.path.join(REPO, "ABFT_BENCH.json")))
-    assert rec["methodology"] == bench_abft.METHODOLOGY
-    for key, (lo, hi, kind) in bench_abft.ABFT_BANDS.items():
-        band = rec["bands"].get(key)
-        assert band is not None, f"artifact missing band {key}"
-        assert (band["lo"], band["hi"], band["kind"]) == (lo, hi, kind), (
-            key, band,
-        )
-    par = rec["collective_parity"]
-    assert par["parity"] is True
-    assert par["counts_on"] == par["counts_off"]
-    assert any(par["counts_on"].values()), "parity probe saw no collectives"
-    for row in rec["sizes"]:
-        assert row["dofs"] == row["n"] ** 3
-        ratio = row["abft_on_s_per_it"] / row["abft_off_s_per_it"]
-        assert abs(row["overhead_ratio"] - ratio) <= 1e-3 * ratio, row
-    if rec["platform"] == "tpu":
-        ns = {row["n"] for row in rec["sizes"]}
-        assert set(bench_abft.DEVICE_SIZES) <= ns
-        assert rec["bands_ok_device"] is True
-    else:
-        # the canary must declare itself: platform recorded, device
-        # verdict left open, and the note explains the gating
-        assert rec["bands_ok_device"] is None
-        assert "real TPUs" in rec["note"]
 
 
 def test_env_var_table_agrees_with_source_both_directions():
@@ -441,42 +247,6 @@ def test_env_table_lowering_rows_name_their_key_site():
             )
         else:
             assert "| lowering |" not in rest, name
-
-
-def test_obs_artifact_agrees_with_guard_bands():
-    """The committed telemetry-overhead artifact (round 9) and the
-    bench guard must agree: identical band bounds, the recorded
-    HLO-identity and collective-parity probes actually TRUE (telemetry
-    off is the pre-telemetry program; the trace ring adds zero
-    collectives), and the overhead rows self-consistent. Device-kind
-    bands gate only records measured on real TPUs — a cpu-platform
-    record is the structural canary (its note must say so)."""
-    bench_obs = _load_tool("bench_obs")
-    rec = json.load(open(os.path.join(REPO, "OBS_BENCH.json")))
-    assert rec["methodology"] == bench_obs.METHODOLOGY
-    assert rec["trace_depth"] == bench_obs.TRACE_DEPTH
-    for key, (lo, hi, kind) in bench_obs.OBS_BANDS.items():
-        band = rec["bands"].get(key)
-        assert band is not None, f"artifact missing band {key}"
-        assert (band["lo"], band["hi"], band["kind"]) == (lo, hi, kind), (
-            key, band,
-        )
-    ident = rec["identity"]
-    assert ident["hlo_identity"] is True
-    assert ident["parity"] is True
-    assert ident["counts_on"] == ident["counts_off"]
-    assert any(ident["counts_on"].values()), "probe saw no collectives"
-    for row in rec["sizes"]:
-        assert row["dofs"] == row["n"] ** 3
-        ratio = row["trace_on_s_per_it"] / row["trace_off_s_per_it"]
-        assert abs(row["overhead_ratio"] - ratio) <= 1e-3 * ratio, row
-    if rec["platform"] == "tpu":
-        ns = {row["n"] for row in rec["sizes"]}
-        assert set(bench_obs.DEVICE_SIZES) <= ns
-        assert rec["bands_ok_device"] is True
-    else:
-        assert rec["bands_ok_device"] is None
-        assert "real TPUs" in rec["note"]
 
 
 def test_committed_comms_matrix_fabric_summaries_pin_both_ways():
@@ -539,203 +309,36 @@ def test_memory_footprint_artifact_agrees_with_budgets():
     assert rec.get("platform") and isinstance(rec.get("pa_env"), dict)
 
 
-def test_repro_artifacts_carry_the_shared_envelope():
-    """tools/bench_repro.py writes through the shared schema-versioned
-    artifact writer — the committed ``docs/repro_r*.json`` records must
-    carry the full envelope like every ``*_BENCH.json`` (round-11
-    port of the two straggler bench tools)."""
-    paths = sorted(
-        f for f in os.listdir(os.path.join(REPO, "docs"))
-        if re.fullmatch(r"repro_r\d+\.json", f)
-    )
-    assert paths, "no committed repro records found"
-    for name in paths:
-        rec = json.load(open(os.path.join(REPO, "docs", name)))
-        assert rec.get("schema_version"), name
-        assert rec.get("generated_by") == "bench_repro", name
-        assert rec.get("platform"), name
-        assert isinstance(rec.get("pa_env"), dict), name
-        # the record body the study documents is still intact
-        assert rec["reps"] == len(rec["halo"]) == len(rec["spmv"]), name
-        for k in ("halo", "halo_host_oracle", "spmv"):
-            s = rec[k + "_stats"]
-            assert s["min"] <= s["median"] <= s["max"], (name, k)
-
-
-def test_perf_ledger_covers_every_bench_artifact_and_equals_sources():
-    """The committed PERF_LEDGER.json (round 13 — the perf trajectory
-    as a machine-checked object) must COVER every committed
-    ``*_BENCH.json`` and carry, as each series' latest point, exactly
-    the value its source artifact records — the ledger can never fork
-    from the artifacts it summarizes. It also rides the shared
-    artifact envelope like everything else committed."""
-    from partitionedarrays_jl_tpu.telemetry import (
-        ARTIFACT_SCHEMA_VERSION,
-        ledger,
-    )
-
-    led = json.load(open(os.path.join(REPO, "PERF_LEDGER.json")))
-    assert led["ledger_schema_version"] == ledger.LEDGER_SCHEMA_VERSION
-    assert led.get("schema_version") == ARTIFACT_SCHEMA_VERSION
-    assert led.get("generated_by") == "pareg"
-    assert led.get("platform") and isinstance(led.get("pa_env"), dict)
-    # the tracked set: every *_BENCH.json plus the banded extras the
-    # ledger declares (round 17 added SPECTRUM.json)
-    names = sorted(
-        os.path.basename(p) for p in ledger.artifact_paths(REPO)
-    )
-    assert names, "no committed bench artifacts found"
-    assert any(n.endswith("_BENCH.json") for n in names)
-    assert "SPECTRUM.json" in names
-    assert sorted(led["artifacts"]) == names, (
-        "ledger coverage drifted — run tools/pareg.py --update"
-    )
-    for name in names:
-        rec = json.load(open(os.path.join(REPO, name)))
-        metrics = ledger.extract_metrics(name, rec)
-        assert metrics, f"{name}: no extractable metrics"
-        assert sorted(metrics) == led["artifacts"][name]["metrics"]
-        assert led["artifacts"][name]["source_hash"] == (
-            ledger.content_hash(rec)
-        ), f"{name}: ledger is stale — run tools/pareg.py --update"
-        for key, row in metrics.items():
-            points = led["series"][f"{name}:{key}"]
-            assert points[-1]["value"] == row["value"], (name, key)
-            assert points[-1]["lo"] == row["lo"], (name, key)
-            assert points[-1]["hi"] == row["hi"], (name, key)
-    # the sentinel itself is green on the committed set (the same
-    # invariant tools/pareg.py --check gates in tier-1)
-    assert ledger.check_repo(REPO) == []
-
-
-def test_every_committed_bench_artifact_is_schema_versioned():
-    """Every committed ``*_BENCH.json`` carries the FULL shared artifact
-    envelope (telemetry.artifacts): ``schema_version``, the generating
-    tool, the accelerator ``platform``, and the ``pa_env`` snapshot —
-    everything the writer unconditionally stamps. An artifact written
-    around the shared writer (or hand-stamped with only the two
-    eyeball-able keys) fails here, keeping the schema claim in
-    docs/observability.md enforceable."""
+@pytest.mark.parametrize(
+    "name,tool",
+    [
+        ("MEMORY_FOOTPRINT.json", "palint"),
+        ("COMMS_MATRIX.json", "paprof"),
+        ("SPECTRUM.json", "paspec"),
+        ("PHASE_PROFILE.json", "paprof"),
+        ("ELASTIC_BENCH.json", "paelastic"),
+    ],
+)
+def test_every_committed_record_carries_the_envelope(name, tool):
+    """Every committed record carries the FULL shared envelope
+    (telemetry.artifacts): ``schema_version``, the generating tool, the
+    ``platform`` and the ``pa_env`` snapshot — everything the writer
+    stamps unconditionally. A record written around the shared writer
+    (or hand-stamped with only the two eyeball-able keys) fails here,
+    keeping the schema claim in docs/observability.md enforceable."""
     from partitionedarrays_jl_tpu.telemetry import ARTIFACT_SCHEMA_VERSION
 
-    paths = sorted(
-        f for f in os.listdir(REPO) if f.endswith("_BENCH.json")
+    rec = json.load(open(os.path.join(REPO, name)))
+    assert rec.get("schema_version") == ARTIFACT_SCHEMA_VERSION, (
+        f"{name}: schema_version {rec.get('schema_version')!r}, "
+        f"want {ARTIFACT_SCHEMA_VERSION}"
     )
-    assert paths, "no committed *_BENCH.json artifacts found"
-    for name in paths:
-        rec = json.load(open(os.path.join(REPO, name)))
-        assert rec.get("schema_version") == ARTIFACT_SCHEMA_VERSION, (
-            f"{name} missing/mismatched schema_version "
-            f"(want {ARTIFACT_SCHEMA_VERSION}, "
-            f"got {rec.get('schema_version')!r})"
-        )
-        assert rec.get("generated_by"), (
-            f"{name} must name its generating tool"
-        )
-        assert rec.get("platform"), (
-            f"{name} must record the platform it was measured on"
-        )
-        assert isinstance(rec.get("pa_env"), dict), (
-            f"{name} must carry the PA_* environment snapshot "
-            "(the writer stamps it unconditionally — empty is fine)"
-        )
-
-
-def test_gate_artifact_agrees_with_guard_bands():
-    """The committed front-door artifact (round 14 — ROADMAP item 1's
-    acceptance leg) and the bench guard must agree: identical band
-    bounds, a multi-client leg with N>=2 tenants under a budget that
-    FORCED at least one eviction during load, the per-class attainment
-    read from the pamon registry deltas equal to the client-side
-    outcome table, and the interactive class meeting its target WHILE
-    shedding was active — measured, not asserted. Canary-kind bands
-    gate on every platform."""
-    bench_gate = _load_tool("bench_gate")
-    rec = json.load(open(os.path.join(REPO, "GATE_BENCH.json")))
-    assert rec["methodology"] == bench_gate.METHODOLOGY
-    for key, (lo, hi, kind) in bench_gate.GATE_BANDS.items():
-        band = rec["bands"].get(key)
-        assert band is not None, f"artifact missing band {key}"
-        assert (band["lo"], band["hi"], band["kind"]) == (lo, hi, kind), (
-            key, band,
-        )
-        assert band["in_band"], (key, band)
-    # N>=2 operators under a budget that cannot hold them all resident
-    assert len(rec["tenants"]) >= 2
-    assert rec["budget_bytes"] < sum(
-        t["footprint_bytes"] for t in rec["tenants"]
+    assert rec.get("generated_by") == tool, (name, rec.get("generated_by"))
+    assert rec.get("platform"), f"{name} must record its platform"
+    assert isinstance(rec.get("pa_env"), dict), (
+        f"{name} must carry the PA_* environment snapshot "
+        "(the writer stamps it unconditionally — empty is fine)"
     )
-    multi = rec["multi_client"]
-    assert multi["clients"] >= 2
-    assert multi["evictions_during_load"] >= 1
-    # shedding was ACTIVE, absorbed entirely by the lowest class,
-    # and the interactive target held while it was
-    assert multi["shed_total"] >= 1
-    per = multi["per_class"]
-    assert per["besteffort"]["shed"] == multi["shed_total"]
-    assert per["interactive"]["shed"] == 0
-    target = multi["attainment_target"]
-    assert rec["bands"]["interactive_attainment"]["lo"] == target
-    assert per["interactive"]["attainment"] >= target
-    # attainment is the pamon readout, consistent with the client side
-    for cls, row in per.items():
-        assert row["pamon_requests"] == row["submitted"] - row["shed"], (
-            cls, row,
-        )
-        assert row["pamon_hits"] == row["done"], (cls, row)
-        if row["pamon_requests"]:
-            want = row["pamon_hits"] / row["pamon_requests"]
-            assert abs(row["attainment"] - want) <= 1e-6, (cls, row)
-    # eviction cost is internally consistent
-    ev = rec["eviction_cost"]
-    ratio = ev["cold_solve_s"] / ev["warm_solve_s"]
-    assert abs(ev["ratio"] - ratio) <= 1e-2 * max(ratio, 1.0), ev
-    assert abs(
-        ev["page_in_overhead_s"]
-        - max(0.0, ev["cold_solve_s"] - ev["warm_solve_s"])
-    ) <= 2e-6, ev  # fields round independently of their difference
-    # round 18's saturation leg: an open-loop offered-load curve with
-    # a measured knee — the knee is the LAST level that met the SLO
-    # (all done, interactive attainment >= target, sustained/offered
-    # >= ratio target), and the knee bands are derived from it, not
-    # asserted independently
-    sat = rec["saturation"]
-    assert sat["probe_base_rps"] > 0
-    curve = sat["curve"]
-    assert [lv["capacity_multiple"] for lv in curve] == list(
-        sat["levels_capacity_multiples"]
-    )
-    for lv in curve:
-        assert lv["requests"] == sat["requests_per_level"]
-        assert lv["offered_rps"] > 0 and lv["window_s"] > 0
-        want_sust = lv["sustained_rps"] / lv["offered_rps"]
-        # fields round to 6 decimals independently of their quotient
-        assert abs(lv["sustained_ratio"] - want_sust) <= 1e-4, lv
-        want_ok = (
-            lv["done"] == lv["requests"]
-            and lv["attainment"]["interactive"]
-            >= sat["attainment_target"]
-            and lv["sustained_ratio"] >= sat["sustain_ratio_target"]
-        )
-        assert lv["meets_slo"] == want_ok, lv
-        # pamon saw every completed request of the window
-        assert lv["pamon_count"] == lv["done"], lv
-        assert lv["pamon_p99_s"] >= lv["pamon_p50_s"], lv
-    knee = sat["knee"]
-    assert knee is not None, "the committed curve must exhibit a knee"
-    ok_levels = [lv for lv in curve if lv["meets_slo"]]
-    assert ok_levels and knee == ok_levels[-1]
-    assert rec["bands"]["saturation_knee_rps"]["measured"] == (
-        knee["offered_rps"]
-    )
-    assert rec["bands"]["saturation_attainment_at_knee"]["measured"] == (
-        knee["attainment"]["interactive"]
-    )
-    # the shared artifact envelope
-    assert rec.get("schema_version") and rec.get("generated_by") == (
-        "bench_gate"
-    )
-    assert rec.get("platform") and isinstance(rec.get("pa_env"), dict)
 
 
 def test_spectrum_artifact_agrees_with_analytic_and_bands():
@@ -746,11 +349,8 @@ def test_spectrum_artifact_agrees_with_analytic_and_bands():
     band whose measured ratio is arithmetically consistent with its
     own numbers AND the documented [0.5, 1.05] window (Ritz converges
     from inside — the ratio may never exceed ~1), and >= 3 forecast
-    (operator, tol) pairs with the worst relative error in band. The
-    perf ledger covers it like every bench artifact (the coverage test
-    above picks it up via telemetry.ledger.artifact_paths)."""
+    (operator, tol) pairs with the worst relative error in band."""
     from partitionedarrays_jl_tpu import telemetry
-    from partitionedarrays_jl_tpu.telemetry import ledger
 
     path = os.path.join(REPO, "SPECTRUM.json")
     rec = json.load(open(path))
@@ -798,9 +398,3 @@ def test_spectrum_artifact_agrees_with_analytic_and_bands():
         pairs, key=lambda p: -p["tol"]
     )]
     assert preds == sorted(preds)
-    # the ledger folds it in (extract_metrics sees the bands table)
-    assert path in ledger.artifact_paths(REPO)
-    metrics = ledger.extract_metrics("SPECTRUM.json", rec)
-    assert set(metrics) == {
-        "spectrum_kappa_ratio", "spectrum_forecast_rel_error_max"
-    }

@@ -26,9 +26,6 @@ shed-forward peer picking); this tool runs them:
   trace across the replica hop, and report per-class SLO attainment
   from the survivor.
 
-Saturation benching lives in ``tools/bench_gate.py`` (GATE_BENCH v2's
-open-loop leg); ``pafleet bench`` forwards there.
-
 Usage:
     python tools/pafleet.py --check
     python tools/pafleet.py --drill
@@ -703,10 +700,6 @@ def main(argv=None):
     pr = sub.add_parser("route", help="print a tenant's replica")
     pr.add_argument("--fleet-dir", required=True)
     pr.add_argument("tenant")
-    pb = sub.add_parser(
-        "bench", help="forward to tools/bench_gate.py (GATE_BENCH v2)"
-    )
-    pb.add_argument("rest", nargs=argparse.REMAINDER)
     args = ap.parse_args(argv)
 
     if args.check:
@@ -719,15 +712,6 @@ def main(argv=None):
         return cmd_kill(args)
     if args.cmd == "route":
         return cmd_route(args)
-    if args.cmd == "bench":
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_gate", os.path.join(REPO, "tools", "bench_gate.py")
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.main(args.rest)
     ap.print_help()
     return 2
 

@@ -12,8 +12,8 @@ The operator console of the `telemetry.registry` metrics plane
   `tools/paserve.py --metrics-json`); ``--watch`` re-reads it every
   ``--interval`` seconds and shows histogram deltas since the last
   poll;
-* the committed model — ``--model [PATH]`` renders
-  ``THROUGHPUT_MODEL.json`` (default: the repo's committed artifact),
+* a model file — ``--model PATH`` renders a throughput-model export
+  (``telemetry.throughput_model().export()`` written by your process),
   the online-measured per-RHS curve that feeds adaptive K;
 * a live fleet — ``--fleet FLEET_DIR`` renders one row per gate
   replica (lease state/age, queue depth, residency, and the
@@ -29,7 +29,7 @@ Usage:
     python tools/pamon.py --check                  # tier-1 smoke
     python tools/pamon.py --demo --slo
     python tools/pamon.py --snapshot metrics.json --watch --interval 2
-    python tools/pamon.py --model --json
+    python tools/pamon.py --model model.json --json
     python tools/pamon.py --snapshot metrics.json --prom
     python tools/pamon.py --fleet /tmp/fleet --watch --interval 2
 """
@@ -395,20 +395,6 @@ def render_model(rec):
                 f"per_rhs={e['per_rhs_s_per_it']:.6f} "
                 f"samples={e['samples']}{gain}"
             )
-    ref = rec.get("reference_curve")
-    if ref:
-        lines.append(
-            f"  reference curve ({ref.get('source')}, n={ref.get('n')}, "
-            f"device record):"
-        )
-        for k, v in sorted(
-            ref.get("per_rhs_s_per_it", {}).items(), key=lambda t: int(t[0])
-        ):
-            sp = ref.get("per_rhs_speedup_vs_k1", {}).get(k)
-            lines.append(
-                f"    K={k:<3s} per_rhs={v:.6f}"
-                + (f"  x{sp:.2f} vs K=1" if sp else "")
-            )
     return "\n".join(lines)
 
 
@@ -550,15 +536,8 @@ def _check() -> int:
     expect("pa_service_total_s_count" in prom,
            "prometheus export must expose the total-latency histogram")
     json.loads(reg.to_json())
-    model_path = os.path.join(REPO, "THROUGHPUT_MODEL.json")
-    if os.path.exists(model_path):
-        rec = json.load(open(model_path))
-        print(render_model(rec))
-        expect(
-            rec.get("throughput_schema_version")
-            == telemetry.THROUGHPUT_SCHEMA_VERSION,
-            "committed THROUGHPUT_MODEL.json schema mismatch",
-        )
+    # the model the demo service has just fitted online
+    print(render_model(model.export()))
     for f in failures:
         print(f"pamon --check FAILURE: {f}", file=sys.stderr)
     print("pamon --check:", "FAILED" if failures else "OK")
@@ -573,9 +552,9 @@ def main(argv=None):
                     help="run the demo service, then render")
     ap.add_argument("--snapshot", metavar="FILE",
                     help="render a registry snapshot JSON export")
-    ap.add_argument("--model", nargs="?", const=os.path.join(
-        REPO, "THROUGHPUT_MODEL.json"), metavar="PATH",
-        help="render a THROUGHPUT_MODEL.json (default: committed)")
+    ap.add_argument("--model", metavar="PATH",
+                    help="render a throughput-model export "
+                         "(telemetry.throughput_model().export())")
     ap.add_argument("--prom", action="store_true",
                     help="Prometheus text exposition format")
     ap.add_argument("--json", action="store_true", dest="json_",
