@@ -343,6 +343,27 @@ CATALOG: Dict[str, MetricSpec] = {
               "parallel/tpu.py:_count_box_plan",
               "forward packs taken from the box's own view of the owned "
               "block (one view an exchange): every other sub-box"),
+        # -- the V-cycle's transfers, where a hierarchy is staged -----
+        _spec("gmg.transfer.levels", "counter", "1",
+              "parallel/tpu_gmg.py:_count_transfer",
+              "V-cycle levels whose transfer (restriction and "
+              "prolongation) was staged for the device"),
+        _spec("gmg.transfer.stencil", "counter", "1",
+              "parallel/tpu_gmg.py:_count_transfer",
+              "of those levels, the ones applying S matrix-free in one "
+              "pass of 3^d shifted slices (the full shell arrives)"),
+        _spec("gmg.transfer.separable", "counter", "1",
+              "parallel/tpu_gmg.py:_count_transfer",
+              "of those levels, the ones applying S matrix-free as one "
+              "1-D pass an axis, each behind its face exchange (the "
+              "halo carries faces only)"),
+        _spec("gmg.transfer.operator", "counter", "1",
+              "parallel/tpu_gmg.py:_count_transfer",
+              "of those levels, the ones applying S as an operator "
+              "through device_matrix"),
+        _spec("gmg.transfer.assembled", "counter", "1",
+              "parallel/tpu_gmg.py:_count_transfer",
+              "of those levels, the ones applying the assembled R and P"),
         # -- service lifecycle counters -------------------------------
         _spec("service.admitted", "counter", "1",
               "service/service.py:_admit",

@@ -589,7 +589,8 @@ def test_classed_collapse_declines_variable_coefficients():
 
 
 def _stencil_level_info(h, backend):
-    """(descs_or_False, has_shmask) per level of the staged hierarchy."""
+    """(descs_or_False, has_shmask, form) per level of the staged
+    hierarchy; form is one of `tpu_gmg.TRANSFER_FORMS`."""
     from partitionedarrays_jl_tpu.parallel.tpu_gmg import _device_hierarchy
 
     dh = _device_hierarchy(h, backend)
@@ -597,6 +598,7 @@ def _stencil_level_info(h, backend):
         (
             len(l["stencil"]) if "stencil" in l else False,
             "shmask" in l,
+            l["form"],
         )
         for l in dh["levels"]
     ]
@@ -628,9 +630,11 @@ def test_stencil_transfer_unequal_boxes():
     t = pa.prun(driver, pa.tpu, (2, 2, 2))
     assert (s[0], s[1]) == (t[0], t[1]), (s, t)
     assert max(s[2], t[2]) < 1e-6
-    # the run must actually have exercised the multi-variant switch
+    # the run must actually have exercised the one-pass stencil's
+    # multi-variant switch (the separable level 0 has unequal boxes too)
     assert any(
-        isinstance(d, int) and d > 1 for d, _ in t[3]
+        isinstance(d, int) and d > 1 and f == "stencil"
+        for d, _m, f in t[3]
     ), t[3]
 
 
@@ -659,10 +663,11 @@ def test_stencil_transfer_periodic():
     t = pa.prun(driver, pa.tpu, (2, 2, 2))
     assert (s[0], s[1]) == (t[0], t[1]), (s, t)
     assert max(s[2], t[2]) < 1e-7
-    # level 0 (7-point halo: no corner slabs) must DECLINE; the Galerkin
-    # level must ENGAGE with the wrapped-segment mask staged
-    assert t[3][0][0] is False, t[3]
-    assert any(d and m for d, m in t[3]), t[3]
+    # level 0 (7-point halo: no corner slabs) takes the face-only
+    # separable form with its wrapped faces masked; the Galerkin level
+    # must ENGAGE the one-pass stencil with the wrapped-segment mask staged
+    assert t[3][0][1:] == (True, "separable"), t[3]
+    assert any(d and m and f == "stencil" for d, m, f in t[3]), t[3]
 
 
 def test_aligned_coarse_split_engages_stencil_on_odd_extents():
@@ -689,7 +694,7 @@ def test_aligned_coarse_split_engages_stencil_on_odd_extents():
     info, ncs = pa.prun(driver, pa.tpu, (2, 2, 2))
     # every Galerkin level (full 27-point shell) must take the stencil
     # path — including the odd-extent 29->15 transition
-    assert all(d for d, _ in info[1:]), (info, ncs)
+    assert all(d and f == "stencil" for d, _m, f in info[1:]), (info, ncs)
 
 
 def test_cartesian_partition_dim_firsts():
