@@ -51,12 +51,22 @@ LANES = 128
 VMEM_LIMIT_BYTES = 64 * 2**20
 #: block rows per grid step (vals block = D * BR * 128 * 4B in VMEM,
 #: double-buffered by the pipeline; 1,024 rows -> 3.7 MB per diagonal-7
-#: block). Tuned on a v5e with the window double-buffered (PR 39): one call
-#: at 192^3 and seven diagonals reads 451 / 408 / 387 us at 256 / 512 /
+#: block). Tuned on a v5e with the window double-buffered: one call at
+#: 192^3 and seven diagonals reads 451 / 408 / 387 us at 256 / 512 /
 #: 1,024 rows. A band too wide for the VMEM gate at this size is planned at
-#: MIN_BLOCK_ROWS, where up to 20 diagonals of a 288-row halo fit.
+#: MIN_BLOCK_ROWS, where up to 20 diagonals of a 288-row halo fit; a band
+#: too wide for that too (the 27-point Galerkin operators of a multigrid
+#: hierarchy) takes the 8-aligned block between FLOOR_BLOCK_ROWS and
+#: MIN_BLOCK_ROWS that fits and pads the fewest rows (`plan_dia_pallas`).
 DEF_BLOCK_ROWS = 1024
 MIN_BLOCK_ROWS = 512
+#: the least block a plan takes. At 128 rows a grid step still moves
+#: 64 KiB a diagonal, some 2.5 us of DMA for a 27-point band at the
+#: kernel's 700 GB/s against a step's fixed cost of a few tenths of a
+#: microsecond, and its window fetches x at most 2.2 times for a
+#: 73-row halo (96^3). Below it both costs grow as the block shrinks; a
+#: band that needs a smaller block keeps the XLA form
+FLOOR_BLOCK_ROWS = 128
 #: VMEM slots of the streamed kernel's x window: block i+1's window is
 #: fetched into one while block i's band sum reads the other
 WINDOW_SLOTS = 2
@@ -676,8 +686,15 @@ def plan_dia_pallas(
 ):
     """Static geometry for the kernel: rows after lane tiling, halo rows,
     and the padded owned length. `itemsize` is the operand dtype's byte
-    width (f64 doubles every VMEM figure). Returns None when the band is
-    too wide for a sensible VMEM window (fall back to the XLA path)."""
+    width (f64 doubles every VMEM figure).
+
+    The block is ``block_rows`` (capped at the data's own tiled rows)
+    where its buffers fit the 12 MiB VMEM budget, else MIN_BLOCK_ROWS;
+    where neither fits, the 8-aligned block from FLOOR_BLOCK_ROWS up
+    that fits and streams the fewest padded rows, the larger of two that
+    pad alike (a 27-point band at 96^3 takes 384 rows, at 48^3 288: no
+    padding). Returns None when no block down to the floor holds the
+    band (fall back to the XLA path)."""
     if not offsets:
         return None
     max_off = max(abs(int(o)) for o in offsets)
@@ -695,16 +712,24 @@ def plan_dia_pallas(
             (2 * d + 2) * br + WINDOW_SLOTS * _win_rows(br, halo_rows)
         ) * LANES * itemsize
 
+    def padded_rows(br):
+        return -(-tiled_rows // br) * br
+
     budget = 12 * 2**20
     if vmem_of(block_rows) > budget and block_rows > MIN_BLOCK_ROWS:
         block_rows = MIN_BLOCK_ROWS
-    vmem = vmem_of(block_rows)
-    if vmem > budget:
-        return None
-    n_rows = -(-no_max // (LANES * block_rows)) * block_rows
+    if vmem_of(block_rows) > budget:
+        fits = [
+            br for br in range(FLOOR_BLOCK_ROWS, block_rows, 8)
+            if vmem_of(br) <= budget
+        ]
+        if not fits:
+            return None
+        block_rows = min(fits, key=lambda br: (padded_rows(br), -br))
+    n_rows = padded_rows(block_rows)
     win_rows = _win_rows(block_rows, halo_rows)
     return {
-        "vmem": int(vmem),
+        "vmem": int(vmem_of(block_rows)),
         "n_rows": int(n_rows),
         "halo_rows": int(halo_rows),
         "block_rows": int(block_rows),

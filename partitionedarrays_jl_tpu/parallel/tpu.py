@@ -5928,9 +5928,10 @@ def _count_coded_lowering(plan: dict, pfold: bool, fused: bool) -> None:
 
 def _count_stream_lowering(vals: np.ndarray, plan: Optional[dict]) -> None:
     """The ``lowering.stream.*`` counters of one operator staged as
-    streamed diagonals: how many, the bytes uploaded for them (all parts,
-    the kernel's padding in), whether the Mosaic kernel takes them
-    (``plan`` as `plan_dia_pallas` returned it) or the XLA form; and with a
+    streamed diagonals: the operator, its diagonals, the bytes uploaded
+    for them (all parts, the kernel's padding in), whether the Mosaic
+    kernel takes them (``plan`` as `plan_dia_pallas` returned it) or the
+    XLA form (``.pallas / .operators`` is the share it takes); and with a
     plan the kernel's block, the rows of x it fetches for each block (the
     block and its halo on both sides: ``x_window_rows / block_rows`` is how
     often x is read), its blocks and the VMEM slots of its x window (two:
@@ -5938,6 +5939,7 @@ def _count_stream_lowering(vals: np.ndarray, plan: Optional[dict]) -> None:
     from .. import telemetry
     from ..ops.pallas_dia import WINDOW_SLOTS, _win_rows
 
+    telemetry.bump("lowering.stream.operators", 1)
     telemetry.bump("lowering.stream.diagonals", int(vals.shape[1]))
     telemetry.bump("lowering.stream.value_bytes", int(vals.nbytes))
     telemetry.bump("lowering.stream.pallas", int(plan is not None))

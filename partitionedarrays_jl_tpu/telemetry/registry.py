@@ -230,6 +230,10 @@ CATALOG: Dict[str, MetricSpec] = {
               "parallel/tpu.py:_count_sd_lowering",
               "padded external node slots the products gather"),
         # -- streamed diagonals, where an operator is staged ------------
+        _spec("lowering.stream.operators", "counter", "1",
+              "parallel/tpu.py:_count_stream_lowering",
+              "operators staged as streamed diagonals (dia_mode "
+              "'stream'), 1 each"),
         _spec("lowering.stream.diagonals", "counter", "1",
               "parallel/tpu.py:_count_stream_lowering",
               "stored diagonals of the operators staged as streamed "

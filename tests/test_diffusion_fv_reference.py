@@ -291,7 +291,8 @@ def test_the_control_and_a_scaled_answer_fail_the_check(system):
 
 def test_off_the_chip_the_program_names_the_xla_form(system):
     """No Mosaic kernel on a CPU mesh: the streamed sum is the shifted-slice
-    form under `dia.xla`, and the counters say diagonals and bytes only."""
+    form under `dia.xla`, and the counters say the operator, its diagonals
+    and bytes only."""
     sub = f"{T.SCOPE_SPMV}/{T.SCOPE_DIA_XLA}/"
     assert any(sub in n for n in system["names"])
     for other in (T.SCOPE_DIA_STREAM, T.SCOPE_DIA_EMBED):
@@ -299,6 +300,7 @@ def test_off_the_chip_the_program_names_the_xla_form(system):
     dA, P = system["dA"], system["P"]
     assert dA.pallas_plan is None
     assert system["counters"] == {
+        "lowering.stream.operators": 1,
         "lowering.stream.diagonals": 7,
         "lowering.stream.value_bytes": P * 7 * (N**3 // P) * 4,
         "lowering.stream.pallas": 0,
@@ -335,6 +337,7 @@ def test_the_kernel_path_names_its_parts_and_counts_its_plan(kernel_system):
     assert (plan["block_rows"], plan["n_rows"], plan["halo_rows"]) == (rows, rows, halo)
     window = -(-(rows + 2 * halo + 1) // 8) * 8
     assert kernel_system["counters"] == {
+        "lowering.stream.operators": 1,
         "lowering.stream.diagonals": 7,
         "lowering.stream.value_bytes": P * 7 * rows * LANES * 4,
         "lowering.stream.pallas": 1,

@@ -6,7 +6,9 @@ import numpy as np
 import pytest
 
 from partitionedarrays_jl_tpu.ops.pallas_dia import (
+    FLOOR_BLOCK_ROWS,
     LANES,
+    _win_rows,
     dia_spmv_pallas,
     plan_dia_pallas,
 )
@@ -91,21 +93,137 @@ def test_plan_geometry():
 
 @pytest.mark.parametrize(
     "n_diagonals,block_rows",
-    [(7, 1024), (9, 1024), (10, 512), (20, 512), (21, None)],
+    [(7, 1024), (9, 1024), (10, 512), (20, 512), (21, 432)],
 )
 def test_plan_block_for_the_band_width(n_diagonals, block_rows):
     """At 192^3 (a 288-row halo) the plan takes 1,024-row blocks where the
-    VMEM gate admits them, else 512, else none (the XLA form)."""
+    VMEM gate admits them, else 512; a wider band takes the smaller block
+    that fits and pads the fewest rows (21 diagonals: 432 rows, 128 whole
+    blocks, where 504 would fit but pad)."""
     offsets = tuple(
         int(o) for o in np.linspace(-192 * 192, 192 * 192, n_diagonals)
     )
     plan = plan_dia_pallas(offsets, 192**3)
-    if block_rows is None:
-        assert plan is None
-        return
     assert plan["block_rows"] == block_rows and plan["halo_rows"] == 288
     assert plan["vmem"] <= 12 * 2**20
-    assert plan["n_rows"] == 192**3 // LANES  # 54 or 108 whole blocks
+    assert plan["n_rows"] == 192**3 // LANES  # 54, 108 or 128 whole blocks
+
+
+def _offsets27(n):
+    """The A_oo offsets of a 27-point Galerkin operator on an n^3 box: the
+    levels below the 7-point one of a GMG hierarchy."""
+    return tuple(sorted(
+        k * n * n + j * n + i
+        for k in (-1, 0, 1) for j in (-1, 0, 1) for i in (-1, 0, 1)
+    ))
+
+
+@pytest.mark.parametrize(
+    "n,block_rows,halo_rows", [(96, 384, 73), (48, 288, 19)],
+    ids=["level-1", "level-2"],
+)
+def test_plan_of_the_galerkin_levels(n, block_rows, halo_rows):
+    """Levels 1 and 2 of the hierarchy over a 192^3 box (96^3 and 48^3 a
+    part): neither 1,024 nor 512 rows fit the gate with 27 value blocks,
+    and the block that does pads nothing: 6,912 rows in 18 blocks of 384,
+    864 in 3 of 288 (the largest block that fits, 416, would pad both)."""
+    plan = plan_dia_pallas(_offsets27(n), n**3)
+    assert plan["block_rows"] == block_rows
+    assert plan["halo_rows"] == halo_rows
+    assert plan["vmem"] <= 12 * 2**20
+    assert plan["n_rows"] == n**3 // LANES
+    assert plan["x_rows"] == plan["n_rows"] + _win_rows(block_rows, halo_rows) - block_rows
+
+
+def test_plan_of_the_7_point_operator_at_192_cubed():
+    """The stored-coefficient stencil of `varcoef7_192` keeps its plan:
+    1,024-row blocks, a 1,608-row window (its `lowering.stream.block_rows`
+    and `.x_window_rows`)."""
+    plan = plan_dia_pallas(_poisson7(192), 192**3)
+    assert (plan["block_rows"], plan["halo_rows"]) == (1024, 288)
+    assert _win_rows(plan["block_rows"], plan["halo_rows"]) == 1608
+    assert plan["n_rows"] == 54 * 1024
+
+
+def test_plan_none_where_no_block_at_the_floor_holds():
+    """A 27-point band on an 800^3 box has a 5,008-row halo: its window
+    alone overflows the gate at FLOOR_BLOCK_ROWS, so the XLA form."""
+    assert plan_dia_pallas(_offsets27(800), 800**3) is None
+    assert plan_dia_pallas(_offsets27(740), 740**3)["block_rows"] == FLOOR_BLOCK_ROWS
+
+
+def _plan_before_the_shrink(offsets, no_max, block_rows=1024, itemsize=4):
+    """The rule the plan kept until a band too wide for 512 rows shrank
+    its block: the default block (capped at the data), else 512, else
+    None."""
+    halo = -(-max(abs(int(o)) for o in offsets) // LANES)
+    tiled = -(-no_max // LANES)
+    br = int(min(block_rows, max(8, -(-tiled // 8) * 8)))
+    d = len(offsets)
+
+    def vmem_of(b):
+        return ((2 * d + 2) * b + 2 * _win_rows(b, halo)) * LANES * itemsize
+
+    if vmem_of(br) > 12 * 2**20 and br > 512:
+        br = 512
+    if vmem_of(br) > 12 * 2**20:
+        return None
+    return br
+
+
+@pytest.mark.parametrize("itemsize", [4, 8])
+def test_plan_keeps_every_block_the_old_rule_admitted(itemsize):
+    """Every band the plan admitted before keeps its block; a band it
+    refused gets a block under the one it tried, from the floor up, within
+    the gate, or stays refused. Arithmetic only, over band widths 1 to 30,
+    halos of 0 to 5,008 rows and boxes of 10^3 to 320^3."""
+    admitted = shrunk = refused = 0
+    for d in range(1, 31):
+        for halo in (0, 1, 5, 19, 73, 288, 600, 1500, 5008):
+            offsets = tuple(sorted(set(
+                int(o) for o in np.linspace(-halo * LANES, halo * LANES, d)
+            ))) or (0,)
+            for n in (10, 40, 48, 96, 192, 320):
+                old = _plan_before_the_shrink(offsets, n**3, itemsize=itemsize)
+                plan = plan_dia_pallas(offsets, n**3, itemsize=itemsize)
+                if old is not None:
+                    admitted += 1
+                    assert plan["block_rows"] == old, (d, halo, n)
+                elif plan is None:
+                    refused += 1
+                else:
+                    shrunk += 1
+                    br = plan["block_rows"]
+                    assert FLOOR_BLOCK_ROWS <= br < 512 and br % 8 == 0
+                    assert plan["vmem"] <= 12 * 2**20
+                    assert plan["n_rows"] % br == 0
+    assert admitted and shrunk and refused
+
+
+def test_pallas_at_a_shrunk_block_matches_band_reference():
+    """The kernel at the block the shrunk plan picks for a 27-point band
+    on a 40^3 box (500 tiled rows: 504 do not fit, 168 pad to 504, the
+    fewest), three blocks with the window in both slots, against the
+    reference band sum."""
+    n3, offsets = 40**3, _offsets27(40)
+    plan = plan_dia_pallas(offsets, n3)
+    assert plan["block_rows"] == 168 and plan["n_rows"] == 504
+    R, H, BR = plan["n_rows"], plan["halo_rows"], plan["block_rows"]
+    rng = np.random.default_rng(11)
+    vals = np.zeros((len(offsets), plan["padded_len"]), dtype=np.float32)
+    vals[:, :n3] = rng.standard_normal((len(offsets), n3)).astype(np.float32)
+    for d, off in enumerate(offsets):
+        src = np.arange(n3) + off
+        vals[d, np.arange(n3)[(src < 0) | (src >= n3)]] = 0.0
+    x = rng.standard_normal(n3).astype(np.float32)
+    xp = np.pad(x, (H * LANES, plan["x_rows"] * LANES - H * LANES - n3))
+    y = dia_spmv_pallas(
+        np.ascontiguousarray(vals.reshape(len(offsets), R, LANES)),
+        xp.reshape(-1, LANES), offsets, R, H, BR, interpret=True,
+    )
+    got = np.asarray(y).reshape(-1)[:n3]
+    want = _band_reference(vals[:, :n3], x, offsets, n3)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
 def _poisson7(n):
